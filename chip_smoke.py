@@ -1,0 +1,427 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
+
+    python3 chip_smoke.py              # one TPU chip: device, kernels, train, serve
+    python3 chip_smoke.py --chips 4    # four chips: dp2 x tp2 against one device, only
+
+Drives the main path once through the entry points a user calls, at the
+full width and depth of GPT-2 small (12 layers, d_model 768, 12 heads,
+d_inner 3072, vocabulary 50257, sequence 1024, bf16, batch 8, flash
+attention, fused cross-entropy): ``pt.build`` -> ``pt.Trainer`` ->
+``io.save_inference_model`` -> ``io.load_inference_model`` ->
+``serving.PredictorServer``. Weights and data are random, made from
+``--seed``. One process holds the chip for every phase: the server's
+workers are in-process threads, and nothing here starts a child.
+
+Every phase prints what it saw and raises on the first check that
+fails, so a failed phase ends the script non-zero before the result
+line. Times are wall-clock seconds *as seen in a smoke* — one cold or
+cache-warm run, compiles included where labelled — not a benchmark. The
+last line of standard output is the result:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+It is printed only on a TPU: with no accelerator the device phase
+raises first. The persistent compile cache follows the one rule of
+``paddle_tpu.core.config.compile_cache_dir``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import contextlib
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)  # paddle_tpu from this checkout, never an installed one
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import paddle_tpu as pt
+from paddle_tpu import debugger, io, optimizer as opt, serving
+from paddle_tpu.core.config import enable_compile_cache, set_flag
+from paddle_tpu.models import gpt
+from paddle_tpu.ops.flash_attention import flash_attention
+
+BATCH, SEQ = 8, 1024
+PROMPT, NEW_TOKENS = 128, 32
+BF16_TOL = 2e-2  # largest error over largest reference value, bf16 operands
+
+def gpt2_small() -> gpt.GPTConfig:
+    return gpt.base_config(vocab_size=50257, max_len=SEQ, d_model=768,
+                           d_inner=3072, num_heads=12, num_layers=12,
+                           use_flash=True, fused_ce=True, dropout=0.0,
+                           dtype="bfloat16")
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise RuntimeError(f"chip_smoke: FAILED: {what}")
+
+
+def say(phase: str, msg: str) -> None:
+    print(f"[{phase}] {msg}", flush=True)
+
+
+@contextlib.contextmanager
+def timed(phase: str, what: str):
+    t0 = time.perf_counter()
+    yield
+    say(phase, f"{what}: {time.perf_counter() - t0:.2f} s (as seen in a smoke)")
+
+
+def on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def peak_bytes() -> str:
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    return "not reported by this backend" if peak is None else f"{peak:,}"
+
+
+# ---------------------------------------------------------------------------
+# device
+
+
+def device_phase(chips: int) -> dict:
+    devs = jax.devices()
+    d = devs[0]
+    device = {"platform": d.platform, "kind": d.device_kind, "count": len(devs)}
+    say("device", json.dumps(device))
+    check(d.platform == "tpu",
+          f"jax found no TPU (platform {d.platform!r}); this script passes "
+          f"on a chip only")
+    check(len(devs) >= chips, f"--chips {chips} needs {chips} devices, "
+                              f"jax reports {len(devs)}")
+    # git commits only sources under native/; the helpers rebuild from
+    # the .cc beside them, so a checkout holds none of the binaries
+    built = sorted(n for n in os.listdir(os.path.join(HERE, "paddle_tpu", "native"))
+                   if not n.endswith((".py", ".cc", ".h", "__pycache__")))
+    say("device", "native build products in this tree: "
+        + (", ".join(built) if built else "none")
+        + " (this path builds and loads none)")
+    return device
+
+
+# ---------------------------------------------------------------------------
+# kernels
+
+
+def dense_attention(q, k, v, causal: bool, key_bias=None, segment_ids=None):
+    """Plain f32 softmax(q k^T / sqrt(d) + masks) v — the reference."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
+    if segment_ids is not None:
+        same = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
+        s = jnp.where(same, s, -1e30)
+    if causal:
+        sq, sk = s.shape[-2:]
+        s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq), s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+def kernel_case(name: str, shape, causal: bool, mask: str, seed: int):
+    """Flash forward + backward against the dense f32 composition.
+    ``mask``: "none", "key_bias" (padding) or "segment_ids" (packing)."""
+    b, h, s, d = shape
+    kq, kk, kv, kg, kb = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v, g = (jax.random.normal(key, shape, jnp.bfloat16)
+                  for key in (kq, kk, kv, kg))
+    bias = seg = None
+    if mask == "key_bias":  # the last tenth to half of each row is padding
+        keep = s - jax.random.randint(kb, (b, 1), s // 10, s // 2)
+        bias = jnp.where(jnp.arange(s)[None, :] < keep, 0.0, -1e30)
+    elif mask == "segment_ids":  # documents of uneven length, packed
+        starts = jax.random.bernoulli(kb, 8.0 / s, (b, s))
+        seg = jnp.cumsum(starts, axis=1).astype(jnp.int32)
+
+    def fwd_bwd(attn):
+        def run(q, k, v, g):
+            out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v), q, k, v)
+            return (out,) + vjp(g.astype(out.dtype))
+        return jax.jit(run)
+
+    flash = fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, key_bias=bias, segment_ids=seg))
+    dense = fwd_bwd(lambda q, k, v: dense_attention(
+        q, k, v, causal, key_bias=bias, segment_ids=seg))
+    calls = flash.lower(q, k, v, g).as_text().count("tpu_custom_call")
+    got = flash(q, k, v, g)
+    want = dense(q, k, v, g)
+    errs = {}
+    for label, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        check(np.isfinite(a).all(), f"{name}: {label} is not finite")
+        errs[label] = float(np.abs(a - r).max() / np.abs(r).max())
+    say("kernel", f"{name} {shape} causal={causal} mask={mask}: "
+        f"tpu_custom_call in the lowered fwd+bwd: {calls}; error over "
+        f"largest reference value: "
+        + ", ".join(f"{k} {v:.2e}" for k, v in errs.items()))
+    check(max(errs.values()) <= BF16_TOL,
+          f"{name}: flash differs from the dense f32 reference by "
+          f"{max(errs.values()):.2e} > {BF16_TOL}")
+    if on_tpu():
+        check(calls >= 3, f"{name}: the lowered program holds {calls} "
+                          f"tpu_custom_call, expected fwd + dq + dkv")
+    return errs
+
+
+def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
+                 transformer_shape=(32, 8, 256, 64)) -> None:
+    with timed("kernel", "three cases, compiles included"):
+        kernel_case("gpt", gpt_shape, True, "none", seed)
+        kernel_case("gpt_packed", gpt_shape, True, "segment_ids", seed + 1)
+        kernel_case("transformer_base", transformer_shape, False, "key_bias",
+                    seed + 2)
+
+
+# ---------------------------------------------------------------------------
+# train
+
+
+def lm_batches(vocab: int, batch: int, seq: int, seed: int, n: int = 4,
+               stream: int = 0):
+    """``n`` fixed batches from a noisy cycle over 256 token ids (pad id
+    0 never drawn): nine times in ten the next token is the next of the
+    cycle, so a dozen steps teach the model something that depends on
+    the prompt. ``stream`` draws other sequences over the same cycle."""
+    cycle = np.random.RandomState(seed).permutation(vocab - 3)[:256] + 3
+    rng = np.random.RandomState(seed + 1 + stream)
+    out = []
+    for _ in range(n):
+        hop = np.where(rng.rand(batch, seq + 1) < 0.9, 1,
+                       rng.randint(0, len(cycle), (batch, seq + 1)))
+        ids = cycle[np.cumsum(hop, axis=1) % len(cycle)].astype(np.int32)
+        out.append({"ids": ids[:, :-1], "labels": ids[:, 1:]})
+    return out
+
+
+def make_trainer(cfg, feed, seed: int, mesh=None, rules=None):
+    set_flag("seed", seed)
+    trainer = pt.Trainer(pt.build(gpt.make_model(cfg)),
+                         opt.AdamW(3e-4, weight_decay=0.01),
+                         loss_name="loss", fetch_list=["loss"],
+                         mesh=mesh, sharding_rules=rules)
+    trainer.startup(sample_feed=feed)
+    return trainer
+
+
+def blocked_step(trainer, feed) -> tuple:
+    t0 = time.perf_counter()
+    loss = float(jax.block_until_ready(trainer.step(feed)["loss"]))
+    return loss, time.perf_counter() - t0
+
+
+def train_phase(cfg, batch: int, seq: int, seed: int, steps: int = 12,
+                k: int = 4):
+    """A dozen ``step()`` calls and one ``run_steps(k)``; returns the
+    trainer (its params are what the serve phase exports)."""
+    feeds = lm_batches(cfg.vocab_size, batch, seq, seed)
+    with timed("train", "build + startup (init and placement)"):
+        trainer = make_trainer(cfg, feeds[0], seed)
+    n_params = sum(int(np.prod(v.shape)) for v in trainer.scope.params.values())
+    say("train", f"parameters: {n_params:,}")
+
+    calls = debugger.step_kernel_calls(trainer, feeds[0])
+    say("train", "attention path traced into the train step: "
+        + (f"flash kernel ({calls} tpu_custom_call)" if calls
+           else "no Pallas kernel call (dense, or interpreted off-chip)"))
+    if on_tpu():
+        check(calls >= 3, f"the train step holds {calls} tpu_custom_call: "
+                          f"flash fwd + dq + dkv are not all in it")
+
+    loss0, t_first = blocked_step(trainer, feeds[0])
+    say("train", f"first step, compile included: {t_first:.2f} s "
+                 f"(as seen in a smoke)")
+    losses, times = [loss0], []
+    for i in range(1, steps):
+        loss, dt = blocked_step(trainer, feeds[i % len(feeds)])
+        losses.append(loss)
+        times.append(dt)
+    say("train", f"steps 2..{steps}: median {np.median(times):.4f} s/step, "
+                 f"batch {batch} x seq {seq} (as seen in a smoke)")
+
+    stacked = {name: np.stack([feeds[(steps + j) % len(feeds)][name]
+                               for j in range(k)]) for name in feeds[0]}
+    t0 = time.perf_counter()
+    fused = np.asarray(jax.block_until_ready(
+        trainer.run_steps(stacked, k=k)["loss"]), np.float32)
+    say("train", f"run_steps(k={k}), compile included: "
+                 f"{time.perf_counter() - t0:.2f} s (as seen in a smoke)")
+    losses += [float(x) for x in fused.reshape(-1)]
+    say("train", "losses: " + " ".join(f"{x:.4f}" for x in losses))
+    check(len(losses) == steps + k, f"expected {steps + k} losses, "
+                                    f"got {len(losses)}")
+    check(all(np.isfinite(losses)), "a loss is not finite")
+    check(np.mean(losses[-4:]) < np.mean(losses[:4]) and losses[-1] < losses[0],
+          f"the loss did not fall: first {losses[0]:.4f}, last {losses[-1]:.4f}")
+    check(trainer.global_step == steps + k,
+          f"global_step {trainer.global_step} != {steps + k}")
+    say("train", f"peak bytes in use on device 0: {peak_bytes()}")
+    return trainer
+
+
+# ---------------------------------------------------------------------------
+# serve
+
+
+def serve_phase(cfg, params, batch: int, prompt_len: int, new_tokens: int,
+                seed: int, requests: int = 4) -> None:
+    """Export the generator, load it back, serve it from in-process
+    workers, and hold the served ids to the un-exported program's."""
+    prompts = [f["ids"] for f in lm_batches(cfg.vocab_size, batch, prompt_len,
+                                            seed, n=requests, stream=7)]
+    gen = pt.build(gpt.make_generator(cfg, max_new_tokens=new_tokens))
+    params = dict(params)
+
+    direct = jax.jit(lambda p, ids: gen.apply(p, {}, ids)[0]["ids"])
+    with timed("serve", "un-exported generator, compile + "
+                        f"{requests} batches"):
+        want = [np.asarray(direct(params, ids)) for ids in prompts]
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_model_") as dirname:
+        with timed("serve", "save_inference_model"):
+            io.save_inference_model(dirname, gen, params, {},
+                                    {"prompt_ids": prompts[0]})
+        with timed("serve", "load_inference_model (AOT compile)"):
+            predictor = io.load_inference_model(dirname)
+    with timed("serve", "PredictorServer start (workers + warm-up)"):
+        server = serving.PredictorServer(predictor, workers=2)
+    try:
+        t0 = time.perf_counter()
+        pending = [server.submit({"prompt_ids": ids}) for ids in prompts]
+        got = [np.asarray(p.result(timeout=600)["ids"]) for p in pending]
+        say("serve", f"{requests} generate requests (batch {batch}, prompt "
+            f"{prompt_len}, {new_tokens} new tokens) answered in "
+            f"{time.perf_counter() - t0:.2f} s (as seen in a smoke)")
+        report = server.report()
+    finally:
+        server.close()
+    for i, (g, w) in enumerate(zip(got, want)):
+        check(g.shape == (batch, new_tokens),
+              f"request {i}: ids shape {g.shape} != {(batch, new_tokens)}")
+        check(np.array_equal(g, w),
+              f"request {i}: served ids differ from the un-exported program "
+              f"in {int((g != w).sum())} of {g.size} places")
+    say("serve", f"served ids equal the un-exported program's on all "
+                 f"{requests} requests ({len(np.unique(got))} distinct ids); "
+                 f"first row: {got[0][0, :8].tolist()} ...")
+    counters = {c: report[c] for c in ("submitted", "completed", "errors",
+                                       "timeouts", "hangs",
+                                       "rejected_invalid", "rejected_overload")}
+    say("serve", f"server metrics: {json.dumps(counters)}, "
+        f"compiles_since_warmup {report['compiles_since_warmup']}, "
+        f"latency p50 {report['latency_ms']['p50']} ms (as seen in a smoke)")
+    check(report["completed"] == requests and report["submitted"] == requests,
+          f"server completed {report['completed']} of {requests}")
+    check(report["errors"] == 0 and report["timeouts"] == 0
+          and report["hangs"] == 0, "the server counted an error, timeout or hang")
+    check(report["compiles_since_warmup"] == 0,
+          f"{report['compiles_since_warmup']} compile(s) after warm-up")
+    say("serve", f"peak bytes in use on device 0: {peak_bytes()}")
+
+
+# ---------------------------------------------------------------------------
+# four chips: dp2 x tp2 against one device of the same host
+
+
+def four_chip_phase(cfg, batch: int, seq: int, seed: int, devices,
+                    steps: int = 4) -> None:
+    feeds = lm_batches(cfg.vocab_size, batch, seq, seed)
+
+    def run(mesh, rules):
+        trainer = make_trainer(cfg, feeds[0], seed, mesh=mesh, rules=rules)
+        losses = [blocked_step(trainer, feeds[i % len(feeds)])[0]
+                  for i in range(steps)]
+        return trainer, losses
+
+    with timed("4chip", f"one device, {steps} steps, compile included"):
+        single, one = run(None, None)
+    del single
+    say("4chip", "one-device losses: " + " ".join(f"{x:.4f}" for x in one))
+
+    mesh = pt.make_mesh({"dp": 2, "tp": 2}, devices=list(devices)[:4])
+    rules = pt.parallel.transformer_tp_rules()
+    with timed("4chip", f"dp2 x tp2, {steps} steps, compile included"):
+        trainer, four = run(mesh, rules)
+    say("4chip", "dp2 x tp2 losses:   " + " ".join(f"{x:.4f}" for x in four))
+    check(all(np.isfinite(one + four)), "a loss is not finite")
+    gap = max(abs(a - b) / abs(b) for a, b in zip(four, one))
+    say("4chip", f"largest relative loss gap: {gap:.2e} (limit {BF16_TOL})")
+    check(gap <= BF16_TOL,
+          f"dp2 x tp2 and one-device losses differ by {gap:.2e}")
+
+    tp_ruled = {n: v for n, v in trainer.scope.params.items()
+                if "tp" in jax.tree.leaves(tuple(v.sharding.spec))}
+    spread = [n for n, v in tp_ruled.items()  # devices hold different slices
+              if len({str(s.index) for s in v.addressable_shards}) > 1]
+    say("4chip", f"tp-ruled parameters: {len(tp_ruled)}, sharded across "
+        f"more than one device: {len(spread)} "
+        f"(of {len(trainer.scope.params)} parameters in all)")
+    check(len(spread) > 0, "every parameter sits whole on each device")
+
+    calls = debugger.step_kernel_calls(trainer, feeds[0])
+    say("4chip", f"tpu_custom_call in the sharded train step: {calls}")
+    if on_tpu():
+        check(calls >= 3, "the sharded step does not hold the flash kernels")
+    report = debugger.collective_report(trainer, feeds[0])
+    kinds = {k: v["count"] for k, v in report["collectives"].items()}
+    say("4chip", f"collectives in the compiled step: {json.dumps(kinds)}")
+    check(sum(kinds.values()) > 0, "no collective is in the compiled step")
+    say("4chip", f"peak bytes in use on device 0: {peak_bytes()}")
+
+
+# ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the dp2 x tp2 phase and its one-device twin")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+    t_start = time.perf_counter()
+
+    device = device_phase(args.chips)
+
+    cache_dir = enable_compile_cache()
+    cache_events = collections.Counter()
+    jax.monitoring.register_event_listener(
+        lambda event, **kw: cache_events.update([event]))
+    entries0 = len(os.listdir(cache_dir))
+    say("cache", f"persistent compile cache at {cache_dir}: "
+                 f"{entries0} entries before")
+
+    set_flag("default_compute_dtype", "bfloat16")
+    cfg = gpt2_small()
+    if args.chips == 4:
+        four_chip_phase(cfg, BATCH, SEQ, args.seed, jax.devices())
+    else:
+        kernel_phase(args.seed)
+        trainer = train_phase(cfg, BATCH, SEQ, args.seed)
+        serve_phase(cfg, trainer.scope.params, BATCH, PROMPT, NEW_TOKENS,
+                    args.seed)
+
+    say("cache", f"{len(os.listdir(cache_dir))} entries after; this run: "
+        f"hits {cache_events['/jax/compilation_cache/cache_hits']}, misses "
+        f"{cache_events['/jax/compilation_cache/cache_misses']} (a miss "
+        f"writes a new entry; a size-capped cache may evict old ones)")
+    say("total", f"wall {time.perf_counter() - t_start:.1f} s, imports "
+                 f"excluded (as seen in a smoke)")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,7 +9,6 @@ profiling, quantization, RecordIO data format (C++ core), beam-search
 decoding, and a StableHLO inference/export path.
 """
 
-from . import _jax_compat  # noqa: F401  — must run before any submodule
 from . import analysis, backward, clip, core, data, debugger, evaluator, framework, initializer
 from . import io, layers, lr_scheduler, metrics, models, nets, optimizer
 from . import parallel, quantize, regularizer, resilience, serving, sparse, telemetry, transpiler

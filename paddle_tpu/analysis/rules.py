@@ -157,8 +157,8 @@ def check_quantized_exchange(strategy, mesh, params, report: LintReport,
                              profile=None) -> None:
     """``sharding:unquantized-exchange`` advisory: the run crosses a
     data axis with full-width f32 gradients while the measured profile
-    says the link is the bottleneck — the exact shape BENCH_mid_r05
-    measured (19.9 img/s delivered vs 2174 compute-only at 53 MB/s).
+    says the link is the bottleneck (delivered throughput a small
+    fraction of compute-only).
     Fires only with profile evidence (``profile_report()``'s bottleneck
     naming the link, or an explicit ``link_bound`` flag from bench):
     quantization is a tradeoff, so config alone never triggers it."""

@@ -117,7 +117,41 @@ define_flag("compile_cache_dir", "",
             "Persistent XLA compilation cache directory wired by "
             "Trainer.startup (empty = off). Repeated bench/CI runs skip "
             "recompiling the (fused) train step; hit/miss is logged on "
-            "the first dispatch. Env PDTPU_COMPILE_CACHE_DIR")
+            "the first dispatch. Env PDTPU_COMPILE_CACHE_DIR. Yields to "
+            "JAX_COMPILATION_CACHE_DIR (see compile_cache_dir())")
+
+
+def compile_cache_dir() -> str:
+    """The one rule for where the persistent compile cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (no code
+    then points the cache anywhere else), else the ``compile_cache_dir``
+    flag, else ``<checkout>/.jax_cache``. Always a fixed path — the
+    directory is part of the cache key, so one made from a pid, the
+    time or a temporary name would never hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return get_flag("compile_cache_dir") or os.path.join(checkout, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compile cache on at :func:`compile_cache_dir`
+    and cache every program, however small or quick to compile. Call
+    before the first compile; returns the directory."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    d = compile_cache_dir()
+    os.makedirs(d, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # the cache singleton latches its directory at first use: drop it so
+    # the setting takes effect even mid-process
+    compilation_cache.reset_cache()
+    return d
 define_flag("flash_block_q", 0,
             "flash-attention q-block rows; 0 = kernel default "
             "(ops/flash_attention.DEFAULT_BLOCK_Q). Env "

@@ -222,6 +222,7 @@ def _wire_factor(kind: str, n: int) -> float:
 def _lower_step(trainer, feed):
     """Lower the Trainer's compiled train step for the current scope +
     feed shapes (shared preamble of the compiled-introspection family)."""
+    from .core.config import make_prng_key
     from .core.errors import enforce
 
     enforce(trainer._step_fn is not None,
@@ -231,13 +232,24 @@ def _lower_step(trainer, feed):
     # profile_report publishes
     feed = trainer._put_feed(feed, record=False)
     ls = getattr(trainer.scope, "loss_scale_state", None) or {}
+    # the key type Trainer.step passes: the lowered program is then the
+    # one that runs, and the persistent compile cache serves its compile
     args = (trainer.scope.params, trainer.scope.opt_state,
-            trainer.scope.state, jax.random.PRNGKey(0), feed, ls)
+            trainer.scope.state, make_prng_key(0), feed, ls)
     if getattr(trainer, "_quant_ef", False):
         # error-feedback residual: the quantized-exchange step carries
         # one extra trailing arg (executor._build_step)
         args = args + (trainer.scope.quant_resid,)
     return trainer._step_fn.lower(*args)
+
+
+def step_kernel_calls(trainer, feed) -> int:
+    """How many Pallas TPU kernel calls (``tpu_custom_call``) the train
+    step holds as traced for the current scope + feed shapes. 0 means
+    no kernel is in the step: attention traced a dense path, or the
+    kernels were interpreted (off-chip). Reads the lowered text, so it
+    costs a trace and no compile."""
+    return _lower_step(trainer, feed).as_text().count("tpu_custom_call")
 
 
 def collective_report(trainer, feed) -> Dict[str, Any]:

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .core import profiler
-from .core.config import get_flag, make_prng_key
+from .core.config import enable_compile_cache, get_flag, make_prng_key
 from .core.errors import enforce
 from .core.place import Place, default_place
 from .framework import Program
@@ -595,7 +595,7 @@ class Trainer:
                           f"the context — {hint}")
 
     def _loss_and_aux(self, params, state, rng, feed):
-        from .framework import pipeline_mode, remat_mode, sp_mode
+        from .framework import mesh_mode, pipeline_mode, remat_mode, sp_mode
 
         # strategy.remat (memory_optimize analog) flips the ambient
         # trace-time switch; zoo models wrap their repeated blocks in
@@ -614,7 +614,7 @@ class Trainer:
                             impl=getattr(self.strategy, "sp_impl", "ring")))
         with remat_mode(bool(getattr(self.strategy, "remat", False)),
                         policy=getattr(self.strategy, "remat_policy", None)), \
-                pp_ctx as pp_cfg, sp_ctx as sp_cfg:
+                mesh_mode(self.mesh), pp_ctx as pp_cfg, sp_ctx as sp_cfg:
             out, new_state = self.program.apply(params, state, training=True,
                                                 rng=rng, **feed)
         self._warn_unconsumed(
@@ -1220,7 +1220,7 @@ class Trainer:
             # microbatch-divisibility requirement). Plain-pp trainers
             # keep the old scan-path eval: logical row order is intact
             # and any batch size works.
-            from .framework import pipeline_mode
+            from .framework import mesh_mode, pipeline_mode
             pp_m, pp_v = self._pp_settings()
             if getattr(self, "_pp_perm", None):
                 b = jax.tree.leaves(feed)[0].shape[0]
@@ -1236,7 +1236,7 @@ class Trainer:
                                  param_layout="interleaved")
                    if getattr(self, "_pp_perm", None)
                    else contextlib.nullcontext())
-            with ctx:
+            with mesh_mode(self.mesh), ctx:
                 out, _ = self.program.apply(params, state, training=False,
                                             **feed)
             return out
@@ -1253,23 +1253,14 @@ class Trainer:
         first dispatch (``paddle_tpu.trainer`` logger)."""
         import os
 
-        d = get_flag("compile_cache_dir")
-        self._cache_dir = d or None
+        self._cache_dir = None
         self._cache_logged = False
-        if not d:
+        if not get_flag("compile_cache_dir"):
             return
-        os.makedirs(d, exist_ok=True)
         _install_cpu_cache_read_gate()
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        try:
-            # the cache singleton latches the dir at first use: drop it
-            # so the flag takes effect even mid-process
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
+        # the flag yields to JAX_COMPILATION_CACHE_DIR: one rule, in
+        # core/config.compile_cache_dir
+        self._cache_dir = d = enable_compile_cache()
         self._cache_entries0 = len(os.listdir(d))
         _trainer_log().info(
             "persistent compilation cache at %s (%d entries)", d,

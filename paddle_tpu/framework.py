@@ -682,6 +682,30 @@ def pipeline_config() -> Optional[dict]:
     return cfg
 
 
+_mesh_mode = threading.local()
+
+
+@contextlib.contextmanager
+def mesh_mode(mesh):
+    """Ambient device mesh of the step being traced (trace-time, like
+    :func:`pipeline_mode`). Trainer enters this around ``program.apply``
+    when it has a mesh: GSPMD partitions every XLA op from the operand
+    shardings, but it cannot partition a Mosaic kernel, so attention
+    reads the mesh here and runs the flash kernel per shard under
+    ``shard_map`` (layers/attention.flash_sdpa)."""
+    old = getattr(_mesh_mode, "mesh", None)
+    _mesh_mode.mesh = mesh
+    try:
+        yield mesh
+    finally:
+        _mesh_mode.mesh = old
+
+
+def active_mesh():
+    """The mesh of :func:`mesh_mode`, or None."""
+    return getattr(_mesh_mode, "mesh", None)
+
+
 _sp_mode = threading.local()
 
 

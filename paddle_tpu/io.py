@@ -932,8 +932,6 @@ def save_inference_model(dirname: str, program, params: Dict[str, jax.Array],
     batch size is always a bucket."""
     import shutil
 
-    import jax.export  # noqa: F401  (jax 0.4.x: submodule needs explicit import)
-
     from . import resilience
 
     feed_names = sorted(example_feed)
@@ -1080,8 +1078,6 @@ def save_train_artifact(dirname: str, trainer, example_feed: Dict[str, Any]) -> 
     traced step: threefry, so the artifact is backend-portable); the
     C++ driver feeds the step index.
     """
-    import jax.export  # noqa: F401  (jax 0.4.x: submodule needs explicit import)
-
     program, optimizer = trainer.program, trainer.optimizer
     enforce(trainer.scope.params is not None, "save_train_artifact: call "
             "trainer.startup() first")
@@ -1357,8 +1353,6 @@ def load_inference_model(dirname: str) -> Predictor:
     :class:`~paddle_tpu.resilience.CheckpointCorrupt` instead of a
     random decoder error three frames deep. Pre-manifest (legacy)
     directories load without validation."""
-    import jax.export  # noqa: F401  (jax 0.4.x: submodule needs explicit import)
-
     from . import resilience
 
     resilience.validate_checkpoint(dirname)  # None for legacy dirs

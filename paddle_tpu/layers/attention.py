@@ -14,18 +14,76 @@ from __future__ import annotations
 
 
 import math
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ..framework import LayerHelper, cast_compute, in_training
+from ..framework import LayerHelper, active_mesh, cast_compute, in_training
 from .. import initializer as init
 from .nn import dropout as _dropout
 
 from ..ops.attention_scores import scores_mxu as _scores_mxu
 
 NEG_INF = -1e9  # matches the additive-mask convention (finite to stay bf16-safe)
+
+
+def flash_applies(use_flash: bool, dropout_rate: float) -> bool:
+    """Does this attention call trace the flash kernel? The kernel has
+    no dropout, but dropout is a no-op outside training — eval/serving
+    traces of a dropout>0 model keep the kernel. A training trace with
+    dropout takes the dense O(s^2) path, and says so (once per call
+    site, at trace time): a cell meant to measure the kernel must not
+    measure the dense path unawares."""
+    if not use_flash:
+        return False
+    if dropout_rate == 0.0 or not in_training():
+        return True
+    warnings.warn(
+        f"use_flash with dropout {dropout_rate} in training: the flash "
+        f"kernel has no dropout, so attention traces the dense O(s^2) "
+        f"path", stacklevel=3)
+    return False
+
+
+def flash_sdpa(q, k, v, causal: bool, key_bias=None):
+    """The flash kernel over [b, h, s, d] with an optional additive
+    [b, s_k] key bias. Under a Trainer's multi-device mesh the kernel
+    runs per shard inside ``shard_map`` — batch over the data axes,
+    heads over ``tp`` — because GSPMD cannot partition a Mosaic kernel
+    (its lowering refuses any jit over more than one device). A dim the
+    axis size does not divide stays whole on every shard, as GSPMD
+    itself would leave it. Inside an enclosing ``shard_map`` (pipeline
+    stages, the shard-local gradient paths) the call is already
+    per-shard and goes straight to the kernel."""
+    from ..ops.flash_attention import flash_attention
+    from ..parallel.mesh import DATA_AXES, TP
+
+    mesh = active_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return flash_attention(q, k, v, causal=causal, key_bias=key_bias)
+
+    def axes_dividing(n, names):
+        names = tuple(a for a in names
+                      if a in mesh.axis_names and mesh.shape[a] > 1)
+        size = math.prod(mesh.shape[a] for a in names)
+        return names if names and n % size == 0 else None
+
+    batch = axes_dividing(q.shape[0], DATA_AXES)
+    heads = axes_dividing(q.shape[1], (TP,))
+    qkv = P(batch, heads, None, None)
+    args, specs = [q, k, v], [qkv, qkv, qkv]
+    if key_bias is not None:
+        args.append(jnp.broadcast_to(key_bias, (q.shape[0], k.shape[2])))
+        specs.append(P(batch, None))
+    fn = jax.shard_map(
+        lambda q_, k_, v_, *bias: flash_attention(
+            q_, k_, v_, causal=causal, key_bias=bias[0] if bias else None),
+        mesh=mesh, in_specs=tuple(specs), out_specs=qkv, check_vma=False)
+    return fn(*args)
 
 
 def scaled_dot_product_attention(
@@ -44,10 +102,13 @@ def scaled_dot_product_attention(
     """
     if use_flash is None:
         use_flash = False
-    # the flash kernel has no dropout, but dropout is a no-op outside
-    # training — eval/serving traces of a dropout>0 model keep the
-    # kernel instead of paying the dense O(s^2) path
-    if use_flash and (dropout_rate == 0.0 or not in_training()):
+    if flash_applies(use_flash, dropout_rate):
+        if attn_mask is None:
+            return flash_sdpa(q, k, v, causal)
+        if attn_mask.ndim == 4 and attn_mask.shape[1:3] == (1, 1):
+            return flash_sdpa(q, k, v, causal, key_bias=attn_mask[:, 0, 0, :])
+        # a dense mask is not the kernel's: flash_attention traces the
+        # XLA composition (and warns), which GSPMD partitions itself
         from ..ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, attn_mask=attn_mask)
 

@@ -43,6 +43,7 @@ from ..core.errors import enforce
 from ..framework import (LayerHelper, cast_compute, in_training as _in_training,
                          maybe_remat, pipeline_config, rng_fold, sp_config)
 from .. import initializer as init
+from .attention import flash_applies, flash_sdpa
 
 NEG_INF = -1e9
 
@@ -110,13 +111,8 @@ def _sdpa(q, k, v, key_bias, causal: bool, use_flash: bool, sp_cfg=None,
                               schedule="zigzag" if (causal and layout == "zigzag")
                               else "auto",
                               layout=layout)
-    if use_flash and (dropout_rate == 0.0 or not _in_training()):
-        # same gate as layers/attention.py: the flash kernel has no
-        # dropout; rate > 0 falls to the dense path with softmax dropout
-        # during training, while eval/serving traces (dropout no-op)
-        # keep the kernel
-        from ..ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, key_bias=key_bias)
+    if flash_applies(use_flash, dropout_rate):
+        return flash_sdpa(q, k, v, causal, key_bias=key_bias)
     from ..ops.attention_scores import scores_mxu
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = scores_mxu(q, k, scale)

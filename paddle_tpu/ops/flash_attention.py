@@ -8,8 +8,7 @@ O(seq) -memory attention on TPU:
 
 - K/V are streamed through VMEM on the innermost grid dimension
   (Pallas double-buffers the HBM→VMEM DMA automatically), so sequence
-  length is bounded by HBM, not by the ~16MB VMEM — the v1 kernel's
-  whole-K/V-in-VMEM BlockSpec was the line VERDICT r1 told us to kill.
+  length is bounded by HBM, not by the ~16MB VMEM.
 - Online softmax state (m, l, acc) lives in VMEM scratch that persists
   across the innermost grid steps; output is finalized on the last step.
 - Backward is two pallas kernels of the same shape: a dq pass
@@ -19,6 +18,11 @@ O(seq) -memory attention on TPU:
 - Masking: causal, an additive per-key bias [b, s_k] (padding), and
   segment ids (the LoD ragged-batch equivalent, layers/sequence.py
   design) — all fused into the kernels.
+- Per-row vectors (bias, segment ids, lse, delta) cross the kernel
+  boundary as (bh, 1, s) arrays in (1, 1, block) blocks: the form the
+  Mosaic tiling rule accepts (a (1, block) block of a (bh, s) array
+  breaks its sublane rule) at 8x sublane padding in HBM, against 128x
+  for a lane-replicated (bh, s, 128) layout.
 
 Ring/context-parallel attention (parallel/ring_attention.py) reuses
 these kernels per shard and merges (out, lse) pairs in log-space.
@@ -28,6 +32,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 from typing import Optional
 
 import jax
@@ -35,17 +40,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# chip-tuned at seq 32k, h=8, d=64 bf16: (1024, 1024) gives 33 TFLOP/s fwd /
-# 42 TFLOP/s bwd vs 19/29 at (512, 512); 2048 blocks exceed the 16MB VMEM
+# Chosen because it compiles, not tuned (ROADMAP S6): the v5e compiler
+# (jax 0.9.0 / libtpu 0.0.34) refuses the causal forward at (1024, 1024)
+# once seq >= 2048 — 17.29-17.79 MB of scoped VMEM against the 16 MB
+# limit, the causal mask's f32 intermediates — while (1024, 512),
+# (512, 1024) and (512, 512) compile forward and backward at every
+# shape in tests/test_tpu_compile.py, also with bias + segment ids at
+# d=256. The rates once quoted here (33/42 TFLOP/s at 1024x1024) were
+# measured on an earlier kernel body and another JAX; no rate has been
+# measured for this one.
 DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
+DEFAULT_BLOCK_K = 512
 
 
 def resolve_block_shapes(block_q, block_k):
     """Resolve block sizes: explicit args win; None falls to the
     ``flash_block_q``/``flash_block_k`` config flags (env
     ``PDTPU_FLASH_BLOCK_Q``/``_K`` — a microbench sweep winner applies
-    without a code edit), flag 0 to the chip-tuned module defaults.
+    without a code edit), flag 0 to the module defaults.
     Validated here so a typo'd env value fails naming the flag instead
     of as a Mosaic tiling error deep in kernel lowering. NOTE: like all
     shape-affecting knobs this is read at TRACE time — set the flag
@@ -63,6 +75,14 @@ def resolve_block_shapes(block_q, block_k):
                 f"{name}: block size must be a positive multiple of 8 "
                 f"(TPU sublane tiling), got {val!r}")
     return block_q, block_k
+
+
+def default_interpret() -> bool:
+    """Interpret the kernels only where there is no TPU to compile them
+    for (the CPU tests); on a TPU they always lower through Mosaic."""
+    return jax.devices()[0].platform == "cpu"
+
+
 NEG_INF = -1e30
 LANES = 128  # lane width for 1-d-per-row scratch (m/l/lse/delta)
 
@@ -108,7 +128,7 @@ def _block_scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, qi, kj, *,
     if bias_ref is not None:
         s = s + bias_ref[0, 0, :][None, :]
     if segq_ref is not None:
-        s = _segment_mask(s, segq_ref[0], segk_ref[0])
+        s = _segment_mask(s, segq_ref[0, 0, :], segk_ref[0, 0, :])
     if causal:
         fully_visible = (kj + 1) * block_k - 1 <= qi * block_q + causal_offset
         s = jax.lax.cond(
@@ -287,11 +307,12 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                                      lambda i, j, kk: (i, 0, ck(kk, j))))
         args.append(bias_r.astype(jnp.float32))
     if have_seg:
-        segq_r = jnp.broadcast_to(seg_q[:, None, :], (b, h, sq)).reshape(bh, sq)
-        segk_r = jnp.broadcast_to(seg_k[:, None, :], (b, h, sk)).reshape(bh, sk)
-        in_specs.append(pl.BlockSpec((1, block_q), lambda i, j, kk: (i, j)))
-        in_specs.append(pl.BlockSpec((1, block_k),
-                                     lambda i, j, kk: (i, ck(kk, j))))
+        segq_r = jnp.broadcast_to(seg_q[:, None, :], (b, h, sq)).reshape(bh, 1, sq)
+        segk_r = jnp.broadcast_to(seg_k[:, None, :], (b, h, sk)).reshape(bh, 1, sk)
+        in_specs.append(pl.BlockSpec((1, 1, block_q),
+                                     lambda i, j, kk: (i, 0, j)))
+        in_specs.append(pl.BlockSpec((1, 1, block_k),
+                                     lambda i, j, kk: (i, 0, ck(kk, j))))
         args += [segq_r.astype(jnp.int32), segk_r.astype(jnp.int32)]
 
     def kernel(*refs):
@@ -313,10 +334,7 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            # lse as (bh, 1, sq): the (1, 1, block_q) block satisfies the
-            # Mosaic tiling rules with only 8x sublane padding in HBM
-            # (a (1, block_q) 2-d block would violate the sublane rule,
-            # and a lane-replicated (bh, sq, 128) layout costs 128x HBM)
+            # lse as (bh, 1, sq) — see the module docstring
             pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, j)),
         ],
         out_shape=[
@@ -361,8 +379,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             causal_offset=causal_offset)
         vb = v_ref[0]
         g = g_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0, :]
+        delta = delta_ref[0, 0, :]
         p = _maybe_zero_masked(jnp.exp(s - lse[:, None]), s, masked)
         dp = jax.lax.dot_general(g, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -399,8 +417,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             causal_offset=causal_offset)
         vb = v_ref[0]
         g = g_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0, :]
+        delta = delta_ref[0, 0, :]
         p = _maybe_zero_masked(jnp.exp(s - lse[:, None]), s, masked)  # [bq, bk]
         # dv += p^T g
         dv_scr[...] += jax.lax.dot_general(
@@ -447,8 +465,8 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
     k_r = k.reshape(bh, sk, d)
     v_r = v.reshape(bh, sk, d)
     g_r = g.reshape(bh, sq, d)
-    lse_r = lse.reshape(bh, sq)
-    delta_r = delta.reshape(bh, sq)
+    lse_r = lse.reshape(bh, 1, sq)
+    delta_r = delta.reshape(bh, 1, sq)
 
     have_bias = bias is not None
     have_seg = seg_q is not None
@@ -458,9 +476,9 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
             .reshape(bh, 1, sk).astype(jnp.float32)
     if have_seg:
         segq_r = jnp.broadcast_to(seg_q[:, None, :], (b, h, sq)) \
-            .reshape(bh, sq).astype(jnp.int32)
+            .reshape(bh, 1, sq).astype(jnp.int32)
         segk_r = jnp.broadcast_to(seg_k[:, None, :], (b, h, sk)) \
-            .reshape(bh, sk).astype(jnp.int32)
+            .reshape(bh, 1, sk).astype(jnp.int32)
 
     # ---- dq pass: grid (bh, nq, nk), K/V streamed on the inner dim;
     # causal iterations past the diagonal re-request the same block so
@@ -477,14 +495,15 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
                                      lambda i, j, kk: (i, 0, ck(kk, j))))
         dq_args.append(bias_r)
     if have_seg:
-        dq_specs.append(pl.BlockSpec((1, block_q), lambda i, j, kk: (i, j)))
-        dq_specs.append(pl.BlockSpec((1, block_k),
-                                     lambda i, j, kk: (i, ck(kk, j))))
+        dq_specs.append(pl.BlockSpec((1, 1, block_q),
+                                     lambda i, j, kk: (i, 0, j)))
+        dq_specs.append(pl.BlockSpec((1, 1, block_k),
+                                     lambda i, j, kk: (i, 0, ck(kk, j))))
         dq_args += [segq_r, segk_r]
     dq_specs += [
         pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, block_q), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((1, block_q), lambda i, j, kk: (i, j)),
+        pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, j)),
+        pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, j)),
     ]
     dq_args += [g_r, lse_r, delta_r]
 
@@ -524,14 +543,15 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
         dkv_specs.append(pl.BlockSpec((1, 1, block_k), lambda i, j, kk: (i, 0, j)))
         dkv_args.append(bias_r)
     if have_seg:
-        dkv_specs.append(pl.BlockSpec((1, block_q),
-                                      lambda i, j, kk: (i, cq(kk, j))))
-        dkv_specs.append(pl.BlockSpec((1, block_k), lambda i, j, kk: (i, j)))
+        dkv_specs.append(pl.BlockSpec((1, 1, block_q),
+                                      lambda i, j, kk: (i, 0, cq(kk, j))))
+        dkv_specs.append(pl.BlockSpec((1, 1, block_k),
+                                      lambda i, j, kk: (i, 0, j)))
         dkv_args += [segq_r, segk_r]
     dkv_specs += [
         pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, cq(kk, j), 0)),
-        pl.BlockSpec((1, block_q), lambda i, j, kk: (i, cq(kk, j))),
-        pl.BlockSpec((1, block_q), lambda i, j, kk: (i, cq(kk, j))),
+        pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, cq(kk, j))),
+        pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, cq(kk, j))),
     ]
     dkv_args += [g_r, lse_r, delta_r]
 
@@ -622,7 +642,7 @@ def flash_attention(
     - ``attn_mask``: a [b,1,1,s_k] additive mask is converted to a key
       bias; any other dense mask falls back to the XLA composition.
     - ``block_q``/``block_k``: None resolves the ``flash_block_q``/``_k``
-      config flags then the chip-tuned defaults — see
+      config flags then the module defaults — see
       :func:`resolve_block_shapes` (read at trace time).
     - ``return_lse``: also return the per-query logsumexp [b, h, s_q]
       (forward only — used by ring attention to merge shards).
@@ -631,7 +651,7 @@ def flash_attention(
 
     block_q, block_k = resolve_block_shapes(block_q, block_k)
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = default_interpret()
     enforce(kv_segment_ids is None or segment_ids is not None,
             "flash_attention: kv_segment_ids requires segment_ids (the "
             "query-side ids) as well")
@@ -642,6 +662,11 @@ def flash_attention(
         else:
             # general dense mask: XLA path, with bias/segment masking
             # folded in so nothing is silently dropped
+            warnings.warn(
+                f"flash_attention: a dense attn_mask of shape "
+                f"{tuple(attn_mask.shape)} cannot ride the kernel (only "
+                f"[b,1,1,s_k] can, as a key bias); this call traces the "
+                f"dense O(s^2) XLA composition", stacklevel=2)
             mask = attn_mask
             if key_bias is not None:
                 mask = mask + key_bias[:, None, None, :]

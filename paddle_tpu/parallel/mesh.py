@@ -61,11 +61,8 @@ def make_mesh(axes: Optional[Dict[str, int]] = None,
 
 def pvary(x, axis_names):
     """Mark ``x`` as device-varying over ``axis_names`` inside shard_map
-    (vma bookkeeping for mixing replicated operands with sharded ones).
-    Wraps lax.pcast with fallback to the deprecated lax.pvary."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to="varying")
-    return jax.lax.pvary(x, axis_names)
+    (vma bookkeeping for mixing replicated operands with sharded ones)."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def data_axis_names(mesh: Mesh) -> tuple:

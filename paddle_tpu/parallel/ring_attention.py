@@ -228,7 +228,7 @@ def _ring_bwd_body(q, k0, v0, out, lse, g, *, axis_name, varying_axes, schedule)
     n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
-    interpret = jax.devices()[0].platform == "cpu"
+    interpret = fa.default_interpret()
     # delta is k/v-shard-invariant: compute once, not per ring step
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     branches = schedule.bwd_branches(q, out, lse, g, delta, interpret)

@@ -412,7 +412,9 @@ def test_fused_dispatch_reduces_per_step_wall_time():
 # ---------------------------------------------------------------------------
 
 
-def test_compile_cache_flag_wires_and_logs(tmp_path, caplog):
+def test_compile_cache_flag_wires_and_logs(tmp_path, caplog, monkeypatch):
+    # the flag yields to this variable (core/config.compile_cache_dir)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min_t = jax.config.jax_persistent_cache_min_compile_time_secs
     prev_min_b = jax.config.jax_persistent_cache_min_entry_size_bytes

@@ -78,8 +78,7 @@ def test_load_trace_reads_newest_gz(tmp_path):
 @pytest.mark.slow
 def test_bench_profile_emits_readable_trace(tmp_path):
     """End-to-end: bench.py --profile materializes a *.trace.json.gz
-    that trace_summary can parse — the exact flow link_watch pass 3
-    runs on chip."""
+    that trace_summary can parse."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(ROOT, "bench.py"), "--model",

@@ -1,0 +1,101 @@
+"""The flash kernels, compiled for a described TPU v5e with no chip
+attached (the `on-chip-measurement` guide, section 2, third rehearsal).
+
+Interpret mode — what every other flash test runs on the CPU — checks
+none of what the chip's compiler refuses: block shapes that break the
+(8, 128) tiling rule, kernels that need more than the 16 MB of scoped
+VMEM. These cases are the shapes the zoo trains and the long-context
+shapes, forward and backward. A compile that passes is not a chip run;
+``chip_smoke.py`` is.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described v5e:2x2, with the persistent compile
+    cache off: an entry written by such a compile cannot be read back
+    without a chip, and the next run would warn about it."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 here: {type(e).__name__}: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _attention(causal, mask=None):
+    def fn(q, k, v, *rest):
+        kw = {mask: rest[0]} if mask else {}
+        return fa.flash_attention(q, k, v, causal=causal, interpret=False, **kw)
+    return fn
+
+
+def _ring_backward(q, k, v, out, lse, g, delta):
+    # the call parallel/ring_attention.py makes on every ring step: the
+    # shard-invariant delta computed once outside and passed in (here
+    # the zigzag schedule's half-shard of queries against a whole shard)
+    bq, bk = fa.resolve_block_shapes(None, None)
+    return fa._flash_bwd(q, k, v, None, None, None, False, out, lse, g,
+                         bq, bk, interpret=False, delta=delta)
+
+
+# name -> (fn, (b, h, s, d), per-row mask operand or None)
+SHAPES = {
+    "gpt2_small": (_attention(True), (8, 12, 1024, 64), None),
+    "transformer_base_key_bias": (_attention(False, "key_bias"),
+                                  (32, 8, 256, 64), jnp.float32),
+    "gpt2_small_segment_ids": (_attention(True, "segment_ids"),
+                               (8, 12, 1024, 64), jnp.int32),
+    "long_4k_d128": (_attention(True), (2, 8, 4096, 128), None),
+    "long_32k": (_attention(True), (1, 8, 32768, 64), None),
+}
+# Every shape's gradient (which compiles its forward kernel too), and
+# the forward alone once: the tier is close to its time limit.
+CASES = [("gpt2_small", False)] + [(name, True) for name in SHAPES]
+
+
+@pytest.mark.parametrize("name,grad", CASES,
+                         ids=[f"{n}-{'grad' if g else 'fwd'}" for n, g in CASES])
+def test_flash_compiles_for_v5e(chip, name, grad):
+    fn, shape, mask_dtype = SHAPES[name]
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    args = [qkv, qkv, qkv]
+    if mask_dtype is not None:
+        args.append(jax.ShapeDtypeStruct((shape[0], shape[2]), mask_dtype,
+                                         sharding=chip))
+    if grad:
+        fwd = fn
+        fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # forward alone is one kernel; its gradient adds the dq and dkv passes
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+def test_ring_backward_with_delta_compiles_for_v5e(chip):
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    q = sds((1, 8, 4096, 64))
+    kv = sds((1, 8, 8192, 64))
+    row = sds((1, 8, 4096), jnp.float32)
+    text = jax.jit(_ring_backward).lower(q, kv, kv, q, row, q,
+                                         row).compile().as_text()
+    assert text.count("tpu_custom_call") == 2

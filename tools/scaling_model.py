@@ -60,7 +60,8 @@ ICI_BW = 45e9               # B/s per-direction ring bandwidth per chip
 DCN_BW = 6.25e9             # B/s per host (50 Gbps)
 CHIPS_PER_HOST = 8
 # measured compute-only MFU where an on-chip BENCH row exists
-# (BENCH_mid_r04: resnet50 0.271, transformer 0.168); conservative
+# (a 2026-07 capture, record since deleted: resnet50 0.271,
+# transformer 0.168); conservative
 # default for configs never captured on chip
 MEASURED_MFU = {"resnet50": 0.271, "transformer": 0.168}
 DEFAULT_MFU = 0.30
