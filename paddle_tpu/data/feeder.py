@@ -24,6 +24,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import jax
 import numpy as np
 
+from ..core import profiler
 from ..core.dtypes import convert_dtype
 
 
@@ -678,7 +679,10 @@ class DeviceFeeder:
             while True:
                 t_wait = time.perf_counter()
                 try:
-                    item = q.get(timeout=0.5)
+                    # the wait record_starved times, as a span: a
+                    # starved gap on the device trace's clock
+                    with profiler.record_event("fit.next_batch"):
+                        item = q.get(timeout=0.5)
                     # starvation accounting: the training loop waited
                     # this long for input (END arrival is shutdown, not
                     # starvation — skip it below)

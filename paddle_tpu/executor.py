@@ -380,6 +380,11 @@ class Trainer:
         any warning-or-worse finding (collective inside the microbatch
         scan, mis-sharded params, dead weights...); ``"off"`` (default)
         skips it. The report is kept at ``self.lint_report``."""
+        with profiler.record_event("trainer.startup",
+                                   inst=self.telemetry_inst):
+            return self._startup(rng, sample_feed, lint)
+
+    def _startup(self, rng, sample_feed, lint):
         enforce(lint in ("off", "warn", "error"),
                 f"Trainer.startup(lint={lint!r}): expected off|warn|error")
         self._setup_compile_cache()
@@ -395,7 +400,8 @@ class Trainer:
             # an augmentation normalize likewise casts the feed before
             # the model sees it (shape-preserving by construction)
             feed = self.feed_augment.logical_feed(feed)
-        params, state = self.program.init(rng, **feed)
+        with profiler.record_event("trainer.init_params"):
+            params, state = self.program.init(rng, **feed)
         params = self._interleave_stacked_params(params)
         sd = getattr(self.strategy, "opt_state_dtype", None) if self.strategy else None
         if sd is not None:
@@ -461,7 +467,8 @@ class Trainer:
             self.scope.opt_state = zero_mod.partition_opt_state(
                 self.scope.opt_state, zspec, self.mesh)
             self._zero = zspec
-        self._build_step()
+        with profiler.record_event("trainer.build_step"):
+            self._build_step()
         self.lint_report = None
         if lint != "off":
             from . import analysis
@@ -1031,88 +1038,91 @@ class Trainer:
                 (loss, (out, new_state)), grads = jax.value_and_grad(
                     loss_and_aux, has_aux=True)(params, state, rng, feed)
 
-            if zspec is not None:
-                # reduce-scatter: the row constraint keeps only this
-                # replica's slice of the exchanged grads; rebinding the
-                # shard rows makes everything below — unscale,
-                # all_finite, optimizer.update, overflow/guard rollback
-                # — shard-local over matching (N, k) trees (grad pads
-                # are exact zeros, so norms and finiteness agree with
-                # the logical grads)
-                grads = zero_mod.partition_grads(grads, zspec, self.mesh)
-                params = pshards
+            # the gradient exchange's tail, clipping and the update, named on
+            # the device: operation metadata only
+            with jax.named_scope("optimizer"):
+                if zspec is not None:
+                    # reduce-scatter: the row constraint keeps only this
+                    # replica's slice of the exchanged grads; rebinding the
+                    # shard rows makes everything below — unscale,
+                    # all_finite, optimizer.update, overflow/guard rollback
+                    # — shard-local over matching (N, k) trees (grad pads
+                    # are exact zeros, so norms and finiteness agree with
+                    # the logical grads)
+                    grads = zero_mod.partition_grads(grads, zspec, self.mesh)
+                    params = pshards
 
-            if scaler is not None:
-                if quant_cfg is None:
-                    # the quant path already unscaled inside the
-                    # shard_map body (pre-encode)
-                    grads = scaler.unscale(grads, ls)
-                finite = scaler.all_finite(grads)
-                new_params, new_opt = self.optimizer.update(
-                    grads, opt_state, params, self.program.param_info)
-                # overflow-skip: keep old params/opt/state on non-finite grads
-                new_params = scaler.select(finite, new_params, params)
-                new_opt = scaler.select(finite, new_opt, opt_state)
-                new_state = scaler.select(finite, new_state, state)
-                if new_qresid is not None:
-                    # a skipped step must not bank a NaN-poisoned (or
-                    # phantom) residual: EF state rolls back with the
-                    # rest of the carry
-                    new_qresid = scaler.select(finite, new_qresid, qresid)
-                new_ls = scaler.update(ls, finite)
-                out = dict(out)
-                out["loss_scale"] = new_ls["scale"]
-            else:
-                new_params, new_opt = self.optimizer.update(
-                    grads, opt_state, params, self.program.param_info)
-                new_ls = ls
-            if guard is not None:
-                # fused on-device NaN/Inf guard: ONE scalar bitmask over
-                # the gradients and every inexact fetch output, computed
-                # inside the compiled step. On a non-finite step the
-                # update is discarded branchlessly — the pre-step carry
-                # (params/opt_state/state) IS the last-good snapshot,
-                # already on device. Loss-scale state is deliberately
-                # NOT rolled back: the scaler's overflow backoff must
-                # persist or the same overflow recurs forever.
-                from .amp import LossScaler
-                # with a loss scaler, grad overflow is the SCALER's
-                # domain: it already skipped the update and backed the
-                # scale off, and routine calibration overflows must not
-                # count as guard incidents (much less abort the run via
-                # the check_nan_inf route) — the guard then watches the
-                # fetch outputs only
-                names, flags = [], []
-                if scaler is None:
-                    names, flags = ["grads"], [_tree_nonfinite(grads)]
-                for kname in sorted(out):
-                    v = out[kname]
-                    if hasattr(v, "dtype") and jnp.issubdtype(v.dtype,
-                                                              jnp.inexact):
-                        names.append(kname)
-                        flags.append(_tree_nonfinite(v))
-                if len(flags) > 32:
-                    # uint32 mask: shifts past bit 31 are undefined and
-                    # would silently drop detection — fold the tail into
-                    # one combined bit (detection stays exact, only the
-                    # which-output attribution coarsens)
-                    rest = flags[31:]
-                    flags = flags[:31] + [jnp.stack(rest).any()]
-                    names = names[:31] + [
-                        f"any-of-{len(rest)}-more:{'/'.join(names[31:34])}…"]
-                mask = jnp.zeros((), jnp.uint32)
-                for i, fl in enumerate(flags):
-                    mask = mask | (fl.astype(jnp.uint32) << i)
-                finite = mask == 0
-                new_params = LossScaler.select(finite, new_params, params)
-                new_opt = LossScaler.select(finite, new_opt, opt_state)
-                new_state = LossScaler.select(finite, new_state, state)
-                if new_qresid is not None:
-                    new_qresid = LossScaler.select(finite, new_qresid,
-                                                   qresid)
-                self._guard_bit_names = tuple(names)  # trace-time capture
-                out = dict(out)
-                out["guard_nonfinite"] = mask
+                if scaler is not None:
+                    if quant_cfg is None:
+                        # the quant path already unscaled inside the
+                        # shard_map body (pre-encode)
+                        grads = scaler.unscale(grads, ls)
+                    finite = scaler.all_finite(grads)
+                    new_params, new_opt = self.optimizer.update(
+                        grads, opt_state, params, self.program.param_info)
+                    # overflow-skip: keep old params/opt/state on non-finite grads
+                    new_params = scaler.select(finite, new_params, params)
+                    new_opt = scaler.select(finite, new_opt, opt_state)
+                    new_state = scaler.select(finite, new_state, state)
+                    if new_qresid is not None:
+                        # a skipped step must not bank a NaN-poisoned (or
+                        # phantom) residual: EF state rolls back with the
+                        # rest of the carry
+                        new_qresid = scaler.select(finite, new_qresid, qresid)
+                    new_ls = scaler.update(ls, finite)
+                    out = dict(out)
+                    out["loss_scale"] = new_ls["scale"]
+                else:
+                    new_params, new_opt = self.optimizer.update(
+                        grads, opt_state, params, self.program.param_info)
+                    new_ls = ls
+                if guard is not None:
+                    # fused on-device NaN/Inf guard: ONE scalar bitmask over
+                    # the gradients and every inexact fetch output, computed
+                    # inside the compiled step. On a non-finite step the
+                    # update is discarded branchlessly — the pre-step carry
+                    # (params/opt_state/state) IS the last-good snapshot,
+                    # already on device. Loss-scale state is deliberately
+                    # NOT rolled back: the scaler's overflow backoff must
+                    # persist or the same overflow recurs forever.
+                    from .amp import LossScaler
+                    # with a loss scaler, grad overflow is the SCALER's
+                    # domain: it already skipped the update and backed the
+                    # scale off, and routine calibration overflows must not
+                    # count as guard incidents (much less abort the run via
+                    # the check_nan_inf route) — the guard then watches the
+                    # fetch outputs only
+                    names, flags = [], []
+                    if scaler is None:
+                        names, flags = ["grads"], [_tree_nonfinite(grads)]
+                    for kname in sorted(out):
+                        v = out[kname]
+                        if hasattr(v, "dtype") and jnp.issubdtype(v.dtype,
+                                                                  jnp.inexact):
+                            names.append(kname)
+                            flags.append(_tree_nonfinite(v))
+                    if len(flags) > 32:
+                        # uint32 mask: shifts past bit 31 are undefined and
+                        # would silently drop detection — fold the tail into
+                        # one combined bit (detection stays exact, only the
+                        # which-output attribution coarsens)
+                        rest = flags[31:]
+                        flags = flags[:31] + [jnp.stack(rest).any()]
+                        names = names[:31] + [
+                            f"any-of-{len(rest)}-more:{'/'.join(names[31:34])}…"]
+                    mask = jnp.zeros((), jnp.uint32)
+                    for i, fl in enumerate(flags):
+                        mask = mask | (fl.astype(jnp.uint32) << i)
+                    finite = mask == 0
+                    new_params = LossScaler.select(finite, new_params, params)
+                    new_opt = LossScaler.select(finite, new_opt, opt_state)
+                    new_state = LossScaler.select(finite, new_state, state)
+                    if new_qresid is not None:
+                        new_qresid = LossScaler.select(finite, new_qresid,
+                                                       qresid)
+                    self._guard_bit_names = tuple(names)  # trace-time capture
+                    out = dict(out)
+                    out["guard_nonfinite"] = mask
             if qef:
                 return (new_params, new_opt, new_state, out, new_ls,
                         new_qresid)
@@ -1303,7 +1313,8 @@ class Trainer:
         ls = getattr(self.scope, "loss_scale_state", None) or {}
         base_step = self.global_step
         t0 = _time.perf_counter()
-        with profiler.record_event("trainer.step"):
+        with profiler.record_event("trainer.step", step=base_step, steps=1,
+                                   inst=self.telemetry_inst):
             if self._quant_ef:
                 p, o, s, out, new_ls, new_qr = self._step_fn(
                     self.scope.params, self.scope.opt_state,
@@ -1364,7 +1375,8 @@ class Trainer:
         ls = getattr(self.scope, "loss_scale_state", None) or {}
         step0 = np.int32(self.global_step)
         t0 = _time.perf_counter()
-        with profiler.record_event("trainer.run_steps"):
+        with profiler.record_event("trainer.run_steps", step=int(step0),
+                                   steps=k, inst=self.telemetry_inst):
             if self._quant_ef:
                 p, o, s, outs, new_ls, new_qr = self._multi_step_fn(
                     self.scope.params, self.scope.opt_state,
@@ -1551,7 +1563,8 @@ class Trainer:
         ``record=False`` suppresses the pipeline-metrics accounting —
         used when a DeviceFeeder owns the timing of this call."""
         metrics = self.pipeline_metrics if record else None
-        return self._put_feed_impl(feed, stacked, metrics)
+        with profiler.record_event("trainer.put_feed"):
+            return self._put_feed_impl(feed, stacked, metrics)
 
     def fusion_report(self, feed: Feed, top_k: int = 8) -> Dict[str, Any]:
         """Fusion-level cost attribution of the compiled train step

@@ -226,11 +226,14 @@ def reuse_names():
 
 @contextlib.contextmanager
 def name_scope(name: str):
-    """Hierarchical naming scope (fluid.name_scope analog)."""
+    """Hierarchical naming scope (fluid.name_scope analog). Parameters
+    created inside are named under it, and so are the operations traced
+    inside (``jax.named_scope``: the ``op_name`` a device trace shows)."""
     ctx = _ctx()
     ctx.name_stack.append(name)
     try:
-        yield
+        with jax.named_scope(name):
+            yield
     finally:
         ctx.name_stack.pop()
 

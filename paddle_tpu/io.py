@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .core import profiler
 from .core.errors import EnforceError, enforce
 
 SEP = "||"  # path separator for nested pytree keys (param names use '/')
@@ -930,6 +931,13 @@ def save_inference_model(dirname: str, program, params: Dict[str, jax.Array],
     ragged request batches up to, so adversarial batch shapes can never
     trigger a recompile on the request path. The example feed's own
     batch size is always a bucket."""
+    with profiler.record_event("io.save_inference_model"):
+        _save_inference_model(dirname, program, params, state, example_feed,
+                              batch_buckets)
+
+
+def _save_inference_model(dirname, program, params, state, example_feed,
+                          batch_buckets) -> None:
     import shutil
 
     from . import resilience
@@ -945,14 +953,16 @@ def save_inference_model(dirname: str, program, params: Dict[str, jax.Array],
         out, _ = program.apply(params_, state_, training=False, **feed)
         return out
 
-    host_params, host_state = jax.device_get(params), jax.device_get(state)
+    with profiler.record_event("io.write_params"):   # device -> host
+        host_params, host_state = jax.device_get(params), jax.device_get(state)
 
-    def _export_at(feed):
-        vals = [jnp.asarray(np.asarray(feed[k])) for k in feed_names]
-        return jax.export.export(jax.jit(infer_fn))(
-            host_params, host_state, *vals)
+    def _export_at(feed, bucket):
+        with profiler.record_event("io.export", bucket=bucket):
+            vals = [jnp.asarray(np.asarray(feed[k])) for k in feed_names]
+            return jax.export.export(jax.jit(infer_fn))(
+                host_params, host_state, *vals)
 
-    exported = _export_at(example_feed)
+    exported = _export_at(example_feed, batch)
     bucket_exports = {}
     for b in buckets:
         if b == batch:
@@ -960,7 +970,7 @@ def save_inference_model(dirname: str, program, params: Dict[str, jax.Array],
         bucket_exports[b] = _export_at(
             {k: (_resize_batch(np.asarray(v), b) if k in batched_feeds
                  else np.asarray(v))
-             for k, v in example_feed.items()})
+             for k, v in example_feed.items()}, b)
 
     path = os.path.abspath(dirname)
     parent = os.path.dirname(path)
@@ -976,9 +986,10 @@ def save_inference_model(dirname: str, program, params: Dict[str, jax.Array],
     for b, exp in bucket_exports.items():
         with open(os.path.join(tmp, f"model.b{b}.stablehlo"), "wb") as f:
             f.write(exp.serialize())
-    flat_params, flat_state = _flatten(host_params), _flatten(host_state)
-    np.savez(os.path.join(tmp, "params.npz"), **flat_params)
-    np.savez(os.path.join(tmp, "state.npz"), **flat_state)
+    with profiler.record_event("io.write_params"):   # host -> npz
+        flat_params, flat_state = _flatten(host_params), _flatten(host_state)
+        np.savez(os.path.join(tmp, "params.npz"), **flat_params)
+        np.savez(os.path.join(tmp, "state.npz"), **flat_state)
     arrays_spec = {name: {k: {"shape": list(v.shape), "dtype": str(v.dtype)}
                           for k, v in flat.items()}
                    for name, flat in (("params.npz", flat_params),
@@ -1200,8 +1211,9 @@ class Predictor:
                  batched_feeds: Optional[Sequence[str]] = None,
                  _buckets: Optional[Dict[int, Any]] = None):
         self._exported = exported
-        self._params = jax.device_put(params)
-        self._state = jax.device_put(state)
+        with profiler.record_event("io.device_put"):
+            self._params = jax.device_put(params)
+            self._state = jax.device_put(state)
         self.feed_names = list(feed_names)
         # feed avals are the trailing in_avals (flat order is
         # (params..., state..., *feeds) with feeds in sorted-name order)
@@ -1215,7 +1227,9 @@ class Predictor:
         self.batched_feeds = frozenset(batched_feeds)
         if _compiled is None:
             try:
-                _compiled = _aot_compile(exported)
+                with profiler.record_event("io.aot_compile",
+                                           bucket=self.batch_size):
+                    _compiled = _aot_compile(exported)
             except Exception as e:
                 # fall back to the jit dispatch cache: first run() traces,
                 # subsequent calls still skip tracing/compilation. This
@@ -1236,7 +1250,9 @@ class Predictor:
                 if int(b) == self.batch_size:
                     continue
                 try:
-                    self._buckets[int(b)] = _aot_compile(exp)
+                    with profiler.record_event("io.aot_compile",
+                                               bucket=int(b)):
+                        self._buckets[int(b)] = _aot_compile(exp)
                 except Exception as e:
                     _log().warning(
                         "bucket %d AOT compile failed (%s: %s); falling back "
@@ -1353,13 +1369,19 @@ def load_inference_model(dirname: str) -> Predictor:
     :class:`~paddle_tpu.resilience.CheckpointCorrupt` instead of a
     random decoder error three frames deep. Pre-manifest (legacy)
     directories load without validation."""
+    with profiler.record_event("io.load_inference_model"):
+        return _load_inference_model(dirname)
+
+
+def _load_inference_model(dirname: str) -> Predictor:
     from . import resilience
 
     resilience.validate_checkpoint(dirname)  # None for legacy dirs
     try:
         with open(os.path.join(dirname, "model.stablehlo"), "rb") as f:
             exported = jax.export.deserialize(f.read())
-        params, state, _, meta = load_persistables(dirname)
+        with profiler.record_event("io.read_params"):
+            params, state, _, meta = load_persistables(dirname)
     except (resilience.CheckpointCorrupt, FileNotFoundError):
         raise
     except Exception as e:

@@ -61,6 +61,7 @@ class StackedInit:
         return jnp.stack([self.base(k, shape[1:], dtype) for k in keys])
 
 
+@jax.named_scope("ln")
 def _ln(x, scale, bias, eps: float = 1e-5):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
@@ -198,6 +199,7 @@ def decoder_stack_params(num_layers: int, d_model: int, d_inner: int,
 # -- block functions ---------------------------------------------------------
 
 
+@jax.named_scope("attn")
 def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
                     sp_cfg=None, dropout_rate: float = 0.0):
     q, k, v = _attn_qkv(x, p, num_heads)
@@ -206,6 +208,7 @@ def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
                      tp_axis, dropout_rate=dropout_rate)
 
 
+@jax.named_scope("ffn")
 def _ffn(x, p, tp_axis, dropout_rate: float = 0.0):
     h = _ln(x, p["ln2/scale"], p["ln2/bias"])
     h, w1, w2 = cast_compute(h, p["ffn_in/w"], p["ffn_out/w"])
@@ -255,19 +258,22 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
         head_dim = x.shape[-1] // num_heads
         x = _self_attention(x, p, num_heads, causal, use_flash, None, tp_axis,
                             dropout_rate=dropout_rate)
-        h = _ln(x, p["lnx/scale"], p["lnx/bias"])
-        h, wq, wkv, enc = cast_compute(h, p["xq/w"], p["xkv/w"], extra["enc"])
-        q = jnp.matmul(h, wq) + p["xq/b"].astype(h.dtype)
-        kv = jnp.einsum("bsd,dke->bske", enc, wkv) + p["xkv/b"].astype(h.dtype)
-        q = _split_heads(q, head_dim)
-        k, v = (_split_heads(kv[:, :, i], head_dim) for i in range(2))
-        o = _merge_heads(_sdpa(q, k, v, extra.get("enc_bias"), False, use_flash,
-                               dropout_rate=dropout_rate))
-        o, ow = cast_compute(o, p["xout/w"])
-        o = jnp.matmul(o, ow)
-        if tp_axis:
-            o = jax.lax.psum(o, tp_axis)
-        x = x + _drop(o + p["xout/b"].astype(o.dtype), dropout_rate)
+        with jax.named_scope("attn"):
+            h = _ln(x, p["lnx/scale"], p["lnx/bias"])
+            h, wq, wkv, enc = cast_compute(h, p["xq/w"], p["xkv/w"],
+                                           extra["enc"])
+            q = jnp.matmul(h, wq) + p["xq/b"].astype(h.dtype)
+            kv = jnp.einsum("bsd,dke->bske", enc, wkv) \
+                + p["xkv/b"].astype(h.dtype)
+            q = _split_heads(q, head_dim)
+            k, v = (_split_heads(kv[:, :, i], head_dim) for i in range(2))
+            o = _merge_heads(_sdpa(q, k, v, extra.get("enc_bias"), False,
+                                   use_flash, dropout_rate=dropout_rate))
+            o, ow = cast_compute(o, p["xout/w"])
+            o = jnp.matmul(o, ow)
+            if tp_axis:
+                o = jax.lax.psum(o, tp_axis)
+            x = x + _drop(o + p["xout/b"].astype(o.dtype), dropout_rate)
         return _ffn(x, p, tp_axis, dropout_rate=dropout_rate)
 
     return block
@@ -296,8 +302,9 @@ def prefill_block(x, p, num_heads: int, use_flash: bool = False):
     """Causal block that also returns its (k, v) for cache seeding —
     the stacked-layer analog of the transformer decoder's cache path
     (models/transformer.py make_decoder)."""
-    q, k, v = _attn_qkv(x, p, num_heads)
-    x = _attn_out(x, p, _sdpa(q, k, v, None, True, use_flash))
+    with jax.named_scope("attn"):
+        q, k, v = _attn_qkv(x, p, num_heads)
+        x = _attn_out(x, p, _sdpa(q, k, v, None, True, use_flash))
     return _ffn(x, p, None), (k, v)
 
 
@@ -324,44 +331,46 @@ def decode_block_q8(x, p, k_q, k_s, v_q, v_s, index, num_heads: int):
     out ∝ probs∘v_s), so no dequantized cache array is ever
     materialized: the int8→compute-dtype convert feeds the dot
     operands directly. Returns (x, k_q, k_s, v_q, v_s)."""
-    q, k1, v1 = _attn_qkv(x, p, num_heads)
-    k1q, k1s = quantize_kv(k1)
-    v1q, v1s = quantize_kv(v1)
-    k_q = jax.lax.dynamic_update_slice(k_q, k1q, (0, 0, index, 0))
-    k_s = jax.lax.dynamic_update_slice(k_s, k1s.astype(k_s.dtype),
-                                       (0, 0, index, 0))
-    v_q = jax.lax.dynamic_update_slice(v_q, v1q, (0, 0, index, 0))
-    v_s = jax.lax.dynamic_update_slice(v_s, v1s.astype(v_s.dtype),
-                                       (0, 0, index, 0))
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_q.astype(q.dtype),
-                        preferred_element_type=jnp.float32)
-    logits = logits * k_s[..., 0][:, :, None, :] * scale
-    pos = jnp.arange(k_q.shape[2])
-    logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    pv = (probs * v_s[..., 0][:, :, None, :]).astype(q.dtype)
-    o = jnp.einsum("bhqk,bhkd->bhqd", pv, v_q.astype(q.dtype))
-    x = _attn_out(x, p, o)
+    with jax.named_scope("attn"):
+        q, k1, v1 = _attn_qkv(x, p, num_heads)
+        k1q, k1s = quantize_kv(k1)
+        v1q, v1s = quantize_kv(v1)
+        k_q = jax.lax.dynamic_update_slice(k_q, k1q, (0, 0, index, 0))
+        k_s = jax.lax.dynamic_update_slice(k_s, k1s.astype(k_s.dtype),
+                                           (0, 0, index, 0))
+        v_q = jax.lax.dynamic_update_slice(v_q, v1q, (0, 0, index, 0))
+        v_s = jax.lax.dynamic_update_slice(v_s, v1s.astype(v_s.dtype),
+                                           (0, 0, index, 0))
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_q.astype(q.dtype),
+                            preferred_element_type=jnp.float32)
+        logits = logits * k_s[..., 0][:, :, None, :] * scale
+        pos = jnp.arange(k_q.shape[2])
+        logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        pv = (probs * v_s[..., 0][:, :, None, :]).astype(q.dtype)
+        o = jnp.einsum("bhqk,bhkd->bhqd", pv, v_q.astype(q.dtype))
+        x = _attn_out(x, p, o)
     return _ffn(x, p, None), k_q, k_s, v_q, v_s
 
 
 def decode_block(x, p, k_cache, v_cache, index, num_heads: int):
     """One-token step: x [rows, 1, d]; caches [rows, h, T, hd]; attends
     to cache positions <= index. Returns (x, new_k, new_v)."""
-    q, k1, v1 = _attn_qkv(x, p, num_heads)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k1.astype(k_cache.dtype),
-                                           (0, 0, index, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v1.astype(v_cache.dtype),
-                                           (0, 0, index, 0))
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(k_cache.shape[2])
-    logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
-    o = jnp.einsum("bhqk,bhkd->bhqd", probs, v_cache)
-    x = _attn_out(x, p, o)
+    with jax.named_scope("attn"):
+        q, k1, v1 = _attn_qkv(x, p, num_heads)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k1.astype(k_cache.dtype),
+                                               (0, 0, index, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v1.astype(v_cache.dtype),
+                                               (0, 0, index, 0))
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        pos = jnp.arange(k_cache.shape[2])
+        logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
+        o = jnp.einsum("bhqk,bhkd->bhqd", probs, v_cache)
+        x = _attn_out(x, p, o)
     return _ffn(x, p, None), k_cache, v_cache
 
 

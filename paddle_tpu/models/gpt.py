@@ -95,8 +95,8 @@ def make_model(cfg: GPTConfig):
         with name_scope("tok"):
             x = L.embedding(ids, size=[cfg.vocab_size, cfg.d_model],
                             dtype=cfg.dtype)
-        pe = A.positional_encoding(cfg.max_len, cfg.d_model, dtype)
-        x = x + pe[positions][None]
+            pe = A.positional_encoding(cfg.max_len, cfg.d_model, dtype)
+            x = x + pe[positions][None]
 
         with name_scope("gpt"):
             stack = S.encoder_stack_params(cfg.num_layers, cfg.d_model,
@@ -157,20 +157,21 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
             initializer=init.Xavier())
 
         def head(x_last):  # [rows, d] -> log-probs [rows, vocab]
-            h = S._ln(x_last[:, None, :], ln_scale, ln_bias)[:, 0]
-            return jax.nn.log_softmax(
-                jnp.matmul(h, w_head).astype(jnp.float32), axis=-1)
+            with jax.named_scope("head"):
+                h = S._ln(x_last[:, None, :], ln_scale, ln_bias)[:, 0]
+                return jax.nn.log_softmax(
+                    jnp.matmul(h, w_head).astype(jnp.float32), axis=-1)
 
         # ---- prefill: run the prompt causally, capture per-layer k/v
         # (cast_compute keeps the scan carry dtype consistent with the
         # blocks' compute dtype regardless of cfg.dtype)
-        x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
-
         def pre(a, lp):
             return S.prefill_block(a, lp, cfg.num_heads, cfg.use_flash)
 
-        x, (ks, vs) = jax.lax.scan(pre, x, stack)
-        logp0 = head(x[:, -1])  # first generated token comes from here
+        with jax.named_scope("prefill"):
+            x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
+            x, (ks, vs) = jax.lax.scan(pre, x, stack)
+            logp0 = head(x[:, -1])  # first generated token comes from here
 
         K = beam_size
         rows = b * K
@@ -211,6 +212,7 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
         def step_fn(tokens, state):
             # the prefill already produced the first step's distribution;
             # afterwards embed the chosen token and run the cached stack
+            @jax.named_scope("decode_step")
             def incremental(_):
                 xt = cast_compute(w_emb[tokens][:, None, :]
                                   + pe[state["index"]][None, None])

@@ -21,17 +21,18 @@ def lm_head_loss(x, labels, vocab_size: int, dtype, fused_ce: bool,
     helper = LayerHelper("lm_head")
     w = helper.create_parameter("w", (x.shape[-1], vocab_size), dtype,
                                 initializer=init.Xavier())
-    lab = labels.astype(jnp.int32)
-    nonpad = (labels != pad_id).astype(jnp.float32)
-    token_count = jnp.maximum(nonpad.sum(), 1.0)
-    b, t, d = x.shape
-    if fused_ce:
-        ce = chunked_softmax_cross_entropy(
-            x.reshape(b * t, d), w, None, lab.reshape(-1), 0.0,
-            ce_chunk).reshape(b, t)
-    else:
-        logits = jnp.matmul(x, w)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ce = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-    loss = jnp.sum(ce * nonpad) / token_count
+    with jax.named_scope("ce"):
+        lab = labels.astype(jnp.int32)
+        nonpad = (labels != pad_id).astype(jnp.float32)
+        token_count = jnp.maximum(nonpad.sum(), 1.0)
+        b, t, d = x.shape
+        if fused_ce:
+            ce = chunked_softmax_cross_entropy(
+                x.reshape(b * t, d), w, None, lab.reshape(-1), 0.0,
+                ce_chunk).reshape(b, t)
+        else:
+            logits = jnp.matmul(x, w)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ce = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+        loss = jnp.sum(ce * nonpad) / token_count
     return loss, token_count

@@ -330,6 +330,7 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -521,6 +522,7 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
@@ -569,6 +571,7 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_specs,
         out_specs=[

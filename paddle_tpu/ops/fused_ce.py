@@ -46,6 +46,7 @@ def chunked_softmax_cross_entropy(hidden, weight, bias, labels,
     return nll
 
 
+@jax.named_scope("ce")
 def _fwd_stats(hidden, weight, bias, labels, smooth_eps, chunk, logit_dtype):
     n_tok, d = hidden.shape
     v = weight.shape[1]
@@ -92,6 +93,7 @@ def _fwd(hidden, weight, bias, labels, smooth_eps, chunk, logit_dtype):
     return nll, (hidden, weight, bias, labels, lse)
 
 
+@jax.named_scope("ce")  # traced apart from the forward: named apart too
 def _bwd(smooth_eps, chunk, logit_dtype, res, g):
     hidden, weight, bias, labels, lse = res
     n_tok, d = hidden.shape

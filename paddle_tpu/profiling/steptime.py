@@ -3,8 +3,10 @@
 PR 4's ``PipelineMetrics`` named the input-pipeline bottleneck; this
 module extends that discipline to the compiled step itself. The Trainer
 records every ``step``/``run_steps`` dispatch into a :class:`StepTimer`
-(two ``perf_counter`` reads and a list append — cheap enough to stay
-always-on; the <2% overhead contract is test-pinned), and
+(two ``perf_counter`` reads and a few additions — cheap enough to stay
+always-on; the <2% overhead contract is test-pinned; the dispatch's span
+is the ``trainer.step`` / ``trainer.run_steps`` that ``core.profiler``
+recorded round it), and
 ``Trainer.profile_report()`` merges the dispatch timeline with
 ``pipeline_report()`` into one compute / h2d / host-encode / starvation
 breakdown, emitted on ``Event.end_epoch``.
@@ -20,12 +22,13 @@ single-dispatch numbers are a lower bound on device time.
 
 from __future__ import annotations
 
-from collections import deque
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
-# ring-buffer cap on retained spans: a week-long fit must not grow an
-# unbounded list just because profiling is always-on
-_MAX_SPANS = 8192
+from ..core import profiler
+
+
+_DISPATCH_SPANS = ("trainer.step", "trainer.run_steps")
 
 
 class StepTimer:
@@ -41,9 +44,10 @@ class StepTimer:
     timer the journal's dispatch feed: every recorded dispatch emits a
     ``trainer.dispatch`` event carrying the chunk's span id (minted by
     the DeviceFeeder fill thread, or fresh here) — the training-side
-    half of the submit→execution correlation story. One ring append +
-    one journal emit per DISPATCH (not per step) keeps the cost inside
-    the <2% K=16 budget the tests pin."""
+    half of the submit→execution correlation story. One journal emit per
+    DISPATCH (not per step) keeps the cost inside the <2% K=16 budget the
+    tests pin. The timer keeps counters only: a dispatch's span is in
+    ``core.profiler``'s ring, under this timer's ``inst``."""
 
     def __init__(self, journal=None, inst: Optional[str] = None):
         self.journal = journal
@@ -57,7 +61,7 @@ class StepTimer:
         self.by_kind: Dict[str, int] = {}
         self.first_t0: Optional[float] = None
         self.last_t1: Optional[float] = None
-        self._spans: deque = deque(maxlen=_MAX_SPANS)
+        self._since_ns = time.time_ns()
 
     def record_dispatch(self, t0: float, t1: float, num_steps: int = 1,
                         kind: str = "step", span: Optional[str] = None,
@@ -73,7 +77,6 @@ class StepTimer:
         if self.first_t0 is None:
             self.first_t0 = t0
         self.last_t1 = t1
-        self._spans.append((kind, num_steps, t0, t1))
         if self.journal is not None:
             self.journal.emit(
                 "trainer.dispatch",
@@ -108,10 +111,13 @@ class StepTimer:
         ]
 
     def spans_us(self) -> List[Tuple[str, float, float, int]]:
-        """Retained dispatch spans as ``(name, start_us, dur_us, tid)``
-        tuples — the shape ``core.profiler.timeline`` consumes."""
-        return [(f"trainer.{kind}[{n}]", t0 * 1e6, (t1 - t0) * 1e6, 1)
-                for kind, n, t0, t1 in self._spans]
+        """This trainer's dispatch spans since the last ``reset``, read
+        from ``core.profiler``'s ring (so bounded by it), as
+        ``(name[steps], start_us, dur_us, tid)`` tuples — the shape
+        ``core.profiler.timeline`` consumes."""
+        return [(f"{name}[{ids['steps']}]", start / 1e3, dur / 1e3, 1)
+                for name, start, dur, _, ids in profiler.spans(self._since_ns)
+                if name in _DISPATCH_SPANS and ids.get("inst") == self.inst]
 
     def report(self) -> Dict[str, Any]:
         span = ((self.last_t1 - self.first_t0)
@@ -125,7 +131,6 @@ class StepTimer:
                             if self.steps else None),
             "avg_dispatch_ms": (round(self.dispatch_s / self.dispatches * 1e3,
                                       4) if self.dispatches else None),
-            "spans_retained": len(self._spans),
         }
 
 
@@ -182,9 +187,7 @@ def profile_report(trainer, fusion: Optional[Dict[str, Any]] = None
 
 
 def export_chrome_trace(trainer, path: str) -> int:
-    """Dump the trainer's retained dispatch spans (plus any host spans
-    the ``core.profiler`` collected while enabled) as chrome://tracing
-    JSON. Returns the number of events written."""
-    from ..core import profiler
-
+    """Dump the trainer's dispatch spans (plus the ring's spans of an
+    enabled ``core.profiler`` window) as chrome://tracing JSON. Returns
+    the number of events written."""
     return profiler.timeline(path, extra_spans=trainer.step_timer.spans_us())

@@ -44,6 +44,7 @@ surrounding runtime, the serving-side sibling of the fault-tolerant
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import queue as _queue
 import threading
@@ -52,6 +53,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from .core import profiler
 from .core.errors import EnforceError
 from .fleet import batching as _batching
 from .io import InvalidRequest  # noqa: F401  (re-exported: submit raises it)
@@ -303,7 +305,8 @@ class ServingMetrics:
                  "rejected_overload", "rejected_breaker", "timeouts",
                  "errors", "hangs", "workers_replaced", "reloads",
                  "reload_failures", "coalesced_batches",
-                 "coalesced_requests")
+                 "coalesced_requests", "dispatches", "dispatched_rows",
+                 "queued_seconds")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -322,6 +325,16 @@ class ServingMetrics:
     def record_latency(self, seconds: float):
         with self._lock:
             self.hist.record(seconds)
+
+    def record_dispatch(self, rows: int, queued_seconds: float):
+        """One dispatch, lone or coalesced, counted where it starts:
+        its real rows and the seconds its requests waited in the queue.
+        Occupancy is ``dispatched_rows / (dispatches x bucket)``, mean
+        queue wait ``queued_seconds / completed``."""
+        with self._lock:
+            self.dispatches += 1
+            self.dispatched_rows += rows
+            self.queued_seconds += queued_seconds
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -390,6 +403,15 @@ class ServingMetrics:
             counter_family("paddle_tpu_serving_coalesced_requests_total",
                            "Requests served inside a coalesced dispatch",
                            [(labels, snap["coalesced_requests"])]),
+            counter_family("paddle_tpu_serving_dispatches_total",
+                           "Dispatches to the executable, lone or coalesced",
+                           [(labels, snap["dispatches"])]),
+            counter_family("paddle_tpu_serving_dispatched_rows_total",
+                           "Real (un-padded) rows carried by dispatches",
+                           [(labels, snap["dispatched_rows"])]),
+            counter_family("paddle_tpu_serving_queued_seconds_total",
+                           "Seconds requests waited from submit to dispatch",
+                           [(labels, round(snap["queued_seconds"], 6))]),
         ]
         h = snap["latency_hist"]
         fams.append(histogram_family(
@@ -408,7 +430,8 @@ def _ms(seconds: Optional[float]) -> Optional[float]:
 
 class _Request:
     __slots__ = ("feed", "n", "bucket", "deadline", "token", "done",
-                 "value", "error", "submitted", "completed", "span")
+                 "value", "error", "submitted", "submitted_ns", "thread",
+                 "completed", "span")
 
     def __init__(self, feed, n, bucket, deadline, token, span=None):
         self.feed = feed
@@ -421,6 +444,8 @@ class _Request:
         self.value = None
         self.error: Optional[BaseException] = None
         self.submitted = time.monotonic()
+        self.submitted_ns = time.time_ns()    # the span ring's clock
+        self.thread = threading.get_ident()   # whose wait serving.queued is
         self.completed: Optional[float] = None
 
 
@@ -549,6 +574,7 @@ class PredictorServer:
         self._do_warmup = bool(warmup)
         self._queue: _queue.Queue = _queue.Queue(maxsize=self.queue_size)
         self._complete_lock = threading.Lock()
+        self._dispatch_seq = itertools.count(1)   # the spans' dispatch=<n>
         self.metrics = ServingMetrics()
         # unified telemetry: journal spans per request, a scrape-time
         # collector in the process registry (the `inst` label keeps
@@ -606,9 +632,10 @@ class PredictorServer:
         request sees steady-state latency."""
         clone = predictor.clone()
         for b in predictor.batch_buckets:
-            feed = self._bucket_feed(predictor, b)
-            out = clone.run(feed)
-            _block_on(out)
+            with profiler.record_event("serving.warmup", bucket=b):
+                feed = self._bucket_feed(predictor, b)
+                out = clone.run(feed)
+                _block_on(out)
 
     def _bucket_feed(self, predictor, bucket: int) -> Dict[str, np.ndarray]:
         spec = predictor.feed_spec(bucket)
@@ -765,17 +792,21 @@ class PredictorServer:
         ``span`` adopts an externally-minted trace id (the wire trace
         token of a cross-process front door) instead of minting one —
         both processes' journals then carry ONE id end to end."""
+        # the request's trace id is minted HERE, at submit (unless the
+        # front door handed one over the wire): every journal event and
+        # every span of its life (queue, worker dispatch, outcome, a
+        # watchdog hang) carries it — PendingResult.span exposes it
+        span = span or self.journal.new_span()
+        with profiler.record_event("serving.submit", req=span):
+            return self._submit(feed, deadline, span)
+
+    def _submit(self, feed, deadline, span) -> PendingResult:
         with self._state_lock:
             state = self._state
         if state in ("draining", "stopping", "stopped"):
             raise ServerClosed(f"server is {state}")
         if state == "starting":
             raise ServerClosed("server not started (call start())")
-        # the request's trace id is minted HERE, at submit (unless the
-        # front door handed one over the wire): every journal event of
-        # its life (queue, worker dispatch, outcome, a watchdog hang)
-        # carries it — PendingResult.span exposes it
-        span = span or self.journal.new_span()
         token = self.breaker.acquire()
         if token is None:
             self.metrics.bump("rejected_breaker")
@@ -951,6 +982,9 @@ class PredictorServer:
     def _worker_loop(self, w: _Worker) -> None:
         clone = None
         gen = 0
+        # when this worker became free: a turn, and the wait for its
+        # first request, are timed from here (core.profiler's clocks)
+        free_ns, free_t = time.time_ns(), time.perf_counter_ns()
         while not self._stop.is_set() and not w.abandoned:
             if w.carry:
                 req = w.carry.pop(0)
@@ -959,11 +993,19 @@ class PredictorServer:
                     req = self._queue.get(timeout=0.05)
                 except _queue.Empty:
                     continue
+            waited = time.perf_counter_ns() - free_t
             req = self._admit(req)
+            ids = {"worker": w.index}
+            if req is not None:
+                ids["dispatch"] = next(self._dispatch_seq)
+            profiler.record_span("serving.dequeue", free_ns, waited, **ids)
             if req is None:
                 continue
-            group = ([req] if self.batch_policy is None
-                     else self._coalesce(w, req))
+            if self.batch_policy is None:
+                group = [req]
+            else:
+                with profiler.record_event("serving.coalesce", **ids):
+                    group = self._coalesce(w, req)
             with self._model_lock:
                 pred, gen_now = self._predictor, self._generation
             total = sum(r.n for r in group)
@@ -973,24 +1015,36 @@ class PredictorServer:
             w.request = req
             w.group = group
             w.busy_since = now = time.monotonic()
+            queued = 0.0
             for (off, n), r in zip(spans, group):
                 extra = ({"coalesced": len(group), "row": off}
                          if len(group) > 1 else {})
+                queued += now - r.submitted
+                # the request's wait, on its submitter's thread: the
+                # span that names the dispatch that served it
+                profiler.record_span(
+                    "serving.queued", r.submitted_ns,
+                    int((now - r.submitted) * 1e9), thread=r.thread,
+                    req=r.span, dispatch=ids["dispatch"])
                 self.journal.emit("serving.dispatch", span=r.span,
                                   inst=self.telemetry_inst, worker=w.index,
                                   n=n, bucket=bucket,
                                   queued_s=round(now - r.submitted, 6),
                                   **extra)
+            self.metrics.record_dispatch(total, queued)
             try:
-                if clone is None or gen != gen_now:
-                    clone = pred.clone()
-                    gen = gen_now
-                feed = (self._pad(pred, req) if len(group) == 1
-                        else _batching.merge_feeds(group, pred.feed_names,
-                                                   pred.batched_feeds,
-                                                   bucket))
-                out = clone.run(feed)
-                _block_on(out)
+                with profiler.record_event("serving.merge", **ids):
+                    if clone is None or gen != gen_now:
+                        clone = pred.clone()
+                        gen = gen_now
+                    feed = (self._pad(pred, req) if len(group) == 1
+                            else _batching.merge_feeds(
+                                group, pred.feed_names, pred.batched_feeds,
+                                bucket))
+                with profiler.record_event("serving.run", **ids):
+                    out = clone.run(feed)
+                with profiler.record_event("serving.block", **ids):
+                    _block_on(out)
             except BaseException as e:
                 for r in group:
                     first = self._complete(r, error=e)
@@ -1009,29 +1063,17 @@ class PredictorServer:
                             inst=self.telemetry_inst, worker=w.index,
                             error=f"{type(e).__name__}: {e}"[:300])
             else:
-                if len(group) > 1:
-                    self.metrics.bump("coalesced_batches")
-                    self.metrics.bump("coalesced_requests", by=len(group))
-                done_t = time.monotonic()
-                for (off, n), r in zip(spans, group):
-                    if not w.abandoned:
-                        self.breaker.record(r.token, success=True)
-                    sliced = _batching.slice_rows(out, off, n, bucket)
-                    if self._complete(r, value=sliced):
-                        latency = done_t - r.submitted
-                        self.metrics.bump("completed")
-                        self.metrics.record_latency(latency)
-                        extra = ({"coalesced": len(group)}
-                                 if len(group) > 1 else {})
-                        self.journal.emit("serving.complete", span=r.span,
-                                          inst=self.telemetry_inst,
-                                          worker=w.index,
-                                          latency_s=round(latency, 6),
-                                          **extra)
+                with profiler.record_event("serving.reply", **ids):
+                    self._reply(w, group, spans, out, bucket)
             finally:
                 w.busy_since = None
                 w.request = None
                 w.group = None
+                now_ns, now_t = time.time_ns(), time.perf_counter_ns()
+                profiler.record_span("serving.turn", free_ns, now_t - free_t,
+                                     rows=total, bucket=bucket,
+                                     requests=len(group), **ids)
+                free_ns, free_t = now_ns, now_t
         # loop exit with requests still carried (stop flag raced the
         # coalescer): they were never dispatched — fail them typed so
         # no client blocks forever, probe tokens go back
@@ -1039,6 +1081,30 @@ class PredictorServer:
             self.breaker.cancel(r.token)
             self._complete(r, error=ServerClosed("server stopping"))
         w.carry = []
+
+    def _reply(self, w: _Worker, group: List[_Request], spans, out,
+               bucket: int) -> None:
+        """Hand every request of a finished dispatch its rows, and count
+        it: breaker, metrics, journal."""
+        if len(group) > 1:
+            self.metrics.bump("coalesced_batches")
+            self.metrics.bump("coalesced_requests", by=len(group))
+        done_t = time.monotonic()
+        for (off, n), r in zip(spans, group):
+            if not w.abandoned:
+                self.breaker.record(r.token, success=True)
+            sliced = _batching.slice_rows(out, off, n, bucket)
+            if self._complete(r, value=sliced):
+                latency = done_t - r.submitted
+                self.metrics.bump("completed")
+                self.metrics.record_latency(latency)
+                extra = ({"coalesced": len(group)}
+                         if len(group) > 1 else {})
+                self.journal.emit("serving.complete", span=r.span,
+                                  inst=self.telemetry_inst,
+                                  worker=w.index,
+                                  latency_s=round(latency, 6),
+                                  **extra)
 
     @staticmethod
     def _pad(predictor, req: _Request) -> Dict[str, Any]:

@@ -749,3 +749,72 @@ def test_predictor_fallback_logs_reason(artifact, monkeypatch, caplog):
                for r in caplog.records)
     # the fallback still serves (first call traces)
     assert np.asarray(p.run(artifact["feed8"])["logits"]).shape == (8, 10)
+
+
+# -- spans and dispatch counters ---------------------------------------------
+
+
+def test_every_dispatch_has_a_turn_with_its_children_and_counters_agree(pred):
+    """Each dispatch leaves one ``serving.turn`` whose six children lie
+    inside it in order; each request one ``serving.queued`` naming the
+    dispatch that served it; ``dispatches`` / ``dispatched_rows`` /
+    ``queued_seconds`` are the same facts as counters."""
+    from paddle_tpu.core import profiler
+    from paddle_tpu.fleet import BatchPolicy
+
+    since = time.time_ns()
+    with PredictorServer(pred, workers=1, queue_size=32,
+                         batch_policy=BatchPolicy(max_wait_ms=30.0)) as srv:
+        pending = [srv.submit(_feed(1, seed=i)) for i in range(6)]
+        srv.run(_feed(8, seed=9), timeout=60)      # a lone, full dispatch
+        for p in pending:
+            p.result(timeout=60)
+        snap = srv.metrics.snapshot()
+        worker = srv._workers[0].thread.ident
+    spans = [s for s in profiler.spans(since) if s[0].startswith("serving.")]
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    assert "serving.warmup" in by and {s[4]["bucket"] for s in
+                                       by["serving.warmup"]} == {4, 8}
+
+    turns = {s[4]["dispatch"]: s for s in by["serving.turn"]}
+    assert len(turns) == len(by["serving.turn"]) == snap["dispatches"] >= 2
+    assert sum(s[4]["rows"] for s in turns.values()) \
+        == snap["dispatched_rows"] == 6 + 8
+    assert sum(s[4]["requests"] for s in turns.values()) == 7
+    children = ("serving.dequeue", "serving.coalesce", "serving.merge",
+                "serving.run", "serving.block", "serving.reply")
+    for n, turn in turns.items():
+        assert turn[3] == worker and turn[4]["worker"] == 0
+        assert turn[4]["bucket"] in (4, 8)
+        mine = [next(s for s in by[c] if s[4].get("dispatch") == n)
+                for c in children]
+        assert all(s[3] == worker for s in mine)
+        assert mine[0][1] == turn[1]                # from the worker free
+        for a, b in zip(mine, mine[1:]):            # in order, not overlapping
+            assert a[1] + a[2] <= b[1] + 50_000
+        assert mine[-1][1] + mine[-1][2] <= turn[1] + turn[2] + 50_000
+
+    queued = by["serving.queued"]
+    submits = {s[4]["req"]: s for s in by["serving.submit"]}
+    assert len(queued) == len(submits) == snap["completed"] == 7
+    assert {s[4]["req"] for s in queued} == set(submits) \
+        >= {p.span for p in pending}
+    for q in queued:
+        assert q[4]["dispatch"] in turns
+        sub = submits[q[4]["req"]]
+        assert q[3] == sub[3] == threading.get_ident()    # the caller's thread
+        assert sub[1] <= q[1] <= sub[1] + sub[2]          # starts inside submit
+        run = next(s for s in by["serving.run"]
+                   if s[4]["dispatch"] == q[4]["dispatch"])
+        assert q[1] + q[2] <= run[1] + 1_000_000          # ends at the dispatch
+    assert snap["queued_seconds"] == pytest.approx(
+        sum(q[2] for q in queued) / 1e9, abs=1e-6)
+    # coalesced_* keep their meaning: only dispatches of more than one
+    coalesced = [t for t in turns.values() if t[4]["requests"] > 1]
+    assert snap["coalesced_batches"] == len(coalesced)
+    assert snap["coalesced_requests"] == sum(t[4]["requests"] for t in coalesced)
+    fams = {f.name: f for f in srv.metrics.telemetry_families("0")}
+    for name in ("dispatches", "dispatched_rows", "queued_seconds"):
+        assert f"paddle_tpu_serving_{name}_total" in fams
