@@ -153,13 +153,12 @@ def enable_compile_cache() -> str:
     compilation_cache.reset_cache()
     return d
 define_flag("flash_block_q", 0,
-            "flash-attention q-block rows; 0 = kernel default "
-            "(ops/flash_attention.DEFAULT_BLOCK_Q). Env "
-            "PDTPU_FLASH_BLOCK_Q lets an on-chip sweep winner "
-            "(tools/flash_microbench.py) apply without a code edit")
+            "flash-attention q rows a grid step brings in; 0 = chosen "
+            "from the call's shape (ops/flash_attention.plan_blocks). "
+            "Env PDTPU_FLASH_BLOCK_Q")
 define_flag("flash_block_k", 0,
-            "flash-attention k-block rows; 0 = kernel default "
-            "(see flash_block_q)")
+            "flash-attention k rows a grid step brings in; 0 = chosen "
+            "from the call's shape (see flash_block_q)")
 
 
 def default_rng_impl() -> str:

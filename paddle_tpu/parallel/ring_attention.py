@@ -334,8 +334,8 @@ def ring_attention(
     Batch may additionally be sharded over ``batch_axes``; heads stay
     unsharded here (combine with TP by sharding h outside via shard_map
     composition). ``block_q``/``block_k`` default through the same
-    flag resolution as :func:`flash_attention` (flash_block_q/_k), so
-    a tuned block shape reaches the ring schedules too.
+    flag resolution as :func:`flash_attention` (flash_block_q/_k), and
+    unset they leave each shard's kernels to the flash plan.
 
     ``schedule``: "auto" picks the load-balanced "zigzag" for causal
     attention (falling back to "ring" when s is not divisible by 2n) and

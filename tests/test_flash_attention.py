@@ -316,23 +316,27 @@ def test_bf16_kernel_close_to_f32_reference():
 
 
 def test_block_shape_flags_resolve():
-    """block_q/block_k=None resolve the flash_block_* config flags (a
-    microbench sweep winner applies via PDTPU_FLASH_BLOCK_* without a
-    code edit); 0 means the chip-tuned defaults; explicit args always
-    win. Asserts the RESOLVED values (output is block-size-invariant,
-    so numerics alone cannot catch the flags being ignored)."""
+    """block_q/block_k=None resolve the flash_block_* config flags
+    (PDTPU_FLASH_BLOCK_*); unset flags (0) resolve to None, which leaves
+    the blocks to the plan; explicit args always win. Asserts the
+    RESOLVED values and the plan they give (output is block-size-
+    invariant, so numerics alone cannot catch the flags being ignored)."""
     from paddle_tpu.core.config import get_flag, set_flag
     from paddle_tpu.core.errors import EnforceError
 
-    assert fa.resolve_block_shapes(None, None) == (fa.DEFAULT_BLOCK_Q,
-                                                   fa.DEFAULT_BLOCK_K)
-    assert fa.resolve_block_shapes(256, None) == (256, fa.DEFAULT_BLOCK_K)
+    assert fa.resolve_block_shapes(None, None) == (None, None)
+    assert fa.resolve_block_shapes(256, None) == (256, None)
     old_q, old_k = get_flag("flash_block_q"), get_flag("flash_block_k")
     try:
         set_flag("flash_block_q", 64)
         set_flag("flash_block_k", 64)
         assert fa.resolve_block_shapes(None, None) == (64, 64)
         assert fa.resolve_block_shapes(128, 128) == (128, 128)  # args win
+        # the plan takes explicit blocks as the DMA block, and the
+        # compute tile follows them
+        plan = fa.plan_blocks(128, 128, 32, block_q=64, block_k=64)
+        assert (plan.block_q, plan.block_k, plan.tile_q, plan.tile_k) == (
+            64, 64, 64, 64)
         # a typo'd value fails loudly, naming the flag
         set_flag("flash_block_k", 100)
         with pytest.raises(EnforceError, match="flash_block_k"):
@@ -346,6 +350,109 @@ def test_block_shape_flags_resolve():
     finally:
         set_flag("flash_block_q", old_q)
         set_flag("flash_block_k", old_k)
+
+
+# -- the plan's regimes: blocks chosen from the shape ------------------------
+
+# (sq, sk): two 512-tiles with the triangle skipped; 896 = one tile of
+# queries, two of 448 keys, nothing padded; cross attention with the
+# bottom-right causal offset; a length no tile divides (padded keys are
+# masked by index)
+PLAN_SHAPES = [(1024, 1024), (896, 896), (384, 640), (200, 200)]
+
+
+def _plan_case(sq, sk, mask, seed, dtype=jnp.float32, d=32):
+    q, k, v = (x.astype(dtype) for x in _rand(b=1, h=2, s=sq, sk=sk, d=d,
+                                             seed=seed))
+    kw, ref = {"causal": True}, lambda q, k, v: _ref(q, k, v, causal=True)
+    if mask == "key_bias":
+        bias = jnp.asarray(np.where(np.arange(sk) < sk - 37, 0.0, -1e30)
+                           [None].astype(np.float32))
+        kw = {"key_bias": bias}
+        ref = lambda q, k, v: _ref(q, k, v, key_bias=bias)
+    elif mask == "segment_ids":
+        seg_q = jnp.asarray((np.arange(sq) * 3 // sq)[None])
+        seg_k = jnp.asarray((np.arange(sk) * 3 // sk)[None])
+        kw = {"segment_ids": seg_q, "kv_segment_ids": seg_k}
+        ref = lambda q, k, v: _ref_seg(q, k, v, seg_q, seg_k)
+    return (q, k, v), kw, ref
+
+
+@pytest.mark.parametrize("mask", ["causal", "key_bias", "segment_ids"])
+@pytest.mark.parametrize("sq,sk", PLAN_SHAPES)
+def test_planned_blocks_match_dense_reference(sq, sk, mask):
+    """No blocks given: the plan chooses them. Forward and gradients
+    against the dense f32 reference at this file's tolerances."""
+    (q, k, v), kw, ref = _plan_case(sq, sk, mask, seed=sq + sk)
+    np.testing.assert_allclose(np.asarray(fa.flash_attention(q, k, v, **kw)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    gf = jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, **kw) ** 2), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
+                                   rtol=2e-3, err_msg=f"d{name} mismatch")
+
+
+def test_planned_blocks_bf16_scale_folded_into_still_operand():
+    """d = 64: the scale 1/8 is a power of two and is folded into the
+    operand a walk holds still (exact in bf16); forward and gradients
+    hold test_bf16_kernel_close_to_f32_reference's limits."""
+    (q, k, v), kw, ref = _plan_case(1024, 1024, "causal", seed=11, d=64)
+    assert fa.plan_blocks(1024, 1024, 64, causal=True).fold_scale
+    assert not fa.plan_blocks(1024, 1024, 32, causal=True).fold_scale
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    np.testing.assert_allclose(
+        np.asarray(fa.flash_attention(qb, kb, vb, **kw), np.float32),
+        np.asarray(ref(q, k, v)), rtol=0.05, atol=0.05)
+    gf = jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, **kw) ** 2), (0, 1, 2))(qb, kb, vb)
+    gr = jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v) ** 2),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=0.1, atol=0.1)
+
+
+# name -> (bh, sq, d): what one chip's kernels see in the benchmark's cells
+CELL_SHAPES = {"gpt2m_train": (512, 1024, 64),
+               "gpt2l_train_shard": (160, 1024, 64),
+               "gpt2m_prefill": (256, 896, 64)}
+
+
+@pytest.mark.parametrize("name", CELL_SHAPES)
+def test_plan_for_cell_shapes(name):
+    """The plan for each cell shape: the causal triangle is skipped where
+    the sequence holds more than one tile (at 512-row tiles 3 of 4; the
+    chip puts 256-row tiles, 10 of 16, 1.4x slower: PERF.md), 896 is not
+    padded on either axis, a grid step holds whole heads and the walk's
+    share is what the flash.plan span reports."""
+    from paddle_tpu.core import profiler
+
+    bh, s, d = CELL_SHAPES[name]
+    plan = fa.plan_blocks(s, s, d, jnp.bfloat16, causal=True, bh=bh)
+    assert (plan.sq_p, plan.sk_p) == (s, s)             # no padding
+    assert plan.block_q == s and plan.block_k == s      # K/V resident
+    assert plan.block_q % plan.tile_q == 0 and plan.block_k % plan.tile_k == 0
+    assert bh % plan.heads == 0
+    share = plan.tiles_run / plan.tiles_all
+    if s == 1024:
+        assert (plan.tile_q, plan.tile_k) == (512, 512)
+        assert share == 0.75
+    else:
+        assert (plan.tile_q, plan.tile_k) == (896, 448)  # 896: one q tile
+        assert share == 1.0
+    # every attention traced leaves its plan in the program's span ring
+    since = profiler.time.time_ns()
+    q = jnp.zeros((1, 2, s, d), jnp.bfloat16)
+    jax.eval_shape(lambda q: fa.flash_attention(q, q, q, causal=True), q)
+    spans = [sp for sp in profiler.spans(since) if sp[0] == "flash.plan"]
+    assert spans, "no flash.plan span recorded at trace time"
+    ids = spans[-1][4]
+    assert (ids["sq"], ids["sk"], ids["d"]) == (s, s, d)
+    assert (ids["tile_q"], ids["tile_k"]) == (plan.tile_q, plan.tile_k)
+    assert ids["tiles_run"] / ids["tiles_all"] == share
 
 
 def test_causal_multiblock_interior_tiles():

@@ -4,7 +4,8 @@ attached (the `on-chip-measurement` guide, section 2, third rehearsal).
 Interpret mode — what every other flash test runs on the CPU — checks
 none of what the chip's compiler refuses: block shapes that break the
 (8, 128) tiling rule, kernels that need more than the 16 MB of scoped
-VMEM. These cases are the shapes the zoo trains and the long-context
+VMEM. These cases are the shapes the zoo trains, the shapes the
+benchmark's cells run (one chip's share of them) and the long-context
 shapes, forward and backward. A compile that passes is not a chip run;
 ``chip_smoke.py`` is.
 """
@@ -51,9 +52,8 @@ def _ring_backward(q, k, v, out, lse, g, delta):
     # the call parallel/ring_attention.py makes on every ring step: the
     # shard-invariant delta computed once outside and passed in (here
     # the zigzag schedule's half-shard of queries against a whole shard)
-    bq, bk = fa.resolve_block_shapes(None, None)
     return fa._flash_bwd(q, k, v, None, None, None, False, out, lse, g,
-                         bq, bk, interpret=False, delta=delta)
+                         None, None, interpret=False, delta=delta)
 
 
 # name -> (fn, (b, h, s, d), per-row mask operand or None)
@@ -65,10 +65,18 @@ SHAPES = {
                                (8, 12, 1024, 64), jnp.int32),
     "long_4k_d128": (_attention(True), (2, 8, 4096, 128), None),
     "long_32k": (_attention(True), (1, 8, 32768, 64), None),
+    # the benchmark's cells: gpt2m-train-s1024, a dp2 x tp2 shard of
+    # gpt2l-train-dp2tp2, and the serve cells' prefill (forward only)
+    "gpt2m_train": (_attention(True), (32, 16, 1024, 64), None),
+    "gpt2l_train_shard": (_attention(True), (16, 10, 1024, 64), None),
+    "gpt2m_prefill": (_attention(True), (16, 16, 896, 64), None),
 }
+FORWARD_ONLY = ("gpt2m_prefill",)
 # Every shape's gradient (which compiles its forward kernel too), and
-# the forward alone once: the tier is close to its time limit.
-CASES = [("gpt2_small", False)] + [(name, True) for name in SHAPES]
+# the forward alone where that is what runs: the tier is close to its
+# time limit.
+CASES = [("gpt2_small", False)] + [(name, name not in FORWARD_ONLY)
+                                   for name in SHAPES]
 
 
 @pytest.mark.parametrize("name,grad", CASES,

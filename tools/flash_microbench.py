@@ -1,194 +1,208 @@
-"""Flash-attention kernel microbench + block-shape sweep (round-4
-verdict #2: re-measure post-dtype-pins, then retune; target >=40% MFU
-at 32k bf16 — kernel ceiling was 33/42 TFLOP/s fwd/bwd pre-pins).
+"""Flash-attention kernel microbench: device time of each of the three
+kernels at the shapes the benchmark's cells run, over a sweep of the
+plan's compute tiles, beside JAX's own Pallas kernel as the yardstick.
 
-    python tools/flash_microbench.py                    # default sweep
-    python tools/flash_microbench.py --seq 32768 --sweep 1024x1024,512x2048
+    chiprun -- python3 tools/flash_microbench.py
+    chiprun -- python3 tools/flash_microbench.py --tiles plan,128,256
+    chiprun -- python3 tools/flash_microbench.py --shapes long_4k_d128 --reference 0
 
-Times the repo kernel (ops/flash_attention.py) fwd and fwd+bwd at the
-flagship long-context shape over a grid of (block_q, block_k), plus —
-when the jax pallas reference kernel is importable — the same shape
-through jax.experimental.pallas.ops.tpu.flash_attention as an
-independent ceiling probe (comparison only; nothing is vendored).
-Appends one JSON line per measurement to profiles/flash_microbench.jsonl
-so partial sweeps still land. One process: it owns the chip for the
-whole sweep and starts no child. MFU is against the measured-matmul peak (core.flops), matching
-bench.py's accounting.
+For every shape and every largest compute tile of ``--tiles`` (``plan``: the
+``TILE`` that ``ops/flash_attention.plan_blocks`` uses itself) it runs forward +
+backward (forward alone for the prefill shape) ``--iters`` times under the
+profiler and reads each kernel's device time from the trace, by the
+kernel's name (``flash_fwd`` / ``flash_dq`` / ``flash_dkv``): milliseconds a
+call and TFLOP/s of the matmuls the algorithm needs (2 / 3 / 4 of
+``2 * sq * sk * d`` a head, halved under causal) against the bf16 peak of
+``benchmarks/peaks.json``. At head_dim 64 the MXU's ceiling for these
+kernels is about half the peak: the contraction (q k^T) or the output
+width (p v) fills 64 of its 128 columns. ``--reference 1`` times
+``jax.experimental.pallas.ops.tpu.flash_attention`` the same way over a few
+block sizes and prints its best. One JSON line per measurement goes to
+``--out``. One process: it owns the chip and starts no child; it fails
+where there is no TPU (a CPU time is not a device time).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
+import re
 import sys
-import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from paddle_tpu.core.config import enable_compile_cache
+# name -> ((b, h, s, d), backward too): what one chip's kernels see in
+# gpt2m-train-s1024, gpt2l-train-dp2tp2 (a dp2 x tp2 shard) and the
+# serve cells' prefill; then the long-context shapes of test_tpu_compile
+SHAPES = {
+    "gpt2m_train": ((32, 16, 1024, 64), True),
+    "gpt2l_train_shard": ((16, 10, 1024, 64), True),
+    "gpt2m_prefill": ((16, 16, 896, 64), False),
+    "long_4k_d128": ((2, 8, 4096, 128), True),
+    "long_32k": ((1, 8, 32768, 64), True),
+}
+# matmuls of 2 * sq * sk * d flops a head that each kernel needs
+MATMULS = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}
 
 
-def attn_flops(b, h, sq, sk, d, causal):
-    """MXU flops of one attention fwd: qk^T + pv = 2 * 2*sq*sk*d per
-    (b,h); causal halves the score rectangle."""
-    f = 4.0 * b * h * sq * sk * d
-    return f / 2 if causal else f
+def kernel_flops(kernel, shape, causal=True):
+    b, h, s, d = shape
+    return MATMULS[kernel] * 2.0 * b * h * s * s * d / (2 if causal else 1)
 
 
-def _time(fn, args, iters, jax):
-    # two warmups (compile + first dispatch), then a blocked timing loop
+def traced_kernel_ms(fn, args, iters):
+    """{kernel name: (ms a call, calls)} of the Mosaic kernels ``fn`` runs,
+    in the order they first ran, from a profiler trace of ``iters`` calls
+    after two warm ones."""
+    import jax
+
+    from benchmarks.tracing import Tracer
+
     for _ in range(2):
-        r = fn(*args)
-    jax.block_until_ready(r)
-    t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+    tracer = Tracer(chips=1)
+    tracer.start()
     for _ in range(iters):
-        r = fn(*args)
-    jax.block_until_ready(r)
-    return (time.perf_counter() - t0) / iters
+        out = fn(*args)
+    jax.block_until_ready(out)
+    trace = tracer.stop()
+    by_name = {}
+    for label, _, dur in sorted(trace.ops.get(0, []), key=lambda e: e[1]):
+        if trace.is_kernel(label):
+            # jvp_flash_fwd_.3 [custom-call] -> flash_fwd; other names whole
+            name = re.sub(r"\.\d+$", "", label.split(" ")[0])
+            ours = re.search(r"flash_(fwd|dq|dkv)", name)
+            name = ours.group(0) if ours else name
+            ms, n = by_name.get(name, (0.0, 0))
+            by_name[name] = (ms + dur / 1e6, n + 1)
+    return {k: (ms / n, n) for k, (ms, n) in by_name.items()}
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--batch", type=int, default=1)
-    ap.add_argument("--heads", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=32768)
-    ap.add_argument("--head_dim", type=int, default=64)
-    ap.add_argument("--causal", type=int, default=1)
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="gpt2m_train,gpt2l_train_shard,"
+                                        "gpt2m_prefill")
+    ap.add_argument("--tiles", default="plan",
+                    help="comma list of largest compute tiles (the plan's "
+                         "TILE), 'plan' = its own")
     ap.add_argument("--iters", type=int, default=5)
-    ap.add_argument("--sweep", default="1024x1024,512x1024,1024x512,"
-                                       "512x2048,2048x512,512x512")
-    ap.add_argument("--bwd", type=int, default=1)
     ap.add_argument("--reference", type=int, default=1,
                     help="also time the jax pallas reference kernel")
     ap.add_argument("--out", default=os.path.join(
-        ROOT, "profiles", "flash_microbench.jsonl"))
+        ROOT, "chiprun_out", "flash_microbench.jsonl"))
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from paddle_tpu.core.config import enable_compile_cache
+    from paddle_tpu.ops import flash_attention as fa
+
     enable_compile_cache()
-
-    from paddle_tpu.core import flops as F
-    from paddle_tpu.ops.flash_attention import flash_attention
-
     dev = jax.devices()[0]
-    on_cpu = dev.platform == "cpu"
-    peak, peak_src = F.device_peak_flops(dev)
-    b, h, s, d = args.batch, args.heads, args.seq, args.head_dim
-    causal = bool(args.causal)
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
-    fwd_f = attn_flops(b, h, s, s, d, causal)
-    # bwd: dq(qk^T+dsk) + dkv(p^T g + g v^T + ds^T q) ~= 2.5x fwd MXU work
-    bwd_f = fwd_f * 2.5
-
-    outdir = os.path.dirname(args.out)
-    if outdir:
-        os.makedirs(outdir, exist_ok=True)
-    shape_key = {"b": b, "h": h, "seq": s, "d": d, "causal": causal}
-    # resume: a killed sweep must not
-    # re-measure what already landed — prior good rows for this exact
-    # shape are skipped so retries spend the window on the tail
-    done = set()
-    if os.path.exists(args.out):
-        with open(args.out) as f:
-            for line in f:
-                try:
-                    r = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if r.get("shape") == shape_key and "error" not in r:
-                    done.add((r.get("kernel"), r.get("pass"),
-                              r.get("block_q"), r.get("block_k")))
-    rows = []
+    if dev.platform != "tpu":
+        sys.exit(f"flash_microbench: no TPU here ({dev.platform}): a time "
+                 f"from this device is not a device time")
+    with open(os.path.join(ROOT, "benchmarks", "peaks.json")) as f:
+        peak = json.load(f)[dev.device_kind]["bf16_flops_per_s"]
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
 
     def record(row):
-        row.update({"device": getattr(dev, "device_kind", str(dev)),
-                    "peak_flops": peak, "peak_source": peak_src,
-                    "shape": {"b": b, "h": h, "seq": s, "d": d,
-                              "causal": causal},
-                    "ts": time.time()})
-        rows.append(row)
+        row["device"] = dev.device_kind
         with open(args.out, "a") as f:
             f.write(json.dumps(row) + "\n")
-        print(json.dumps(row))
+        print(json.dumps(row), flush=True)
 
-    for spec in args.sweep.split(","):
-        bq, bk = (int(x) for x in spec.strip().split("x"))
+    def rates(shape, ms_by_kernel, names):
+        """ms and TFLOP/s per kernel; ``names`` maps the trace's kernel
+        names onto flash_fwd / flash_dq / flash_dkv."""
+        out = {}
+        for traced, (ms, calls) in ms_by_kernel.items():
+            kernel = names(traced)
+            tf = kernel_flops(kernel, shape) / (ms / 1e3) / 1e12
+            out[kernel] = {"ms": round(ms, 4), "calls": calls,
+                           "tflops": round(tf, 2),
+                           "of_peak": round(tf * 1e12 / peak, 4)}
+        return out
 
-        @jax.jit
-        def fwd(q, k, v, bq=bq, bk=bk):
-            return flash_attention(q, k, v, causal=causal,
-                                   block_q=bq, block_k=bk)
+    def step_fn(attn, grad):
+        if not grad:
+            return jax.jit(attn)
+        return jax.jit(jax.grad(
+            lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))
 
-        if ("repo", "fwd", bq, bk) in done:
-            print(f"# skip fwd {bq}x{bk} (already recorded)")
-        else:
+    plan_tile = fa.TILE
+    best = {}
+    for name in args.shapes.split(","):
+        shape, grad = SHAPES[name]
+        b, h, s, d = shape
+        rng = np.random.RandomState(0)
+        qkv = [jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(3)]
+        for spec in args.tiles.split(","):
+            fa.TILE = plan_tile if spec == "plan" else int(spec)
+            plan = fa.plan_blocks(s, s, d, jnp.bfloat16, True, bh=b * h)
+            row = {"kernel": "repo", "shape": name, "tiles": spec,
+                   "plan": plan._asdict()}
             try:
-                dt = _time(fwd, (q, k, v), args.iters, jax)
-                record({"kernel": "repo", "pass": "fwd", "block_q": bq,
-                        "block_k": bk, "ms": round(dt * 1e3, 3),
-                        "tflops": round(fwd_f / dt / 1e12, 2),
-                        "mfu": round(fwd_f / dt / peak, 4)})
-            except Exception as e:
-                record({"kernel": "repo", "pass": "fwd", "block_q": bq,
-                        "block_k": bk,
-                        "error": f"{type(e).__name__}: {e}"[:200]})
+                fn = step_fn(lambda q, k, v: fa.flash_attention(
+                    q, k, v, causal=True), grad)
+                row["kernels"] = rates(shape, traced_kernel_ms(
+                    fn, qkv, args.iters), lambda n: n)
+                row["ms_all"] = round(sum(
+                    v["ms"] for v in row["kernels"].values()), 4)
+                if row["ms_all"] < best.get((name, "repo"),
+                                            (math.inf,))[0]:
+                    best[name, "repo"] = (row["ms_all"], spec)
+            except Exception as e:  # a tile the compiler refuses
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            record(row)
+        fa.TILE = plan_tile
+
+        if not args.reference:
+            continue
+        from jax.experimental.pallas.ops.tpu import flash_attention as jfa
+
+        # its pallas_calls carry no name of ours: the kernels are told
+        # apart by the order they first run (forward, then dk/dv, then dq)
+        ref_order = ("flash_fwd", "flash_dkv", "flash_dq")
+        for bq, bkm, bk in ((512, 512, 512), (512, 1024, 512),
+                            (1024, 1024, 512), (256, 512, 256),
+                            (512, 512, 128), (128, 128, 128)):
+            if s % bq or s % bkm:
                 continue
-        if args.bwd and ("repo", "fwd+bwd", bq, bk) in done:
-            print(f"# skip fwd+bwd {bq}x{bk} (already recorded)")
-        elif args.bwd:
-            @jax.jit
-            def both(q, k, v, bq=bq, bk=bk):
-                def loss(q, k, v):
-                    return flash_attention(
-                        q, k, v, causal=causal, block_q=bq,
-                        block_k=bk).astype(jnp.float32).sum()
-                return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
+            sizes = jfa.BlockSizes(
+                block_q=bq, block_k_major=bkm, block_k=bk, block_b=1,
+                block_q_major_dkv=bq, block_k_major_dkv=bkm, block_k_dkv=bk,
+                block_q_dkv=bq, block_k_major_dq=bkm, block_k_dq=bk,
+                block_q_dq=bq)
+            row = {"kernel": "jax_reference", "shape": name,
+                   "blocks": [bq, bkm, bk]}
             try:
-                dt = _time(both, (q, k, v), max(2, args.iters // 2), jax)
-                record({"kernel": "repo", "pass": "fwd+bwd", "block_q": bq,
-                        "block_k": bk, "ms": round(dt * 1e3, 3),
-                        "tflops": round((fwd_f + bwd_f) / dt / 1e12, 2),
-                        "mfu": round((fwd_f + bwd_f) / dt / peak, 4)})
+                fn = step_fn(lambda q, k, v: jfa.flash_attention(
+                    q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d),
+                    block_sizes=sizes), grad)
+                by_kernel = traced_kernel_ms(fn, qkv, args.iters)
+                row["traced_names"] = list(by_kernel)
+                as_ours = dict(zip(by_kernel, ref_order))
+                row["kernels"] = rates(shape, by_kernel, as_ours.get)
+                row["ms_all"] = round(sum(
+                    v["ms"] for v in row["kernels"].values()), 4)
+                if row["kernels"] and row["ms_all"] < best.get(
+                        (name, "jax_reference"), (math.inf,))[0]:
+                    best[name, "jax_reference"] = (row["ms_all"],
+                                                   f"{bq}/{bkm}/{bk}")
             except Exception as e:
-                record({"kernel": "repo", "pass": "fwd+bwd", "block_q": bq,
-                        "block_k": bk,
-                        "error": f"{type(e).__name__}: {e}"[:200]})
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            record(row)
 
-    if args.reference and not on_cpu and \
-            ("jax_reference", "fwd", None, None) not in done:
-        # independent ceiling probe: the public jax pallas TPU kernel
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention as jref)
-
-            @jax.jit
-            def ref_fwd(q, k, v):
-                return jref(q, k, v, causal=causal)
-
-            dt = _time(ref_fwd, (q, k, v), args.iters, jax)
-            record({"kernel": "jax_reference", "pass": "fwd",
-                    "ms": round(dt * 1e3, 3),
-                    "tflops": round(fwd_f / dt / 1e12, 2),
-                    "mfu": round(fwd_f / dt / peak, 4)})
-        except Exception as e:
-            record({"kernel": "jax_reference", "pass": "fwd",
-                    "error": f"{type(e).__name__}: {e}"[:200]})
-
-    good = [r for r in rows if r.get("pass") == "fwd" and "mfu" in r
-            and r["kernel"] == "repo"]
-    if good:
-        best = max(good, key=lambda r: r["mfu"])
-        print(f"# best fwd: {best['block_q']}x{best['block_k']} "
-              f"{best['tflops']} TFLOP/s ({best['mfu']:.1%} MFU)")
+    for (name, kernel), (ms, spec) in sorted(best.items()):
+        print(f"# best {kernel:<14} {name:<18} {ms:8.3f} ms all kernels "
+              f"at {spec}")
     return 0
 
 
