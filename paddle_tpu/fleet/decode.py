@@ -12,6 +12,14 @@ pad rows converts wasted cache-read bandwidth directly into served
 tokens. Rows are independent through prefill and decode (per-row
 attention, per-row argmax), so a coalesced request's token ids equal
 its sequential pad-alone decode — pinned in ``tests/test_fleet.py``.
+
+The cache lives inside the exported program and never crosses its
+boundary: one lane-dense ``[rows, T, heads*head_dim]`` slab for k and
+one for v a layer (int8: the same, with scales ``[rows, T, heads]``),
+rows leading and the model width minor, so the TPU's (8, 128) tiles
+hold no padding and a decode step reads each slab once, as stored
+(``layers/stacked.py``; a ``[rows, h, T, 64]`` cache was held and read
+at twice its size).
 """
 
 from __future__ import annotations

@@ -203,9 +203,10 @@ def decoder_stack_params(num_layers: int, d_model: int, d_inner: int,
 def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
                     sp_cfg=None, dropout_rate: float = 0.0):
     q, k, v = _attn_qkv(x, p, num_heads)
-    return _attn_out(x, p, _sdpa(q, k, v, key_bias, causal, use_flash, sp_cfg,
-                                 dropout_rate=dropout_rate),
-                     tp_axis, dropout_rate=dropout_rate)
+    o = _sdpa(q, k, v, key_bias, causal, use_flash, sp_cfg,
+              dropout_rate=dropout_rate)
+    return _attn_out(x, p, _merge_heads(o), tp_axis,
+                     dropout_rate=dropout_rate)
 
 
 @jax.named_scope("ffn")
@@ -280,18 +281,36 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
 
 
 # -- incremental decoding (KV cache over stacked params) ---------------------
+#
+# The cache of one layer is LANE-DENSE: ``[rows, T, h*hd]``, every head's
+# vector side by side in the minor dimension, the form a position's k and v
+# have when they leave the fused projection. The TPU tiles an array's two
+# minor dimensions (8, 128); a ``[rows, h, T, hd]`` cache with hd = 64
+# fills 64 lanes of every 128 and is stored, and read each step, at twice
+# its size (PERF.md, PR 28). ``h*hd`` is the model width, dense for every
+# head_dim. Rows lead, as ``beam_search._gather_beams`` needs of a state
+# leaf. The one-row attention below reads a slab as stored: nothing in the
+# decode loop reshapes a slab's minor dimension, which the compiler would
+# answer with a relayout of the whole slab.
+
+
+def _qkv(x, p):
+    """LayerNorm and the fused projection: ``[b, s, 3, h*hd]``, q, k and
+    v on axis 2, each with its heads side by side in the last."""
+    h = _ln(x, p["ln1/scale"], p["ln1/bias"])
+    h, w = cast_compute(h, p["qkv/w"])
+    return jnp.einsum("bsd,dke->bske", h, w) + p["qkv/b"].astype(h.dtype)
 
 
 def _attn_qkv(x, p, num_heads):
     head_dim = x.shape[-1] // num_heads
-    h = _ln(x, p["ln1/scale"], p["ln1/bias"])
-    h, w = cast_compute(h, p["qkv/w"])
-    qkv = jnp.einsum("bsd,dke->bske", h, w) + p["qkv/b"].astype(h.dtype)
+    qkv = _qkv(x, p)
     return tuple(_split_heads(qkv[:, :, i], head_dim) for i in range(3))
 
 
 def _attn_out(x, p, o, tp_axis=None, dropout_rate: float = 0.0):
-    o, ow = cast_compute(_merge_heads(o), p["out/w"])
+    """Output projection and residual; ``o`` is ``[b, s, h*hd]``."""
+    o, ow = cast_compute(o, p["out/w"])
     o = jnp.matmul(o, ow)
     if tp_axis:
         o = jax.lax.psum(o, tp_axis)
@@ -299,77 +318,103 @@ def _attn_out(x, p, o, tp_axis=None, dropout_rate: float = 0.0):
 
 
 def prefill_block(x, p, num_heads: int, use_flash: bool = False):
-    """Causal block that also returns its (k, v) for cache seeding —
-    the stacked-layer analog of the transformer decoder's cache path
-    (models/transformer.py make_decoder)."""
+    """Causal block that also returns its (k, v) for cache seeding, each
+    ``[b, s, h*hd]``: the cache's own layout, so seeding it transposes
+    nothing back (the stacked-layer analog of the transformer decoder's
+    cache path, models/transformer.py make_decoder)."""
+    head_dim = x.shape[-1] // num_heads
     with jax.named_scope("attn"):
-        q, k, v = _attn_qkv(x, p, num_heads)
-        x = _attn_out(x, p, _sdpa(q, k, v, None, True, use_flash))
-    return _ffn(x, p, None), (k, v)
+        qkv = _qkv(x, p)
+        q, k, v = (_split_heads(qkv[:, :, i], head_dim) for i in range(3))
+        x = _attn_out(x, p, _merge_heads(_sdpa(q, k, v, None, True,
+                                               use_flash)))
+    return _ffn(x, p, None), (qkv[:, :, 1], qkv[:, :, 2])
 
 
-def quantize_kv(x):
-    """Symmetric per-vector int8 quantization of a cache entry over
-    the head_dim axis. One quantizer for the whole repo: delegates to
+def quantize_kv(x, num_heads: int):
+    """Symmetric int8 quantization of cache entries ``[..., h*hd]``, one
+    scale for each head's vector: int8 ``[..., h*hd]`` and float32 scales
+    ``[..., h]``. One quantizer for the whole repo: delegates to
     quantize._quant_dynamic and converts its absmax scale convention
     (dequant = q/qmax·scale) to the multiply-direct one the decode
     matmuls factor out (dequant = q·scale), so the two can never
-    drift. Scale shape [..., 1] float32; zero vectors dequantize to
-    exact 0."""
+    drift. Zero vectors dequantize to exact 0."""
     from ..quantize import _quant_dynamic
 
-    q, scale = _quant_dynamic(x, axes=(-1,))
-    return q, scale / 127.0
+    heads = x.reshape(x.shape[:-1] + (num_heads, x.shape[-1] // num_heads))
+    q, scale = _quant_dynamic(heads, axes=(-1,))
+    return q.reshape(x.shape), scale[..., 0] / 127.0
+
+
+def _head_blocks(width: int, num_heads: int, dtype):
+    """``[h*hd, h]`` of 0/1: column ``j`` selects head ``j``'s lanes."""
+    lane = jnp.arange(width)[:, None] // (width // num_heads)
+    return (lane == jnp.arange(num_heads)[None, :]).astype(dtype)
+
+
+def _cache_attention(q, k_cache, v_cache, index, num_heads: int,
+                     k_scale=None, v_scale=None):
+    """One query row of every head against a lane-dense cache, read in
+    place. q ``[rows, 1, h*hd]``; caches ``[rows, T, h*hd]``; positions
+    ``<= index`` attended; returns ``[rows, 1, h*hd]``, heads merged.
+
+    Heads are separated on the MXU, not by a reshape: the query row is
+    laid block-diagonally in a ``[h*hd, h]`` operand, so ``cache @ that``
+    is every head's score ``[T, h]`` (float32 accumulation), and
+    ``probs^T @ cache`` gives ``[h, h*hd]`` whose block diagonal is the
+    output. An int8 cache's scales ``[rows, T, h]`` factor out of both
+    matmuls (score[t] ∝ k_scale[t], out ∝ probs∘v_scale), so no
+    dequantized cache is ever materialized."""
+    _, T, width = k_cache.shape
+    blocks = _head_blocks(width, num_heads, q.dtype)
+    q_blocks = q[:, 0, :, None] * blocks                        # [rows, c, h]
+    scale = 1.0 / math.sqrt(width // num_heads)
+    logits = jnp.einsum("rtc,rch->rth", k_cache.astype(q.dtype), q_blocks,
+                        preferred_element_type=jnp.float32) * scale
+    if k_scale is not None:
+        logits = logits * k_scale
+    live = jnp.arange(T)[None, :, None] <= index
+    probs = jax.nn.softmax(jnp.where(live, logits, NEG_INF), axis=1)
+    if v_scale is not None:
+        probs = probs * v_scale
+    o = jnp.einsum("rth,rtc->rhc", probs.astype(q.dtype),
+                   v_cache.astype(q.dtype))
+    return jnp.einsum("rhc,ch->rc", o, blocks)[:, None, :]
+
+
+def _write_row(cache, row, index):
+    return jax.lax.dynamic_update_slice(cache, row.astype(cache.dtype),
+                                        (0, index, 0))
 
 
 def decode_block_q8(x, p, k_q, k_s, v_q, v_s, index, num_heads: int):
-    """decode_block with an int8 KV cache: k_q/v_q int8 [rows, h, T,
-    hd] plus per-position scales k_s/v_s [rows, h, T, 1]. Decode is
-    HBM-bound — the cache read dominates — so halving (vs bf16) or
-    quartering (vs f32) the cache bytes is direct serving throughput.
-    The scales FACTOR OUT of both attention matmuls (score[t] ∝ k_s[t],
-    out ∝ probs∘v_s), so no dequantized cache array is ever
-    materialized: the int8→compute-dtype convert feeds the dot
-    operands directly. Returns (x, k_q, k_s, v_q, v_s)."""
+    """decode_block with an int8 KV cache: k_q/v_q int8 ``[rows, T,
+    h*hd]`` plus one scale a head and position, k_s/v_s ``[rows, T, h]``.
+    Decode is HBM-bound — the cache read dominates — so halving (vs
+    bf16) or quartering (vs f32) the cache bytes is direct serving
+    throughput. Returns (x, k_q, k_s, v_q, v_s)."""
     with jax.named_scope("attn"):
-        q, k1, v1 = _attn_qkv(x, p, num_heads)
-        k1q, k1s = quantize_kv(k1)
-        v1q, v1s = quantize_kv(v1)
-        k_q = jax.lax.dynamic_update_slice(k_q, k1q, (0, 0, index, 0))
-        k_s = jax.lax.dynamic_update_slice(k_s, k1s.astype(k_s.dtype),
-                                           (0, 0, index, 0))
-        v_q = jax.lax.dynamic_update_slice(v_q, v1q, (0, 0, index, 0))
-        v_s = jax.lax.dynamic_update_slice(v_s, v1s.astype(v_s.dtype),
-                                           (0, 0, index, 0))
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_q.astype(q.dtype),
-                            preferred_element_type=jnp.float32)
-        logits = logits * k_s[..., 0][:, :, None, :] * scale
-        pos = jnp.arange(k_q.shape[2])
-        logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        pv = (probs * v_s[..., 0][:, :, None, :]).astype(q.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", pv, v_q.astype(q.dtype))
+        qkv = _qkv(x, p)
+        q, k1, v1 = (qkv[:, :, i] for i in range(3))
+        k1q, k1s = quantize_kv(k1, num_heads)
+        v1q, v1s = quantize_kv(v1, num_heads)
+        k_q, k_s = _write_row(k_q, k1q, index), _write_row(k_s, k1s, index)
+        v_q, v_s = _write_row(v_q, v1q, index), _write_row(v_s, v1s, index)
+        o = _cache_attention(q, k_q, v_q, index, num_heads, k_s, v_s)
         x = _attn_out(x, p, o)
     return _ffn(x, p, None), k_q, k_s, v_q, v_s
 
 
 def decode_block(x, p, k_cache, v_cache, index, num_heads: int):
-    """One-token step: x [rows, 1, d]; caches [rows, h, T, hd]; attends
-    to cache positions <= index. Returns (x, new_k, new_v)."""
+    """One-token step: x ``[rows, 1, d]``; caches ``[rows, T, h*hd]``
+    (lane-dense, see above), written in place at ``index``; attends to
+    cache positions <= index. Returns (x, new_k, new_v)."""
     with jax.named_scope("attn"):
-        q, k1, v1 = _attn_qkv(x, p, num_heads)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k1.astype(k_cache.dtype),
-                                               (0, 0, index, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v1.astype(v_cache.dtype),
-                                               (0, 0, index, 0))
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache,
-                            preferred_element_type=jnp.float32) * scale
-        pos = jnp.arange(k_cache.shape[2])
-        logits = jnp.where(pos[None, None, None, :] <= index, logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", probs, v_cache)
+        qkv = _qkv(x, p)
+        q, k1, v1 = (qkv[:, :, i] for i in range(3))
+        k_cache = _write_row(k_cache, k1, index)
+        v_cache = _write_row(v_cache, v1, index)
+        o = _cache_attention(q, k_cache, v_cache, index, num_heads)
         x = _attn_out(x, p, o)
     return _ffn(x, p, None), k_cache, v_cache
 

@@ -20,6 +20,7 @@ TRAINING PATH:
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -50,11 +51,15 @@ class GPTConfig:
     # same way the unrolled attention layer does)
     dropout: float = 0.0
     dtype: str = "float32"
-    # KV cache storage for the incremental generator: "compute" keeps
-    # the compute dtype; "int8" stores symmetric per-vector int8 with
-    # f32 scales (layers/stacked.quantize_kv) — half the bf16 cache
-    # bytes on the HBM-bound decode read, scales factored out of both
-    # attention matmuls so nothing is dequantized into memory
+    # KV cache storage for the incremental generator, one lane-dense
+    # [rows, T, heads*head_dim] slab for k and one for v a layer (the
+    # minor dimension is the model width, so the TPU's (8, 128) tiles
+    # hold no padding whatever the head_dim): "compute" keeps the
+    # compute dtype; "int8" stores symmetric int8 with one f32 scale a
+    # head and position, [rows, T, heads] (layers/stacked.quantize_kv) —
+    # half the bf16 cache bytes on the HBM-bound decode read, scales
+    # factored out of both attention matmuls so nothing is dequantized
+    # into memory
     kv_cache_dtype: str = "compute"
 
 
@@ -113,6 +118,23 @@ def make_model(cfg: GPTConfig):
         return {"loss": loss, "token_count": token_count}
 
     return gpt
+
+
+def _record_decode_plan(cfg: GPTConfig, state):
+    """One zero-length span in the program's ring for each generator
+    traced: the cache the decode loop carries, as held. ``lane_width`` is
+    the minor dimension of a stored slab; a multiple of 128 means the
+    chip's tiling pads nothing."""
+    from ..core import profiler
+
+    slabs = jax.tree.leaves(state)
+    rows, max_len, lane_width = slabs[0].shape
+    profiler.record_span(
+        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
+        heads=cfg.num_heads, head_dim=cfg.d_model // cfg.num_heads,
+        layers=cfg.num_layers, cache_dtype=str(slabs[0].dtype),
+        lane_width=lane_width,
+        cache_bytes=sum(a.size * a.dtype.itemsize for a in slabs))
 
 
 def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
@@ -177,23 +199,27 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
         rows = b * K
         L = cfg.num_layers
 
-        def grow(a):  # [b, h, p, hd] -> [rows, h, total, hd]
+        def grow(a):  # [b, p, ...] -> [rows, total, ...]
             a = jnp.repeat(a, K, axis=0) if K > 1 else a
-            pad = jnp.zeros(a.shape[:2] + (total - p, a.shape[3]), a.dtype)
-            return jnp.concatenate([a, pad], axis=2)
+            pad = jnp.zeros((rows, total - p) + a.shape[2:], a.dtype)
+            return jnp.concatenate([a, pad], axis=1)
 
-        # caches are PER-LAYER lists of [rows, ...] arrays — beam_search
-        # reorders state leaves whose leading dim is batch*beam, so the
-        # layer axis must NOT lead (the transformer decoder's contract,
-        # layers/beam_search.py _gather_beams)
+        # caches are PER-LAYER lists of lane-dense [rows, total, h*hd]
+        # arrays (layers/stacked.py: heads side by side in the minor
+        # dimension, so the TPU's (8, 128) tiling pads nothing) —
+        # beam_search reorders state leaves whose leading dim is
+        # batch*beam, so the layer axis must NOT lead (the transformer
+        # decoder's contract, layers/beam_search.py _gather_beams)
         enforce(cfg.kv_cache_dtype in ("compute", "int8"),
                 f"kv_cache_dtype={cfg.kv_cache_dtype!r} (compute|int8)")
         int8_kv = cfg.kv_cache_dtype == "int8"
         if int8_kv:
             # quantize the prefix BEFORE growing: padded tail positions
             # get int8 zeros with zero scales (dequantize to exact 0)
-            kq, ksc = zip(*(S.quantize_kv(ks[i]) for i in range(L)))
-            vq, vsc = zip(*(S.quantize_kv(vs[i]) for i in range(L)))
+            kq, ksc = zip(*(S.quantize_kv(ks[i], cfg.num_heads)
+                            for i in range(L)))
+            vq, vsc = zip(*(S.quantize_kv(vs[i], cfg.num_heads)
+                            for i in range(L)))
             state0 = {"kq": [grow(a) for a in kq],
                       "ks": [grow(a) for a in ksc],
                       "vq": [grow(a) for a in vq],
@@ -201,6 +227,7 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
         else:
             state0 = {"k": [grow(ks[i]) for i in range(L)],
                       "v": [grow(vs[i]) for i in range(L)]}
+        _record_decode_plan(cfg, state0)
         state0.update(
             index=jnp.asarray(p, jnp.int32),
             logp0=jnp.repeat(logp0, K, axis=0) if K > 1 else logp0,

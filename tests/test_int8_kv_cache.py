@@ -18,23 +18,31 @@ from paddle_tpu.models import gpt
 
 
 def test_quantize_kv_roundtrip_error_bound():
+    """Cache entries are lane-dense, [..., heads*head_dim]; one scale a
+    head's vector, [..., heads]."""
     rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, 4, 8, 64).astype(np.float32) * 3.0)
-    q, s = S.quantize_kv(x)
-    assert q.dtype == jnp.int8 and s.shape == (2, 4, 8, 1)
-    deq = q.astype(jnp.float32) * s
+    heads, hd = 4, 64
+    x = jnp.asarray(rng.randn(2, 8, heads * hd).astype(np.float32) * 3.0)
+    q, s = S.quantize_kv(x, heads)
+    assert q.dtype == jnp.int8 and q.shape == x.shape
+    assert s.shape == (2, 8, heads) and s.dtype == jnp.float32
+    per_head = x.reshape(2, 8, heads, hd)
+    deq = q.reshape(per_head.shape).astype(jnp.float32) * s[..., None]
     # symmetric int8: error <= scale/2 = max|x|/254 per vector
-    err = np.abs(np.asarray(deq - x))
-    bound = np.asarray(jnp.max(jnp.abs(x), axis=-1, keepdims=True)) / 254 + 1e-6
+    err = np.abs(np.asarray(deq - per_head))
+    bound = np.asarray(jnp.max(jnp.abs(per_head), axis=-1,
+                               keepdims=True)) / 254 + 1e-6
     assert (err <= bound).all()
     # zero vectors dequantize to exactly zero
-    qz, sz = S.quantize_kv(jnp.zeros((1, 1, 1, 8)))
-    assert np.asarray(qz.astype(jnp.float32) * sz).sum() == 0.0
+    qz, sz = S.quantize_kv(jnp.zeros((1, 1, 8)), 2)
+    assert sz.shape == (1, 1, 2)
+    assert np.asarray(qz.astype(jnp.float32)).sum() == 0.0
 
 
 def test_decode_block_q8_close_to_fp():
     """One cached step: the int8-cache block must track the fp block
-    within quantization error (loose block-output tolerance)."""
+    within quantization error (loose block-output tolerance). Caches
+    are [rows, T, heads*head_dim], scales [rows, T, heads]."""
     rng = np.random.RandomState(1)
     d, h, rows, T = 32, 4, 2, 16
     p = {k: jnp.asarray(v) for k, v in {
@@ -52,16 +60,24 @@ def test_decode_block_q8_close_to_fp():
         "ffn_out/b": np.zeros((d,), np.float32),
     }.items()}
     x = jnp.asarray(rng.randn(rows, 1, d).astype(np.float32))
-    hist = jnp.asarray(rng.randn(rows, h, T, d // h).astype(np.float32))
-    vals = jnp.asarray(rng.randn(rows, h, T, d // h).astype(np.float32))
+    hist = jnp.asarray(rng.randn(rows, T, d).astype(np.float32))
+    vals = jnp.asarray(rng.randn(rows, T, d).astype(np.float32))
     idx = jnp.asarray(5, jnp.int32)
 
     o_fp, _, _ = S.decode_block(x, p, hist, vals, idx, h)
-    kq, ks = S.quantize_kv(hist)
-    vq, vs = S.quantize_kv(vals)
-    o_q8, *_ = S.decode_block_q8(x, p, kq, ks, vq, vs, idx, h)
+    kq, ks = S.quantize_kv(hist, h)
+    vq, vs = S.quantize_kv(vals, h)
+    o_q8, kq2, ks2, vq2, vs2 = S.decode_block_q8(x, p, kq, ks, vq, vs, idx, h)
     np.testing.assert_allclose(np.asarray(o_q8), np.asarray(o_fp),
                                atol=0.05, rtol=0.05)
+    assert kq2.shape == vq2.shape == (rows, T, d) and kq2.dtype == jnp.int8
+    assert ks2.shape == vs2.shape == (rows, T, h)
+    # only the row at the index was written
+    keep = np.arange(T) != 5
+    np.testing.assert_array_equal(np.asarray(kq2)[:, keep],
+                                  np.asarray(kq)[:, keep])
+    np.testing.assert_array_equal(np.asarray(vs2)[:, keep],
+                                  np.asarray(vs)[:, keep])
 
 
 @pytest.mark.slow

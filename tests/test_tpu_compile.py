@@ -1,23 +1,31 @@
-"""The flash kernels, compiled for a described TPU v5e with no chip
-attached (the `on-chip-measurement` guide, section 2, third rehearsal).
+"""The flash kernels and the serve cells' generator, compiled for a
+described TPU v5e with no chip attached (the `on-chip-measurement`
+guide, section 2, third rehearsal).
 
 Interpret mode — what every other flash test runs on the CPU — checks
 none of what the chip's compiler refuses: block shapes that break the
 (8, 128) tiling rule, kernels that need more than the 16 MB of scoped
 VMEM. These cases are the shapes the zoo trains, the shapes the
 benchmark's cells run (one chip's share of them) and the long-context
-shapes, forward and backward. A compile that passes is not a chip run;
+shapes, forward and backward. The generator's case reads what only the
+chip's compiler decides about the KV cache: the layout and bytes of the
+slabs the decode loop carries. A compile that passes is not a chip run;
 ``chip_smoke.py`` is.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+import paddle_tpu as pt
+from paddle_tpu.core import config
+from paddle_tpu.models import gpt
 from paddle_tpu.ops import flash_attention as fa
 
 
@@ -107,3 +115,50 @@ def test_ring_backward_with_delta_compiles_for_v5e(chip):
     text = jax.jit(_ring_backward).lower(q, kv, kv, q, row, q,
                                          row).compile().as_text()
     assert text.count("tpu_custom_call") == 2
+
+
+def test_generator_cache_is_lane_dense_for_v5e(chip, monkeypatch):
+    """gpt2-medium's generator at the serve cells' shape (16 rows, prompt
+    896 + 128 new, bfloat16): the decode loop carries its 48 cache slabs
+    as ``bf16[16,1024,1024]`` with the model width minor, which (8, 128)
+    tiles hold without padding (33.5 MB each; ``[rows, h, T, hd]`` with
+    hd = 64 was held at 67 MB), no step copies a slab, and the
+    temporaries stay under 4.2 GB (5.31 GB with the padded cache)."""
+    rows, prompt, new = 16, 896, 128
+    cfg = gpt.base_config(vocab_size=50257, max_len=1024, d_model=1024,
+                          d_inner=4096, num_heads=16, num_layers=24,
+                          use_flash=True, fused_ce=True, dtype="bfloat16")
+    prog = pt.build(gpt.make_generator(cfg, max_new_tokens=new))
+    before = config.get_flag("default_compute_dtype")
+    config.set_flag("default_compute_dtype", "bfloat16")
+    # the flash kernel asks jax.devices() whether to interpret: a described
+    # chip is not attached, so the test steers it (SKILL.md), not an option
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    try:
+        one_row = np.zeros((1, prompt), np.int32)
+        shapes = jax.eval_shape(
+            lambda key: prog.init(key, prompt_ids=one_row)[0],
+            jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+            shapes)
+        ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+        compiled = jax.jit(
+            lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]["ids"]
+        ).lower(params, ids).compile()
+    finally:
+        config.set_flag("default_compute_dtype", before)
+
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.2e9
+    slab = re.escape("bf16[%d,%d,%d]" % (rows, prompt + new, cfg.d_model))
+    step = [ln for ln in compiled.as_text().splitlines()
+            if "decode_step/" in ln]
+    # every in-place write of the step produces a dense slab: the minor
+    # dimension is the last logical one (1024 = 8 x 128 lanes), tiled (8, 128)
+    writes = [ln for ln in step if re.search(
+        r"= %s\{2,1,0:T\(8,128\)\(2,1\)\} (fusion|dynamic-update-slice)\("
+        % slab, ln)]
+    assert len(writes) == 2 * cfg.num_layers, len(writes)
+    moved = [ln for ln in step if re.search(
+        r"= %s\S* (copy|transpose|copy-start)\(" % slab, ln)]
+    assert not moved, moved[0][:300]
