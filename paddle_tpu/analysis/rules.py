@@ -5,7 +5,7 @@ scope, mesh/rules, example arguments) and appends :class:`Finding`s to a
 :class:`LintReport`. Codes are ``family:rule``:
 
 - ``collective:*`` — collective-placement hazards (the unhoisted-accum
-  class of bug pinned by SCALING.md §2): reduction collectives nested in
+  class of bug): reduction collectives nested in
   loop bodies multiply their wire bytes by the trip count.
 - ``dtype:*``      — mixed-precision flow: f32 MXU ops surviving under
   an amp compute dtype, f64 leaks, no-op cast round-trips.
@@ -126,8 +126,8 @@ def check_accum_exchange(strategy, mesh, params, report: LintReport) -> None:
     default GSPMD exchange on a data-parallel mesh rides one full
     gradient all-reduce INSIDE the microbatch scan per iteration (the
     collective is inserted by the SPMD partitioner, so it is invisible
-    to the jaxpr walk — this rule reasons from the config, the way
-    SCALING.md §2 measured it)."""
+    to the jaxpr walk — this rule reasons from the config; the compiled
+    HLO's in-loop all-reduce is pinned by tests/test_collective_report.py)."""
     accum = int(getattr(strategy, "accum_steps", 1) or 1) if strategy else 1
     mode = getattr(strategy, "accum_exchange", "gspmd") if strategy else "gspmd"
     if accum <= 1 or mode != "gspmd" or mesh is None:

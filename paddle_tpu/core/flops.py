@@ -2,8 +2,7 @@
 
 MFU = model FLOPs (the math the model *defines* — excluding remat
 recompute and XLA bookkeeping) / step time / chip peak FLOP/s. This is
-the honest utilization denominator BASELINE.json asks for ("CUDA-parity
-… ≥70% scaling"), replacing throughput-vs-2018-Xeon ratios.
+the utilization denominator, in place of throughput-vs-2018-Xeon ratios.
 
 Conventions (PaLM appendix-B style, Megatron matmul accounting):
 - dense matmul train FLOPs = 6 · (matmul params) · tokens
@@ -22,7 +21,7 @@ no reference counterpart and is TPU-first by design.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 # -- chip peak ---------------------------------------------------------------
 
@@ -37,56 +36,6 @@ _PEAK_BF16 = [
     ("v3", 61.5e12),   # one jax device = one core on v2/v3 (2 cores/chip)
     ("v2", 22.5e12),
 ]
-
-
-def device_peak_flops(device=None, dtype: str = "bfloat16") -> Tuple[float, str]:
-    """(peak FLOP/s, source) for one jax device. Falls back to a measured
-    large-matmul rate when the device kind is unknown (e.g. CPU), so MFU
-    stays meaningful everywhere the bench runs."""
-    import jax
-
-    device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, peak in _PEAK_BF16:
-        if sub in kind:
-            if dtype in ("float32", "f32"):
-                # MXU fp32 runs at 1/~8 of bf16 on recent TPUs; we only
-                # report bf16-denominated MFU, so keep bf16 peak and let
-                # f32 configs show the (real) utilization hit.
-                pass
-            return peak, f"table:{kind}"
-    return measured_matmul_peak(device=device, dtype=dtype), "measured_matmul"
-
-
-def measured_matmul_peak(device=None, dtype: str = "bfloat16", n: Optional[int] = None,
-                         iters: int = 4) -> float:
-    """Achieved FLOP/s of an n×n×n matmul chain — a practical peak proxy
-    on platforms missing from the table."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    device = device or jax.devices()[0]
-    if n is None:  # keep the CPU fallback cheap; accelerators get a real tile
-        n = 1024 if device.platform == "cpu" else 4096
-    dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-    a = jax.device_put(jnp.ones((n, n), dt), device)
-    b = jax.device_put(jnp.ones((n, n), dt), device)
-
-    @jax.jit
-    def chain(a, b):
-        for _ in range(4):
-            a = jnp.matmul(a, b)
-        return a
-
-    chain(a, b).block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = chain(a, b)
-    out.block_until_ready()
-    dtm = time.perf_counter() - t0
-    return 2.0 * n ** 3 * 4 * iters / dtm
 
 
 # -- transformer family ------------------------------------------------------

@@ -645,8 +645,8 @@ class Trainer:
     def _hoisted_accum_axes(self):
         """Validate and resolve DistStrategy.accum_exchange="hoisted":
         the shard_map-local accumulation that exchanges gradients ONCE
-        per optimizer step (the wire lever SCALING.md §2 names as the
-        follow-up to the measured in-loop GSPMD exchange)."""
+        per optimizer step (under GSPMD the exchange rides inside the
+        accumulation loop: tests/test_collective_report.py pins it)."""
         return self._local_exchange_axes("accum_exchange='hoisted'")
 
     def _local_exchange_axes(self, why: str):
@@ -796,7 +796,7 @@ class Trainer:
         """shard_map-local gradient accumulation: each data shard scans
         its accum_steps microbatches with NO cross-shard traffic, then
         the summed gradients are pmean'd ONCE — the hoisted exchange
-        GSPMD will not produce on its own (SCALING.md §2). Params enter
+        GSPMD will not produce on its own. Params enter
         replicated (enforced), the model trace is collective-free per
         shard, float outputs are pmean'd to match the GSPMD path's
         global means.
@@ -1016,8 +1016,9 @@ class Trainer:
                 # gradient accumulation (multi_batch_merge_pass analog):
                 # microbatch over the leading feed axis with lax.scan.
                 # NOTE the grad exchange rides inside this loop under
-                # GSPMD (SCALING.md §2); accum_exchange="hoisted" is
-                # the once-per-step alternative.
+                # GSPMD (tests/test_collective_report.py pins it);
+                # accum_exchange="hoisted" is the once-per-step
+                # alternative.
                 def micro(carry, mb):
                     acc, st = carry
                     (loss, (out, new_st)), grads = jax.value_and_grad(

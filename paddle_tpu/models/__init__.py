@@ -1,5 +1,5 @@
 """Model zoo mirroring the reference's book/benchmark configs
-(BASELINE.json: MNIST MLP, ResNet-50, Transformer-base, DeepFM,
+(MNIST MLP, ResNet-50, Transformer-base, DeepFM,
 BERT-base; plus VGG/AlexNet/GoogLeNet/LSTM from benchmark/fluid/models/
 and the recommender_system / label_semantic_roles book chapters), plus
 the post-reference TPU-first families: GPT (decoder-only LM with

@@ -19,9 +19,9 @@ class DistStrategy:
     # - "gspmd" (default): the model runs under GSPMD inside the
     #   microbatch scan; the partitioner reduces EVERY microbatch's
     #   gradients (it does not hoist the exchange past the accumulator
-    #   — measured, see SCALING.md §2), so accumulation is a memory
-    #   lever only. Fully general (any sharding rules, stateful
-    #   models).
+    #   — pinned by tests/test_collective_report.py), so accumulation
+    #   is a memory lever only. Fully general (any sharding rules,
+    #   stateful models).
     # - "hoisted": the microbatch loop runs shard_map-LOCAL per data
     #   shard and the summed gradients are pmean'd ONCE per optimizer
     #   step — accum_steps becomes a wire lever (the DCN-scaling

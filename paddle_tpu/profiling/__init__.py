@@ -16,9 +16,6 @@ Fusion in XLA", PAPERS.md):
   ``memory:remat-candidate`` findings whose suggested
   ``DistStrategy.remat`` is verified against XLA's ``temp_mb``
   (:func:`advisor.verify_remat`).
-
-Bench train rows record their ``top_fusions`` table so two rounds diff
-to "this fusion got slower" (``tools/profile_diff.py``).
 """
 
 from .advisor import advise, device_hbm_bytes, memory_estimate, verify_remat

@@ -466,8 +466,8 @@ def _xla_cost_totals(compiled) -> Dict[str, Optional[float]]:
 
 
 def unit_row(u: Unit) -> Dict[str, Any]:
-    """JSON-ready rendering of one unit (the bench ``top_fusions``
-    row schema; tools/profile_diff.py matches rows by ``key``)."""
+    """JSON-ready rendering of one unit (a ``top_fusions`` row;
+    ``key`` is stable across compiles of the same program)."""
     return {
         "key": u.key,
         "name": u.name,

@@ -559,8 +559,7 @@ def reshard_restore(checkpoint_dir: str, trainer,
 
     Returns a report dict: ``saved_axes``/``target_axes``,
     ``global_step``, ``bytes_moved`` (checkpoint bytes re-placed) and
-    ``seconds`` (restore wall time) — the ``elastic_reshard`` bench row
-    reads these."""
+    ``seconds`` (restore wall time)."""
     from . import io as _io
     from .analysis import contracts as _contracts
 

@@ -398,8 +398,8 @@ def lint_rules(specs: List[Dict[str, Any]],
 
 
 # -- the preset pack ----------------------------------------------------------
-# Derived from the MIGRATION.md metric name table: the conditions two
-# bench rounds and five drills said should page, as data. Ships
+# Derived from the MIGRATION.md metric name table: the conditions five
+# drills said should page, as data. Ships
 # through tools/alert_check.py in CI (tier-1).
 
 PRESET_PACK: List[Dict[str, Any]] = [
@@ -407,8 +407,8 @@ PRESET_PACK: List[Dict[str, Any]] = [
      "expr": "rate(paddle_tpu_feeder_consumer_starved_seconds_total[30s])"
              " > 0.5 for 30s",
      "annotations": {"summary": "training loop starved of input >50% of "
-                                "wall time (the BENCH_r05 degraded-link "
-                                "signature)"}},
+                                "wall time (the signature of a slow "
+                                "host-to-device link)"}},
     {"name": "serving_shed_rate", "severity": "warn",
      "expr": "rate(paddle_tpu_serving_rejected_total[30s]) > 1 for 30s",
      "annotations": {"summary": "serving front door shedding >1 req/s"}},

@@ -80,28 +80,3 @@ def rng():
 def _seed_numpy():
     np.random.seed(0)
 
-
-def pytest_terminal_summary(terminalreporter, exitstatus, config):
-    """Persist per-test call durations for the smoke-budget checker
-    (tools/smoke_budget.py; VERDICT r4 #9: the tier keeps absorbing new
-    tests — without a CI-visible timing record it drifts back past the
-    10-minute goal). Only full-ish runs are recorded so a single-test
-    debug invocation never overwrites the tier's record."""
-    import json
-    import os
-
-    stats = terminalreporter.stats
-    calls = [r for r in stats.get("passed", []) + stats.get("failed", [])
-             if getattr(r, "when", "call") == "call"]
-    if len(calls) < 100:
-        return
-    rec = {
-        "total_s": round(sum(r.duration for r in calls), 1),
-        "num_tests": len(calls),
-        "markexpr": str(config.option.markexpr or ""),
-        "durations": {r.nodeid: round(r.duration, 2)
-                      for r in sorted(calls, key=lambda r: -r.duration)[:60]},
-    }
-    path = os.path.join(os.path.dirname(__file__), ".last_run_durations.json")
-    with open(path, "w") as f:
-        json.dump(rec, f, indent=1)

@@ -158,12 +158,7 @@ def _zoo():
 _ZOO = _zoo()
 
 
-_SLOW = {"resnet"}  # ~20s compile; the rest stay in the smoke tier
-
-
-@pytest.mark.parametrize(
-    "family", [pytest.param(f, marks=pytest.mark.slow) if f in _SLOW else f
-               for f in sorted(_ZOO)])
+@pytest.mark.parametrize("family", sorted(_ZOO))
 def test_one_train_step_under_bf16_amp(family):
     with amp_guard("bfloat16"):
         model_fn, feed = _ZOO[family]()

@@ -42,8 +42,9 @@ def dp_mesh():
 
 
 def _unhoisted_program(mesh):
-    """psum INSIDE the microbatch scan: the hazard class SCALING.md §2
-    measured (per-microbatch gradient exchange)."""
+    """psum INSIDE the microbatch scan: the hazard class of the
+    per-microbatch gradient exchange
+    (test_collective_report.test_accum_grad_exchange_is_per_microbatch)."""
     def fn(x):
         w = create_parameter((4, 4), name="w")
 
@@ -492,7 +493,7 @@ def test_trainer_lint_off_and_bad_value():
 
 
 def test_eval_enforces_pp_microbatch_divisibility():
-    """ADVICE r5 executor.py:549: interleaved-pp eval runs the training
+    """Round-5 advisor finding: interleaved-pp eval runs the training
     schedule; the enforce must name pp_microbatches."""
     tr = pt.Trainer(pt.build(_mlp), opt.Adam(1e-3),
                     strategy=DistStrategy(pp_microbatches=3, pp_interleave=2))
@@ -506,7 +507,7 @@ def test_eval_enforces_pp_microbatch_divisibility():
 
 
 def test_apply_row_perm_walks_all_name_keyed_state():
-    """ADVICE r5 executor.py:167: per-param opt state OUTSIDE 'accums'
+    """Round-5 advisor finding: per-param opt state OUTSIDE 'accums'
     (but keyed by param name per the Optimizer contract) must round-trip
     through the interleaved layout too."""
     tr = pt.Trainer(pt.build(_mlp), opt.Adam(1e-3))

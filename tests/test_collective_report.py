@@ -95,7 +95,6 @@ def test_collective_report_dp_sees_grad_allreduce():
     assert rep["mesh"] == {"dp": 8}
 
 
-@pytest.mark.slow
 def test_collective_report_interleave_traffic_tradeoff():
     """The interleaved pipeline's documented cost is V× more
     collective-permute traffic: M·V+P-1 ticks of ring hops vs M+P-1.
@@ -155,16 +154,16 @@ def test_collective_report_3d_mesh_shows_sharding_collectives():
     assert rep_3d["est_wire_mb_per_device"] > 0
 
 
-@pytest.mark.slow
 def test_accum_grad_exchange_is_per_microbatch():
-    """Pin the measured reality SCALING.md §2 is built on: under GSPMD
+    """Pin what the comments in executor.py, parallel/strategy.py and
+    analysis/rules.py cite: under GSPMD
     the dp grad all-reduce sits INSIDE the accum_steps scan body — the
     partitioner reduces every microbatch's gradients instead of
     hoisting one exchange past the accumulator, so accumulation is a
     memory lever, NOT a wire lever. The day this fails is the day the
     exchange got hoisted (partitioner upgrade or the shard_map
-    follow-up): celebrate, then upgrade SCALING.md's projection and
-    invert this assertion."""
+    follow-up): celebrate, then correct those comments and invert this
+    assertion."""
     import re
 
     from paddle_tpu.parallel import DistStrategy
@@ -197,5 +196,5 @@ def test_accum_grad_exchange_is_per_microbatch():
     assert in_body_ar_bytes > 0.5 * param_bytes, (
         f"only {in_body_ar_bytes:.0f}B of all-reduce inside loop bodies "
         f"vs {param_bytes:.0f}B of params: the grad exchange got hoisted "
-        "— update SCALING.md §2 (accumulation became a wire lever) and "
-        "invert this test")
+        "— accumulation became a wire lever: correct the comments that "
+        "cite this test and invert it")

@@ -13,7 +13,7 @@ import paddle_tpu as pt
 from paddle_tpu import sparse
 
 
-VOCAB = 1_048_576  # 2^20 rows per field-group; bench.py runs the 10.4M config
+VOCAB = 1_048_576  # 2^20 rows per field-group
 DIM = 16
 
 

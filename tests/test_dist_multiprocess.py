@@ -93,9 +93,8 @@ def test_two_process_hoisted_accum_matches_single_process():
     """Cross-PROCESS hoisted accumulation: 2 processes × 1 device each,
     mesh {dp: 2}, DistStrategy(accum_steps=2, accum_exchange="hoisted")
     — each process scans its microbatches collective-free and the ONE
-    pmean per optimizer step crosses the process (DCN analog) boundary,
-    which is exactly the wire pattern SCALING.md §2's projection
-    charges. Per-step losses must match a single process holding the
+    pmean per optimizer step crosses the process (DCN analog) boundary.
+    Per-step losses must match a single process holding the
     same global mesh on 2 local devices."""
     steps = 4
     single = _losses(_run_procs(1, steps, mode="dp_hoisted")[0])

@@ -555,7 +555,7 @@ def test_async_trainer_rides_through_membership_change():
         t.client.close()
 
 
-# -- injectors + bench row ---------------------------------------------------
+# -- injectors + the report ----------------------------------------------------
 
 
 def test_membership_injectors_are_deterministic():
@@ -569,20 +569,28 @@ def test_membership_injectors_are_deterministic():
         faults.visible_devices(99)
 
 
-def test_bench_elastic_reshard_row_schema():
-    """The elastic_reshard suite row measures a REAL dp N→M
-    reshard-restore on the CPU mesh and pins its schema (the keys
-    downstream round-diffs read)."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
-    import bench
+def test_reshard_restore_dp2_to_dp1_moves_bytes_and_lands_on_new_axes(tmp_path):
+    """A REAL dp 2->1 reshard-restore on the CPU mesh: the report names
+    both meshes, every byte of the checkpoint's files is counted as
+    moved, the restore is timed, and the parameters arrive on the target
+    trainer's (one-device) mesh."""
+    def make(n):  # _trainer(1) has no mesh at all; here dp 1 is a mesh
+        tr = pt.Trainer(pt.build(_PROG_FN), opt.SGD(0.1), loss_name="loss",
+                        mesh=pt.make_mesh({"dp": n},
+                                          devices=jax.devices()[:n]))
+        tr.startup(sample_feed=_FEED)
+        return tr
 
-    row = bench.bench_elastic_reshard(1.0, batch_size=16, iters=1,
-                                      n_from=2, n_to=1)
-    for key in ("value", "unit", "same_mesh_restore_ms",
-                "reshard_overhead_x", "bytes_moved", "from_axes", "to_axes",
-                "batch_size", "iters"):
-        assert key in row, key
-    assert row["value"] > 0 and row["bytes_moved"] > 0
-    assert row["from_axes"] == {"dp": 2} and row["to_axes"] == {"dp": 1}
-    assert "dp 2->1" in row["unit"]
+    src = make(2)
+    src.step(_FEED)
+    ck = str(tmp_path / "ck")
+    pio.save_trainer(ck, src)
+    tgt = make(1)
+    rep = resilience.reshard_restore(ck, tgt, sample_feed=_FEED)
+    assert rep["saved_axes"] == {"dp": 2} and rep["target_axes"] == {"dp": 1}
+    files = resilience.read_manifest(ck)["files"]
+    assert rep["bytes_moved"] == sum(f["size"] for f in files.values()) > 0
+    assert rep["seconds"] > 0
+    assert _params_equal(src.scope.params, tgt.scope.params)
+    for v in tgt.scope.params.values():
+        assert v.sharding.mesh.shape == {"dp": 1}

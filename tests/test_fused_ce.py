@@ -51,7 +51,6 @@ def test_fused_ce_bf16_inputs_close_to_f32():
     np.testing.assert_allclose(np.asarray(f32), np.asarray(bf), rtol=0.05, atol=0.05)
 
 
-@pytest.mark.slow
 def test_transformer_fused_ce_equals_dense():
     rng = np.random.RandomState(0)
     from paddle_tpu.models import transformer

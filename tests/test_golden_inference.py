@@ -35,8 +35,6 @@ from paddle_tpu import layers as L
 from paddle_tpu import optimizer as opt
 from paddle_tpu import quantize
 
-pytestmark = pytest.mark.slow
-
 
 def _net(image, label):
     """Small conv+BN+fc classifier: the three surfaces the deployment

@@ -1,5 +1,5 @@
 """accum_exchange="hoisted": shard_map-local gradient accumulation
-with ONE pmean per optimizer step — the wire lever SCALING.md §2 names
+with ONE pmean per optimizer step, which makes accum_steps a wire lever
 (the default GSPMD path reduces every microbatch, pinned by
 test_collective_report.test_accum_grad_exchange_is_per_microbatch).
 """
@@ -38,7 +38,6 @@ def _trainer(strategy, mesh=None, rules=None, fetch_list=("loss",)):
     return tr
 
 
-@pytest.mark.slow
 def test_hoisted_accum_matches_gspmd_and_single_device():
     """Same seed, dropout 0: hoisted accumulation must reproduce the
     GSPMD accumulation path and plain single-device accumulation, step
@@ -58,7 +57,6 @@ def test_hoisted_accum_matches_gspmd_and_single_device():
     np.testing.assert_allclose(hoisted, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_hoisted_accum_has_no_in_loop_grad_exchange():
     """The point of the mode: grad-order all-reduce bytes inside while
     bodies drop to ~nothing (vs the GSPMD path where they are the full
@@ -116,7 +114,6 @@ def test_hoisted_accum_preconditions_enforced():
         tr.step(_feed(16))
 
 
-@pytest.mark.slow
 def test_hoisted_accum_composes_with_loss_scaling():
     """bf16 AMP + dynamic loss scaling over the hoisted path: the
     scaled loss is computed inside the shard_map microbatch loop (ls

@@ -9,7 +9,6 @@ at all).
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import optimizer as opt
@@ -80,7 +79,6 @@ def test_decode_block_q8_close_to_fp():
                                   np.asarray(vs)[:, keep])
 
 
-@pytest.mark.slow
 def test_int8_kv_generator_matches_fp_on_overfit_model():
     """After overfitting a periodic stream, greedy decode with the int8
     cache must emit the same continuation as the compute-dtype cache

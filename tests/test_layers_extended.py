@@ -563,7 +563,6 @@ def test_layers_io_surface():
 from op_test import check_grad
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_grad_roi_align():
     x = np.random.randn(1, 2, 6, 6).astype(np.float32)
     rois = np.array([[1.0, 1.0, 4.0, 4.0]], np.float32)

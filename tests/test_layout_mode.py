@@ -76,7 +76,7 @@ def test_conv_pool_bn_nhwc_matches_nchw():
     np.testing.assert_allclose(got_h, got_c, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 68 s under -n 6 (26 s alone; 89 s in tier-1's company)
 def test_googlenet_nhwc_matches_nchw():
     """Inception concat must switch to the channel axis under NHWC."""
     got_c, got_h = _logits_pair(lambda: convnets.make_googlenet(class_num=5),
@@ -84,7 +84,6 @@ def test_googlenet_nhwc_matches_nchw():
     np.testing.assert_allclose(got_h, got_c, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_se_resnext_nhwc_matches_nchw():
     """SE scale broadcast + shortcut channel check under NHWC."""
     got_c, got_h = _logits_pair(
@@ -92,7 +91,6 @@ def test_se_resnext_nhwc_matches_nchw():
     np.testing.assert_allclose(got_h, got_c, rtol=5e-4, atol=5e-4)
 
 
-@pytest.mark.slow
 def test_alexnet_and_vgg_nhwc_match_nchw():
     got_c, got_h = _logits_pair(lambda: convnets.make_alexnet(class_num=5),
                                 (224, 224), n=1)

@@ -183,7 +183,6 @@ def test_backtrack_decode_to_lod_round_trip():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_seq2seq_train_decode_lod_round_trip():
     import paddle_tpu as pt
     from paddle_tpu import optimizer as opt
@@ -284,7 +283,6 @@ def test_lod_structure_invariants(case):
 
 @settings(max_examples=40, deadline=None)
 @given(nested_lod(min_len=1))
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_lod_pool_composition_property(case):
     """sum-pool at the innermost level then sum-pooling the pooled rows
     at the outer level == sum-pooling level 0 directly — for ANY

@@ -4,7 +4,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.parallel.moe import moe
@@ -23,7 +22,6 @@ def _input(b=8, s=4, d=16, seed=0):
     return rng.randn(b, s, d).astype(np.float32)
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_ep_matches_dense():
     x = _input()
     dense = _build(None)
@@ -42,7 +40,6 @@ def test_ep_matches_dense():
     assert np.isfinite(float(out_e["aux"])) and float(out_e["aux"]) >= 1.0 - 1e-5
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_ep_with_dp_axis():
     x = _input(b=8)
     dense = _build(None)
@@ -56,7 +53,6 @@ def test_ep_with_dp_axis():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.slow
 def test_ep_gradients_match_dense():
     x = _input()
     dense = _build(None)

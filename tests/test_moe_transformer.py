@@ -5,7 +5,6 @@ all-to-all-dispatched — the model-level realization of parallel/moe.py
 
 import jax
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import optimizer as opt
@@ -29,7 +28,6 @@ def _feed(bs, seq=16, vocab=64, seed=0):
     return {"ids": ids, "labels": labels}
 
 
-@pytest.mark.slow
 def test_moe_lm_trains_dense():
     prog = pt.build(moe_transformer.make_model(_cfg()))
     feed = _feed(4)
@@ -43,7 +41,6 @@ def test_moe_lm_trains_dense():
     assert float(out["aux_loss"]) > 0  # routing actually happened
 
 
-@pytest.mark.slow
 def test_moe_lm_ep_mesh_parity_with_dense():
     """dp2×ep4 expert-parallel training == dense single-device training
     step for step (aux off, ample capacity → identical routing)."""
@@ -66,7 +63,6 @@ def test_moe_lm_ep_mesh_parity_with_dense():
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_moe_expert_params_sharded_over_ep():
     mesh = pt.make_mesh({"dp": 2, "ep": 4})
     prog = pt.build(moe_transformer.make_model(_cfg(), mesh=mesh))

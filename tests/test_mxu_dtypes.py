@@ -69,7 +69,6 @@ def test_gpt_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots in GPT train step: {bad}"
 
 
-@pytest.mark.slow
 def test_transformer_train_step_mxu_clean():
     from paddle_tpu.models import transformer
     rng = np.random.RandomState(0)
@@ -84,7 +83,6 @@ def test_transformer_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots in transformer train step: {bad}"
 
 
-@pytest.mark.slow
 def test_moe_train_step_mxu_clean():
     from paddle_tpu.models import moe_transformer as mt
     rng = np.random.RandomState(0)
@@ -145,7 +143,6 @@ def test_flash_kernels_dot_operands_stay_bf16():
     assert not bad, f"f32-operand dots inside flash kernels: {bad}"
 
 
-@pytest.mark.slow
 def test_resnet_train_step_mxu_clean():
     from paddle_tpu.framework import layout_mode
     from paddle_tpu.models import resnet
@@ -158,7 +155,6 @@ def test_resnet_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots/convs in ResNet train step: {bad}"
 
 
-@pytest.mark.slow
 def test_bert_train_step_mxu_clean():
     """BERT pretrain step (attention + pooler + fused-CE MLM head +
     NSP head): the masked-LM gather and the two heads are paths the
@@ -181,7 +177,6 @@ def test_bert_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots in BERT train step: {bad}"
 
 
-@pytest.mark.slow
 def test_lstm_train_step_mxu_clean():
     """Fused-gate LSTM backward runs through lax.scan: a f32 carry or
     cotangent upcast would put every per-step gate matmul on the slow
@@ -197,7 +192,6 @@ def test_lstm_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots in LSTM train step: {bad}"
 
 
-@pytest.mark.slow
 def test_deepfm_train_step_mxu_clean():
     """DeepFM: FM pairwise interactions + the DNN tower. The FM part is
     einsum-heavy and was never covered by the transformer/conv pins."""
@@ -214,7 +208,6 @@ def test_deepfm_train_step_mxu_clean():
     assert not bad, f"f32xf32 dots in DeepFM train step: {bad}"
 
 
-@pytest.mark.slow
 def test_seq2seq_train_step_mxu_clean():
     """GRU seq2seq with additive attention (the machine-translation
     bench config): the hand-rolled decoder scan cell casts its own

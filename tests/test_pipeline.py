@@ -58,7 +58,6 @@ def test_pipeline_degenerate_no_pp_axis():
                                atol=1e-6)
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_pipeline_differentiable():
     mesh = pt.make_mesh({"pp": 2}, devices=jax.devices()[:2])
     d = 4
@@ -99,7 +98,6 @@ def test_interleaved_uneven_microbatch_group():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
 def test_interleaved_differentiable():
     mesh = pt.make_mesh({"pp": 2}, devices=jax.devices()[:2])
     d = 4

@@ -162,7 +162,6 @@ def test_stacked_decoder_is_causal():
                            np.asarray(out2["logits"])[:, 6:], atol=1e-3)
 
 
-@pytest.mark.slow
 def test_pipeline_transformer_e2e_loss_parity():
     """dp2×pp4 pipelined training == single-device training, step for
     step (same seed → same stacked init → same losses)."""
@@ -183,7 +182,6 @@ def test_pipeline_transformer_e2e_loss_parity():
     np.testing.assert_allclose(pp_losses, ref_losses, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_pipeline_transformer_3d_dp_tp_pp():
     """dp2×tp2×pp2: stacked blocks tp-shard heads inside each stage and
     psum the projections; losses stay parity with single-device."""
@@ -204,7 +202,6 @@ def test_pipeline_transformer_3d_dp_tp_pp():
     np.testing.assert_allclose(pp_losses, ref_losses, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_pipeline_transformer_interleaved_loss_parity():
     """dp2×pp2 with pp_interleave=2 (Megatron virtual stages): each rank
     holds two non-adjacent block chunks; losses stay parity with
@@ -243,7 +240,6 @@ def test_stacked_params_sharded_over_pp():
     assert spec[0] == "pp", spec
 
 
-@pytest.mark.slow
 def test_interleaved_rest_layout_checkpoints_logical(tmp_path):
     """Trainer with pp_interleave=2 stores stacked rows chunk-
     interleaved at rest (Megatron layout, no per-step re-layout), but
@@ -286,7 +282,6 @@ def test_interleaved_rest_layout_checkpoints_logical(tmp_path):
     assert np.isfinite(float(tr.step(feed)["loss"]))
 
 
-@pytest.mark.slow
 def test_pipeline_composes_with_grad_accumulation():
     """pp_microbatches × accum_steps: the scan-microbatched feed halves
     feed the pipeline's own microbatching; parity vs plain single-device
@@ -308,7 +303,6 @@ def test_pipeline_composes_with_grad_accumulation():
     np.testing.assert_allclose(pp, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_pipeline_trained_model_eval_and_reshape_restore(tmp_path):
     """The pp-sharded stacked model evaluates (eval enters the same
     pipeline ctx as training, so its collectives ride the same mesh
@@ -395,7 +389,7 @@ def test_stacked_dropout_masks_decorrelate_across_layers():
     assert abs(frac - p_keep ** L) < 0.03,         f"kept {frac:.3f}; shared-mask reuse would keep ~{p_keep}"
 
 
-@pytest.mark.slow  # >20s on the 1-core host (smoke budget, r5 #9)
+@pytest.mark.slow  # 39 s under -n 6 (31 s alone)
 def test_dropout_on_pipeline_path():
     """The pipeline schedule threads rng per (layer, microbatch,
     data-shard): training under pp with dropout>0 yields finite,
@@ -428,7 +422,6 @@ def test_dropout_on_pipeline_path():
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.slow
 def test_pipeline_dropout_masks_decorrelate():
     """Distinct dropout masks per (layer, microbatch): a pp run of an
     identity stack with dropout must not reuse one mask across layers

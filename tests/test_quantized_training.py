@@ -151,7 +151,6 @@ def test_unquantized_exchange_advisory_needs_profile_evidence():
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_int8_ef_losses_track_fp32():
     """The pinned train-equivalence tolerance: int8 block-scaled
     exchange with error feedback stays within 5e-3 of the fp32 loss
@@ -164,7 +163,6 @@ def test_int8_ef_losses_track_fp32():
     np.testing.assert_allclose(lq, lr, atol=5e-3, rtol=0)
 
 
-@pytest.mark.slow
 def test_int4_ef_losses_track_fp32():
     """int4 is coarse; error feedback is what keeps the curve attached.
     Wider tolerance, same contract."""
@@ -176,7 +174,6 @@ def test_int4_ef_losses_track_fp32():
     np.testing.assert_allclose(lq, lr, atol=5e-2, rtol=0)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("k", [2, 4])
 def test_fused_k_matches_sequential_with_residual_carry(k):
     """run_steps(k) threads the error-feedback residual through the
@@ -199,7 +196,6 @@ def test_fused_k_matches_sequential_with_residual_carry(k):
             np.asarray(fused.scope.quant_resid[name]))
 
 
-@pytest.mark.slow
 def test_int4_sweep_block_sizes():
     """int4 multi-block-size sweep: every configuration trains with
     finite losses and honors its own bytes attribution."""
@@ -211,7 +207,6 @@ def test_int4_sweep_block_sizes():
         assert tr.collective_bytes["reduction"] > 5.0
 
 
-@pytest.mark.slow
 def test_check_trainer_clean_on_quantized_ef_trainer():
     """The static analyzer must trace the 7-arg EF step (quant_resid
     rides the signature) without findings on a healthy config."""

@@ -33,7 +33,6 @@ def _trainer(strategy=None):
                       donate=False)
 
 
-@pytest.mark.slow
 def test_memory_optimize_strategy_consumed_by_trainer():
     """The VERDICT 'phantom knob' check: memory_optimize() must actually
     change the compiled step. The Trainer's loss path must contain one
@@ -91,7 +90,6 @@ def test_remat_reduces_memory_on_tpu():
     assert m_remat["temp_mb"] < 0.5 * m_plain["temp_mb"], (m_plain, m_remat)
 
 
-@pytest.mark.slow
 def test_model_config_remat_equivalent_numerics():
     feed = _feed()
     p0 = pt.build(transformer.make_model(_cfg()))
@@ -108,7 +106,6 @@ def test_model_config_remat_equivalent_numerics():
                                    atol=1e-5, rtol=1e-4, err_msg=k)
 
 
-@pytest.mark.slow
 def test_bert_remat_flag():
     from paddle_tpu.models import bert
 
@@ -154,11 +151,7 @@ def _no_remat_losses():
     return feeds, [float(ref.step(f)["loss"]) for f in feeds]
 
 
-@pytest.mark.parametrize("policy", [
-    "dots",
-    pytest.param("dots_no_batch", marks=pytest.mark.slow),
-    pytest.param("everything", marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("policy", ["dots", "dots_no_batch", "everything"])
 def test_remat_policy_numerics_unchanged(policy, _no_remat_losses):
     """Checkpoint policies change WHAT is saved (memory/recompute), not
     the computed values: per-step losses must equal the no-remat run."""

@@ -52,7 +52,6 @@ def test_ring_with_dp_batch_sharding():
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 def test_ring_gradients():
     mesh = pt.make_mesh({"sp": 4}, devices=jax.devices()[:4])
     q, k, v = _rand(b=1, h=1, s=32, d=8, seed=3)
@@ -98,7 +97,6 @@ def test_zigzag_with_dp_batch_sharding():
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 def test_zigzag_gradients():
     mesh = pt.make_mesh({"sp": 4}, devices=jax.devices()[:4])
     q, k, v = _rand(b=1, h=1, s=32, d=8, seed=9)

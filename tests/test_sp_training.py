@@ -66,7 +66,6 @@ def test_sp_training_loss_parity():
     np.testing.assert_allclose(sp, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_sp_with_fused_ce_and_flash():
     """The production long-context config: flash attention inside the
     ring + chunked logits-free CE, still parity with the dense path."""
@@ -106,7 +105,6 @@ def test_sp_ulysses_loss_parity():
     np.testing.assert_allclose(sp, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_sp_ulysses_with_flash_parity():
     """ulysses + the pallas flash kernel as the full-sequence inner
     attention (the composition DESIGN.md advertises)."""

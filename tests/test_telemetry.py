@@ -527,6 +527,28 @@ def test_trainer_dispatch_journal_and_registry_series(fresh_telemetry):
     assert reg.validate() == []
 
 
+def test_counter_snapshot_delta_over_a_window_of_steps(fresh_telemetry):
+    """Two ``counter_values()`` snapshots round a window of steps,
+    reduced by ``counter_deltas(per=steps)``: the trainer's step counter
+    reads 1.0 a step, only counters appear (the global_step gauge does
+    not), and a series that did not move in the window is left out."""
+    tr = _trainer()
+    tr.step(_FEED)
+    reg = telemetry.get_registry()
+    before = reg.counter_values()
+    for _ in range(3):
+        tr.step(_FEED)
+    per_step = counter_deltas(before, reg.counter_values(), per=3)
+    inst = tr.telemetry_inst
+    assert per_step[f'paddle_tpu_trainer_steps_total{{inst="{inst}"}}'] == 1.0
+    assert per_step[
+        f'paddle_tpu_trainer_dispatches_total{{inst="{inst}",kind="step"}}'
+    ] == 1.0
+    assert not any(k.startswith("paddle_tpu_trainer_global_step")
+                   for k in per_step)
+    assert counter_deltas(reg.counter_values(), reg.counter_values()) == {}
+
+
 def test_fit_fill_span_shared_with_dispatch(fresh_telemetry):
     j = fresh_telemetry
     tr = _trainer()

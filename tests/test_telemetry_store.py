@@ -762,6 +762,36 @@ def test_persisted_ingest_under_2pct_of_k16_dispatch(fresh, tmp_path):
         col.close()
 
 
+def test_stats_verb_prices_store_ingest_over_the_wire(fresh, tmp_path):
+    """``Shipper.collector_stats`` (the ``STATS`` verb) reads the
+    attached collector's counters over the shipper's own socket: with
+    persistence on, the store's ingest-writes (appends, bytes, append
+    seconds) grow with what was shipped; a collector without a store
+    reports ``persistence: False`` and no ``store``; an unreachable one
+    gives None, never an exception."""
+    with TelemetryCollector(eval_interval=3600,
+                            store_dir=str(tmp_path / "log")) as col:
+        sh = tshipper.ship_to(col.addr, origin="o-stats",
+                              flush_interval=3600)
+        before = sh.collector_stats()
+        assert before["persistence"] is True
+        for i in range(8):
+            fresh.emit("a.b", span="s", n=i)
+        sh.flush()
+        after = sh.collector_stats()
+        for key in ("appends", "bytes", "append_seconds"):
+            assert after["store"][key] > before["store"][key], key
+        assert after["store"] == col.stats()["store"]
+        tshipper.stop_shipping()
+    with TelemetryCollector(eval_interval=3600) as col:
+        sh = tshipper.ship_to(col.addr, origin="o-stats",
+                              flush_interval=3600)
+        stats = sh.collector_stats()
+        assert stats["persistence"] is False and "store" not in stats
+    assert sh.collector_stats() is None  # collector gone
+    tshipper.stop_shipping()
+
+
 # ---------------------------------------------------------------------------
 # the HA drill end to end (real SIGKILL)
 # ---------------------------------------------------------------------------

@@ -51,7 +51,6 @@ def test_ulysses_with_dp():
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 def test_ulysses_gradients():
     mesh = pt.make_mesh({"sp": 4}, devices=jax.devices()[:4])
     q, k, v = _rand(b=1, h=4, s=32, d=8, seed=3)

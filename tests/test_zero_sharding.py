@@ -28,7 +28,7 @@ Pinned here:
 - the lint flip (``sharding:replicated-optstate`` quiet under ZeRO,
   ``sharding:zero-active`` info with realized per-device bytes), the
   ``ckpt:zero-mismatch`` finding, the advisor/device-cache HBM
-  dividend, and the bench row schema.
+  dividend, and the dividend on the MNIST MLP with its all-gather bytes.
 """
 
 import os
@@ -453,19 +453,43 @@ def test_advisor_dividend_and_device_cache_admits_more():
     assert 0 < n_rep < n_zer, (n_rep, n_zer)
 
 
-def test_bench_zero_sharding_row_schema():
-    """The zero_sharding suite row: headline value is the per-device
-    optimizer-HBM reduction at the largest dp, per-dp sub-rows carry
-    both step times and the all-gather bytes attribution."""
-    import bench
+def test_mnist_mlp_opt_hbm_falls_6x_at_dp8_and_allgather_is_counted():
+    """The MNIST MLP with Momentum, trained through the fused K-step
+    dispatch both ways: per-device optimizer HBM falls >= 6x at dp 8
+    (8 shards less the replicated step counter), and the trainer's
+    collective attribution counts the top-of-step all-gather: (N-1)
+    hops of every parameter's 1/N row, at dp 2 and dp 8."""
+    from paddle_tpu.data.feeder import stack_batches
+    from paddle_tpu.models import mnist
+    from paddle_tpu.profiling.advisor import memory_estimate
 
-    row = bench.bench_zero_sharding(1.0, batch_size=16, iters=2, k=2)
-    assert row["value"] >= 6.0
-    assert "dp8_opt_hbm_reduction_x" in row
-    assert row["dp8_opt_hbm_reduction_x"] >= 6.0
-    assert row["dp2_allgather_bytes_per_step"] > 0
-    assert row["steps_per_dispatch"] == 2
-    for key in ("dp2_step_time_ms_k1_replicated", "dp2_step_time_ms_k1_zero",
-                "dp2_step_time_ms_k2_replicated", "dp2_step_time_ms_k2_zero",
-                "dp8_step_time_ratio_fused"):
-        assert key in row, key
+    rng = np.random.RandomState(0)
+    feeds = [{"image": rng.randn(16, 784).astype(np.float32),
+              "label": rng.randint(0, 10, (16, 1)).astype(np.int64)}
+             for _ in range(2)]
+
+    def build(n, zero):
+        tr = pt.Trainer(pt.build(mnist.mlp), opt.Momentum(0.01, momentum=0.9),
+                        loss_name="loss", fetch_list=["loss"], mesh=_mesh(n),
+                        sharding_rules=pt.parallel.replicated(),
+                        strategy=DistStrategy(zero_sharding=zero))
+        tr.startup(sample_feed=feeds[0])
+        out = tr.run_steps(tr._put_feed(stack_batches(feeds), stacked=True),
+                           k=2)
+        assert np.isfinite(np.asarray(out["loss"])).all()
+        return tr
+
+    for n in (2, 8):
+        rep, zer = build(n, False), build(n, True)
+        est_rep = memory_estimate(rep, feeds[0], project_remat=False)
+        est_zer = memory_estimate(zer, feeds[0], project_remat=False)
+        reduction = est_rep["opt_state_bytes"] / est_zer["opt_state_bytes"]
+        assert reduction >= (6.0 if n == 8 else 1.9), (n, reduction)
+        assert not (rep.collective_bytes or {}).get("zero")
+        param_bytes = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                          for v in zer._logical_params().values())
+        moved = zer.collective_bytes["zero"]["allgather_bytes_per_step"]
+        # rows are padded up to a multiple of N: at least the exact
+        # share, and short of one whole copy
+        assert (n - 1) * param_bytes // n <= moved < param_bytes, \
+            (n, moved, param_bytes)
