@@ -146,12 +146,13 @@ _DTYPE_BYTES = {
     "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
 }
 
-# Tuple shapes may carry /*index=N*/ comments between elements, so match
-# the whole parenthesized group opaquely (shapes contain no parens) and
-# let _shape_sizes scan the dtypes/dims inside.
+# Tuple shapes may carry /*index=N*/ comments between elements, and on
+# the TPU tiled layouts with parentheses of their own
+# (``{2,1,0:T(8,128)(2,1)S(1)}``), so match the whole group opaquely, up
+# to the opcode, and let _shape_sizes scan the dtypes/dims inside.
 _HLO_SHAPE = r"(?:\w+\[[^\]]*\](?:\{[^}]*\})?)"
 _COLLECTIVE_RE = _re.compile(
-    r"=\s+(\([^)]*\)|" + _HLO_SHAPE + r")\s+"
+    r"=\s+(\(.*?\)|" + _HLO_SHAPE + r")\s+"
     r"(all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all|collective-broadcast)(-start)?\(")
 _GROUP_RE = _re.compile(r"replica_groups=\{?\{([0-9,]+)\}")

@@ -59,21 +59,15 @@ def flash_sdpa(q, k, v, causal: bool, key_bias=None):
     stages, the shard-local gradient paths) the call is already
     per-shard and goes straight to the kernel."""
     from ..ops.flash_attention import flash_attention
-    from ..parallel.mesh import DATA_AXES, TP
+    from ..parallel.mesh import DATA_AXES, TP, dividing_axes
 
     mesh = active_mesh()
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
         return flash_attention(q, k, v, causal=causal, key_bias=key_bias)
 
-    def axes_dividing(n, names):
-        names = tuple(a for a in names
-                      if a in mesh.axis_names and mesh.shape[a] > 1)
-        size = math.prod(mesh.shape[a] for a in names)
-        return names if names and n % size == 0 else None
-
-    batch = axes_dividing(q.shape[0], DATA_AXES)
-    heads = axes_dividing(q.shape[1], (TP,))
+    batch = dividing_axes(mesh, q.shape[0], DATA_AXES) or None
+    heads = dividing_axes(mesh, q.shape[1], (TP,)) or None
     qkv = P(batch, heads, None, None)
     args, specs = [q, k, v], [qkv, qkv, qkv]
     if key_bias is not None:

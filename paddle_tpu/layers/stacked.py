@@ -10,8 +10,10 @@ TPU-native design: per-layer parameters live STACKED on a leading
 scope (so save/load, sharding rules, and optimizers see ordinary named
 params). The stack is applied either
 
-- sequentially with ``lax.scan`` (single chip, or dp/fsdp/tp meshes where
-  GSPMD partitions the scanned matmuls), or
+- sequentially with ``lax.scan`` (single chip, or dp/fsdp meshes where
+  GSPMD partitions the scanned matmuls; under a ``tp`` axis the scan runs
+  per shard and the block's exchanges go under its own matmuls, see
+  :func:`apply_stacked`), or
 - pipelined with ``parallel.pipeline.pipeline_apply`` when the Trainer
   has entered :func:`framework.pipeline_mode` (``DistStrategy.pp_microbatches``),
   each pp rank owning a contiguous span of layers.
@@ -33,6 +35,7 @@ covers the tp-axis caveat).
 from __future__ import annotations
 
 import math
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -40,9 +43,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.errors import enforce
-from ..framework import (LayerHelper, cast_compute, in_training as _in_training,
-                         maybe_remat, pipeline_config, rng_fold, sp_config)
+from ..framework import (LayerHelper, active_mesh, cast_compute,
+                         in_training as _in_training, maybe_remat,
+                         pipeline_config, remat_enabled, rng_fold, sp_config)
 from .. import initializer as init
+from ..parallel.collective_matmul import (BatchSharded, gather_matmul,
+                                          matmul_scatter, ring_order)
 from .attention import flash_applies, flash_sdpa
 
 NEG_INF = -1e9
@@ -197,12 +203,37 @@ def decoder_stack_params(num_layers: int, d_model: int, d_inner: int,
 
 
 # -- block functions ---------------------------------------------------------
+#
+# ``tp_axis`` says how a block's projections meet tensor parallelism:
+# None, under GSPMD or on one chip, where they are plain matmuls; the
+# name of a manual axis, in a pipeline stage, where the activation is
+# whole and a row-parallel matmul's partial sums are closed by ``psum``;
+# a ``BatchSharded`` name, where the activation is ``[b/tp, s, d]`` between
+# matmuls, the exchanges run in chunks under the matmuls themselves, and
+# what lies between a column- and a row-parallel matmul holds the tp
+# group's rows in ring order (parallel/collective_matmul.py).
+
+
+def _column_parallel(h, proj, tp_axis):
+    """``proj`` (a matmul with tp-local columns) of every row."""
+    if isinstance(tp_axis, BatchSharded):
+        return jnp.concatenate(gather_matmul(h, proj, tp_axis))
+    return proj(h)
+
+
+def _row_parallel(o, w, tp_axis):
+    """``o @ w`` with tp-local rows of ``w``, partial sums closed."""
+    if isinstance(tp_axis, BatchSharded):
+        chunks = jnp.split(o, jax.lax.axis_size(tp_axis))
+        return matmul_scatter(chunks, lambda c: jnp.matmul(c, w), tp_axis)
+    o = jnp.matmul(o, w)
+    return jax.lax.psum(o, tp_axis) if tp_axis else o
 
 
 @jax.named_scope("attn")
 def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
                     sp_cfg=None, dropout_rate: float = 0.0):
-    q, k, v = _attn_qkv(x, p, num_heads)
+    q, k, v = _attn_qkv(x, p, num_heads, tp_axis)
     o = _sdpa(q, k, v, key_bias, causal, use_flash, sp_cfg,
               dropout_rate=dropout_rate)
     return _attn_out(x, p, _merge_heads(o), tp_axis,
@@ -213,11 +244,17 @@ def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
 def _ffn(x, p, tp_axis, dropout_rate: float = 0.0):
     h = _ln(x, p["ln2/scale"], p["ln2/bias"])
     h, w1, w2 = cast_compute(h, p["ffn_in/w"], p["ffn_out/w"])
-    h = jax.nn.relu(jnp.matmul(h, w1) + p["ffn_in/b"].astype(h.dtype))
-    h = _drop(h, dropout_rate)
-    h = jnp.matmul(h, w2)
-    if tp_axis:
-        h = jax.lax.psum(h, tp_axis)
+
+    def inner(c):
+        c = jax.nn.relu(jnp.matmul(c, w1) + p["ffn_in/b"].astype(c.dtype))
+        return _drop(c, dropout_rate)
+
+    if isinstance(tp_axis, BatchSharded):
+        # the inner activation is made and used chunk by chunk
+        h = matmul_scatter(gather_matmul(h, inner, tp_axis),
+                           lambda c: jnp.matmul(c, w2), tp_axis)
+    else:
+        h = _row_parallel(inner(h), w2, tp_axis)
     return x + _drop(h + p["ffn_out/b"].astype(h.dtype), dropout_rate)
 
 
@@ -263,7 +300,10 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
             h = _ln(x, p["lnx/scale"], p["lnx/bias"])
             h, wq, wkv, enc = cast_compute(h, p["xq/w"], p["xkv/w"],
                                            extra["enc"])
-            q = jnp.matmul(h, wq) + p["xq/b"].astype(h.dtype)
+            q = _column_parallel(
+                h, lambda c: jnp.matmul(c, wq) + p["xq/b"].astype(c.dtype),
+                tp_axis)
+            # the encoder's output is whole on every tp rank
             kv = jnp.einsum("bsd,dke->bske", enc, wkv) \
                 + p["xkv/b"].astype(h.dtype)
             q = _split_heads(q, head_dim)
@@ -271,9 +311,7 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
             o = _merge_heads(_sdpa(q, k, v, extra.get("enc_bias"), False,
                                    use_flash, dropout_rate=dropout_rate))
             o, ow = cast_compute(o, p["xout/w"])
-            o = jnp.matmul(o, ow)
-            if tp_axis:
-                o = jax.lax.psum(o, tp_axis)
+            o = _row_parallel(o, ow, tp_axis)
             x = x + _drop(o + p["xout/b"].astype(o.dtype), dropout_rate)
         return _ffn(x, p, tp_axis, dropout_rate=dropout_rate)
 
@@ -294,26 +332,26 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
 # answer with a relayout of the whole slab.
 
 
-def _qkv(x, p):
+def _qkv(x, p, tp_axis=None):
     """LayerNorm and the fused projection: ``[b, s, 3, h*hd]``, q, k and
     v on axis 2, each with its heads side by side in the last."""
     h = _ln(x, p["ln1/scale"], p["ln1/bias"])
     h, w = cast_compute(h, p["qkv/w"])
-    return jnp.einsum("bsd,dke->bske", h, w) + p["qkv/b"].astype(h.dtype)
+    return _column_parallel(
+        h, lambda c: jnp.einsum("bsd,dke->bske", c, w)
+        + p["qkv/b"].astype(c.dtype), tp_axis)
 
 
-def _attn_qkv(x, p, num_heads):
+def _attn_qkv(x, p, num_heads, tp_axis=None):
     head_dim = x.shape[-1] // num_heads
-    qkv = _qkv(x, p)
+    qkv = _qkv(x, p, tp_axis)
     return tuple(_split_heads(qkv[:, :, i], head_dim) for i in range(3))
 
 
 def _attn_out(x, p, o, tp_axis=None, dropout_rate: float = 0.0):
     """Output projection and residual; ``o`` is ``[b, s, h*hd]``."""
     o, ow = cast_compute(o, p["out/w"])
-    o = jnp.matmul(o, ow)
-    if tp_axis:
-        o = jax.lax.psum(o, tp_axis)
+    o = _row_parallel(o, ow, tp_axis)
     return x + _drop(o + p["out/b"].astype(o.dtype), dropout_rate)
 
 
@@ -446,6 +484,95 @@ def stack_tp_specs(stacked: Dict[str, Any]) -> Dict[str, Any]:
 # -- apply -------------------------------------------------------------------
 
 
+def _local_batch(x, mesh) -> int:
+    """Rows of ``x`` on one data shard (a batch the data axes do not
+    divide stays whole, as GSPMD would leave it)."""
+    from ..parallel.mesh import DATA_AXES, dividing_axes
+
+    return x.shape[0] // math.prod(
+        mesh.shape[a] for a in dividing_axes(mesh, x.shape[0], DATA_AXES))
+
+
+def _batch_sharded_why_not(x, stacked, mesh, tp: int, num_heads: int, sp,
+                           dropout_rate: float) -> Optional[str]:
+    """Why this stack cannot run with its activation sharded over the
+    mesh's ``tp`` axis between matmuls (it then keeps the GSPMD form),
+    or None if it can."""
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return "already inside a shard_map"
+    if sp is not None:
+        return "sequence parallelism has its own shard_map"
+    if any(k not in _DECODER_TP_SPECS for k in stacked):
+        return "not the encoder or decoder stack: no tp specs for it"
+    if x.ndim != 3 or _local_batch(x, mesh) % tp:
+        return f"tp={tp} does not divide the rows of a data shard"
+    inner = stacked["ffn_in/w"].shape[-1]
+    if num_heads % tp or inner % tp:
+        return f"tp={tp} does not divide {num_heads} heads and inner {inner}"
+    if dropout_rate > 0.0 and _in_training():
+        return "dropout masks are not folded per shard"
+    return None
+
+
+def _record_tp_plan(x, mesh, tp: int, stacked, why_not: Optional[str],
+                    remat: bool):
+    """One zero-length span in the program's ring for each stack traced
+    under a ``tp`` axis: the exchanges a layer makes and their size on
+    one chip. ``form`` is ``windowed`` (chunks of ``chunk_rows`` batch
+    rows, sent under the block's matmuls; an exchange is ``tp - 1`` hops
+    of ``bytes_per_exchange``) or ``all_reduce`` (the whole activation,
+    closed by the partitioner or a ``psum``), with ``why`` naming what
+    kept the stack from the first."""
+    from ..core import profiler
+
+    windowed = why_not is None
+    # windowed: a gather before each column-parallel matmul and a scatter
+    # after each row-parallel one; all_reduce: one after each row-parallel
+    # matmul, and in the backward pass one for each column-parallel dx.
+    # Remat's second forward needs all but the last result again.
+    rows = 3 if "xq/w" in stacked else 2      # row-parallel matmuls a layer
+    each_pass = 2 * rows if windowed else rows
+    train = _in_training()
+    batch = _local_batch(x, mesh)
+    chunk_rows = batch // tp if windowed else batch
+    profiler.record_span(
+        "tp.plan", time.time_ns(), 0, tp=tp,
+        seq=x.shape[1] if x.ndim == 3 else 0, chunk_rows=chunk_rows,
+        form="windowed" if windowed else "all_reduce", why=why_not or "",
+        exchanges_per_layer={
+            "forward": each_pass,
+            "remat": each_pass - 1 if train and remat else 0,
+            "backward": each_pass if train else 0},
+        bytes_per_exchange=chunk_rows * math.prod(x.shape[1:])
+        * jnp.dtype(cast_compute(x).dtype).itemsize)
+
+
+def _batch_sharded(run, mesh, x, stacked, extras):
+    """``run`` per shard under ``shard_map`` over the whole mesh, ``tp``
+    manual: the activation is ``[b/dp/tp, s, d]`` from its slice at entry
+    (no traffic) to one gather at exit, and the side inputs, whole on
+    every tp rank, are put in the rank's ring order once, outside the
+    layer loop. The parameters enter in float32 and are cast inside, so
+    their cotangents cross the boundary, and are reduced over ``dp``, in
+    float32."""
+    from ..parallel.mesh import DATA_AXES, TP, dividing_axes
+
+    data = dividing_axes(mesh, x.shape[0], DATA_AXES)
+    specs = stack_tp_specs(stacked)
+
+    def mapped(x_, stacked_, extras_):
+        extras_ = jax.tree.map(lambda e: ring_order(e, TP), extras_)
+        out = run(x_, stacked_, extras_)
+        return jax.lax.all_gather(out, TP, axis=0, tiled=True)
+
+    return jax.shard_map(
+        mapped, mesh=mesh,
+        in_specs=(P(data + (TP,)),
+                  {k: P(None, *specs[k]) for k in stacked},
+                  jax.tree.map(lambda e: P(data or None), extras)),
+        out_specs=P(data or None), check_vma=False)(x, stacked, extras)
+
+
 def apply_stacked(x, stacked: Dict[str, jax.Array], make_block: Callable,
                   extras=None, num_heads: int = 8, use_flash: bool = False,
                   causal: bool = False, remat: bool = False,
@@ -453,49 +580,71 @@ def apply_stacked(x, stacked: Dict[str, jax.Array], make_block: Callable,
     """Run a parameter stack over ``x``: pipelined across the ``pp`` mesh
     axis when the Trainer has entered :func:`framework.pipeline_mode`
     (DistStrategy.pp_microbatches — the BuildStrategy-knob analog),
-    sequential ``lax.scan`` otherwise (where GSPMD still tp/fsdp-shards
-    the scanned matmuls from the rule-table shardings).
+    sequential ``lax.scan`` otherwise. Under a mesh whose ``tp`` axis is
+    larger than 1 the scan runs per shard with the activation sharded
+    over ``tp`` between matmuls (:func:`_batch_sharded`); where that form
+    cannot apply (:func:`_batch_sharded_why_not`) GSPMD tp/fsdp-shards
+    the scanned matmuls from the rule-table shardings, as it does for
+    every other axis.
 
     ``make_block(num_heads=…, use_flash=…, causal=…, tp_axis=…)`` builds
     the layer fn — tp_axis is set when the pipeline mesh also has a
     ``tp`` axis, making dp×tp×pp one call.
     """
+    from ..parallel.mesh import TP
+
     cfg = pipeline_config()
     sp = sp_config()
     enforce(not (cfg is not None and sp is not None),
             "pipeline and sequence parallelism cannot wrap the same stack "
             "(ring attention's shard_map cannot nest inside the pipeline's)")
+    mesh = cfg["mesh"] if cfg is not None else active_mesh()
+    tp_size = mesh.shape.get(TP, 1) if mesh is not None else 1
     if cfg is None:
+        why_not = _batch_sharded_why_not(
+            x, stacked, mesh, tp_size, num_heads, sp,
+            dropout_rate) if tp_size > 1 else "no tp axis"
         block = make_block(num_heads=num_heads, use_flash=use_flash,
-                           causal=causal, tp_axis=None, sp_cfg=sp,
-                           dropout_rate=dropout_rate)
+                           causal=causal,
+                           tp_axis=None if why_not else BatchSharded(TP),
+                           sp_cfg=sp, dropout_rate=dropout_rate)
         num_layers = next(iter(stacked.values())).shape[0]
 
-        def scan_body(a, xs):
-            lp, idx = xs
+        def run(x, stacked, extras):
+            def scan_body(a, xs):
+                lp, idx = xs
 
-            def fn(a_, lp_):
-                # per-layer rng: the traced layer index folds into the
-                # ambient stream so dropout masks decorrelate across
-                # scan iterations (the body is traced ONCE)
-                with rng_fold(idx):
-                    return block(a_, lp_, extras) if extras is not None \
-                        else block(a_, lp_)
-            # remat=True forces per-layer checkpointing (cfg.remat);
-            # False defers to the ambient strategy.remat switch
-            return maybe_remat(fn, enabled=remat or None)(a, lp), None
-        out, _ = jax.lax.scan(scan_body, x,
-                              (stacked, jnp.arange(num_layers)))
-        return out
+                def fn(a_, lp_):
+                    # per-layer rng: the traced layer index folds into the
+                    # ambient stream so dropout masks decorrelate across
+                    # scan iterations (the body is traced ONCE)
+                    with rng_fold(idx):
+                        return block(a_, lp_, extras) if extras is not None \
+                            else block(a_, lp_)
+                # remat=True forces per-layer checkpointing (cfg.remat);
+                # False defers to the ambient strategy.remat switch
+                return maybe_remat(fn, enabled=remat or None)(a, lp), None
+            out, _ = jax.lax.scan(scan_body, x,
+                                  (stacked, jnp.arange(num_layers)))
+            return out
+
+        if tp_size > 1:
+            _record_tp_plan(x, mesh, tp_size, stacked, why_not,
+                            remat or remat_enabled())
+        if why_not:
+            return run(x, stacked, extras)
+        return _batch_sharded(run, mesh, x, stacked, extras)
 
     from ..framework import next_rng_key
     from ..parallel.pipeline import pipeline_apply
-    mesh = cfg["mesh"]
-    tp = "tp" if ("tp" in mesh.axis_names and mesh.shape["tp"] > 1) else None
+    tp = TP if tp_size > 1 else None
     if tp:
-        enforce(num_heads % mesh.shape["tp"] == 0,
-                f"stacked blocks with tp={mesh.shape['tp']} need num_heads "
+        enforce(num_heads % tp_size == 0,
+                f"stacked blocks with tp={tp_size} need num_heads "
                 f"({num_heads}) divisible by tp")
+        _record_tp_plan(x, mesh, tp_size, stacked,
+                        "pipeline stages close partial sums with psum",
+                        remat or remat_enabled())
     block = make_block(num_heads=num_heads, use_flash=use_flash,
                        causal=causal, tp_axis=tp, sp_cfg=None,
                        dropout_rate=dropout_rate)

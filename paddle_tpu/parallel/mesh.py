@@ -22,6 +22,7 @@ broadcasting an ncclUniqueId over gRPC).
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -63,6 +64,15 @@ def pvary(x, axis_names):
     """Mark ``x`` as device-varying over ``axis_names`` inside shard_map
     (vma bookkeeping for mixing replicated operands with sharded ones)."""
     return jax.lax.pcast(x, axis_names, to="varying")
+
+
+def dividing_axes(mesh: Mesh, n: int, names: Sequence[str]) -> tuple:
+    """Those of ``names`` that are mesh axes larger than 1, if their
+    product divides ``n``; else none: a dim the axes do not divide stays
+    whole on every shard, as GSPMD itself would leave it."""
+    names = tuple(a for a in names
+                  if a in mesh.axis_names and mesh.shape[a] > 1)
+    return names if n % math.prod(mesh.shape[a] for a in names) == 0 else ()
 
 
 def data_axis_names(mesh: Mesh) -> tuple:

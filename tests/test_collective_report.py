@@ -48,6 +48,22 @@ def test_parse_hlo_async_start_counts_result_once():
     assert got[2] == ("all-reduce", (10 + 20) * 4, 8)   # variadic: summed
 
 
+def test_parse_hlo_collectives_with_tpu_layouts():
+    """The TPU's compiled text carries tiled layouts with parentheses
+    inside a tuple shape; the async exchange of the tp block and a
+    variadic gradient all-reduce are both such lines."""
+    hlo = """
+  %collective-permute-start = (bf16[8,1024,1280]{2,1,0:T(8,128)(2,1)S(1)}, bf16[8,1024,1280]{2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%add_convert_fusion.4), channel_id=1, source_target_pairs={{0,1},{1,0},{2,3},{3,2}}
+  %collective-permute-done = bf16[8,1024,1280]{2,1,0:T(8,128)(2,1)} collective-permute-done(%collective-permute-start)
+  %all-reduce.4 = (f32[2,2560]{1,0:T(2,128)}, /*index=1*/f32[2,1280,2560]{2,1,0:T(8,128)}) all-reduce(%a, %b), channel_id=5, replica_groups={{0,2},{1,3}}, to_apply=%sum
+  %all-gather.4 = bf16[16,1024,1280]{2,1,0:T(8,128)(2,1)S(1)} all-gather(%x), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}
+"""
+    assert _parse_hlo_collectives(hlo, fallback_group_size=4) == [
+        ("collective-permute", 8 * 1024 * 1280 * 2, 4),
+        ("all-reduce", (2 * 2560 + 2 * 1280 * 2560) * 4, 2),
+        ("all-gather", 16 * 1024 * 1280 * 2, 2)]
+
+
 def test_reduce_scatter_wire_is_result_times_n_minus_1():
     """A reduce-scatter RESULT is 1/n of the logical input; ring wire is
     result*(n-1), not result*(n-1)/n — the dominant FSDP collective must
@@ -198,3 +214,34 @@ def test_accum_grad_exchange_is_per_microbatch():
         f"vs {param_bytes:.0f}B of params: the grad exchange got hoisted "
         "— accumulation became a wire lever: correct the comments that "
         "cite this test and invert it")
+
+
+def test_collective_report_counts_the_tp_blocks_exchanges():
+    """A stacked GPT on dp2 x tp2 exchanges the block's activation in
+    chunks under its matmuls (layers/stacked.py ``_batch_sharded``):
+    the report holds them as ``collective-permute``, each of a layer's
+    11 once (they sit in the scan's loop bodies: 4 forward, 3 in remat's
+    second forward, 4 backward), with the bytes of one chunk. The train
+    cell's ``correct`` reads this report: at least one collective."""
+    from paddle_tpu.models import gpt
+
+    d, seq, batch = 32, 16, 8
+    cfg = gpt.base_config(vocab_size=64, max_len=seq, d_model=d, d_inner=64,
+                          num_heads=4, num_layers=2, use_flash=False,
+                          remat=True)
+    rng = np.random.RandomState(0)
+    feed = {"ids": rng.randint(3, 64, (batch, seq)).astype(np.int32),
+            "labels": rng.randint(3, 64, (batch, seq)).astype(np.int32)}
+    mesh = pt.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices("cpu")[:4])
+    tr = pt.Trainer(pt.build(gpt.make_model(cfg)), opt.SGD(0.1),
+                    loss_name="loss", mesh=mesh,
+                    sharding_rules=transformer_tp_rules())
+    tr.startup(sample_feed=feed)
+    rep = debugger.collective_report(tr, feed)
+    hops = rep["collectives"]["collective-permute"]
+    assert hops["count"] == 11, rep["collectives"]
+    chunk_mb = batch // 2 // 2 * seq * d * 4 / 1e6     # float32 on the CPU
+    assert hops["payload_mb"] == pytest.approx(11 * chunk_mb)
+    assert hops["wire_mb"] == pytest.approx(hops["payload_mb"])
+    # the gradient exchange over dp is still an all-reduce
+    assert rep["collectives"]["all-reduce"]["count"] >= 1

@@ -1,6 +1,6 @@
-"""The flash kernels and the serve cells' generator, compiled for a
-described TPU v5e with no chip attached (the `on-chip-measurement`
-guide, section 2, third rehearsal).
+"""The flash kernels, the serve cells' generator and the tensor-parallel
+block, compiled for a described TPU v5e with no chip attached (the
+`on-chip-measurement` guide, section 2, third rehearsal).
 
 Interpret mode — what every other flash test runs on the CPU — checks
 none of what the chip's compiler refuses: block shapes that break the
@@ -9,8 +9,9 @@ VMEM. These cases are the shapes the zoo trains, the shapes the
 benchmark's cells run (one chip's share of them) and the long-context
 shapes, forward and backward. The generator's case reads what only the
 chip's compiler decides about the KV cache: the layout and bytes of the
-slabs the decode loop carries. A compile that passes is not a chip run;
-``chip_smoke.py`` is.
+slabs the decode loop carries. The block's case reads the schedule of the
+four-chip train cell's exchanges: whether each still has a matmul beside
+it. A compile that passes is not a chip run; ``chip_smoke.py`` is.
 """
 
 import os
@@ -21,17 +22,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu as pt
+from paddle_tpu import debugger
 from paddle_tpu.core import config
+from paddle_tpu.framework import mesh_mode
+from paddle_tpu.layers import stacked
 from paddle_tpu.models import gpt
 from paddle_tpu.ops import flash_attention as fa
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One device of a described v5e:2x2, with the persistent compile
+def chips():
+    """The devices of a described v5e:2x2, with the persistent compile
     cache off: an entry written by such a compile cannot be read back
     without a chip, and the next run would warn about it."""
     from jax.experimental import topologies
@@ -44,9 +49,14 @@ def chip():
         pytest.skip(f"cannot describe a v5e:2x2 here: {type(e).__name__}: {e}")
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(chips):
+    return SingleDeviceSharding(chips[0])
 
 
 def _attention(causal, mask=None):
@@ -162,3 +172,108 @@ def test_generator_cache_is_lane_dense_for_v5e(chip, monkeypatch):
     moved = [ln for ln in step if re.search(
         r"= %s\S* (copy|transpose|copy-start)\(" % slab, ln)]
     assert not moved, moved[0][:300]
+
+
+def _computations(text):
+    """name -> lines of every computation of an HLO module's text."""
+    out, name = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", ln)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(ln)
+    return out
+
+
+def test_tp_block_exchanges_run_under_matmuls_for_v5e(chips, monkeypatch):
+    """Two layers at gpt2-large's widths (d 1280, inner 5120, 20 heads,
+    16 x 1024 a replica, scan + remat, ``jax.grad``) as dp2 x tp2: the
+    loop bodies hold no all-reduce of an activation, the 11 exchanges of
+    a layer are ``collective-permute-start`` / ``-done`` pairs of half a
+    replica's rows in bf16, matmul fusions are scheduled between a start
+    and its done, and the gradients of the float32 parameters are
+    reduced over dp in float32. Times are the chip's to give
+    (PERF.md, PR 30); this holds the schedule they came from."""
+    d, inner, heads, batch, seq, layers = 1280, 5120, 20, 32, 1024, 2
+    mesh = Mesh(np.array(chips).reshape(2, 2), ("dp", "tp"))
+
+    def net(x):
+        stack = stacked.encoder_stack_params(layers, d, inner)
+        y = stacked.apply_stacked(x, stack, stacked.make_encoder_block,
+                                  num_heads=heads, use_flash=True,
+                                  causal=True, remat=True)
+        return {"loss": jnp.mean(jnp.square(y.astype(jnp.float32)))}
+
+    prog = pt.build(net)
+    before = config.get_flag("default_compute_dtype")
+    config.set_flag("default_compute_dtype", "bfloat16")
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    try:
+        small = np.zeros((2, 8, d), jnp.bfloat16)
+        shapes = jax.eval_shape(lambda key: prog.init(key, x=small)[0],
+                                jax.random.PRNGKey(0))
+        rules = pt.parallel.transformer_tp_rules().adapted_to(mesh)
+        params = {k: jax.ShapeDtypeStruct(
+            v.shape, v.dtype,
+            sharding=NamedSharding(mesh, rules.spec_for(k, v.shape, mesh)))
+            for k, v in shapes.items()}
+        x = jax.ShapeDtypeStruct((batch, seq, d), jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, P("dp")))
+
+        def loss(p, x):
+            with mesh_mode(mesh):
+                return prog.apply(p, {}, training=True, x=x)[0]["loss"]
+
+        text = jax.jit(jax.grad(loss), out_shardings={
+            k: v.sharding for k, v in params.items()}
+        ).lower(params, x).compile().as_text()
+    finally:
+        config.set_flag("default_compute_dtype", before)
+
+    comps = _computations(text)
+    half = re.escape("bf16[%d,%d,%d]" % (batch // 4, seq, d))
+    whole = re.escape("bf16[%d,%d,%d]" % (batch // 2, seq, d))
+    matmuls = {name for name, lines in comps.items()
+               if any(" convolution(" in ln for ln in lines)}
+    starts = dones = covered = 0
+    for lines in comps.values():
+        open_at = {}
+        for i, ln in enumerate(lines):
+            m = re.search(r"%(collective-permute-start[.\d]*) = \(" + half, ln)
+            if m:
+                starts += 1
+                open_at[m.group(1)] = i
+            m = re.search("= " + half
+                          + r"\S* collective-permute-done\(%([\w.\-]+)\)", ln)
+            if m:
+                dones += 1
+                between = lines[open_at.pop(m.group(1)):i]
+                covered += any(
+                    re.search(r" fusion\(.*calls=%([\w.\-]+)", b)
+                    and re.search(r" fusion\(.*calls=%([\w.\-]+)",
+                                  b).group(1) in matmuls for b in between)
+        assert not open_at            # every start has its done in its body
+    # 4 forward, 3 in remat's second forward, 4 backward
+    assert (starts, dones) == (11, 11), (starts, dones)
+    # 8 when the chip measured it (PERF.md, PR 30): the out-projection's
+    # scatter, forward and in remat, and the forward ffn gather have none
+    # (the compiler fuses an own-half matmul with the add of the arrival,
+    # or runs it under the neighbouring exchange)
+    assert covered >= 8, covered
+    # debugger.collective_report reads the same text (the train cell's
+    # ``correct`` goes through it): it sees each exchange once, one chunk
+    hops = [c for c in debugger._parse_hlo_collectives(text, 4)
+            if c[0] == "collective-permute"]
+    assert len(hops) == 11 and {c[1] for c in hops} == {
+        batch // 4 * seq * d * 2}, hops
+    reduces = [ln for ln in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert reduces and not any(re.search(half + "|" + whole, ln)
+                               for ln in reduces), reduces
+    # the stacked float32 gradients, summed over the dp pairs {0,2},{1,3}
+    dp = [ln for ln in reduces
+          if "f32[%d,%d,3,%d]" % (layers, d, d // 2) in ln]
+    assert len(dp) == 1 and "replica_groups={{0,2},{1,3}}" in dp[0], dp
+    assert "bf16[" not in dp[0].split(" all-reduce(")[0]
