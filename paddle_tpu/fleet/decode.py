@@ -32,22 +32,27 @@ import numpy as np
 def export_decoder(dirname: str, cfg, max_new_tokens: int,
                    example_prompt, params: Optional[Dict[str, Any]] = None,
                    batch_buckets: Sequence[int] = (),
-                   seed: int = 0) -> Tuple[Any, Dict[str, Any]]:
-    """Export a ``gpt.make_generator`` program (greedy decode over the
-    config's KV cache — ``cfg.kv_cache_dtype="int8"`` for the int8
-    cache) as a multi-bucket ``save_inference_model`` artifact.
+                   seed: int = 0, model=None) -> Tuple[Any, Dict[str, Any]]:
+    """Export a generator program (greedy decode over the config's cache)
+    as a multi-bucket ``save_inference_model`` artifact. ``model`` is the
+    module whose ``make_generator(cfg, max_new_tokens=...)`` builds it:
+    ``models.gpt`` when not given (a KV cache, ``cfg.kv_cache_dtype="int8"``
+    for the int8 one), ``models.kimi_k2`` for the latent cache; every
+    family goes through this one door.
 
     ``example_prompt``: int32 ``[b, p]`` prompt ids — its batch size
     becomes a bucket; ``batch_buckets`` adds more. ``params`` defaults
     to a fresh init (params trained via ``gpt.make_model`` share names
-    and load directly). Returns ``(program, params)``."""
+    and load directly); given on the host, they never touch the device
+    here. Returns ``(program, params)``."""
     import jax
 
     import paddle_tpu as pt
     from .. import io as pio
-    from ..models import gpt
+    if model is None:
+        from ..models import gpt as model
 
-    prog = pt.build(gpt.make_generator(cfg, max_new_tokens=max_new_tokens))
+    prog = pt.build(model.make_generator(cfg, max_new_tokens=max_new_tokens))
     feed = {"prompt_ids": np.asarray(example_prompt, np.int32)}
     if params is None:
         params, _ = prog.init(jax.random.PRNGKey(seed), **feed)

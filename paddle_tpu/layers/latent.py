@@ -1,0 +1,301 @@
+"""Latent attention (MLA) blocks and what stands round them in the
+DeepseekV3 decoder: RMSNorm, a gated SiLU FFN, rotary positions with
+YaRN's frequency blend. A sibling of ``layers/stacked.py``, written once in
+the stacked form: parameters carry a leading ``[num_layers, ...]`` axis,
+blocks are pure functions of ``(activation, layer_params)`` with no
+``LayerHelper`` call inside, so they trace under ``lax.scan``.
+
+Latent attention projects a token to one 512-wide latent ``c_kv`` and one
+64-wide rotary key ``k_rope`` shared by all heads; keys and values are
+linear in the latent. That gives the block two forms, which agree
+(``tests/test_kimi_k2.py``):
+
+- **expanded** (:func:`mla_prefill`): per head ``[k_nope | v] = c_kv W_kvb``,
+  scores over ``[q_nope | q_rope] . [k_nope | k_rope]`` (192 wide), values
+  128 wide, through the flash kernel (``ops/flash_attention.py`` takes the
+  value width from ``v``). ``k_rope`` is copied to every head for the
+  kernel: one 192-wide product a tile, not a 128-wide and a 64-wide one.
+- **absorbed** (:func:`mla_decode`): ``W_kvb`` moves onto the query and the
+  output (``q' = q_nope W_k^T``, 512 a head; ``o = (P c_kv) W_v``), so a
+  cached step reads 576 numbers a token and layer, whatever the head count,
+  and never builds a key or a value.
+
+The cache is what the absorbed form reads, stored so that the TPU's
+(8, 128) tiles pad nothing: the latents as ``[rows, T, 512]`` (512 = 4 x
+128 lanes) and the rotary keys transposed, ``[rows, 64, T]`` (positions on
+lanes; ``q_rope @ that`` is the rotary part of every score with no
+transposition). One ``[rows, T, 576]`` slab would be held at 640 lanes.
+
+Rotary pairs are ``(2i, 2i + 1)``; the published code de-interleaves and
+rotates halves, the same map up to one fixed permutation of the 64 rotary
+dimensions applied to q and k alike, which leaves every score unchanged.
+"""
+
+from __future__ import annotations
+
+import math
+import time
+from typing import Dict, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from .. import initializer as init
+from ..framework import LayerHelper
+from ..ops.flash_attention import flash_attention
+from .stacked import NEG_INF, StackedInit
+
+
+class MLADims(NamedTuple):
+    """The widths of one latent-attention block (published key names in
+    brackets)."""
+    d_model: int          # hidden_size
+    heads: int            # num_attention_heads
+    q_lora: int           # q_lora_rank
+    kv_lora: int          # kv_lora_rank
+    nope: int             # qk_nope_head_dim
+    rope: int             # qk_rope_head_dim
+    v: int                # v_head_dim
+    eps: float = 1e-5     # rms_norm_eps
+
+    @property
+    def qk(self) -> int:
+        return self.nope + self.rope
+
+
+class Yarn(NamedTuple):
+    """``rope_theta`` and the ``rope_scaling`` group of the config."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+# -- norm, FFN, rotary ---------------------------------------------------------
+
+
+@jax.named_scope("rms")
+def rms_norm(x, g, eps: float = 1e-5):
+    """``x * rsqrt(mean(x^2) + eps) * g``, statistics and scale in float32,
+    result in ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * (W_up x))``; products accumulate in
+    float32, the gate is taken in float32 and rounded once."""
+    gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, y: Yarn):
+    """The ``dim // 2`` rotary frequencies, float32: ``theta^(-2i/dim)``,
+    divided by ``factor`` for the slow dimensions, left alone for the fast
+    ones, blended linearly between the two correction dimensions."""
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    f = y.theta ** (-2.0 * i / dim)
+    if y.factor <= 1.0:
+        return f
+
+    def correction_dim(rotations):
+        return (dim * math.log(y.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(y.theta)))
+
+    lo = max(math.floor(correction_dim(y.beta_fast)), 0)
+    hi = min(math.ceil(correction_dim(y.beta_slow)), dim - 1)
+    ramp = jnp.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return f / y.factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_cos_sin_scale(y: Yarn) -> float:
+    """What YaRN multiplies cos and sin by (1 where ``mscale`` equals
+    ``mscale_all_dim``, as in Kimi-K2.5)."""
+    return (_yarn_mscale(y.factor, y.mscale)
+            / _yarn_mscale(y.factor, y.mscale_all_dim))
+
+
+def softmax_scale(dims: MLADims, y: Yarn) -> float:
+    """``qk^-0.5``, times ``yarn_mscale(factor, mscale_all_dim)^2`` where
+    that is set."""
+    m = _yarn_mscale(y.factor, y.mscale_all_dim) if y.mscale_all_dim else 1.0
+    return dims.qk ** -0.5 * m * m
+
+
+def rope(x, positions, freqs, scale: float = 1.0, head_axis: bool = False):
+    """Rotate the pairs ``(2i, 2i + 1)`` of ``x [..., s, dim]`` (with
+    ``head_axis``: ``[..., s, H, dim]``) by ``positions[s] * freqs[i]``;
+    angles, cos and sin in float32."""
+    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    if head_axis:
+        ang = ang[:, None, :]
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+# -- parameters ------------------------------------------------------------------
+
+
+def _normal(fan_in: int, layers: Optional[int]):
+    base = init.Normal(0.0, fan_in ** -0.5)
+    return base if layers is None else StackedInit(base)
+
+
+def _params(helper: LayerHelper, shapes, layers: Optional[int], dtype):
+    """``name -> (shape, fan_in)``, fan_in ``None`` for a norm's scale
+    (ones, float32); with ``layers`` every shape gains the leading axis."""
+    out = {}
+    for name, (shape, fan_in) in shapes.items():
+        full = shape if layers is None else (layers,) + shape
+        if fan_in is None:
+            out[name] = helper.create_parameter(
+                name, full, jnp.float32, initializer=init.Constant(1.0))
+        else:
+            out[name] = helper.create_parameter(
+                name, full, dtype, initializer=_normal(fan_in, layers))
+    return out
+
+
+def mla_params(dims: MLADims, dtype, layers: Optional[int] = None,
+               name: str = "mla") -> Dict[str, jax.Array]:
+    """One block's attention parameters (or ``layers`` of them, stacked),
+    every matrix in ``dtype``. The published ``kv_b_proj`` is held as its
+    two halves, heads leading: ``kv_b_k [H, nope, kv_lora]`` (so that
+    ``q_nope @ kv_b_k`` is the absorbed query) and ``kv_b_v [H, kv_lora,
+    v]``."""
+    d, h = dims.d_model, dims.heads
+    return _params(LayerHelper(name, name=name), {
+        "attn_norm/g": ((d,), None),
+        "q_a/w": ((d, dims.q_lora), d),
+        "q_norm/g": ((dims.q_lora,), None),
+        "q_b/w": ((dims.q_lora, h * dims.qk), dims.q_lora),
+        "kv_a/w": ((d, dims.kv_lora + dims.rope), d),
+        "kv_norm/g": ((dims.kv_lora,), None),
+        "kv_b_k/w": ((h, dims.nope, dims.kv_lora), dims.kv_lora),
+        "kv_b_v/w": ((h, dims.kv_lora, dims.v), dims.kv_lora),
+        "o/w": ((h * dims.v, d), h * dims.v),
+    }, layers, dtype)
+
+
+def gated_ffn_params(d_model: int, width: int, dtype,
+                     layers: Optional[int] = None, name: str = "ffn",
+                     with_norm: bool = True) -> Dict[str, jax.Array]:
+    shapes = {"gate/w": ((d_model, width), d_model),
+              "up/w": ((d_model, width), d_model),
+              "down/w": ((width, d_model), width)}
+    if with_norm:
+        shapes["ffn_norm/g"] = ((d_model,), None)
+    return _params(LayerHelper(name, name=name), shapes, layers, dtype)
+
+
+# -- the block's two forms ----------------------------------------------------------
+
+
+def _record_plan(dims: MLADims, form: str, seq: int, scale: float):
+    """One zero-length span in the program's ring for each attention
+    traced: its widths and which form it took."""
+    from ..core import profiler
+
+    profiler.record_span(
+        "mla.plan", time.time_ns(), 0, heads=dims.heads, q_lora=dims.q_lora,
+        kv_lora=dims.kv_lora, rope_dim=dims.rope, nope_dim=dims.nope,
+        v_dim=dims.v, form=form, seq=seq, softmax_scale=scale)
+
+
+def _queries(h, p, dims: MLADims, positions, freqs, cs_scale):
+    """``(q_nope [b, s, H, nope], q_rope [b, s, H, rope])`` of the normed
+    input ``h [b, s, d]``, the rotary part rotated."""
+    b, s, _ = h.shape
+    c_q = rms_norm(jnp.matmul(h, p["q_a/w"]), p["q_norm/g"], dims.eps)
+    q = jnp.matmul(c_q, p["q_b/w"]).reshape(b, s, dims.heads, dims.qk)
+    return q[..., :dims.nope], rope(q[..., dims.nope:], positions, freqs,
+                                    cs_scale, head_axis=True)
+
+
+def _latents(h, p, dims: MLADims, positions, freqs, cs_scale):
+    """What the cache holds of ``h [b, s, d]``: ``c_kv [b, s, kv_lora]``
+    after its norm and ``k_rope [b, s, rope]`` after RoPE."""
+    kv = jnp.matmul(h, p["kv_a/w"])
+    c_kv = rms_norm(kv[..., :dims.kv_lora], p["kv_norm/g"], dims.eps)
+    return c_kv, rope(kv[..., dims.kv_lora:], positions, freqs, cs_scale)
+
+
+def mla_prefill(x, p, dims: MLADims, y: Yarn):
+    """The expanded form over a whole prompt: ``x [b, s, d]`` ->
+    ``(x + attention, (c_kv [b, s, kv_lora], k_rope [b, rope, s]))``, the
+    pair being this layer's cache entries in the cache's own layout."""
+    b, s, _ = x.shape
+    positions, freqs = jnp.arange(s), yarn_frequencies(dims.rope, y)
+    scale, cs = softmax_scale(dims, y), yarn_cos_sin_scale(y)
+    _record_plan(dims, "expanded", s, scale)
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["attn_norm/g"], dims.eps)
+        q_nope, q_rope = _queries(h, p, dims, positions, freqs, cs)
+        c_kv, k_rope = _latents(h, p, dims, positions, freqs, cs)
+        k_nope = jnp.einsum("bsc,hnc->bhsn", c_kv, p["kv_b_k/w"])
+        v = jnp.einsum("bsc,hcv->bhsv", c_kv, p["kv_b_v/w"])
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, None], (b, dims.heads, s,
+                                                        dims.rope))], axis=-1)
+        o = flash_attention(q, k, v, causal=True, scale=scale)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, dims.heads * dims.v)
+        x = x + jnp.matmul(o, p["o/w"])
+    return x, (c_kv, k_rope.transpose(0, 2, 1))
+
+
+def mla_decode(x, p, c_cache, r_cache, index, dims: MLADims, y: Yarn):
+    """The absorbed form for one token at position ``index``: ``x [rows,
+    1, d]``; this layer's ``c_cache [rows, T, kv_lora]`` and ``r_cache
+    [rows, rope, T]`` written in place at ``index`` and read as stored;
+    positions ``<= index`` attended. Returns ``(x + attention, c_cache,
+    r_cache)``."""
+    T = c_cache.shape[1]
+    positions, freqs = index[None], yarn_frequencies(dims.rope, y)
+    scale, cs = softmax_scale(dims, y), yarn_cos_sin_scale(y)
+    _record_plan(dims, "absorbed", T, scale)
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["attn_norm/g"], dims.eps)
+        q_nope, q_rope = _queries(h, p, dims, positions, freqs, cs)
+        c_new, r_new = _latents(h, p, dims, positions, freqs, cs)
+        c_cache = jax.lax.dynamic_update_slice(
+            c_cache, c_new.astype(c_cache.dtype), (0, index, 0))
+        r_cache = jax.lax.dynamic_update_slice(
+            r_cache, r_new.transpose(0, 2, 1).astype(r_cache.dtype),
+            (0, 0, index))
+        q_lat = jnp.einsum("rhn,hnc->rhc", q_nope[:, 0], p["kv_b_k/w"])
+        s = (jnp.einsum("rhc,rtc->rht", q_lat, c_cache,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("rhe,ret->rht", q_rope[:, 0], r_cache,
+                          preferred_element_type=jnp.float32)) * scale
+        live = jnp.arange(T)[None, None, :] <= index
+        probs = jax.nn.softmax(jnp.where(live, s, NEG_INF), axis=-1)
+        o_lat = jnp.einsum("rht,rtc->rhc", probs.astype(x.dtype), c_cache)
+        o = jnp.einsum("rhc,hcv->rhv", o_lat, p["kv_b_v/w"])
+        x = x + jnp.matmul(o.reshape(x.shape[0], 1, dims.heads * dims.v),
+                           p["o/w"])
+    return x, c_cache, r_cache
+
+
+def ffn_block(x, p, eps: float = 1e-5):
+    """``x + FFN(RMSNorm(x))`` with the block's own norm."""
+    h = rms_norm(x, p["ffn_norm/g"], eps)
+    return x + gated_ffn(h, p["gate/w"], p["up/w"], p["down/w"])
+
+
+__all__ = ["MLADims", "Yarn", "ffn_block", "gated_ffn", "gated_ffn_params",
+           "mla_decode", "mla_params", "mla_prefill", "rms_norm", "rope",
+           "softmax_scale", "yarn_cos_sin_scale", "yarn_frequencies"]

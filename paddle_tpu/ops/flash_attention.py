@@ -144,6 +144,7 @@ class FlashPlan(NamedTuple):
     fold_scale: bool  # scale folded into the still operand (power of two)
     tiles_run: int   # compute tiles the forward walk visits ...
     tiles_all: int   # ... of the tiles in the padded score rectangle
+    dv: int = 0      # width of a value and of an output row (plan_blocks sets it)
 
 
 def _round_up(n, m):
@@ -204,7 +205,7 @@ def _chunk_bounds(r0, tile_q, c_base, n_chunks, tile_k, *, causal, offset,
 
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
-                bh=1) -> FlashPlan:
+                bh=1, dv=None, scale=None) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -214,11 +215,14 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     computes ``STEP_SCORES`` scores. Explicit ``block_q``/``block_k`` win
     (the compute tile then follows the block). ``have_bias``/``have_seg`` do
     not change the blocks today; they are part of what a plan may depend
-    on."""
+    on. ``d`` is the width the scores contract over and ``dv`` that of a
+    value (latent attention: 192 and 128); ``scale`` is the softmax scale
+    where it is not ``d ** -0.5``."""
     del dtype, have_bias, have_seg
+    dv = d if dv is None else dv
     sq_p, block_q, tile_q = _axis_plan(sq, block_q, 128)
     sk_p, block_k, tile_k = _axis_plan(sk, block_k, 16)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     fold = math.frexp(scale)[0] == 0.5
 
     # the forward walk's executed share of the score rectangle
@@ -235,12 +239,13 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     # STEP_BYTES of VMEM (about a dozen double-buffered row blocks a head)
     share = run / tiles_all if (sq_p, sk_p) == (block_q, block_k) else 1.0
     step_scores = block_q * block_k * share
-    step_bytes = 12 * max(block_q, block_k) * _round_up(d, 128) * 2
+    step_bytes = (6 * max(block_q, block_k)
+                  * (_round_up(d, 128) + _round_up(dv, 128)) * 2)
     heads = max(g for g in range(1, bh + 1) if bh % g == 0 and (
         g == 1 or (g * step_scores <= STEP_SCORES
                    and g * step_bytes <= STEP_BYTES)))
     return FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
-                     sq_p, sk_p, causal, fold, run, tiles_all)
+                     sq_p, sk_p, causal, fold, run, tiles_all, dv)
 
 
 def _record_plan(p: FlashPlan):
@@ -249,7 +254,7 @@ def _record_plan(p: FlashPlan):
     from ..core import profiler
 
     profiler.record_span(
-        "flash.plan", time.time_ns(), 0, sq=p.sq, sk=p.sk, d=p.d,
+        "flash.plan", time.time_ns(), 0, sq=p.sq, sk=p.sk, d=p.d, dv=p.dv,
         block_q=p.block_q, block_k=p.block_k, tile_q=p.tile_q,
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all)
@@ -442,7 +447,7 @@ def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan):
     nqt, nkt = p.block_q // p.tile_q, p.block_k // p.tile_k
     q = _pad_seq(q, p.sq_p, 2).reshape(bh, p.sq_p, d)
     k = _pad_seq(k, p.sk_p, 2).reshape(bh, p.sk_p, d)
-    v = _pad_seq(v, p.sk_p, 2).reshape(bh, p.sk_p, d)
+    v = _pad_seq(v, p.sk_p, 2).reshape(bh, p.sk_p, p.dv)
 
     def per_head(x, s_p, value, dtype):
         x = _pad_seq(x.astype(dtype), s_p, 1, value)
@@ -598,24 +603,30 @@ def _grid_maps(p: FlashPlan, offset):
             lambda i, j, kk: (i, ck(kk, j), 0, 0))
 
 
-def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k):
+def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
+             scale=None):
     """(plan, walk, operands) of one call."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     p = plan_blocks(sq, sk, d, q.dtype, causal, bias is not None,
-                    seg_q is not None, block_q, block_k, bh=b * h)
-    w = _Walk(p, 1.0 / math.sqrt(d), sk - sq, bias is not None,
-              seg_q is not None)
+                    seg_q is not None, block_q, block_k, bh=b * h,
+                    dv=v.shape[-1], scale=scale)
+    w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
     return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p)
 
 
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
-               interpret: bool):
+               interpret: bool, scale: Optional[float] = None):
+    """``q`` and ``k`` are ``[b, h, s, d]`` and ``v`` ``[b, h, s_k, dv]``:
+    the scores contract over ``d``, the output rows are ``dv`` wide (the
+    kernel reads both from its blocks' shapes)."""
     b, h, sq, d = q.shape
+    dv = v.shape[-1]
     bh = b * h
     p, w, ops = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                         block_k)
+                         block_k, scale)
     _record_plan(p)
     g, nq, nk = p.heads, w.nq, w.nk
     q_map, k_map, qrow_map, krow_map = _grid_maps(p, w.offset)
@@ -627,18 +638,18 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
         grid=(bh // g, nq, nk),
         in_specs=[pl.BlockSpec((g, p.block_q, d), q_map),
                   pl.BlockSpec((g, p.block_k, d), k_map),
-                  pl.BlockSpec((g, p.block_k, d), k_map)] + mask_specs,
-        out_specs=[pl.BlockSpec((g, p.block_q, d), q_map),
+                  pl.BlockSpec((g, p.block_k, dv), k_map)] + mask_specs,
+        out_specs=[pl.BlockSpec((g, p.block_q, dv), q_map),
                    pl.BlockSpec((g, 1, w.nqt, p.tile_q), qrow_map)],
-        out_shape=[jax.ShapeDtypeStruct((bh, p.sq_p, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, p.sq_p, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
                         pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
-                        pltpu.VMEM((g, w.nqt, d, p.tile_q), jnp.float32)],
+                        pltpu.VMEM((g, w.nqt, dv, p.tile_q), jnp.float32)],
         interpret=interpret,
     )(ops.q, ops.k, ops.v, *mask_args)
-    out = out.reshape(b, h, p.sq_p, d)[:, :, :sq]
+    out = out.reshape(b, h, p.sq_p, dv)[:, :, :sq]
     lse = lse.reshape(b, h, p.sq_p)[:, :, :sq]
     return out, lse
 
@@ -787,14 +798,20 @@ def _dkv_kernel(*refs, w: _Walk):
 
 def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
                block_q: Optional[int], block_k: Optional[int],
-               interpret: bool, delta=None):
+               interpret: bool, delta=None, scale: Optional[float] = None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
+    if v.shape[-1] != d:
+        # the dq and dkv kernels hold dO, K and V in blocks of one width
+        raise NotImplementedError(
+            f"flash_attention: no backward pass for values {v.shape[-1]} "
+            f"wide under scores that contract over {d}; the forward takes "
+            f"unequal widths, the dq and dkv kernels do not yet")
     if delta is None:
         delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     p, w, ops = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                         block_k)
+                         block_k, scale)
     hg, nq, nk = p.heads, w.nq, w.nk
 
     # padded q rows: g/delta 0 and lse huge, so p=exp(s-lse)=0 — they
@@ -858,25 +875,25 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
 # custom VJP plumbing
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _flash_core(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-                interpret):
+                interpret, scale=None):
     out, _ = _flash_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                        block_k, interpret)
+                        block_k, interpret, scale)
     return out
 
 
 def _flash_core_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-                    interpret):
+                    interpret, scale=None):
     out, lse = _flash_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                          block_k, interpret)
+                          block_k, interpret, scale)
     return out, (q, k, v, bias, seg_q, seg_k, out, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_core_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, bias, seg_q, seg_k, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
-                            block_q, block_k, interpret)
+                            block_q, block_k, interpret, scale=scale)
     return dq, dk, dv, None, None, None
 
 
@@ -894,8 +911,12 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     return_lse: bool = False,
+    scale: Optional[float] = None,
 ):
-    """Flash attention over [b, h, s, d].
+    """Flash attention over ``q``, ``k`` [b, h, s, d] and ``v``
+    [b, h, s_k, dv]; the output is [b, h, s_q, dv]. ``dv`` may differ
+    from ``d`` in the forward pass (latent attention scores over 192 and
+    sums values 128 wide); the backward pass raises for unequal widths.
 
     - ``key_bias``: additive [b, s_k] (padding mask).
     - ``segment_ids`` / ``kv_segment_ids``: int [b, s] ragged-batch ids
@@ -909,6 +930,7 @@ def flash_attention(
       leave the choice to :func:`plan_blocks` (read at trace time).
     - ``return_lse``: also return the per-query logsumexp [b, h, s_q]
       (forward only — used by ring attention to merge shards).
+    - ``scale``: the softmax scale; None is ``d ** -0.5``.
     """
     from ..core.errors import enforce
 
@@ -937,20 +959,20 @@ def flash_attention(
                 seg_k_ = kv_segment_ids if kv_segment_ids is not None else segment_ids
                 same = segment_ids[:, None, :, None] == seg_k_[:, None, None, :]
                 mask = jnp.where(same, mask, NEG_INF)
-            return _mask_fallback(q, k, v, mask, causal)
+            return _mask_fallback(q, k, v, mask, causal, scale)
     seg_q = segment_ids
     seg_k = kv_segment_ids if kv_segment_ids is not None else segment_ids
     bias = None if key_bias is None else key_bias.astype(jnp.float32)
     if return_lse:
         return _flash_fwd(q, k, v, bias, seg_q, seg_k, causal,
-                          block_q, block_k, interpret)
+                          block_q, block_k, interpret, scale)
     return _flash_core(q, k, v, bias, seg_q, seg_k, causal,
-                       block_q, block_k, interpret)
+                       block_q, block_k, interpret, scale)
 
 
-def _mask_fallback(q, k, v, attn_mask, causal):
+def _mask_fallback(q, k, v, attn_mask, causal, scale=None):
     from .attention_scores import scores_mxu
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     s = scores_mxu(q, k, scale)
     s = s + attn_mask
     if causal:

@@ -31,6 +31,7 @@ import contextlib
 import functools
 import math
 import threading
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -244,6 +245,124 @@ def moe(
         in_specs=(xspec, P(), espec, espec, espec, espec),
         out_specs=(xspec, P()))
     return fn(x, wg, w1, b1, w2, b2)
+
+
+# -- a held share of a wide expert layer (sigmoid, bias-corrected, dropless) ----
+
+# Rows of one grouped product. The (token, held expert) pairs of a call are
+# data: a prompt of t tokens gives t * top_k * held / total of them on
+# average and t * top_k at most. They are walked in blocks of this many, as
+# many blocks as there are pairs, so nothing is dropped, no capacity is
+# stated, and the buffers stay one block large whatever the routing does.
+# 512 is what the scatter-add that returns a block's rows to their tokens
+# allows on the TPU: XLA walks up to some count of indices one by one, a
+# microsecond a row (0.51 ms for 512 rows of 7,168 into 15,872), and above
+# it takes a form that costs 12.7 ms whatever the count (2,048 rows 12.7 ms,
+# 4,096 13.1; a one-hot product on the MXU 1.43 / 2.50 / 4.98 ms; my chip
+# run, PR 31, PERF.md section 6).
+PAIR_BLOCK = 512
+
+
+def sigmoid_topk_route(h, router_w, select_bias, top_k: int,
+                       scaling: float = 1.0):
+    """DeepseekV3's ``noaux_tc`` routing with one group: scores
+    ``sigmoid(h W_r)`` in float32 at full precision (a score that decides a
+    selection may not depend on the matmul's rounding mode), the ``top_k``
+    of ``score + select_bias`` selected, and weights from the unbiased
+    scores of the selected, normalised to sum 1 and times ``scaling``.
+    ``h [t, d]`` -> ``(experts [t, top_k] int32, weights [t, top_k] f32)``."""
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            h.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
+                                   top_k)
+        picked = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
+    return experts.astype(jnp.int32), weights
+
+
+def _record_held_plan(tokens, total, held, first, top_k, banks):
+    """One zero-length span for each expert layer traced; ``banks`` may
+    hold more groups than the ``held`` this layer reads."""
+    from ..core import profiler
+
+    profiler.record_span(
+        "moe.plan", time.time_ns(), 0, experts_total=total, experts_held=held,
+        first_expert=first, top_k=top_k, tokens=tokens, form="ragged_dot",
+        pair_block=min(PAIR_BLOCK, tokens * top_k),
+        expert_bytes_held=sum(
+            held * math.prod(w.shape[1:]) * w.dtype.itemsize for w in banks))
+
+
+def moe_held(h, experts, weights, w_gate, w_up, w_down, *, first_expert: int,
+             experts_held: int, experts_total: int, bank_offset=0):
+    """The part of a routed layer's result that the experts held here
+    give: ``sum over e in a token's selection, first_expert <= e <
+    first_expert + experts_held, of weights_e * E_e(h)``, each ``E_e`` a
+    gated SiLU FFN. ``h [t, d]``; ``experts``, ``weights`` ``[t, top_k]``
+    from :func:`sigmoid_topk_route` over all ``experts_total``. Returns
+    ``[t, d]`` float32. What the experts held elsewhere would add is left
+    out; no code stands in for them or for their exchange.
+
+    The pairs are sorted by expert and each block of them goes through
+    one grouped product a matrix (``jax.lax.ragged_dot``: on the TPU a
+    grouped-matmul kernel that visits only the row tiles and the experts
+    that a group fills) and back to its tokens by a scatter-add. The
+    banks ``[G, d, f]`` / ``[G, f, d]`` may hold more groups than this
+    layer's (a stack of layers, flattened):
+    ``bank_offset`` (traced or static) says where this layer's
+    ``experts_held`` start, and every other group gets size 0, so a layer
+    of a scanned stack reads its experts in place, with no slice taken."""
+    t, top_k = experts.shape
+    groups = w_gate.shape[0]
+    _record_held_plan(t, experts_total, experts_held, first_expert, top_k,
+                      (w_gate, w_up, w_down))
+    with jax.named_scope("moe"):
+        local = experts.reshape(-1) - first_expert          # [t * top_k]
+        here = (local >= 0) & (local < experts_held)
+        key = jnp.where(here, local, experts_held)          # absent ones last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.bincount(key, length=experts_held + 1)[:experts_held]
+        ends = jnp.cumsum(sizes)
+        n_pairs = ends[-1]
+        block = min(PAIR_BLOCK, t * top_k)
+        order = jnp.pad(order, (0, block))
+        flat_w = weights.reshape(-1)
+
+        def one_block(i, out):
+            lo = i * block
+            pair = jax.lax.dynamic_slice(order, (lo,), (block,))
+            live = lo + jnp.arange(block) < n_pairs
+            token = jnp.where(live, pair // top_k, 0)
+            # this block's rows of each group: the group's span, clipped
+            in_block = (jnp.clip(ends, lo, lo + block)
+                        - jnp.clip(ends - sizes, lo, lo + block))
+            gs = jax.lax.dynamic_update_slice(
+                jnp.zeros((groups,), jnp.int32), in_block.astype(jnp.int32),
+                (bank_offset,))
+            x = h[token]
+            gate = jax.lax.ragged_dot(x, w_gate, gs,
+                                      preferred_element_type=jnp.float32)
+            up = jax.lax.ragged_dot(x, w_up, gs,
+                                    preferred_element_type=jnp.float32)
+            y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(h.dtype),
+                                   w_down, gs,
+                                   preferred_element_type=jnp.float32)
+            y = jnp.where(live[:, None], y * flat_w[pair][:, None], 0.0)
+            if t > block:
+                return out.at[token].add(y)     # a dead row adds 0 to token 0
+            # a step's few tokens: a 0/1 [t, block] operand times the block,
+            # microseconds on the MXU where the scatter walks every row
+            back = (jnp.arange(t)[:, None] == token[None, :]) & live[None, :]
+            return out + jnp.matmul(back.astype(h.dtype), y.astype(h.dtype),
+                                    preferred_element_type=jnp.float32)
+
+        out = jnp.zeros((t, h.shape[-1]), jnp.float32)
+        if block == t * top_k:      # every pair fits one block (a decode step)
+            return one_block(0, out)
+        return jax.lax.fori_loop(0, (n_pairs + block - 1) // block,
+                                 one_block, out)
 
 
 def moe_ep_rules():

@@ -30,7 +30,7 @@ from paddle_tpu import debugger
 from paddle_tpu.core import config
 from paddle_tpu.framework import mesh_mode
 from paddle_tpu.layers import stacked
-from paddle_tpu.models import gpt
+from paddle_tpu.models import gpt, kimi_k2
 from paddle_tpu.ops import flash_attention as fa
 
 
@@ -88,8 +88,13 @@ SHAPES = {
     "gpt2m_train": (_attention(True), (32, 16, 1024, 64), None),
     "gpt2l_train_shard": (_attention(True), (16, 10, 1024, 64), None),
     "gpt2m_prefill": (_attention(True), (16, 16, 896, 64), None),
+    # k25-serve-batch's prefill: latent attention scores over 192 (128
+    # nope + 64 rope) and sums values 128 wide, under YaRN's softmax scale
+    "k25_prefill": (lambda q, k, v: fa.flash_attention(
+        q, k, v[..., :128], causal=True, interpret=False, scale=0.1147),
+        (8, 64, 1984, 192), None),
 }
-FORWARD_ONLY = ("gpt2m_prefill",)
+FORWARD_ONLY = ("gpt2m_prefill", "k25_prefill")
 # Every shape's gradient (which compiles its forward kernel too), and
 # the forward alone where that is what runs: the tier is close to its
 # time limit.
@@ -172,6 +177,78 @@ def test_generator_cache_is_lane_dense_for_v5e(chip, monkeypatch):
     moved = [ln for ln in step if re.search(
         r"= %s\S* (copy|transpose|copy-start)\(" % slab, ln)]
     assert not moved, moved[0][:300]
+
+
+def _k25_generator(chip, monkeypatch, layers):
+    """The ``k25-serve-batch`` generator (8 rows, prompt 1984 + 64 new,
+    bfloat16, the benchmark's configuration file at ``layers`` of its
+    depth) compiled for one described chip."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import kimi_k2 as family
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "kimi-k2.5-ep32.json")) as f:
+        cell_config = dict(json.load(f), num_hidden_layers=layers)
+    rows, prompt, new = 8, 1984, 64
+    prog = pt.build(kimi_k2.make_generator(family.program_config(cell_config),
+                                           max_new_tokens=new))
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    one_row = np.zeros((1, prompt), np.int32)
+    shapes = jax.eval_shape(lambda key: prog.init(key, prompt_ids=one_row)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
+    ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]["ids"]
+                       ).lower(params, ids).compile()
+    return cell_config, compiled
+
+
+def test_k25_latent_cache_is_lane_dense_for_v5e(chip, monkeypatch):
+    """Two expert layers suffice for layouts: the decode loop carries each
+    layer's latents as ``bf16[8,2048,512]`` and its rotary keys as
+    ``bf16[8,64,2048]``, minor dimension last and a multiple of 128, tiled
+    (8, 128) with nothing padded (one ``[8,2048,576]`` slab would be held at
+    640 lanes); no step copies a slab; no bank of routed experts is sliced
+    or copied (every layer reads its experts in place through the grouped
+    product's group sizes); flash and the grouped products are kernels."""
+    _, compiled = _k25_generator(chip, monkeypatch, layers=3)
+    text = compiled.as_text()
+    slabs = set(re.findall(r"bf16\[8,(?:2048,512|64,2048)\]\{[^}]*\}", text))
+    assert slabs and all(s.split("{")[1].startswith("2,1,0:T(8,128)(2,1)")
+                         for s in slabs), slabs
+    assert not re.search(r"bf16\[8,2048,(576|640)\]", text)
+    step = [ln for ln in text.splitlines() if "decode_step" in ln]
+    assert step
+    copies = [ln for ln in step if re.search(
+        r"= bf16\[8,(2048,512|64,2048)\]\S* (copy|transpose)\(", ln)]
+    assert not copies, copies[:2]
+    banks = [ln for ln in text.splitlines() if re.search(
+        r"= bf16\[(12|24),(7168,2048|2048,7168)\]\S* "
+        r"(copy|slice|dynamic-slice|copy-start)\(", ln)]
+    assert not banks, banks[:2]
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 6
+
+
+def test_k25_generator_fits_one_v5e(chip, monkeypatch):
+    """The whole cut (1 dense + 5 expert layers, 12 experts a layer, 20480
+    rows of the vocabulary): 8.37 GB of arguments and 2.47 GB of
+    temporaries, under 14.5 GB together; the configuration file's ``memory``
+    group records what this compile said."""
+    cell_config, compiled = _k25_generator(chip, monkeypatch, layers=6)
+    m = compiled.memory_analysis()
+    assert 8.3e9 < m.argument_size_in_bytes < 8.45e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9
+    recorded = cell_config["memory"]
+    assert abs(recorded["generator_weights_bytes"]
+               - m.argument_size_in_bytes) < 1e6
+    assert abs(recorded["generator_rows_8_temporaries_bytes"]
+               - m.temp_size_in_bytes) < 0.15e9
 
 
 def _computations(text):
