@@ -131,9 +131,12 @@ def dense_attention(q, k, v, causal: bool, key_bias=None, segment_ids=None):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
 
 
-def kernel_case(name: str, shape, causal: bool, mask: str, seed: int):
+def kernel_case(name: str, shape, causal: bool, mask: str, seed: int,
+                bsd: bool = False):
     """Flash forward + backward against the dense f32 composition.
-    ``mask``: "none", "key_bias" (padding) or "segment_ids" (packing)."""
+    ``mask``: "none", "key_bias" (padding) or "segment_ids" (packing).
+    ``bsd``: the kernels get the same operands as ``[b, s, h*d]``, the
+    layout a block's projections leave them in, and read it in place."""
     b, h, s, d = shape
     kq, kk, kv, kg, kb = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k, v, g = (jax.random.normal(key, shape, jnp.bfloat16)
@@ -152,8 +155,18 @@ def kernel_case(name: str, shape, causal: bool, mask: str, seed: int):
             return (out,) + vjp(g.astype(out.dtype))
         return jax.jit(run)
 
-    flash = fwd_bwd(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, key_bias=bias, segment_ids=seg))
+    def heads_together(x):
+        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+    def attend(q, k, v):
+        if not bsd:
+            return flash_attention(q, k, v, causal=causal, key_bias=bias,
+                                   segment_ids=seg)
+        out = flash_attention(*map(heads_together, (q, k, v)), causal=causal,
+                              key_bias=bias, segment_ids=seg, num_heads=h)
+        return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+    flash = fwd_bwd(attend)
     dense = fwd_bwd(lambda q, k, v: dense_attention(
         q, k, v, causal, key_bias=bias, segment_ids=seg))
     calls = flash.lower(q, k, v, g).as_text().count("tpu_custom_call")
@@ -179,11 +192,14 @@ def kernel_case(name: str, shape, causal: bool, mask: str, seed: int):
 
 def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
                  transformer_shape=(32, 8, 256, 64)) -> None:
-    with timed("kernel", "three cases, compiles included"):
+    with timed("kernel", "five cases, compiles included"):
         kernel_case("gpt", gpt_shape, True, "none", seed)
         kernel_case("gpt_packed", gpt_shape, True, "segment_ids", seed + 1)
         kernel_case("transformer_base", transformer_shape, False, "key_bias",
                     seed + 2)
+        kernel_case("gpt_bsd", gpt_shape, True, "none", seed + 3, bsd=True)
+        kernel_case("transformer_base_bsd", transformer_shape, False,
+                    "key_bias", seed + 4, bsd=True)
 
 
 # ---------------------------------------------------------------------------

@@ -48,35 +48,67 @@ def flash_applies(use_flash: bool, dropout_rate: float) -> bool:
     return False
 
 
-def flash_sdpa(q, k, v, causal: bool, key_bias=None):
+def tp_partitioned() -> bool:
+    """Does the partitioner split this trace over a ``tp`` axis larger
+    than 1 (a mesh is active and the trace is not already per shard,
+    inside a ``shard_map``)? Weights then carry the rule table's ``tp``
+    shardings, and a reshape that merges a sharded dimension into another
+    makes the partitioner gather them."""
+    from ..parallel.mesh import TP
+
+    mesh = active_mesh()
+    return (mesh is not None and mesh.shape.get(TP, 1) > 1
+            and not jax.sharding.get_abstract_mesh().manual_axes)
+
+
+def flash_sdpa(q, k, v, causal: bool, key_bias=None, num_heads=None):
     """The flash kernel over [b, h, s, d] with an optional additive
-    [b, s_k] key bias. Under a Trainer's multi-device mesh the kernel
-    runs per shard inside ``shard_map`` — batch over the data axes,
-    heads over ``tp`` — because GSPMD cannot partition a Mosaic kernel
-    (its lowering refuses any jit over more than one device). A dim the
-    axis size does not divide stays whole on every shard, as GSPMD
-    itself would leave it. Inside an enclosing ``shard_map`` (pipeline
-    stages, the shard-local gradient paths) the call is already
-    per-shard and goes straight to the kernel."""
+    [b, s_k] key bias; with ``num_heads``, over [b, s, h*d] as a
+    projection leaves it, or over a fused self-attention projection
+    [b, s, 3 * h*d] given as ``q`` with ``k`` and ``v`` None (the kernels
+    read and write those in place: ops/flash_attention.py). Under a
+    Trainer's multi-device mesh the kernel runs per shard inside
+    ``shard_map`` — batch over the data axes, heads over ``tp`` (whole
+    heads of the minor dimension in the projections' layout) — because
+    GSPMD cannot partition a Mosaic kernel (its lowering refuses any jit
+    over more than one device). A dim the axis size does not divide
+    stays whole on every shard, as GSPMD itself would leave it. Inside
+    an enclosing ``shard_map`` (pipeline stages, the shard-local
+    gradient paths) the call is already per-shard and goes straight to
+    the kernel."""
     from ..ops.flash_attention import flash_attention
     from ..parallel.mesh import DATA_AXES, TP, dividing_axes
 
+    args = [x for x in (q, k, v) if x is not None]
     mesh = active_mesh()
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
-        return flash_attention(q, k, v, causal=causal, key_bias=key_bias)
+        return flash_attention(*args, causal=causal, key_bias=key_bias,
+                               num_heads=num_heads)
 
     batch = dividing_axes(mesh, q.shape[0], DATA_AXES) or None
-    heads = dividing_axes(mesh, q.shape[1], (TP,)) or None
-    qkv = P(batch, heads, None, None)
-    args, specs = [q, k, v], [qkv, qkv, qkv]
+    heads = dividing_axes(mesh, num_heads or q.shape[1], (TP,)) or None
+    if num_heads is None:
+        spec = P(batch, heads, None, None)
+    else:
+        spec = P(batch, None, heads)
+        if heads:
+            num_heads //= mesh.shape[TP]
+    if k is None and heads:
+        # a shard's lanes would have to be its third of each of q, k, v
+        args = list(jnp.split(q, 3, axis=-1))
+    specs = [spec] * len(args)
     if key_bias is not None:
-        args.append(jnp.broadcast_to(key_bias, (q.shape[0], k.shape[2])))
+        args.append(jnp.broadcast_to(key_bias, (q.shape[0], key_bias.shape[-1])))
         specs.append(P(batch, None))
-    fn = jax.shard_map(
-        lambda q_, k_, v_, *bias: flash_attention(
-            q_, k_, v_, causal=causal, key_bias=bias[0] if bias else None),
-        mesh=mesh, in_specs=tuple(specs), out_specs=qkv, check_vma=False)
+
+    def per_shard(*a):
+        qkv, bias = (a[:-1], a[-1]) if key_bias is not None else (a, None)
+        return flash_attention(*qkv, causal=causal, key_bias=bias,
+                               num_heads=num_heads)
+
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=spec, check_vma=False)
     return fn(*args)
 
 
@@ -206,6 +238,17 @@ def multi_head_attention(
         k = proj(keys, "k_proj", d_model)
         v = proj(values, "v_proj", d_model)
 
+    # the flash kernels take the projections as they are; a cache and a
+    # dense mask are the [b, h, s, hd] paths'
+    key_mask = attn_mask is None or (attn_mask.ndim == 4
+                                     and attn_mask.shape[1:3] == (1, 1))
+    flash = bool(use_flash) and cache is None and key_mask
+    if flash and flash_applies(True, dropout_rate):
+        out = flash_sdpa(
+            q, k, v, causal, num_heads=num_heads,
+            key_bias=None if attn_mask is None else attn_mask[:, 0, 0, :])
+        return proj(out, "out_proj", d_model)
+
     def split_heads(x):
         b, s, _ = x.shape
         return x.reshape(b, s, num_heads, head_dim).transpose(0, 2, 1, 3)
@@ -225,8 +268,10 @@ def multi_head_attention(
         attn_mask = step_mask if attn_mask is None else attn_mask + step_mask
         causal = False
 
+    # ``flash``: flash_applies has spoken (and warned) for this call
     out = scaled_dot_product_attention(q, k, v, attn_mask=attn_mask, causal=causal,
-                                       dropout_rate=dropout_rate, use_flash=use_flash)
+                                       dropout_rate=dropout_rate,
+                                       use_flash=use_flash and not flash)
     b, h, s, hd = out.shape
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
     out = proj(out, "out_proj", d_model)

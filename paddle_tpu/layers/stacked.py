@@ -49,7 +49,7 @@ from ..framework import (LayerHelper, active_mesh, cast_compute,
 from .. import initializer as init
 from ..parallel.collective_matmul import (BatchSharded, gather_matmul,
                                           matmul_scatter, ring_order)
-from .attention import flash_applies, flash_sdpa
+from .attention import flash_applies, flash_sdpa, tp_partitioned
 
 NEG_INF = -1e9
 
@@ -230,14 +230,53 @@ def _row_parallel(o, w, tp_axis):
     return jax.lax.psum(o, tp_axis) if tp_axis else o
 
 
+def _attend(q, k, v, head_dim, key_bias, causal: bool, use_flash: bool,
+            dropout_rate: float = 0.0):
+    """Attention over the projections' own layout: q, k and v are each
+    ``[b, s, h*hd]`` with their heads side by side, and so is the result,
+    what the output projection takes. The flash kernels read and write
+    that layout in place (no head is transposed, no 64-wide minor
+    dimension padded to 128 lanes: PERF.md, PR 32); the dense path works
+    on ``[b, h, s, hd]``."""
+    if flash_applies(use_flash, dropout_rate):
+        return flash_sdpa(q, k, v, causal, key_bias=key_bias,
+                          num_heads=q.shape[-1] // head_dim)
+    q, k, v = (_split_heads(t, head_dim) for t in (q, k, v))
+    return _merge_heads(_sdpa(q, k, v, key_bias, causal, False,
+                              dropout_rate=dropout_rate))
+
+
+def _self_attend(x, p, head_dim, key_bias, causal, use_flash, tp_axis=None,
+                 sp_cfg=None, dropout_rate: float = 0.0):
+    """Layer norm, the fused projection and self-attention over it:
+    ``(o, qkv)``, ``o`` ``[b, s, h*hd]`` and ``qkv`` as :func:`_qkv` gave
+    it (:func:`_part` picks k and v out of either form)."""
+    if sp_cfg is None and flash_applies(use_flash, dropout_rate):
+        # one flat matmul's output, q, k and v side by side, read as it
+        # lies; under a ``tp`` axis the partitioner splits, the einsum it
+        # partitions (see :func:`_qkv`), and the kernels take its parts
+        flat = not tp_partitioned()
+        qkv = _qkv(x, p, tp_axis, flat=flat)
+        parts = (qkv, None, None) if flat else tuple(
+            _part(qkv, i) for i in range(3))
+        o = flash_sdpa(*parts, causal, key_bias=key_bias,
+                       num_heads=_part(qkv, 0).shape[-1] // head_dim)
+        return o, qkv
+    qkv = _qkv(x, p, tp_axis)
+    q, k, v = (_split_heads(qkv[:, :, i], head_dim) for i in range(3))
+    # flash_applies has spoken (and warned, if it had to) for this call:
+    # only the sequence-parallel paths still ask for the kernel
+    o = _sdpa(q, k, v, key_bias, causal, use_flash and sp_cfg is not None,
+              sp_cfg, dropout_rate=dropout_rate)
+    return _merge_heads(o), qkv
+
+
 @jax.named_scope("attn")
 def _self_attention(x, p, num_heads, causal, use_flash, key_bias, tp_axis,
                     sp_cfg=None, dropout_rate: float = 0.0):
-    q, k, v = _attn_qkv(x, p, num_heads, tp_axis)
-    o = _sdpa(q, k, v, key_bias, causal, use_flash, sp_cfg,
-              dropout_rate=dropout_rate)
-    return _attn_out(x, p, _merge_heads(o), tp_axis,
-                     dropout_rate=dropout_rate)
+    o, _ = _self_attend(x, p, x.shape[-1] // num_heads, key_bias, causal,
+                        use_flash, tp_axis, sp_cfg, dropout_rate)
+    return _attn_out(x, p, o, tp_axis, dropout_rate=dropout_rate)
 
 
 @jax.named_scope("ffn")
@@ -306,10 +345,9 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
             # the encoder's output is whole on every tp rank
             kv = jnp.einsum("bsd,dke->bske", enc, wkv) \
                 + p["xkv/b"].astype(h.dtype)
-            q = _split_heads(q, head_dim)
-            k, v = (_split_heads(kv[:, :, i], head_dim) for i in range(2))
-            o = _merge_heads(_sdpa(q, k, v, extra.get("enc_bias"), False,
-                                   use_flash, dropout_rate=dropout_rate))
+            o = _attend(q, kv[:, :, 0], kv[:, :, 1], head_dim,
+                        extra.get("enc_bias"), False, use_flash,
+                        dropout_rate)
             o, ow = cast_compute(o, p["xout/w"])
             o = _row_parallel(o, ow, tp_axis)
             x = x + _drop(o + p["xout/b"].astype(o.dtype), dropout_rate)
@@ -332,20 +370,36 @@ def make_decoder_block(num_heads: int, use_flash: bool = False,
 # answer with a relayout of the whole slab.
 
 
-def _qkv(x, p, tp_axis=None):
+def _qkv(x, p, tp_axis=None, flat: bool = False):
     """LayerNorm and the fused projection: ``[b, s, 3, h*hd]``, q, k and
-    v on axis 2, each with its heads side by side in the last."""
+    v on axis 2, each with its heads side by side in the last; ``flat``,
+    ``[b, s, 3 * h*hd]``, the three side by side: one plain matmul, whose
+    output the flash kernels read as it lies (the compiler gives a
+    ``[b, s, 3, e]`` result a layout of its own choosing, and three
+    slices of it are three copies). Who needs the first form: the dense
+    and sequence-parallel paths, which split heads out of each part
+    anyway, and a stack whose ``tp`` axis the partitioner splits
+    (:func:`_batch_sharded_why_not`): ``qkv/w`` is then sharded over its
+    last axis, and merging that axis with the 3 would have the weight
+    gathered every layer."""
     h = _ln(x, p["ln1/scale"], p["ln1/bias"])
     h, w = cast_compute(h, p["qkv/w"])
+    b = p["qkv/b"]
+    if flat:
+        w, b = w.reshape(w.shape[0], -1), b.reshape(-1)
+        return _column_parallel(
+            h, lambda c: jnp.matmul(c, w) + b.astype(c.dtype), tp_axis)
     return _column_parallel(
-        h, lambda c: jnp.einsum("bsd,dke->bske", c, w)
-        + p["qkv/b"].astype(c.dtype), tp_axis)
+        h, lambda c: jnp.einsum("bsd,dke->bske", c, w) + b.astype(c.dtype),
+        tp_axis)
 
 
-def _attn_qkv(x, p, num_heads, tp_axis=None):
-    head_dim = x.shape[-1] // num_heads
-    qkv = _qkv(x, p, tp_axis)
-    return tuple(_split_heads(qkv[:, :, i], head_dim) for i in range(3))
+def _part(qkv, i: int):
+    """q, k or v (``i`` = 0, 1, 2) of :func:`_qkv`'s result, ``[b, s, h*hd]``."""
+    if qkv.ndim == 4:
+        return qkv[:, :, i]
+    e = qkv.shape[-1] // 3
+    return qkv[:, :, i * e:(i + 1) * e]
 
 
 def _attn_out(x, p, o, tp_axis=None, dropout_rate: float = 0.0):
@@ -362,11 +416,9 @@ def prefill_block(x, p, num_heads: int, use_flash: bool = False):
     cache path, models/transformer.py make_decoder)."""
     head_dim = x.shape[-1] // num_heads
     with jax.named_scope("attn"):
-        qkv = _qkv(x, p)
-        q, k, v = (_split_heads(qkv[:, :, i], head_dim) for i in range(3))
-        x = _attn_out(x, p, _merge_heads(_sdpa(q, k, v, None, True,
-                                               use_flash)))
-    return _ffn(x, p, None), (qkv[:, :, 1], qkv[:, :, 2])
+        o, qkv = _self_attend(x, p, head_dim, None, True, use_flash)
+        x = _attn_out(x, p, o)
+    return _ffn(x, p, None), (_part(qkv, 1), _part(qkv, 2))
 
 
 def quantize_kv(x, num_heads: int):

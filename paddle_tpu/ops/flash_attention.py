@@ -42,6 +42,37 @@ The tile walk, shared by the three kernels:
   it is a power of two (exact in bf16: d = 16, 64, 256), else applied to
   the f32 scores; gradients take it once, when they are written.
 
+Which layout a call gets, and why. The TPU tiles an array's two minor
+dimensions (8, 128): a ``[b, h, s, 64]`` operand fills 64 lanes of every
+128 and is held, written and read at twice its size, and a block's
+projections leave q, k and v as ``[b, s, h*d]``, so every call on
+``[b, h, s, d]`` stood between transposes that wrote and read such padded
+arrays (twelve a layer of a remat train step: PERF.md, PR 32). So the
+layout follows the call's shape, one tile walk for both:
+
+- rank-4 ``[b, h, s, d]`` operands (ring attention, Ulysses, latent
+  attention): ``bhsd``. A grid step's block is ``(heads, rows, d)`` of
+  the flattened ``(b*h, s, d)`` array and a head is a leading index.
+- rank-3 ``[b, s, h*d]`` operands with their head count: ``bsd``, where
+  :func:`lane_heads` finds whole 128-lane groups (a head 128-multiple
+  wide, or ``128 // d`` narrower ones dividing the head count). A step's
+  block is ``(1, rows, heads*d)``, whole lane groups of one batch row,
+  picked by the index map over the array as it lies; inside, heads that
+  share a lane group are told apart where they lie (zeros in the other
+  head's lanes of the still operand, whole groups streaming past it:
+  :attr:`_Walk.shared_lanes`), and what a walk writes transposed (o, dq)
+  is kept a group in scratch and written a group, one transpose and 128
+  dense lanes (:meth:`_Walk.write_groups`). The chip measured that form
+  faster than lane slices of every operand, and at the ``bhsd`` kernels'
+  time (2% over it at 16 x 10 heads). Bias and ids are a batch row's; lse
+  and delta stay a head's. A fused self-attention projection ``[b, s, 3*h*d]``
+  goes in as ``q`` alone and is passed three times with lane-block
+  offsets, because three slices of it would be three copies. Shapes the
+  form does not fit (192 / 128 wide scores and values, an odd count of
+  64-wide heads) are transposed to ``bhsd`` inside the call.
+
+``flash.plan`` records ``layout`` and ``lane_heads`` for every call traced.
+
 Masking: causal (bottom-right aligned), an additive per-key bias
 [b, s_k] (padding), and segment ids (the LoD ragged-batch equivalent,
 layers/sequence.py design) — all fused into the kernels. Per-row
@@ -145,6 +176,8 @@ class FlashPlan(NamedTuple):
     tiles_run: int   # compute tiles the forward walk visits ...
     tiles_all: int   # ... of the tiles in the padded score rectangle
     dv: int = 0      # width of a value and of an output row (plan_blocks sets it)
+    layout: str = "bhsd"  # the operands' layout: ``bhsd``, or ``bsd`` (packed)
+    lane_heads: int = 0   # bsd: heads a 128-lane group of the minor dimension
 
 
 def _round_up(n, m):
@@ -203,9 +236,23 @@ def _chunk_bounds(r0, tile_q, c_base, n_chunks, tile_k, *, causal, offset,
     return n_plain, n_end
 
 
+def lane_heads(d, dv, num_heads) -> int:
+    """Heads a 128-lane group of ``[b, s, num_heads * d]`` operands, or 0
+    where the kernels cannot read that layout: a head 128-multiple wide
+    is its own group, ``128 // d`` narrower ones fill one where they
+    divide the head count, and anything else (192 / 128 wide scores and
+    values, an odd count of 64-wide heads) has a head astride a group's
+    edge."""
+    if num_heads is None or dv != d:
+        return 0
+    if d % 128 == 0:
+        return 1
+    return 128 // d if 128 % d == 0 and num_heads % (128 // d) == 0 else 0
+
+
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
-                bh=1, dv=None, scale=None) -> FlashPlan:
+                bh=1, dv=None, scale=None, num_heads=None) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -217,9 +264,17 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     not change the blocks today; they are part of what a plan may depend
     on. ``d`` is the width the scores contract over and ``dv`` that of a
     value (latent attention: 192 and 128); ``scale`` is the softmax scale
-    where it is not ``d ** -0.5``."""
+    where it is not ``d ** -0.5``.
+
+    ``num_heads`` says the call's operands are ``[b, s, num_heads * d]``,
+    as a projection leaves them. The plan keeps that layout (``bsd``)
+    where a step's block of the minor dimension can be whole 128-lane
+    groups (:func:`lane_heads`); a step then holds whole groups of one
+    batch row. Else the call is laid out ``[b, h, s, d]`` first
+    (``bhsd``), which is also what a rank-4 call is."""
     del dtype, have_bias, have_seg
     dv = d if dv is None else dv
+    packed = lane_heads(d, dv, num_heads)
     sq_p, block_q, tile_q = _axis_plan(sq, block_q, 128)
     sk_p, block_k, tile_k = _axis_plan(sk, block_k, 16)
     scale = 1.0 / math.sqrt(d) if scale is None else scale
@@ -239,6 +294,21 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     # STEP_BYTES of VMEM (about a dozen double-buffered row blocks a head)
     share = run / tiles_all if (sq_p, sk_p) == (block_q, block_k) else 1.0
     step_scores = block_q * block_k * share
+    if packed:
+        # whole lane groups of one batch row, each head's walk written
+        # out (a lane offset is static): as many as keep the step's code
+        # within UNROLL tile bodies, or head bodies where tiles loop
+        written = (sq_p, sk_p) == (block_q, block_k) and tiles_all <= UNROLL
+        step_bytes = 6 * max(block_q, block_k) * (d + dv) * 2
+        heads = max(g for g in range(packed, num_heads + 1, packed)
+                    if num_heads % g == 0 and (
+                        g == packed or (
+                            g * step_scores <= STEP_SCORES
+                            and g * step_bytes <= STEP_BYTES
+                            and g * (tiles_all if written else 1) <= UNROLL)))
+        return FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
+                         sq_p, sk_p, causal, fold, run, tiles_all, dv,
+                         "bsd", packed)
     step_bytes = (6 * max(block_q, block_k)
                   * (_round_up(d, 128) + _round_up(dv, 128)) * 2)
     heads = max(g for g in range(1, bh + 1) if bh % g == 0 and (
@@ -257,7 +327,8 @@ def _record_plan(p: FlashPlan):
         "flash.plan", time.time_ns(), 0, sq=p.sq, sk=p.sk, d=p.d, dv=p.dv,
         block_q=p.block_q, block_k=p.block_k, tile_q=p.tile_q,
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
-        tiles_run=p.tiles_run, tiles_all=p.tiles_all)
+        tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
+        lane_heads=p.lane_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -295,6 +366,107 @@ class _Walk(NamedTuple):
         loops are traced."""
         return self.nq == self.nk == 1 and self.nqt * self.nkt <= UNROLL
 
+    @property
+    def packed(self):
+        return self.plan.layout == "bsd"
+
+    def head(self, g, rows):
+        """Where head ``g`` of the step has ``rows`` in a block of q, k,
+        v, o or their gradients: its own leading index of a
+        ``(heads, rows, d)`` block, or its lanes of a ``(1, rows,
+        heads * d)`` one (``g`` is then a python int: a lane offset is
+        static)."""
+        if self.packed:
+            return (0, rows, pl.ds(g * self.plan.d, self.plan.d))
+        return (g, rows, slice(None))
+
+    @property
+    def shared_lanes(self):
+        """Do several heads share a 128-lane group (64-wide heads: two)?
+        A head is then picked out of the group's lanes where it lies, not
+        brought to lane 0 first: in a product that contracts over lanes
+        (scores, dp) by zeros in the other heads' lanes of the operand a
+        walk holds still (:meth:`still`, once a tile) against the whole
+        group of the one that streams (:meth:`moving`); in dk / dv, whose
+        lanes are the streaming operand's, by keeping a group's worth and
+        writing the head's (:meth:`own_lanes`). Only a product that
+        contracts over rows has to cut the lanes out (:meth:`rows_dot`).
+        Measured against lane slices of every operand: PERF.md §6, PR 32."""
+        return self.packed and self.plan.lane_heads > 1
+
+    def lanes(self, g):
+        """(first lane, width) of head ``g`` inside its 128-lane group."""
+        return g % self.plan.lane_heads * self.plan.d, self.plan.d
+
+    def moving(self, ref, g, rows):
+        """``rows`` of the operand that streams past the still one: head
+        ``g``'s own lanes, or its whole lane group where heads share one
+        (the still operand's zeros keep the others out of a product that
+        contracts over lanes; a product's other lanes are dropped)."""
+        if not self.shared_lanes:
+            return ref[self.head(g, rows)]
+        return ref[0, rows, pl.ds(g // self.plan.lane_heads * 128, 128)]
+
+    def still(self, ref, g, rows):
+        """``rows`` of the operand a walk holds still, for head ``g``:
+        its lanes, the others' zeroed where heads share a group."""
+        x = self.moving(ref, g, rows)
+        if self.shared_lanes:
+            lo, width = self.lanes(g)
+            lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+            x = jnp.where((lane >= lo) & (lane < lo + width), x,
+                          jnp.zeros_like(x))
+        return x
+
+    def rows_dot(self, x, y, g):
+        """``x.T @ y`` for head ``g``: ``[d, n]`` from the moving operand
+        ``x [rows, lanes]`` and ``y [rows, n]``. A product that contracts
+        over rows cannot mask lanes out, so the head's lanes of a shared
+        group are cut out here (a lane rotate for all but the first)."""
+        return jax.lax.dot_general(self.own_lanes(x, g), y, _TN,
+                                   preferred_element_type=jnp.float32)
+
+    def own_lanes(self, x, g):
+        """Head ``g``'s lanes of a value as wide as :meth:`moving`'s."""
+        if not self.shared_lanes:
+            return x
+        lo, width = self.lanes(g)
+        return x[:, lo:lo + width]
+
+    def acc_at(self, g, t):
+        """Where head ``g`` keeps its ``[d, tile]`` accumulator of still
+        tile ``t`` in a scratch of :meth:`acc_shape`."""
+        if not self.shared_lanes:
+            return (g, t)
+        n, d = self.plan.lane_heads, self.plan.d
+        return (g // n, t, pl.ds(g % n * d, d))
+
+    def acc_shape(self, tiles, tile):
+        """Scratch for a ``[d, tile]`` accumulator a head and still tile.
+        Heads that share a lane group lie one under another, ``[128,
+        tile]`` a group: the group's result is then transposed and
+        written whole (:meth:`write_groups`), dense over its 128 lanes,
+        where a head's own would be rotated to its lanes and written
+        under a mask."""
+        p = self.plan
+        if not self.shared_lanes:
+            return (p.heads, tiles, p.dv, tile)
+        return (p.heads // p.lane_heads, tiles, 128, tile)
+
+    def write_groups(self, scr, ref, tiles, tile, scale=None):
+        """Every lane group's ``[128, tile]`` results in ``scr`` (times
+        ``scale``), transposed, to their rows and lanes of ``ref``."""
+        for grp in range(self.plan.heads // self.plan.lane_heads):
+            for t in range(tiles):
+                x = scr[grp, t] if scale is None else scr[grp, t] * scale
+                ref[0, pl.ds(t * tile, tile), pl.ds(grp * 128, 128)] = (
+                    x.T.astype(ref.dtype))
+
+    def mask_row(self, g):
+        """Head ``g``'s leading index in a block of key bias or segment
+        ids: they are a batch row's, which a packed step has one of."""
+        return 0 if self.packed else g
+
 
 def _scores(keys, queries, r0, c0, w: _Walk, *, masked, bias_col, segq_row,
             segk_col):
@@ -326,7 +498,7 @@ def _scores(keys, queries, r0, c0, w: _Walk, *, masked, bias_col, segq_row,
     return s if keep is None else jnp.where(keep, s, NEG_INF)
 
 
-def _still(x, w: _Walk):
+def _fold_scale(x, w: _Walk):
     """The operand a walk holds still, with the scale folded in where
     that is exact."""
     return x * jnp.asarray(w.scale, x.dtype) if w.plan.fold_scale else x
@@ -436,38 +608,65 @@ class _Operands(NamedTuple):
     segk: Optional[jax.Array]
 
 
-def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan):
-    """Pad the sequence axes to the plan and flatten heads. Padded keys
-    are masked by their index inside the kernels (no bias is invented);
-    padded q/k segment ids get distinct negative ids so they never
-    match."""
-    b, h, _, d = q.shape
-    bh = b * h
+def _kernel_form(x, s_p, p: FlashPlan):
+    """q, k, v, dO as the kernels take them: the sequence padded to the
+    plan; ``[b, h, s, d]`` flattened to ``(bh, s, d)``, ``[b, s, h * d]``
+    as it is."""
+    if p.layout == "bsd":
+        return _pad_seq(x, s_p, 1)
+    x = _pad_seq(x, s_p, 2)
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def _user_form(x, s, like, p: FlashPlan):
+    """A kernel's o, dq, dk or dv in the layout of the operand ``like``,
+    the padded rows cut off."""
+    if p.layout == "bsd":
+        return x[:, :s]
+    return x.reshape(like.shape[:2] + x.shape[1:])[:, :, :s]
+
+
+def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan, b, h):
+    """Pad the sequence axes to the plan and put the operands in kernel
+    form. Padded keys are masked by their index inside the kernels (no
+    bias is invented); padded q/k segment ids get distinct negative ids
+    so they never match. A ``bhsd`` step's heads may be of several batch
+    rows, so each head gets its own copy of a batch row's bias and ids;
+    a ``bsd`` step reads the one of its batch row."""
     nq, nk = p.sq_p // p.block_q, p.sk_p // p.block_k
     nqt, nkt = p.block_q // p.tile_q, p.block_k // p.tile_k
-    q = _pad_seq(q, p.sq_p, 2).reshape(bh, p.sq_p, d)
-    k = _pad_seq(k, p.sk_p, 2).reshape(bh, p.sk_p, d)
-    v = _pad_seq(v, p.sk_p, 2).reshape(bh, p.sk_p, p.dv)
+    if k is q:
+        # q, k and v fused in one array (:func:`_planned`): rows for the
+        # blocks of both axes (queries pad to 8 rows, keys to 16), zeros
+        # beyond the sequence as every padded key and value is
+        q = k = v = _kernel_form(q, max(p.sq_p, p.sk_p), p)
+    else:
+        q = _kernel_form(q, p.sq_p, p)
+        k, v = (_kernel_form(x, p.sk_p, p) for x in (k, v))
 
     def per_head(x, s_p, value, dtype):
         x = _pad_seq(x.astype(dtype), s_p, 1, value)
+        if p.layout == "bsd":
+            return x
         return jnp.broadcast_to(x[:, None, :], (b, h, s_p))
 
+    lead = b if p.layout == "bsd" else b * h
     if bias is not None:
         bias = _rows(per_head(bias, p.sk_p, 0.0, jnp.float32),
-                     bh, nk, nkt, p.tile_k)
+                     lead, nk, nkt, p.tile_k)
     if seg_q is not None:
         seg_q = _rows(per_head(seg_q, p.sq_p, -1, jnp.int32),
-                      bh, nq, nqt, p.tile_q)
+                      lead, nq, nqt, p.tile_q)
         seg_k = _rows(per_head(seg_k, p.sk_p, -2, jnp.int32),
-                      bh, nk, nkt, p.tile_k)
+                      lead, nk, nkt, p.tile_k)
     return _Operands(q, k, v, bias, seg_q, seg_k)
 
 
 def _mask_specs(ops: _Operands, p: FlashPlan, q_map, k_map):
     """BlockSpecs and arrays of the optional mask operands, in the order
     the kernels take them: bias, segq, segk."""
-    g, nqt, nkt = p.heads, p.block_q // p.tile_q, p.block_k // p.tile_k
+    g = 1 if p.layout == "bsd" else p.heads
+    nqt, nkt = p.block_q // p.tile_q, p.block_k // p.tile_k
     specs, args = [], []
     if ops.bias is not None:
         specs.append(pl.BlockSpec((g, 1, nkt, p.tile_k), k_map))
@@ -501,7 +700,8 @@ def _over_tiles(n_tiles, tile_body, w: _Walk):
     still tile ``t`` of the block. Where the walk is written out
     (:attr:`_Walk.written_out`) the tiles are python iterations, and the
     heads too while the step stays within ``UNROLL`` tiles; else traced
-    loops."""
+    loops. A packed step's heads are always python iterations (the plan
+    keeps them few): a head is picked by its lanes."""
     heads = w.plan.heads
 
     def head(g, carry=0):
@@ -514,7 +714,7 @@ def _over_tiles(n_tiles, tile_body, w: _Walk):
             jax.lax.fori_loop(0, n_tiles, tile, 0)
         return carry
 
-    if w.written_out and heads * w.nqt * w.nkt <= UNROLL:
+    if w.packed or (w.written_out and heads * w.nqt * w.nkt <= UNROLL):
         for g in range(heads):
             head(g)
     else:
@@ -540,8 +740,9 @@ def _fwd_kernel(*refs, w: _Walk):
     def q_tile(g, qt):
         q0 = _at(qt, tq)
         r0 = qb * p.block_q + q0
-        q = _still(q_ref[g, pl.ds(q0, tq), :], w)
-        segq = segq_ref[g, 0, pl.ds(qt, 1), :] if w.have_seg else None
+        mg = w.mask_row(g)
+        q = _fold_scale(w.still(q_ref, g, pl.ds(q0, tq)), w)
+        segq = segq_ref[mg, 0, pl.ds(qt, 1), :] if w.have_seg else None
         n_plain, n_end = _chunk_bounds(r0, tq, c_base, w.nkt, tk,
                                        causal=p.causal, offset=w.offset,
                                        sk=p.sk, sk_p=p.sk_p)
@@ -550,13 +751,13 @@ def _fwd_kernel(*refs, w: _Walk):
             def body(j, carry):
                 m, l, acc = carry            # [1, tq], [1, tq], [d, tq]
                 k0 = _at(j, tk)
-                vb = v_ref[g, pl.ds(k0, tk), :]
+                vb = w.moving(v_ref, g, pl.ds(k0, tk))
                 s = _scores(
-                    k_ref[g, pl.ds(k0, tk), :], q, r0, c_base + k0, w,
+                    w.moving(k_ref, g, pl.ds(k0, tk)), q, r0, c_base + k0, w,
                     masked=masked,
-                    bias_col=_col(bias_ref, g, j) if w.have_bias else None,
+                    bias_col=_col(bias_ref, mg, j) if w.have_bias else None,
                     segq_row=segq,
-                    segk_col=_col(segk_ref, g, j) if w.have_seg else None)
+                    segk_col=_col(segk_ref, mg, j) if w.have_seg else None)
                 m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                 # a query every key so far is masked for keeps m at
                 # NEG_INF: its exp(s - m) would be exp(0) = 1, so the
@@ -566,23 +767,23 @@ def _fwd_kernel(*refs, w: _Walk):
                 l = l * alpha + jnp.sum(prob, axis=0, keepdims=True)
                 # prob rounded to the input dtype for the MXU pass;
                 # accumulator f32. v.T @ prob.T = (prob @ v).T
-                acc = acc * alpha + jax.lax.dot_general(
-                    vb, prob.astype(vb.dtype), _TN,
-                    preferred_element_type=jnp.float32)
+                acc = acc * alpha + w.rows_dot(vb, prob.astype(vb.dtype), g)
                 return m_new, l, acc
             return body
 
-        row = (g, pl.ds(qt, 1))
+        row, at = (g, pl.ds(qt, 1)), w.acc_at(g, qt)
         m, l, acc = _two_loops(
-            n_plain, n_end, chunk,
-            (m_scr[row], l_scr[row], acc_scr[g, qt]), w)
-        m_scr[row], l_scr[row], acc_scr[g, qt] = m, l, acc
+            n_plain, n_end, chunk, (m_scr[row], l_scr[row], acc_scr[at]), w)
+        m_scr[row], l_scr[row], acc_scr[at] = m, l, acc
 
         @pl.when(kv == last_kv)
         def _finalize():
             l_safe = jnp.maximum(l, 1e-30)
-            o_ref[g, pl.ds(q0, tq), :] = (
-                acc * (1.0 / l_safe)).T.astype(o_ref.dtype)
+            out = acc * (1.0 / l_safe)
+            if w.shared_lanes:      # written with its lane group, below
+                acc_scr[at] = out
+            else:
+                o_ref[w.head(g, pl.ds(q0, tq))] = out.T.astype(o_ref.dtype)
             lse_ref[g, 0, pl.ds(qt, 1), :] = m + jnp.log(l_safe)
 
     # causal: a key block strictly above the (offset) diagonal is only
@@ -590,67 +791,126 @@ def _fwd_kernel(*refs, w: _Walk):
     @pl.when(_block_runs(qb, kv, w) | (kv == last_kv))
     def _step():
         _over_tiles(w.nqt, q_tile, w)
+        if w.shared_lanes:
+            pl.when(kv == last_kv)(
+                lambda: w.write_groups(acc_scr, o_ref, w.nqt, tq))
 
 
-def _grid_maps(p: FlashPlan, offset):
-    """Index maps of the (bh/heads, nq, nk) grid the forward and dq
-    kernels share: q-side blocks follow j, k-side blocks follow the
-    clamped kk (:func:`_kj_clamp`)."""
-    nk = p.sk_p // p.block_k
-    ck = _kj_clamp(p.causal, p.block_q, p.block_k, nk, offset)
-    return (lambda i, j, kk: (i, j, 0), lambda i, j, kk: (i, ck(kk, j), 0),
-            lambda i, j, kk: (i, j, 0, 0),
-            lambda i, j, kk: (i, ck(kk, j), 0, 0))
+class _Specs(NamedTuple):
+    """The BlockSpecs of one kernel's grid."""
+    q: pl.BlockSpec       # block_q rows of the step's heads
+    k: pl.BlockSpec
+    v: pl.BlockSpec
+    o: pl.BlockSpec       # o; dO and dq, which are as wide as q
+    dk: pl.BlockSpec      # dk, dv
+    qrow: pl.BlockSpec    # lse, delta: a row a head
+    masks: list           # specs and arrays of bias, segq, segk
+    mask_args: list
+
+
+def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
+    """BlockSpecs over a (b * h / heads, nq, nk) grid (forward and dq),
+    or (b * h / heads, nk, nq) (dk/dv): grid step ``i`` holds ``heads``
+    consecutive heads. The q side follows ``j`` and the k side the
+    causally clamped ``kk`` (:func:`_kj_clamp`; mirrored for dk/dv,
+    :func:`_qi_clamp`). ``bhsd`` operands are ``(bh, s, d)`` and step
+    ``i`` is their block ``i``; ``bsd`` operands are ``[b, s, h * d]``
+    and step ``i`` is batch row ``i // per_row``, lane block
+    ``i % per_row``; where q, k and v are one fused ``[b, s, 3 * h * d]``
+    array (a projection's output, passed three times), k's and v's blocks
+    lie ``per_row`` and ``2 * per_row`` lane blocks further on. The
+    per-query rows (lse, delta) are a head's in both layouts."""
+    g, packed = p.heads, p.layout == "bsd"
+    per_row = h // g
+    fused = packed and ops.q.shape[-1] == 3 * h * p.d
+    if dkv:
+        cq = _qi_clamp(p.causal, p.block_q, p.block_k, w.nq, w.offset)
+        q_at, k_at = (lambda j, kk: cq(kk, j)), (lambda j, kk: j)
+    else:
+        ck = _kj_clamp(p.causal, p.block_q, p.block_k, w.nk, w.offset)
+        q_at, k_at = (lambda j, kk: j), (lambda j, kk: ck(kk, j))
+
+    def block(rows, width, at, nth=0):
+        if packed:
+            off = nth * per_row if fused else 0
+            return pl.BlockSpec(
+                (1, rows, g * width),
+                lambda i, j, kk: (i // per_row, at(j, kk),
+                                  i % per_row + off))
+        return pl.BlockSpec((g, rows, width),
+                            lambda i, j, kk: (i, at(j, kk), 0))
+
+    def mask(at):
+        if packed:
+            return lambda i, j, kk: (i // per_row, at(j, kk), 0, 0)
+        return lambda i, j, kk: (i, at(j, kk), 0, 0)
+
+    masks, mask_args = _mask_specs(ops, p, mask(q_at), mask(k_at))
+    return _Specs(
+        q=block(p.block_q, p.d, q_at), k=block(p.block_k, p.d, k_at, 1),
+        v=block(p.block_k, p.dv, k_at, 2), o=block(p.block_q, p.dv, q_at),
+        dk=block(p.block_k, p.d, k_at),
+        qrow=pl.BlockSpec((g, 1, w.nqt, p.tile_q),
+                          lambda i, j, kk: (i, q_at(j, kk), 0, 0)),
+        masks=masks, mask_args=mask_args)
 
 
 def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-             scale=None):
-    """(plan, walk, operands) of one call."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+             scale=None, num_heads=None):
+    """(plan, walk, operands, b, h) of one call: ``[b, h, s, d]`` operands,
+    ``[b, s, num_heads * d]`` ones that :func:`lane_heads` admits, or,
+    with ``k`` and ``v`` None, ``q`` as the three of them fused,
+    ``[b, s, 3 * num_heads * d]``."""
+    if num_heads is None:
+        b, h, sq, d = q.shape
+        sk, dv = k.shape[2], v.shape[-1]
+    else:
+        (b, sq, width), h = q.shape, num_heads
+        if k is None:
+            k, v, width = q, q, width // 3
+        sk, d = k.shape[1], width // h
+        dv = d
     scale = 1.0 / math.sqrt(d) if scale is None else scale
     p = plan_blocks(sq, sk, d, q.dtype, causal, bias is not None,
                     seg_q is not None, block_q, block_k, bh=b * h,
-                    dv=v.shape[-1], scale=scale)
+                    dv=dv, scale=scale, num_heads=num_heads)
     w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
-    return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p)
+    return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h), b, h
 
 
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
-               interpret: bool, scale: Optional[float] = None):
+               interpret: bool, scale: Optional[float] = None,
+               num_heads: Optional[int] = None):
     """``q`` and ``k`` are ``[b, h, s, d]`` and ``v`` ``[b, h, s_k, dv]``:
-    the scores contract over ``d``, the output rows are ``dv`` wide (the
-    kernel reads both from its blocks' shapes)."""
-    b, h, sq, d = q.shape
-    dv = v.shape[-1]
-    bh = b * h
-    p, w, ops = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                         block_k, scale)
+    the scores contract over ``d``, the output rows are ``dv`` wide. With
+    ``num_heads`` they are ``[b, s, num_heads * d]`` (or fused in ``q``,
+    :func:`_planned`) and so is the output; lse is ``[b, h, s_q]`` for
+    both."""
+    p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
+                               block_k, scale, num_heads)
     _record_plan(p)
-    g, nq, nk = p.heads, w.nq, w.nk
-    q_map, k_map, qrow_map, krow_map = _grid_maps(p, w.offset)
-    mask_specs, mask_args = _mask_specs(ops, p, qrow_map, krow_map)
+    bh, g, nq, nk, dv = b * h, p.heads, w.nq, w.nk, p.dv
+    sp = _specs(ops, p, w, h)
+    out_shape = ((bh, p.sq_p, dv) if num_heads is None
+                 else (b, p.sq_p, h * dv))
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, w=w),
         name="flash_fwd",
         grid=(bh // g, nq, nk),
-        in_specs=[pl.BlockSpec((g, p.block_q, d), q_map),
-                  pl.BlockSpec((g, p.block_k, d), k_map),
-                  pl.BlockSpec((g, p.block_k, dv), k_map)] + mask_specs,
-        out_specs=[pl.BlockSpec((g, p.block_q, dv), q_map),
-                   pl.BlockSpec((g, 1, w.nqt, p.tile_q), qrow_map)],
-        out_shape=[jax.ShapeDtypeStruct((bh, p.sq_p, dv), q.dtype),
+        in_specs=[sp.q, sp.k, sp.v] + sp.masks,
+        out_specs=[sp.o, sp.qrow],
+        out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype),
                    jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
                         pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
-                        pltpu.VMEM((g, w.nqt, dv, p.tile_q), jnp.float32)],
+                        pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)],
         interpret=interpret,
-    )(ops.q, ops.k, ops.v, *mask_args)
-    out = out.reshape(b, h, p.sq_p, dv)[:, :, :sq]
-    lse = lse.reshape(b, h, p.sq_p)[:, :, :sq]
+    )(ops.q, ops.k, ops.v, *sp.mask_args)
+    out = _user_form(out, p.sq, q, p)
+    lse = lse.reshape(b, h, p.sq_p)[:, :, :p.sq]
     return out, lse
 
 
@@ -684,10 +944,11 @@ def _dq_kernel(*refs, w: _Walk):
         q0 = _at(qt, tq)
         r0 = qb * p.block_q + q0
         row = (g, 0, pl.ds(qt, 1))
-        q = _still(q_ref[g, pl.ds(q0, tq), :], w)
-        do = g_ref[g, pl.ds(q0, tq), :]
+        mg = w.mask_row(g)
+        q = _fold_scale(w.still(q_ref, g, pl.ds(q0, tq)), w)
+        do = w.still(g_ref, g, pl.ds(q0, tq))
         lse, delta = lse_ref[row], delta_ref[row]
-        segq = segq_ref[row] if w.have_seg else None
+        segq = segq_ref[mg, 0, pl.ds(qt, 1)] if w.have_seg else None
         n_plain, n_end = _chunk_bounds(r0, tq, c_base, w.nkt, tk,
                                        causal=p.causal, offset=w.offset,
                                        sk=p.sk, sk_p=p.sk_p)
@@ -695,33 +956,36 @@ def _dq_kernel(*refs, w: _Walk):
         def chunk(masked):
             def body(j, dq_t):               # [d, tq]
                 k0 = _at(j, tk)
-                kb = k_ref[g, pl.ds(k0, tk), :]
-                vb = v_ref[g, pl.ds(k0, tk), :]
+                kb = w.moving(k_ref, g, pl.ds(k0, tk))
+                vb = w.moving(v_ref, g, pl.ds(k0, tk))
                 s = _scores(
                     kb, q, r0, c_base + k0, w, masked=masked,
-                    bias_col=_col(bias_ref, g, j) if w.have_bias else None,
+                    bias_col=_col(bias_ref, mg, j) if w.have_bias else None,
                     segq_row=segq,
-                    segk_col=_col(segk_ref, g, j) if w.have_seg else None)
+                    segk_col=_col(segk_ref, mg, j) if w.have_seg else None)
                 dp = jax.lax.dot_general(vb, do, _NT,
                                          preferred_element_type=jnp.float32)
                 ds = _probs(s, lse) * (dp - delta)
                 # k.T @ ds.T = (ds @ k).T; ds rounded to the input dtype
-                return dq_t + jax.lax.dot_general(
-                    kb, ds.astype(kb.dtype), _TN,
-                    preferred_element_type=jnp.float32)
+                return dq_t + w.rows_dot(kb, ds.astype(kb.dtype), g)
             return body
 
-        dq_t = _two_loops(n_plain, n_end, chunk, dq_scr[g, qt], w)
-        dq_scr[g, qt] = dq_t
+        at = w.acc_at(g, qt)
+        dq_t = _two_loops(n_plain, n_end, chunk, dq_scr[at], w)
+        dq_scr[at] = dq_t
 
-        @pl.when(kv == last_kv)
-        def _finalize():
-            dq_ref[g, pl.ds(q0, tq), :] = (dq_t * w.scale).T.astype(
-                dq_ref.dtype)
+        if not w.shared_lanes:      # else written with its lane group, below
+            @pl.when(kv == last_kv)
+            def _finalize():
+                dq_ref[w.head(g, pl.ds(q0, tq))] = (dq_t * w.scale).T.astype(
+                    dq_ref.dtype)
 
     @pl.when(_block_runs(qb, kv, w) | (kv == last_kv))
     def _step():
         _over_tiles(w.nqt, q_tile, w)
+        if w.shared_lanes:      # a lane group's heads written together
+            pl.when(kv == last_kv)(
+                lambda: w.write_groups(dq_scr, dq_ref, w.nqt, tq, w.scale))
 
 
 def _dkv_kernel(*refs, w: _Walk):
@@ -744,10 +1008,12 @@ def _dkv_kernel(*refs, w: _Walk):
         k0 = _at(kt, tk)
         c0 = kb_i * p.block_k + k0
         rows = (g, pl.ds(k0, tk))
-        ks = _still(k_ref[rows], w)
-        vb = v_ref[rows]
-        bias_col = _col(bias_ref, g, kt) if w.have_bias else None
-        segk_col = _col(segk_ref, g, kt) if w.have_seg else None
+        at = w.head(*rows)
+        mg = w.mask_row(g)
+        ks = _fold_scale(w.still(k_ref, *rows), w)
+        vb = w.still(v_ref, *rows)
+        bias_col = _col(bias_ref, mg, kt) if w.have_bias else None
+        segk_col = _col(segk_ref, mg, kt) if w.have_seg else None
         # query chunks of this block, ascending: those before n_lo see
         # none of the tile's keys, [n_lo, n_plain) cross the diagonal,
         # [n_plain, nqt) see all of them. Padded keys need no mask here:
@@ -760,14 +1026,15 @@ def _dkv_kernel(*refs, w: _Walk):
 
         def chunk(masked):
             def body(i, carry):
-                dk, dv = carry               # [tk, d] each
+                dk, dv = carry               # [tk, d] each (or a lane group)
                 q0 = _at(i, tq)
                 row = (g, 0, pl.ds(i, 1))
-                qc = q_ref[g, pl.ds(q0, tq), :]
-                do = g_ref[g, pl.ds(q0, tq), :]
+                qc = w.moving(q_ref, g, pl.ds(q0, tq))
+                do = w.moving(g_ref, g, pl.ds(q0, tq))
                 s = _scores(ks, qc, r_base + q0, c0, w, masked=masked,
                             bias_col=bias_col,
-                            segq_row=segq_ref[row] if w.have_seg else None,
+                            segq_row=(segq_ref[mg, 0, pl.ds(i, 1)]
+                                      if w.have_seg else None),
                             segk_col=segk_col)
                 prob = _probs(s, lse_ref[row])
                 dv = dv + jax.lax.dot_general(
@@ -788,120 +1055,175 @@ def _dkv_kernel(*refs, w: _Walk):
 
         @pl.when(qb == last_q)
         def _finalize():
-            dk_ref[rows] = (dk * w.scale).astype(dk_ref.dtype)
-            dv_ref[rows] = dv.astype(dv_ref.dtype)
+            dk_ref[at] = w.own_lanes(dk * w.scale, g).astype(dk_ref.dtype)
+            dv_ref[at] = w.own_lanes(dv, g).astype(dv_ref.dtype)
 
     @pl.when(_block_runs(qb, kb_i, w) | (qb == last_q))
     def _step():
         _over_tiles(w.nkt, k_tile, w)
 
 
+def _head_sums(x, h, exact: bool):
+    """Float32 ``[b, s, h * d]`` summed over each head's lanes: ``[b, h, s]``.
+    On the MXU, against a 0/1 ``[h * d, h]`` operand: a reduction over
+    ``d`` lanes of a 128-lane register has no other cheap form (the
+    compiler answers the reshape to ``[b, s, h, d]`` with a transpose of
+    the whole float32 array). A default-precision product on the chip
+    rounds its operands to bfloat16, so ``x`` goes in as two terms, its
+    rounding to bfloat16 and what the rounding left: 16 bits of every
+    addend under float32 accumulation, which is all of a product of two
+    bfloat16 numbers (8 bits by 8). ``exact``, for wider operands, whose
+    products fill a float32: the product at the highest precision, the
+    float32 sum a ``[b, h, s, d]`` call makes."""
+    width = x.shape[-1]
+    heads = (jnp.arange(width)[:, None] // (width // h)
+             == jnp.arange(h)[None, :]).astype(jnp.float32)
+    if exact:
+        return jnp.einsum("bsc,ch->bhs", x, heads,
+                          precision=jax.lax.Precision.HIGHEST)
+    # reduce_precision, not a cast there and back, which the compiler may
+    # drop as excess precision it is allowed to keep
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return sum(jnp.einsum("bsc,ch->bhs", t, heads) for t in (hi, x - hi))
+
+
 def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
                block_q: Optional[int], block_k: Optional[int],
-               interpret: bool, delta=None, scale: Optional[float] = None):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bh = b * h
-    if v.shape[-1] != d:
+               interpret: bool, delta=None, scale: Optional[float] = None,
+               num_heads: Optional[int] = None):
+    """dq, dk, dv in the operands' layout (``[b, h, s, d]``, or
+    ``[b, s, num_heads * d]`` each, also where q, k and v came fused in
+    ``q``); ``lse`` and ``delta`` are ``[b, h, s_q]``."""
+    if num_heads is None and v.shape[-1] != q.shape[-1]:
         # the dq and dkv kernels hold dO, K and V in blocks of one width
         raise NotImplementedError(
             f"flash_attention: no backward pass for values {v.shape[-1]} "
-            f"wide under scores that contract over {d}; the forward takes "
-            f"unequal widths, the dq and dkv kernels do not yet")
+            f"wide under scores that contract over {q.shape[-1]}; the "
+            f"forward takes unequal widths, the dq and dkv kernels do not yet")
     if delta is None:
-        delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
-    p, w, ops = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                         block_k, scale)
-    hg, nq, nk = p.heads, w.nq, w.nk
+        delta = out.astype(jnp.float32) * g.astype(jnp.float32)
+        delta = (jnp.sum(delta, axis=-1) if num_heads is None
+                 else _head_sums(delta, num_heads,
+                                 exact=jnp.dtype(out.dtype).itemsize > 2))
+    p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
+                               block_k, scale, num_heads)
+    bh, hg, nq, nk, d = b * h, p.heads, w.nq, w.nk, p.d
 
     # padded q rows: g/delta 0 and lse huge, so p=exp(s-lse)=0 — they
     # contribute nothing to dk/dv, and their dq rows are sliced off
-    g_r = _pad_seq(g, p.sq_p, 2).reshape(bh, p.sq_p, d)
+    g_r = _kernel_form(g, p.sq_p, p)
+    kv_shape = g_r.shape[:-2] + (p.sk_p, g_r.shape[-1])
     lse_r = _rows(_pad_seq(lse, p.sq_p, 2, -NEG_INF), bh, nq, w.nqt, p.tile_q)
     delta_r = _rows(_pad_seq(delta, p.sq_p, 2), bh, nq, w.nqt, p.tile_q)
 
     # ---- dq pass: grid (bh/heads, nq, nk), K/V on the inner dim; causal
     # steps past the diagonal re-request the same block so their DMA is
     # skipped (see _kj_clamp)
-    q_map, k_map, qrow_map, krow_map = _grid_maps(p, w.offset)
-    mask_specs, mask_args = _mask_specs(ops, p, qrow_map, krow_map)
-    q_spec = pl.BlockSpec((hg, p.block_q, d), q_map)
-    k_spec = pl.BlockSpec((hg, p.block_k, d), k_map)
-    row_spec = pl.BlockSpec((hg, 1, w.nqt, p.tile_q), qrow_map)
+    sp = _specs(ops, p, w, h)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, w=w),
         name="flash_dq",
         grid=(bh // hg, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec] + mask_specs
-        + [q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, p.sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((hg, w.nqt, d, p.tile_q), jnp.float32)],
+        in_specs=[sp.q, sp.k, sp.v] + sp.masks + [sp.o, sp.qrow, sp.qrow],
+        out_specs=sp.o,
+        out_shape=jax.ShapeDtypeStruct(g_r.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)],
         interpret=interpret,
-    )(ops.q, ops.k, ops.v, *mask_args, g_r, lse_r, delta_r)
+    )(ops.q, ops.k, ops.v, *sp.mask_args, g_r, lse_r, delta_r)
 
     # ---- dk/dv pass: grid (bh/heads, nk, nq), Q/dO on the inner dim;
     # causal steps before a key block's first useful q block re-request
     # that first block (DMA skipped, see _qi_clamp)
-    cq = _qi_clamp(causal, p.block_q, p.block_k, nq, w.offset)
-    q_map = lambda i, j, kk: (i, cq(kk, j), 0)
-    k_map = lambda i, j, kk: (i, j, 0)
-    qrow_map = lambda i, j, kk: (i, cq(kk, j), 0, 0)
-    krow_map = lambda i, j, kk: (i, j, 0, 0)
-    mask_specs, mask_args = _mask_specs(ops, p, qrow_map, krow_map)
-    q_spec = pl.BlockSpec((hg, p.block_q, d), q_map)
-    k_spec = pl.BlockSpec((hg, p.block_k, d), k_map)
-    row_spec = pl.BlockSpec((hg, 1, w.nqt, p.tile_q), qrow_map)
+    sp = _specs(ops, p, w, h, dkv=True)
+    acc_lanes = 128 if w.shared_lanes else d    # dk, dv of a whole lane group
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, w=w),
         name="flash_dkv",
         grid=(bh // hg, nk, nq),
-        in_specs=[q_spec, k_spec, k_spec] + mask_specs
-        + [q_spec, row_spec, row_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct((bh, p.sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, p.sk_p, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((hg, p.block_k, d), jnp.float32),
-                        pltpu.VMEM((hg, p.block_k, d), jnp.float32)],
+        in_specs=[sp.q, sp.k, sp.v] + sp.masks + [sp.o, sp.qrow, sp.qrow],
+        out_specs=[sp.dk, sp.dk],
+        out_shape=[jax.ShapeDtypeStruct(kv_shape, q.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((hg, p.block_k, acc_lanes), jnp.float32),
+                        pltpu.VMEM((hg, p.block_k, acc_lanes), jnp.float32)],
         interpret=interpret,
-    )(ops.q, ops.k, ops.v, *mask_args, g_r, lse_r, delta_r)
+    )(ops.q, ops.k, ops.v, *sp.mask_args, g_r, lse_r, delta_r)
 
-    return (dq.reshape(b, h, p.sq_p, d)[:, :, :sq],
-            dk.reshape(b, h, p.sk_p, d)[:, :, :sk],
-            dv.reshape(b, h, p.sk_p, d)[:, :, :sk])
+    return (_user_form(dq, p.sq, q, p), _user_form(dk, p.sk, q, p),
+            _user_form(dv, p.sk, q, p))
 
 
 # ---------------------------------------------------------------------------
 # custom VJP plumbing
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-                interpret, scale=None):
+                interpret, scale=None, num_heads=None):
     out, _ = _flash_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                        block_k, interpret, scale)
+                        block_k, interpret, scale, num_heads)
     return out
 
 
 def _flash_core_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-                    interpret, scale=None):
+                    interpret, scale=None, num_heads=None):
     out, lse = _flash_fwd(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                          block_k, interpret, scale)
+                          block_k, interpret, scale, num_heads)
     return out, (q, k, v, bias, seg_q, seg_k, out, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, interpret, scale, res, g):
+def _flash_core_bwd(causal, block_q, block_k, interpret, scale, num_heads,
+                    res, g):
     q, k, v, bias, seg_q, seg_k, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
-                            block_q, block_k, interpret, scale=scale)
+                            block_q, block_k, interpret, scale=scale,
+                            num_heads=num_heads)
     return dq, dk, dv, None, None, None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_core_fused(qkv, bias, seg_q, seg_k, causal, block_q, block_k,
+                      interpret, scale, num_heads):
+    """Self-attention over a fused projection's output ``[b, s, 3 * h*d]``,
+    q, k and v side by side: the kernels pick each out by its blocks'
+    lane offset, so nothing slices it into three arrays first."""
+    return _flash_core_fused_fwd(qkv, bias, seg_q, seg_k, causal, block_q,
+                                 block_k, interpret, scale, num_heads)[0]
+
+
+def _flash_core_fused_fwd(qkv, bias, seg_q, seg_k, causal, block_q, block_k,
+                          interpret, scale, num_heads):
+    out, lse = _flash_fwd(qkv, None, None, bias, seg_q, seg_k, causal,
+                          block_q, block_k, interpret, scale, num_heads)
+    return out, (qkv, bias, seg_q, seg_k, out, lse)
+
+
+def _flash_core_fused_bwd(causal, block_q, block_k, interpret, scale,
+                          num_heads, res, g):
+    qkv, bias, seg_q, seg_k, out, lse = res
+    grads = _flash_bwd(qkv, None, None, bias, seg_q, seg_k, causal, out, lse,
+                       g, block_q, block_k, interpret, scale=scale,
+                       num_heads=num_heads)
+    return jnp.concatenate(grads, axis=-1), None, None, None
+
+
+_flash_core_fused.defvjp(_flash_core_fused_fwd, _flash_core_fused_bwd)
+
+
+def _split_heads(x, h):
+    b, s, width = x.shape
+    return x.reshape(b, s, h, width // h).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
 def flash_attention(
-    q, k, v,
+    q, k=None, v=None,
     causal: bool = False,
     attn_mask: Optional[jax.Array] = None,
     key_bias: Optional[jax.Array] = None,
@@ -912,11 +1234,22 @@ def flash_attention(
     interpret: Optional[bool] = None,
     return_lse: bool = False,
     scale: Optional[float] = None,
+    num_heads: Optional[int] = None,
 ):
     """Flash attention over ``q``, ``k`` [b, h, s, d] and ``v``
     [b, h, s_k, dv]; the output is [b, h, s_q, dv]. ``dv`` may differ
     from ``d`` in the forward pass (latent attention scores over 192 and
     sums values 128 wide); the backward pass raises for unequal widths.
+
+    Rank-3 ``q``, ``k``, ``v`` [b, s, num_heads * d], heads side by side
+    as a projection leaves them, give the output in that layout too, and
+    the gradients. Where :func:`lane_heads` admits the shape the kernels
+    read and write it in place; else this call transposes to
+    [b, h, s, d] and back, as its caller would have had to. A fused
+    self-attention projection ``[b, s, 3 * num_heads * d]`` (q, k and v
+    side by side, one matmul's output) goes in whole as ``q`` with ``k``
+    and ``v`` left out: the kernels read its three parts where they lie
+    (three arrays sliced out of it are three copies).
 
     - ``key_bias``: additive [b, s_k] (padding mask).
     - ``segment_ids`` / ``kv_segment_ids``: int [b, s] ragged-batch ids
@@ -934,6 +1267,35 @@ def flash_attention(
     """
     from ..core.errors import enforce
 
+    fused = k is None
+    if fused:
+        enforce(v is None and q.ndim == 3 and num_heads is not None
+                and q.shape[-1] % (3 * num_heads) == 0,
+                "flash_attention: without k and v, q is a fused "
+                "[b, s, 3 * h*d] projection and num_heads is given")
+        d = q.shape[-1] // (3 * num_heads)
+        dense_mask = attn_mask is not None and not (
+            attn_mask.ndim == 4 and attn_mask.shape[1:3] == (1, 1))
+        if dense_mask or return_lse or not lane_heads(d, d, num_heads):
+            q, k, v = jnp.split(q, 3, axis=-1)
+            fused = False
+    if q.ndim == 3 and not fused:
+        enforce(num_heads is not None and q.shape[-1] % num_heads == 0,
+                f"flash_attention: a [b, s, h*d] call names its head count "
+                f"(num_heads={num_heads!r} for a width of {q.shape[-1]})")
+        d = q.shape[-1] // num_heads
+        if not lane_heads(d, v.shape[-1] // num_heads, num_heads):
+            out = flash_attention(
+                *(_split_heads(x, num_heads) for x in (q, k, v)),
+                causal=causal, attn_mask=attn_mask, key_bias=key_bias,
+                segment_ids=segment_ids, kv_segment_ids=kv_segment_ids,
+                block_q=block_q, block_k=block_k, interpret=interpret,
+                return_lse=return_lse, scale=scale)
+            if return_lse:
+                return _merge_heads(out[0]), out[1]
+            return _merge_heads(out)
+    elif q.ndim == 4:
+        num_heads = None
     block_q, block_k = resolve_block_shapes(block_q, block_k)
     if interpret is None:
         interpret = default_interpret()
@@ -959,15 +1321,22 @@ def flash_attention(
                 seg_k_ = kv_segment_ids if kv_segment_ids is not None else segment_ids
                 same = segment_ids[:, None, :, None] == seg_k_[:, None, None, :]
                 mask = jnp.where(same, mask, NEG_INF)
+            if num_heads is not None:
+                return _merge_heads(_mask_fallback(
+                    *(_split_heads(x, num_heads) for x in (q, k, v)),
+                    mask, causal, scale))
             return _mask_fallback(q, k, v, mask, causal, scale)
     seg_q = segment_ids
     seg_k = kv_segment_ids if kv_segment_ids is not None else segment_ids
     bias = None if key_bias is None else key_bias.astype(jnp.float32)
+    if fused:
+        return _flash_core_fused(q, bias, seg_q, seg_k, causal, block_q,
+                                 block_k, interpret, scale, num_heads)
     if return_lse:
         return _flash_fwd(q, k, v, bias, seg_q, seg_k, causal,
-                          block_q, block_k, interpret, scale)
+                          block_q, block_k, interpret, scale, num_heads)
     return _flash_core(q, k, v, bias, seg_q, seg_k, causal,
-                       block_q, block_k, interpret, scale)
+                       block_q, block_k, interpret, scale, num_heads)
 
 
 def _mask_fallback(q, k, v, attn_mask, causal, scale=None):

@@ -485,3 +485,313 @@ def test_causal_multiblock_interior_tiles():
         np.asarray(fa.flash_attention(q2, k2, v2, causal=True, block_q=32,
                                       block_k=32)),
         np.asarray(_ref(q2, k2, v2, causal=True)), atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the projections' own layout: ``[b, s, h*d]`` operands (and a fused
+# ``[b, s, 3*h*d]`` one) read and written in place, against the
+# ``[b, h, s, d]`` path and the dense reference (what the chip's compiler
+# makes of it: test_tpu_compile.py)
+
+# heads of a call at each of CELL_SHAPES
+CELL_HEADS = {"gpt2m_train": 16, "gpt2l_train_shard": 10, "gpt2m_prefill": 16}
+
+
+def _heads_apart(x, h):
+    b, s, width = x.shape
+    return x.reshape(b, s, h, width // h).transpose(0, 2, 1, 3)
+
+
+def _heads_together(x):
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _ref_masked(q, k, v, causal, key_bias=None, seg=None):
+    """Dense reference over [b, h, s, d] with every mask the kernels take."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
+    keep = jnp.ones(s.shape[-2:], jnp.bool_)
+    if causal:
+        keep = jnp.tril(keep, k=s.shape[-1] - s.shape[-2])
+    keep = keep[None, None]
+    if seg is not None:
+        keep = keep & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# name -> (heads, d, dv, layout the plan must take, heads a lane group)
+PACKED_SHAPES = {
+    "d64_even_heads": (4, 64, 64, "bsd", 2),
+    "d128": (2, 128, 128, "bsd", 1),
+    "d32_four_a_group": (4, 32, 32, "bsd", 4),
+    "d64_odd_heads_falls_back": (3, 64, 64, "bhsd", 0),
+    "d192_dv128_falls_back": (2, 192, 128, "bhsd", 0),
+}
+PACKED_MASKS = ("causal", "key_bias", "segment_ids")
+
+
+@pytest.mark.parametrize("mask", PACKED_MASKS)
+@pytest.mark.parametrize("shape", PACKED_SHAPES)
+def test_projection_layout_matches_heads_apart_and_dense(shape, mask):
+    """A rank-3 ``[b, s, h*d]`` call against the same call on
+    ``[b, h, s, d]`` operands and against the dense reference: forward and
+    the three gradients, under each mask; ``flash.plan`` says which layout
+    the kernels got and how many heads share a 128-lane group. Shapes the
+    packed form does not fit (an odd count of 64-wide heads, 192-wide
+    scores over 128-wide values) fall back inside the call and agree too
+    (forward only at unequal widths: the backward raises there)."""
+    from paddle_tpu.core import profiler
+
+    h, d, dv, layout, lane_heads = PACKED_SHAPES[shape]
+    b, s = 2, 192
+    rng = np.random.RandomState(len(shape) + len(mask))
+    q, k = (jnp.asarray(rng.randn(b, s, h * d), jnp.float32) for _ in "qk")
+    v = jnp.asarray(rng.randn(b, s, h * dv), jnp.float32)
+    kw, ref_kw = {"causal": mask == "causal"}, {}
+    if mask == "key_bias":
+        ref_kw["key_bias"] = kw["key_bias"] = jnp.where(
+            jnp.arange(s)[None, :] < jnp.array([[150], [s]]), 0.0, -1e9)
+    if mask == "segment_ids":
+        kw["causal"] = True
+        ref_kw["seg"] = kw["segment_ids"] = jnp.asarray(
+            np.sort(rng.randint(0, 3, (b, s)), axis=1), jnp.int32)
+    if d != dv:
+        kw["scale"] = 0.1147
+
+    def packed(q, k, v):
+        return fa.flash_attention(q, k, v, num_heads=h, **kw)
+
+    def apart(q, k, v):
+        return _heads_together(fa.flash_attention(
+            _heads_apart(q, h), _heads_apart(k, h), _heads_apart(v, h), **kw))
+
+    def dense(q, k, v):
+        scale = kw.get("scale", 1.0 / math.sqrt(d)) * math.sqrt(d)
+        return _heads_together(_ref_masked(
+            _heads_apart(q, h) * scale, _heads_apart(k, h),
+            _heads_apart(v, h), kw["causal"], **ref_kw))
+
+    since = profiler.time.time_ns()
+    out = packed(q, k, v)
+    ids = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"][-1]
+    assert (ids["layout"], ids["lane_heads"]) == (layout, lane_heads)
+    assert ids["heads"] % max(lane_heads, 1) == 0
+    assert out.shape == (b, s, h * dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(apart(q, k, v)),
+                               atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense(q, k, v)),
+                               atol=3e-5, rtol=3e-5)
+    if d != dv:
+        return
+    w = jnp.asarray(rng.randn(*out.shape), jnp.float32)
+    grads = [jax.grad(lambda *a: (f(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+             for f in (packed, apart, dense)]
+    for name, gp, ga, gd in zip("qkv", *grads):
+        np.testing.assert_allclose(np.asarray(gp), np.asarray(ga), atol=5e-6,
+                                   rtol=1e-5, err_msg=f"d{name} vs [b,h,s,d]")
+        np.testing.assert_allclose(np.asarray(gp), np.asarray(gd), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name} vs dense")
+
+
+@pytest.mark.parametrize("shape,seq,blocks", [
+    ("d64_even_heads", 256, None), ("d64_even_heads", 200, None),
+    ("d64_even_heads", 24, None), ("d64_even_heads", 104, None),
+    ("d32_four_a_group", 40, None), ("d64_even_heads", 100, (16, 64)),
+    ("d128", 128, None), ("d64_odd_heads_falls_back", 128, None)])
+def test_fused_projection_matches_its_three_parts(shape, seq, blocks):
+    """q, k and v side by side in one ``[b, s, 3*h*d]`` array (a fused
+    projection's output, passed as ``q`` alone) against the three passed
+    apart: the kernels pick each part by a lane-block offset, a padded
+    sequence (200 -> 256) pads the one array, and the gradient comes back
+    as one array of the same form. Queries pad to 8 rows and keys to 16,
+    so at 24, 40 or 104 rows (a short prompt's prefill), and under
+    explicit blocks of two sizes, the key axis is the longer: the one
+    array has rows for both, zeros beyond the sequence (a key block read
+    past the array's end holds whatever lies there, and 0 x NaN is NaN)."""
+    h, d, _, layout, _ = PACKED_SHAPES[shape]
+    rng = np.random.RandomState(seq)
+    qkv = jnp.asarray(rng.randn(2, seq, 3 * h * d), jnp.float32)
+
+    kw = dict(causal=True, num_heads=h)
+    if blocks:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+
+    def fused(x):
+        return fa.flash_attention(x, **kw)
+
+    def apart(x):
+        return fa.flash_attention(*jnp.split(x, 3, axis=-1), **kw)
+
+    out = fused(qkv)
+    assert out.shape == (2, seq, h * d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(apart(qkv)),
+                               atol=1e-6, rtol=1e-6)
+    w = jnp.asarray(rng.randn(*out.shape), jnp.float32)
+    g_fused, g_apart = (jax.grad(lambda x: (f(x) * w).sum())(qkv)
+                        for f in (fused, apart))
+    assert g_fused.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(g_fused), np.asarray(g_apart),
+                               atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", CELL_SHAPES)
+def test_packed_plan_for_cell_shapes(name):
+    """What the plan gives the benchmark's cells in the projections'
+    layout: pairs of 64-wide heads a step (one 128-lane block of one batch
+    row), the tile walk of the ``[b, h, s, d]`` plan, and the fall back
+    where a head would lie astride a lane group's edge."""
+    bh, s, d = CELL_SHAPES[name]
+    old = fa.plan_blocks(s, s, d, jnp.bfloat16, causal=True, bh=bh)
+    new = fa.plan_blocks(s, s, d, jnp.bfloat16, causal=True, bh=bh,
+                         num_heads=CELL_HEADS[name])
+    assert (new.layout, new.lane_heads, new.heads) == ("bsd", 2, 2)
+    assert (old.layout, old.lane_heads) == ("bhsd", 0)
+    assert new[:7] == old[:7] and new.tiles_run == old.tiles_run
+    assert fa.lane_heads(64, 64, 25) == 0      # GPT-2 XL: an odd head count
+    assert fa.lane_heads(64, 64, 5) == 0       # 20 heads under tp4
+    assert fa.lane_heads(192, 128, 64) == 0    # latent attention
+    assert fa.lane_heads(256, 256, 3) == 1
+    assert fa.lane_heads(64, 64, None) == 0    # a [b, h, s, d] call
+
+
+def test_stacked_block_at_gpt2_medium_shape_records_only_bsd_plans():
+    """Two scan-stacked layers at gpt2-medium's widths (d 1024, 16 heads
+    of 64, sequence 1024, remat, ``jax.grad``), traced and not run: every
+    attention the block traces hands the kernels the fused projection as
+    it lies, and the prefill block too."""
+    import paddle_tpu as pt
+    from paddle_tpu.core import profiler
+    from paddle_tpu.layers import stacked
+
+    d, inner, heads, seq, layers = 1024, 4096, 16, 1024, 2
+
+    def net(x):
+        stack = stacked.encoder_stack_params(layers, d, inner)
+        y = stacked.apply_stacked(x, stack, stacked.make_encoder_block,
+                                  num_heads=heads, use_flash=True,
+                                  causal=True, remat=True)
+        one = {k: v[0] for k, v in stack.items()}
+        z, (k, v) = stacked.prefill_block(x, one, heads, use_flash=True)
+        assert k.shape == v.shape == x.shape
+        return {"loss": jnp.mean(jnp.square(y)) + jnp.mean(z)}
+
+    prog = pt.build(net)
+    x = jax.ShapeDtypeStruct((2, seq, d), jnp.float32)
+    params = jax.eval_shape(
+        lambda key: prog.init(key, x=np.zeros((1, 8, d), np.float32))[0],
+        jax.random.PRNGKey(0))
+    since = profiler.time.time_ns()
+    jax.eval_shape(jax.grad(
+        lambda p, x: prog.apply(p, {}, training=True, x=x)[0]["loss"]),
+        params, x)
+    plans = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"]
+    assert len(plans) >= 2
+    assert {(p["layout"], p["lane_heads"], p["heads"], p["sq"], p["d"])
+            for p in plans} == {("bsd", 2, 2, seq, 64)}
+
+
+@pytest.mark.parametrize("axes", [{"dp": 4, "tp": 2}, {"dp": 8, "tp": 1},
+                                  {"dp": 2, "tp": 4}, {"dp": 1, "tp": 8}],
+                         ids=lambda a: "dp%dtp%d" % (a["dp"], a["tp"]))
+def test_flash_sdpa_shards_the_projection_layout(axes):
+    """Under a Trainer's mesh ``flash_sdpa`` runs the kernel per shard:
+    batch rows over the data axes and whole heads of the minor dimension
+    over ``tp``, a fused projection cut into its three parts first so
+    that a shard's lanes are its heads of each. Four rows over dp 8 and
+    four heads over tp 8 stay whole; one head a shard (tp 4) falls back
+    to ``[b, h, s, d]`` inside the call. All agree with one device."""
+    import paddle_tpu as pt
+    from paddle_tpu.framework import mesh_mode
+    from paddle_tpu.layers.attention import flash_sdpa
+
+    b, s, h, d = 4, 128, 4, 64
+    rng = np.random.RandomState(3)
+    qkv = jnp.asarray(rng.randn(b, s, 3 * h * d), jnp.float32)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+    bias = jnp.where(jnp.arange(s)[None, :] < 100, 0.0, -1e9) * jnp.ones((b, 1))
+
+    def fused(x, bias=None):
+        return flash_sdpa(x, None, None, True, key_bias=bias, num_heads=h)
+
+    one = flash_sdpa(q, k, v, True, key_bias=bias, num_heads=h)
+    grad_one = jax.grad(lambda x: fused(x).sum())(qkv)
+    with mesh_mode(pt.make_mesh(axes)):
+        apart = jax.jit(lambda q, k, v, bias: flash_sdpa(
+            q, k, v, True, key_bias=bias, num_heads=h))(q, k, v, bias)
+        whole = jax.jit(fused)(qkv, bias)
+        grad = jax.jit(jax.grad(lambda x: fused(x).sum()))(qkv)
+    np.testing.assert_allclose(np.asarray(apart), np.asarray(one), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(one), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(grad_one),
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("case", ["self", "self_fused_padding_mask", "cross"])
+def test_multi_head_attention_hands_the_kernel_its_projections(case):
+    """``multi_head_attention(use_flash=True)`` gives the kernels q, k, v
+    as its projections leave them (no head split; 4 heads of 32 share a
+    lane group) and agrees with the dense path on the same parameters."""
+    import paddle_tpu as pt
+    from paddle_tpu.core import profiler
+    from paddle_tpu.layers import attention as A
+
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(2, 64, 128), jnp.float32)
+    mem = jnp.asarray(rng.randn(2, 96, 128), jnp.float32)
+    mask = A.padding_mask(jnp.asarray(rng.randint(0, 3, (2, 64))), pad_id=0)
+
+    def net(use_flash):
+        def fn(x, mem):
+            if case == "cross":
+                y = A.multi_head_attention(x, mem, num_heads=4,
+                                           use_flash=use_flash, name="a")
+            else:
+                fused = case != "self"
+                y = A.multi_head_attention(
+                    x, num_heads=4, causal=not fused, fuse_qkv=fused,
+                    attn_mask=mask if fused else None, use_flash=use_flash,
+                    name="a")
+            return {"y": y}
+        return pt.build(fn)
+
+    params, _ = net(False).init(jax.random.PRNGKey(0), x=x, mem=mem)
+    dense = net(False).apply(params, {}, x=x, mem=mem)[0]["y"]
+    since = profiler.time.time_ns()
+    flash = net(True).apply(params, {}, x=x, mem=mem)[0]["y"]
+    plans = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"]
+    assert [(p["layout"], p["lane_heads"]) for p in plans] == [("bsd", 4)]
+    np.testing.assert_allclose(np.asarray(flash), np.asarray(dense),
+                               atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("shape", ["d64_even_heads",
+                                   "d64_odd_heads_falls_back"])
+def test_projection_layout_lse_and_dense_mask(shape):
+    """What else a ``[b, s, h*d]`` call may ask for: ``return_lse`` (ring
+    attention's merge) gives ``[b, h, s]`` as the ``[b, h, s, d]`` call
+    does, and a dense ``attn_mask`` still takes the XLA composition, with
+    its warning, and comes back in the caller's layout."""
+    h, d, _, _, _ = PACKED_SHAPES[shape]
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(2, 128, h * d), jnp.float32)
+               for _ in "qkv")
+    apart = [_heads_apart(x, h) for x in (q, k, v)]
+    out, lse = fa.flash_attention(q, k, v, causal=True, num_heads=h,
+                                  return_lse=True)
+    out4, lse4 = fa.flash_attention(*apart, causal=True, return_lse=True)
+    assert lse.shape == (2, h, 128)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse4), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_heads_together(out4)), atol=1e-6)
+    dense = jnp.asarray(np.where(rng.rand(2, 1, 128, 128) < 0.8, 0.0, -1e9),
+                        jnp.float32)
+    with pytest.warns(UserWarning, match="dense attn_mask"):
+        masked = fa.flash_attention(q, k, v, attn_mask=dense, num_heads=h)
+    with pytest.warns(UserWarning, match="dense attn_mask"):
+        masked4 = fa.flash_attention(*apart, attn_mask=dense)
+    np.testing.assert_allclose(np.asarray(masked),
+                               np.asarray(_heads_together(masked4)), atol=1e-6)

@@ -66,6 +66,12 @@ def _attention(causal, mask=None):
     return fn
 
 
+def _packed(heads):
+    # [b, s, h*d] operands, as a projection leaves them
+    return lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                              interpret=False, num_heads=heads)
+
+
 def _ring_backward(q, k, v, out, lse, g, delta):
     # the call parallel/ring_attention.py makes on every ring step: the
     # shard-invariant delta computed once outside and passed in (here
@@ -74,7 +80,7 @@ def _ring_backward(q, k, v, out, lse, g, delta):
                          None, None, interpret=False, delta=delta)
 
 
-# name -> (fn, (b, h, s, d), per-row mask operand or None)
+# name -> (fn, (b, h, s, d) or (b, s, h*d), per-row mask operand or None)
 SHAPES = {
     "gpt2_small": (_attention(True), (8, 12, 1024, 64), None),
     "transformer_base_key_bias": (_attention(False, "key_bias"),
@@ -88,13 +94,18 @@ SHAPES = {
     "gpt2m_train": (_attention(True), (32, 16, 1024, 64), None),
     "gpt2l_train_shard": (_attention(True), (16, 10, 1024, 64), None),
     "gpt2m_prefill": (_attention(True), (16, 16, 896, 64), None),
+    # the same three in the projections' own layout, pairs of 64-wide heads
+    # a 128-lane block (what layers/stacked.py hands the kernels)
+    "gpt2m_train_packed": (_packed(16), (32, 1024, 1024), None),
+    "gpt2l_train_shard_packed": (_packed(10), (16, 1024, 640), None),
+    "gpt2m_prefill_packed": (_packed(16), (16, 896, 1024), None),
     # k25-serve-batch's prefill: latent attention scores over 192 (128
     # nope + 64 rope) and sums values 128 wide, under YaRN's softmax scale
     "k25_prefill": (lambda q, k, v: fa.flash_attention(
         q, k, v[..., :128], causal=True, interpret=False, scale=0.1147),
         (8, 64, 1984, 192), None),
 }
-FORWARD_ONLY = ("gpt2m_prefill", "k25_prefill")
+FORWARD_ONLY = ("gpt2m_prefill", "gpt2m_prefill_packed", "k25_prefill")
 # Every shape's gradient (which compiles its forward kernel too), and
 # the forward alone where that is what runs: the tier is close to its
 # time limit.
@@ -118,6 +129,8 @@ def test_flash_compiles_for_v5e(chip, name, grad):
     text = jax.jit(fn).lower(*args).compile().as_text()
     # forward alone is one kernel; its gradient adds the dq and dkv passes
     assert text.count("tpu_custom_call") == (3 if grad else 1)
+    if name.endswith("_packed"):
+        _assert_kernel_operands_lane_dense(text)
 
 
 def test_ring_backward_with_delta_compiles_for_v5e(chip):
@@ -251,6 +264,94 @@ def test_k25_generator_fits_one_v5e(chip, monkeypatch):
                - m.temp_size_in_bytes) < 0.15e9
 
 
+def _minor_dim(shape):
+    """The minor dimension's size of ``bf16[32,1024,16,64]{3,1,2,0:T(8,128)}``
+    (the first index in the braces names it), or None for a scalar."""
+    m = re.match(r"\w+\[([\d,]*)\](?:\{(\d+))?", shape)
+    dims = [int(x) for x in m.group(1).split(",") if x]
+    if not dims:
+        return None
+    return dims[int(m.group(2)) if m.group(2) else len(dims) - 1]
+
+
+def _assert_kernel_operands_lane_dense(text):
+    """Every operand of a ``flash_*`` kernel call has a minor dimension of
+    whole 128-lane registers: the chip holds none of them padded."""
+    calls = [ln for ln in text.splitlines()
+             if re.search(r"%\S*flash_(fwd|dq|dkv)\S* = .*tpu_custom_call", ln)]
+    assert calls
+    for ln in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", ln)
+        for shape in re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}", operands.group(1)):
+            assert _minor_dim(shape) % 128 == 0, (shape, ln[:200])
+
+
+def _assert_no_64_minor_copies_in_loops(text):
+    """No while body holds a ``copy`` or ``transpose`` whose result or
+    operand has a 64-wide minor dimension: under a 128-lane tile such an
+    array is held, written and read at twice its size (the twelve head
+    transposes a layer that stood round the flash kernels: PERF.md, PR 32)."""
+    comps = _computations(text)
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+    assert bodies
+    for name in bodies:
+        shapes = {}
+        for ln in comps[name]:
+            m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+) ", ln)
+            if m:
+                shapes[m.group(1)] = m.group(2)
+        for ln in comps[name]:
+            m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\S+) "
+                         r"(?:copy|transpose|copy-start)\(%([\w.\-]+)", ln)
+            if m and not m.group(1).startswith("("):
+                for shape in (m.group(1), shapes.get(m.group(2), "")):
+                    if shape and not shape.startswith("("):
+                        assert _minor_dim(shape) != 64, ln[:300]
+
+
+def test_stacked_train_block_has_no_padded_head_transposes_for_v5e(
+        chip, monkeypatch):
+    """Two scan-stacked layers at gpt2-medium's widths (32 x 1024 tokens,
+    d 1024, 16 heads of 64, remat, ``jax.grad``, bfloat16): the fused
+    projection's output goes into the flash kernels as it lies and their
+    output into the out-projection, so neither loop body copies or
+    transposes an array with a 64-wide minor dimension (the parent's had
+    twelve a layer, each held at twice its size), every operand of the
+    four kernel calls a layer is lane-dense, and the temporaries are
+    1.17 GB where the parent's were 1.55."""
+    d, inner, heads, batch, seq, layers = 1024, 4096, 16, 32, 1024, 2
+
+    def net(x):
+        stack = stacked.encoder_stack_params(layers, d, inner)
+        y = stacked.apply_stacked(x, stack, stacked.make_encoder_block,
+                                  num_heads=heads, use_flash=True,
+                                  causal=True, remat=True)
+        return {"loss": jnp.mean(jnp.square(y.astype(jnp.float32)))}
+
+    prog = pt.build(net)
+    before = config.get_flag("default_compute_dtype")
+    config.set_flag("default_compute_dtype", "bfloat16")
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    try:
+        small = np.zeros((2, 8, d), jnp.bfloat16)
+        shapes = jax.eval_shape(lambda key: prog.init(key, x=small)[0],
+                                jax.random.PRNGKey(0))
+        params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip)
+                  for k, v in shapes.items()}
+        x = jax.ShapeDtypeStruct((batch, seq, d), jnp.bfloat16, sharding=chip)
+        compiled = jax.jit(jax.grad(
+            lambda p, x: prog.apply(p, {}, training=True, x=x)[0]["loss"])
+        ).lower(params, x).compile()
+    finally:
+        config.set_flag("default_compute_dtype", before)
+    text = compiled.as_text()
+    # forward; remat's second forward, dq and dk/dv in the backward body
+    assert text.count("tpu_custom_call") == 4
+    _assert_kernel_operands_lane_dense(text)
+    _assert_no_64_minor_copies_in_loops(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
 def _computations(text):
     """name -> lines of every computation of an HLO module's text."""
     out, name = {}, None
@@ -264,16 +365,12 @@ def _computations(text):
     return out
 
 
-def test_tp_block_exchanges_run_under_matmuls_for_v5e(chips, monkeypatch):
-    """Two layers at gpt2-large's widths (d 1280, inner 5120, 20 heads,
-    16 x 1024 a replica, scan + remat, ``jax.grad``) as dp2 x tp2: the
-    loop bodies hold no all-reduce of an activation, the 11 exchanges of
-    a layer are ``collective-permute-start`` / ``-done`` pairs of half a
-    replica's rows in bf16, matmul fusions are scheduled between a start
-    and its done, and the gradients of the float32 parameters are
-    reduced over dp in float32. Times are the chip's to give
-    (PERF.md, PR 30); this holds the schedule they came from."""
-    d, inner, heads, batch, seq, layers = 1280, 5120, 20, 32, 1024, 2
+def _dp2tp2_block_text(chips, monkeypatch, batch):
+    """Compiled text of ``jax.grad`` over two scan-stacked layers at
+    gpt2-large's widths (d 1280, inner 5120, 20 heads, ``batch`` x 1024,
+    remat, bfloat16) on a dp2 x tp2 mesh of the described chips, the
+    parameters sharded by the rule table."""
+    d, inner, heads, seq, layers = 1280, 5120, 20, 1024, 2
     mesh = Mesh(np.array(chips).reshape(2, 2), ("dp", "tp"))
 
     def net(x):
@@ -303,12 +400,46 @@ def test_tp_block_exchanges_run_under_matmuls_for_v5e(chips, monkeypatch):
             with mesh_mode(mesh):
                 return prog.apply(p, {}, training=True, x=x)[0]["loss"]
 
-        text = jax.jit(jax.grad(loss), out_shardings={
+        return jax.jit(jax.grad(loss), out_shardings={
             k: v.sharding for k, v in params.items()}
         ).lower(params, x).compile().as_text()
     finally:
         config.set_flag("default_compute_dtype", before)
 
+
+def test_partitioned_tp_block_gathers_no_weight_for_v5e(chips, monkeypatch):
+    """The same two layers with 3 rows a replica, which tp 2 does not
+    divide: the stack keeps the form the partitioner splits
+    (``_batch_sharded_why_not``), ``qkv/w`` sharded over its last axis.
+    The fused projection stays the ``[b, s, 3, e]`` einsum there (a flat
+    ``[d, 3e]`` weight would merge the sharded axis with the 3 and be
+    gathered every layer: six all-gathers in this text), its three parts
+    go to the kernels per shard, five head pairs each, and the text holds
+    the parent's collectives: all-reduces and nothing else."""
+    text = _dp2tp2_block_text(chips, monkeypatch, batch=6)
+    kinds = set(re.findall(r" (all-gather|all-reduce|collective-permute|"
+                           r"all-to-all|reduce-scatter)(?:-start)?\(", text))
+    assert kinds == {"all-reduce"}, kinds
+    assert text.count("tpu_custom_call") == 4
+    _assert_kernel_operands_lane_dense(text)
+
+
+def test_tp_block_exchanges_run_under_matmuls_for_v5e(chips, monkeypatch):
+    """Two layers at gpt2-large's widths (d 1280, inner 5120, 20 heads,
+    16 x 1024 a replica, scan + remat, ``jax.grad``) as dp2 x tp2: the
+    loop bodies hold no all-reduce of an activation, the 11 exchanges of
+    a layer are ``collective-permute-start`` / ``-done`` pairs of half a
+    replica's rows in bf16, matmul fusions are scheduled between a start
+    and its done, and the gradients of the float32 parameters are
+    reduced over dp in float32. Times are the chip's to give
+    (PERF.md, PR 30); this holds the schedule they came from."""
+    d, batch, seq, layers = 1280, 32, 1024, 2
+    text = _dp2tp2_block_text(chips, monkeypatch, batch)
+
+    # the shard_map bodies' kernels read the projections' layout too (ten
+    # local heads, five pairs): no padded head transpose in a loop body
+    _assert_kernel_operands_lane_dense(text)
+    _assert_no_64_minor_copies_in_loops(text)
     comps = _computations(text)
     half = re.escape("bf16[%d,%d,%d]" % (batch // 4, seq, d))
     whole = re.escape("bf16[%d,%d,%d]" % (batch // 2, seq, d))

@@ -5,6 +5,7 @@ plan's compute tiles, beside JAX's own Pallas kernel as the yardstick.
     chiprun -- python3 tools/flash_microbench.py
     chiprun -- python3 tools/flash_microbench.py --tiles plan,128,256
     chiprun -- python3 tools/flash_microbench.py --shapes long_4k_d128 --reference 0
+    chiprun -- python3 tools/flash_microbench.py --layouts bhsd,projection,bsd,fused
 
 For every shape and every largest compute tile of ``--tiles`` (``plan``: the
 ``TILE`` that ``ops/flash_attention.plan_blocks`` uses itself) it runs forward +
@@ -17,8 +18,19 @@ call and TFLOP/s of the matmuls the algorithm needs (2 / 3 / 4 of
 kernels is about half the peak: the contraction (q k^T) or the output
 width (p v) fills 64 of its 128 columns. ``--reference 1`` times
 ``jax.experimental.pallas.ops.tpu.flash_attention`` the same way over a few
-block sizes and prints its best. One JSON line per measurement goes to
-``--out``. One process: it owns the chip and starts no child; it fails
+block sizes and prints its best.
+
+``--layouts`` times a call in each layout the kernels take, at the plan's
+own tile: ``bhsd`` (``[b, h, s, d]`` operands, the kernels alone: the
+sweep above), ``projection`` (``[b, s, h*d]`` operands, as a projection
+leaves them, transposed to ``[b, h, s, d]`` and back round the ``bhsd``
+kernels: what a block paid before the kernels read that layout), ``bsd``
+(the same operands, read and written in place) and ``fused`` (q, k and v
+side by side in one ``[b, s, 3*h*d]`` array, one matmul's output). Beside
+each kernel's time stands ``ms_call``: the device's busy time a call,
+everything the call runs (transposes, pads, the backward's ``delta``), so
+that a kernel PR can compare layouts on the chip without a train step.
+One JSON line per measurement goes to ``--out``. One process: it owns the chip and starts no child; it fails
 where there is no TPU (a CPU time is not a device time).
 """
 
@@ -53,10 +65,11 @@ def kernel_flops(kernel, shape, causal=True):
     return MATMULS[kernel] * 2.0 * b * h * s * s * d / (2 if causal else 1)
 
 
-def traced_kernel_ms(fn, args, iters):
+def traced_kernel_ms(fn, args, iters, busy=None):
     """{kernel name: (ms a call, calls)} of the Mosaic kernels ``fn`` runs,
     in the order they first ran, from a profiler trace of ``iters`` calls
-    after two warm ones."""
+    after two warm ones. ``busy``, a dict, gets ``ms_call``: the device's
+    busy time a call, every operation of ``fn`` counted."""
     import jax
 
     from benchmarks.tracing import Tracer
@@ -69,6 +82,8 @@ def traced_kernel_ms(fn, args, iters):
         out = fn(*args)
     jax.block_until_ready(out)
     trace = tracer.stop()
+    if busy is not None:
+        busy["ms_call"] = round(trace.busy_s() * 1e3 / iters, 4)
     by_name = {}
     for label, _, dur in sorted(trace.ops.get(0, []), key=lambda e: e[1]):
         if trace.is_kernel(label):
@@ -88,6 +103,8 @@ def main():
     ap.add_argument("--tiles", default="plan",
                     help="comma list of largest compute tiles (the plan's "
                          "TILE), 'plan' = its own")
+    ap.add_argument("--layouts", default="bhsd",
+                    help="comma list of bhsd, projection, bsd, fused")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--reference", type=int, default=1,
                     help="also time the jax pallas reference kernel")
@@ -136,23 +153,64 @@ def main():
             lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)))
 
+    def heads_apart(x, h):
+        b, s, width = x.shape
+        return x.reshape(b, s, h, width // h).transpose(0, 2, 1, 3)
+
+    def layout_call(layout, h):
+        """(attention over the layout's operands, how many it takes)"""
+        if layout == "projection":
+            def attn(q, k, v):
+                o = fa.flash_attention(*(heads_apart(x, h) for x in (q, k, v)),
+                                       causal=True)
+                return o.transpose(0, 2, 1, 3).reshape(q.shape)
+            return attn, 3
+        if layout == "bsd":
+            return (lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, num_heads=h)), 3
+        return (lambda qkv: fa.flash_attention(
+            qkv, causal=True, num_heads=h)), 1
+
     plan_tile = fa.TILE
+    layouts = args.layouts.split(",")
     best = {}
     for name in args.shapes.split(","):
         shape, grad = SHAPES[name]
         b, h, s, d = shape
         rng = np.random.RandomState(0)
+        for layout in (x for x in layouts if x != "bhsd"):
+            attn, n = layout_call(layout, h)
+            ops = [jnp.asarray(rng.randn(b, s, (3 if n == 1 else 1) * h * d),
+                               jnp.bfloat16) for _ in range(n)]
+            row = {"kernel": "repo", "shape": name, "layout": layout,
+                   "plan": fa.plan_blocks(
+                       s, s, d, jnp.bfloat16, True, bh=b * h,
+                       num_heads=None if layout == "projection" else h
+                   )._asdict()}
+            try:
+                fn = jax.jit(jax.grad(
+                    lambda *a: attn(*a).astype(jnp.float32).sum(),
+                    argnums=tuple(range(n)))) if grad else jax.jit(attn)
+                row["kernels"] = rates(shape, traced_kernel_ms(
+                    fn, ops, args.iters, busy=row), lambda n: n)
+                row["ms_all"] = round(sum(
+                    v["ms"] for v in row["kernels"].values()), 4)
+                best[name, "repo " + layout] = (row["ms_call"], "ms_call")
+            except Exception as e:
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            record(row)
+            del ops
         qkv = [jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(3)]
-        for spec in args.tiles.split(","):
+        for spec in args.tiles.split(",") if "bhsd" in layouts else ():
             fa.TILE = plan_tile if spec == "plan" else int(spec)
             plan = fa.plan_blocks(s, s, d, jnp.bfloat16, True, bh=b * h)
-            row = {"kernel": "repo", "shape": name, "tiles": spec,
-                   "plan": plan._asdict()}
+            row = {"kernel": "repo", "shape": name, "layout": "bhsd",
+                   "tiles": spec, "plan": plan._asdict()}
             try:
                 fn = step_fn(lambda q, k, v: fa.flash_attention(
                     q, k, v, causal=True), grad)
                 row["kernels"] = rates(shape, traced_kernel_ms(
-                    fn, qkv, args.iters), lambda n: n)
+                    fn, qkv, args.iters, busy=row), lambda n: n)
                 row["ms_all"] = round(sum(
                     v["ms"] for v in row["kernels"].values()), 4)
                 if row["ms_all"] < best.get((name, "repo"),
@@ -201,8 +259,8 @@ def main():
             record(row)
 
     for (name, kernel), (ms, spec) in sorted(best.items()):
-        print(f"# best {kernel:<14} {name:<18} {ms:8.3f} ms all kernels "
-              f"at {spec}")
+        print(f"# best {kernel:<16} {name:<18} {ms:8.3f} ms "
+              f"{'a call, all of it' if spec == 'ms_call' else 'all kernels at ' + spec}")
     return 0
 
 
