@@ -190,9 +190,86 @@ def kernel_case(name: str, shape, causal: bool, mask: str, seed: int,
     return errs
 
 
+def sparse_case(name: str, seed: int, rows=2, kv_heads=2, group=16, d=128,
+                total=8192, queries=256, p0=7936, window_blocks=32,
+                n_sel=31) -> None:
+    """``sparse_fwd`` at the published head shapes (16 grouped heads of 128
+    over one key head, 64-key blocks, a window of 32 and 31 chosen blocks)
+    against its ``jnp`` form: the last ``queries`` of a ``total``-key
+    cache, random choices among the blocks before the window."""
+    from paddle_tpu.ops import sparse_attention as sa
+
+    block = 64
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (rows, kv_heads, queries * group, d), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (rows, total, kv_heads * d), jnp.bfloat16)
+            for key in (kk, kv))
+    rng = np.random.RandomState(seed)
+    sel = np.zeros((rows, kv_heads, queries, n_sel + 1), np.int32)
+    for at in np.ndindex(rows, kv_heads, queries):
+        free = rng.permutation(np.arange(
+            1, max((p0 + at[2]) // block - window_blocks + 1, 1)))[:n_sel]
+        sel[at][:len(free)], sel[at][n_sel] = free, len(free)
+    kw = dict(group=group, block=block, window_blocks=window_blocks,
+              init_blocks=1, scale=d ** -0.5)
+    kernel = jax.jit(lambda *a: sa.sparse_attention(*a, **kw))
+    args = (q, k, v, jnp.asarray(sel), jnp.int32(p0))
+    calls = kernel.lower(*args).as_text().count("tpu_custom_call")
+    got = np.asarray(kernel(*args), np.float32)
+    want = np.asarray(jax.jit(lambda *a: sa.sparse_attention_jnp(*a, **kw))(*args),
+                      np.float32)
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    say("kernel", f"{name} q{q.shape} cache{k.shape} p0={p0}: tpu_custom_call "
+        f"in the lowered call: {calls}; error over largest reference value "
+        f"{err:.2e}")
+    check(np.isfinite(got).all() and err <= BF16_TOL,
+          f"{name}: sparse_fwd differs from its jnp form by {err:.2e}")
+    if on_tpu():
+        check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
+
+
+def lightning_case(name: str, seed: int, rows=2, heads=32, d=128,
+                   seq=1024) -> None:
+    """``lightning_fwd`` at the published head shapes (32 heads of 128,
+    the decays of published layer 7) from a random float32 state, against
+    its ``jnp`` form."""
+    from paddle_tpu.layers.sala import lightning_log_decay
+    from paddle_tpu.ops import lightning_attention as la
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v = (jax.random.normal(key, (rows, seq, heads * d), jnp.bfloat16)
+               for key in keys[:3])
+    q = (q.astype(jnp.float32) * d ** -0.5).astype(jnp.bfloat16)
+    state = jax.random.normal(keys[3], (rows, heads, d, d), jnp.float32)
+    decay = lightning_log_decay(heads, 7, 32)
+    kernel = jax.jit(lambda *a: la.lightning_attention(*a, heads))
+    calls = kernel.lower(q, k, v, decay, state).as_text().count("tpu_custom_call")
+    got, left = kernel(q, k, v, decay, state)
+    split = lambda a: a.reshape(rows, seq, heads, d)
+    want, want_left = jax.jit(la.lightning_chunk)(split(q), split(k), split(v),
+                                                  decay, state)
+    errs = {}
+    for label, a, r in (("out", got, want.reshape(rows, seq, -1)),
+                        ("state", left, want_left)):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        check(np.isfinite(a).all(), f"{name}: {label} is not finite")
+        errs[label] = float(np.abs(a - r).max() / np.abs(r).max())
+    say("kernel", f"{name} {q.shape} heads={heads}: tpu_custom_call in the "
+        f"lowered call: {calls}; error over largest reference value: "
+        + ", ".join(f"{k} {v:.2e}" for k, v in errs.items()))
+    check(max(errs.values()) <= BF16_TOL,
+          f"{name}: lightning_fwd differs from its jnp form by "
+          f"{max(errs.values()):.2e}")
+    if on_tpu():
+        check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
+
+
 def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
-                 transformer_shape=(32, 8, 256, 64)) -> None:
-    with timed("kernel", "five cases, compiles included"):
+                 transformer_shape=(32, 8, 256, 64), sala=None) -> None:
+    with timed("kernel", "seven cases, compiles included"):
+        sparse_case("sala_sparse", seed + 5, **(sala or {}).get("sparse", {}))
+        lightning_case("sala_lightning", seed + 6,
+                       **(sala or {}).get("lightning", {}))
         kernel_case("gpt", gpt_shape, True, "none", seed)
         kernel_case("gpt_packed", gpt_shape, True, "segment_ids", seed + 1)
         kernel_case("transformer_base", transformer_shape, False, "key_bias",

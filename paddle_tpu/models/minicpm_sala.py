@@ -1,0 +1,300 @@
+"""MiniCPM-SALA (``model_type: minicpm_sala``) — the serving path of a
+decoder whose layers are of two kinds (``layers/sala.py``): ``minicpm4``
+mixers, block-selected sparse attention over a grouped key/value cache, and
+``lightning-attn`` mixers, linear attention that carries a float32 state.
+Every layer has a dense gated SiLU FFN; the muP scalings of the config
+apply: the embedding times ``scale_emb``, every residual branch times
+``scale_depth / sqrt(published depth)``, the final norm's output over
+``hidden_size / dim_model_base``.
+
+``mixer_types`` lists the kinds of the layers held here and
+``layer_indices`` their published indices (a pipeline stage holds a run of
+the published stack; a lightning layer's decay follows its published
+index, ``published_layers`` deep); the rest of the depth lies on further
+chips. Nothing stands in for it.
+
+This module serves only: :func:`make_generator`, the contract of
+``gpt.make_generator`` (``prompt_ids [b, p] -> {"ids": [b, new]}`` through
+``greedy_search``). There is no ``make_model``: no cut of this model trains
+on one chip, and neither kernel has a backward (ROADMAP R5, R15). Matrices
+are created and held in ``cfg.dtype``; norm scales are float32.
+
+The carried state has two kinds of entry, in per-layer lists: for each
+sparse layer a key slab, a value slab and a compressed-key slab, lane-dense
+(``[rows, T, kv_heads * 128]``, ``[rows, T / 16, kv_heads * 128]``); for
+each lightning layer one float32 state ``[rows, heads, 128, 128]``.
+``decode.plan`` says so (``cache_kind="kv+state"``).
+
+The prefill walks the prompt in chunks of ``prefill_chunk`` tokens (a
+prompt of 32k does not go through a 16,384-wide FFN in one piece) and
+carries both kinds of state from chunk to chunk under one ``lax.scan`` with
+no conditional in it; a prompt within ``dense_len`` goes through in one
+piece, its sparse layers through the flash kernel. The layers are written
+out in their published order, in the prefill's chunk and in the step alike,
+and each has its own parameters (``layer_<published index>/...``), not a
+slice of a stack: the compiler laid one slice of a twelve-layer stack into
+VMEM ahead of its use, fused the eleven sibling slices into that copy, and
+so copied the whole stack every step (1.4 ms of a 19 ms step a stack:
+PERF.md section 6, PR 33).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import time
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .. import initializer as init
+from ..core.errors import enforce
+from ..framework import LayerHelper, name_scope
+from ..layers import latent as M
+from ..layers import sala as S
+
+SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
+
+# the published stack: sparse at 0, 9, 16, 17, 22, 29, 30, 31
+PUBLISHED_MIXERS = tuple(
+    SPARSE if i in (0, 9, 16, 17, 22, 29, 30, 31) else LIGHTNING
+    for i in range(32))
+
+
+@dataclasses.dataclass
+class MiniCPMSALAConfig:
+    """Published key names; ``sparse_*`` are the ``sparse_config`` group."""
+    vocab_size: int = 73448
+    hidden_size: int = 4096
+    num_hidden_layers: int = 32             # layers held here
+    mixer_types: Tuple[str, ...] = PUBLISHED_MIXERS     # their kinds, in order
+    layer_indices: Optional[Tuple[int, ...]] = None     # their published indices
+    published_layers: int = 32              # the depth the scalings refer to
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    intermediate_size: int = 16384
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    lightning_nh: int = 32
+    lightning_head_dim: int = 128
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    dim_model_base: int = 256
+    max_position_embeddings: int = 524288
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8192
+    prefill_chunk: int = 4096               # tokens of a prompt a pass
+    dtype: str = "bfloat16"
+
+    @property
+    def indices(self) -> Tuple[int, ...]:
+        return (tuple(range(self.num_hidden_layers))
+                if self.layer_indices is None else tuple(self.layer_indices))
+
+    @property
+    def sparse(self) -> S.SparseDims:
+        return S.SparseDims(
+            self.hidden_size, self.num_attention_heads,
+            self.num_key_value_heads, self.head_dim, self.rms_norm_eps,
+            self.sparse_kernel_size, self.sparse_kernel_stride,
+            self.sparse_block_size, self.sparse_init_blocks,
+            self.sparse_window_size, self.sparse_topk, self.sparse_dense_len)
+
+    @property
+    def lightning(self) -> S.LightningDims:
+        return S.LightningDims(self.hidden_size, self.lightning_nh,
+                               self.lightning_head_dim, self.rms_norm_eps,
+                               self.rope_theta)
+
+    @property
+    def branch_scale(self) -> float:
+        return self.scale_depth / math.sqrt(self.published_layers)
+
+
+def base_config(**kw) -> MiniCPMSALAConfig:
+    return MiniCPMSALAConfig(**kw)
+
+
+def _record_plans(cfg: MiniCPMSALAConfig, kv, ck, states, rows, max_len,
+                  chunk, chunks):
+    """``decode.plan`` beside GPT's and Kimi-K2's, with what is new here:
+    the carried state by kind. ``prefill.plan``: how the prompt is walked."""
+    from ..core import profiler
+
+    nbytes = lambda arrays: sum(a.size * a.dtype.itemsize for a in arrays)
+    kv_bytes, index_bytes, state_bytes = nbytes(kv), nbytes(ck), nbytes(states)
+    profiler.record_span(
+        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
+        heads=cfg.num_attention_heads, layers=cfg.num_hidden_layers,
+        cache_kind="kv+state", cache_dtype=cfg.dtype,
+        lane_width=kv[0].shape[-1] if kv else 0,
+        cache_bytes=kv_bytes + index_bytes + state_bytes, kv_bytes=kv_bytes,
+        index_bytes=index_bytes, state_bytes=state_bytes,
+        sparse_layers=len(ck), state_layers=len(states),
+        state_dtype="float32")
+    profiler.record_span("prefill.plan", time.time_ns(), 0, chunk=chunk,
+                         chunks=chunks, rows=rows)
+
+
+def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
+    """``(state0, step_fn)`` for ``layers/beam_search``: the parameters
+    (created or fetched here, once, by name), the chunked prefill of
+    ``prompt_ids`` and the one-token step that follows it."""
+    kinds, indices = tuple(cfg.mixer_types), cfg.indices
+    enforce(len(kinds) == len(indices) == cfg.num_hidden_layers
+            and set(kinds) <= {SPARSE, LIGHTNING},
+            f"minicpm_sala: {cfg.num_hidden_layers} layers, kinds {kinds}, "
+            f"published indices {indices}")
+    sp, li, dtype = cfg.sparse, cfg.lightning, jnp.dtype(cfg.dtype)
+    rows, p_len = prompt_ids.shape
+    enforce(p_len + max_new_tokens <= cfg.max_position_embeddings,
+            f"prompt {p_len} + max_new {max_new_tokens} exceeds "
+            f"max_position_embeddings {cfg.max_position_embeddings}")
+    d, eps, a = cfg.hidden_size, cfg.rms_norm_eps, cfg.branch_scale
+    n_sparse, n_light = kinds.count(SPARSE), kinds.count(LIGHTNING)
+    # which of its kind's entries of the carried state a layer has
+    slot = [kinds[:i].count(k) for i, k in enumerate(kinds)]
+
+    # every parameter once, by name, a layer under its published index; the
+    # loops close over the arrays
+    with name_scope("tok"):
+        w_emb = LayerHelper("embedding").create_parameter(
+            "w", (cfg.vocab_size, d), dtype, initializer=init.Normal(0.0, 1.0))
+    per_layer = []
+    for kind, index in zip(kinds, indices):
+        with name_scope(f"layer_{index}"):
+            mixer = (S.sparse_params(sp, dtype) if kind == SPARSE
+                     else S.lightning_params(li, dtype))
+            per_layer.append((mixer, M.gated_ffn_params(
+                d, cfg.intermediate_size, dtype)))
+    final_g = LayerHelper("final_norm").create_parameter(
+        "g", (d,), jnp.float32, initializer=init.Constant(1.0))
+    w_head = LayerHelper("lm_head").create_parameter(
+        "w", (d, cfg.vocab_size), dtype,
+        initializer=init.Normal(0.0, d ** -0.5))
+    log_decay = [S.lightning_log_decay(li.heads, l, cfg.published_layers)
+                 for l, k in zip(indices, kinds) if k == LIGHTNING]
+
+    def embed(ids):
+        return (w_emb[ids].astype(jnp.float32) * cfg.scale_emb).astype(dtype)
+
+    def head(x_last):   # [rows, d] -> log-probs
+        with jax.named_scope("head"):
+            h = M.rms_norm(x_last, final_g, eps)
+            h = (h.astype(jnp.float32)
+                 / (cfg.hidden_size / cfg.dim_model_base)).astype(dtype)
+            return jax.nn.log_softmax(jnp.matmul(
+                h, w_head, preferred_element_type=jnp.float32), axis=-1)
+
+    # ---- the carried state: slabs a sparse layer, a state a lightning layer
+    blk = sp.block_size
+    total = -(-(p_len + max_new_tokens) // blk) * blk    # whole blocks
+    selected = n_sparse > 0 and p_len > sp.dense_len
+    chunk = min(cfg.prefill_chunk, p_len) if selected else p_len
+    enforce(p_len % chunk == 0 and (not selected or chunk % blk == 0),
+            f"minicpm_sala: a prompt of {p_len} beyond dense_len "
+            f"{sp.dense_len} is walked in whole chunks of {chunk}, whole "
+            f"blocks of {blk}")
+    enforce(not n_sparse or total <= sp.dense_len or (
+        total >= sp.window_size and total // blk >= sp.n_sel),
+            f"minicpm_sala: a cache of {total} is shorter than the window")
+    width = sp.kv_heads * sp.head_dim
+    carried = {
+        "k": [jnp.zeros((rows, total, width), dtype)] * n_sparse,
+        "v": [jnp.zeros((rows, total, width), dtype)] * n_sparse,
+        "ck": [jnp.zeros((rows, total // sp.kernel_stride, width), dtype)]
+        * n_sparse,
+        "s": [jnp.zeros((rows, li.heads, li.head_dim, li.head_dim),
+                        jnp.float32)] * n_light}
+    _record_plans(cfg, carried["k"] + carried["v"], carried["ck"],
+                  carried["s"], rows, total, chunk, p_len // chunk)
+
+    def through(x, carried, mix_sparse, mix_light):
+        """``x`` through the layers held, each with its own entries of the
+        carried state."""
+        carried = {k: list(v) for k, v in carried.items()}
+        for i, kind in enumerate(kinds):
+            lp, ffn = per_layer[i]
+            j = slot[i]
+            if kind == SPARSE:
+                x, (carried["k"][j], carried["v"][j], carried["ck"][j]) = (
+                    mix_sparse(x, lp, (carried["k"][j], carried["v"][j],
+                                       carried["ck"][j])))
+            else:
+                x, carried["s"][j] = mix_light(x, lp, carried["s"][j],
+                                               log_decay[j])
+            x = S.ffn_block(x, ffn, eps, a)
+        return x, carried
+
+    # ---- prefill: the prompt a chunk at a time
+    def prefill_chunk(carried, p0):
+        ids = jax.lax.dynamic_slice_in_dim(prompt_ids, p0, chunk, axis=1)
+        x, carried = through(
+            embed(ids), carried,
+            lambda x, lp, c: S.sparse_prefill(x, lp, sp, c, p0, selected, a),
+            lambda x, lp, s, ld: S.lightning_prefill(x, lp, li, s, ld, p0, a))
+        return carried, x[:, -1]
+
+    with jax.named_scope("prefill"):
+        if p_len == chunk:
+            carried, x_last = prefill_chunk(carried, 0)
+        else:
+            carried, lasts = jax.lax.scan(
+                prefill_chunk, carried,
+                jnp.arange(p_len // chunk, dtype=jnp.int32) * chunk)
+            x_last = lasts[-1]
+        logp0 = head(x_last)
+    state0 = {**carried, "index": jnp.asarray(p_len, jnp.int32),
+              "logp0": logp0, "first": jnp.asarray(True)}
+
+    # ---- one step: each layer's one-token form over its own entries
+    def step_fn(tokens, state):
+        index = state["index"]
+        carried = {k: state[k] for k in ("k", "v", "ck", "s")}
+
+        @jax.named_scope("decode_step")
+        def incremental(_):
+            x, new = through(
+                embed(tokens)[:, None, :], carried,
+                lambda x, lp, c: S.sparse_decode(x, lp, sp, c, index, p_len, a),
+                lambda x, lp, s, ld: S.lightning_decode(x, lp, li, s, ld,
+                                                        index, a))
+            return head(x[:, 0]), new
+
+        # the first step consumes the prefill's distribution and writes
+        # nothing; position p holds the first generated token
+        logp, new = jax.lax.cond(
+            state["first"], lambda _: (state["logp0"], carried), incremental,
+            operand=None)
+        return logp, {**new, "logp0": state["logp0"],
+                      "index": jnp.where(state["first"], index, index + 1),
+                      "first": jnp.asarray(False)}
+
+    return state0, step_fn
+
+
+def make_generator(cfg: MiniCPMSALAConfig, max_new_tokens: int,
+                   bos_id: int = 1, eos_id: int = 2):
+    """Greedy incremental generation over the two-kind carried state.
+    Returns a program fn: ``(prompt_ids [b, p]) -> {"ids": [b,
+    max_new_tokens]}``."""
+    from ..layers.beam_search import greedy_search
+
+    def generate(prompt_ids):
+        state0, step_fn = _decoder(cfg, prompt_ids, max_new_tokens)
+        return {"ids": greedy_search(step_fn, state0, prompt_ids.shape[0],
+                                     max_new_tokens, bos_id=bos_id,
+                                     eos_id=eos_id)}
+
+    return generate
+
+
+__all__ = ["LIGHTNING", "MiniCPMSALAConfig", "PUBLISHED_MIXERS", "SPARSE",
+           "base_config", "make_generator"]

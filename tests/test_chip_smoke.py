@@ -35,8 +35,11 @@ def tiny_gpt():
 
 
 def test_kernel_phase_rehearsal():
-    chip_smoke.kernel_phase(0, gpt_shape=(1, 2, 128, 64),
-                            transformer_shape=(1, 2, 64, 64))
+    chip_smoke.kernel_phase(
+        0, gpt_shape=(1, 2, 128, 64), transformer_shape=(1, 2, 64, 64),
+        sala={"sparse": dict(rows=1, kv_heads=1, group=2, d=32, total=512,
+                             queries=64, p0=448, window_blocks=2, n_sel=3),
+              "lightning": dict(rows=1, heads=2, d=32, seq=512)})
 
 
 def test_a_failed_check_raises(monkeypatch):

@@ -264,6 +264,100 @@ def test_k25_generator_fits_one_v5e(chip, monkeypatch):
                - m.temp_size_in_bytes) < 0.15e9
 
 
+def _sala_generator(chip, monkeypatch, layers):
+    """The ``sala-serve-long`` generator (2 rows, prompt 32,768 + 128 new,
+    bfloat16, the benchmark's configuration file at the first ``layers`` of
+    its stage) compiled for one described chip."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import minicpm_sala as family
+    from paddle_tpu.models import minicpm_sala
+    from paddle_tpu.ops import lightning_attention, sparse_attention
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "minicpm-sala.json")) as f:
+        cell_config = json.load(f)
+    cell_config = dict(cell_config, num_hidden_layers=layers,
+                       layer_indices=cell_config["layer_indices"][:layers])
+    rows, prompt, new = 2, 32768, 128
+    prog = pt.build(minicpm_sala.make_generator(
+        family.program_config(cell_config), max_new_tokens=new))
+    for module in (fa, sparse_attention, lightning_attention):
+        monkeypatch.setattr(module, "default_interpret", lambda: False)
+    one_row = np.zeros((1, prompt), np.int32)
+    shapes = jax.eval_shape(lambda key: prog.init(key, prompt_ids=one_row)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
+    ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]["ids"]
+                       ).lower(params, ids).compile()
+    return cell_config, compiled
+
+
+def test_sala_carried_state_is_lane_dense_and_in_place_for_v5e(chip, monkeypatch):
+    """Three layers (published 7-9: two lightning, one sparse) suffice for
+    layouts. The loops carry the sparse layer's keys and values as
+    ``bf16[2,32896,256]`` and its compressed keys as ``bf16[2,2056,256]``,
+    minor dimension last and 256 = 2 x 128 lanes, tiled (8, 128) with
+    nothing padded, and each lightning state as ``f32[2,32,128,128]``; no
+    step copies or transposes a slab or a state (each is written by an
+    update in place); ``sparse_fwd`` and ``lightning_fwd`` are kernels whose
+    operands are whole 128-lane registers wide, but the block indices, which
+    the kernel reads from SMEM."""
+    _, compiled = _sala_generator(chip, monkeypatch, layers=3)
+    text = compiled.as_text()
+    slabs = set(re.findall(r"bf16\[2,(?:32896|2056),256\]\{[^}]*\}", text))
+    # (a kernel call's operand constraints name the bare order, ``{2,1,0}``)
+    assert slabs and all(s.split("{")[1] == "2,1,0}" or s.split("{")[1]
+                         .startswith("2,1,0:T(8,128)(2,1)") for s in slabs), slabs
+    states = set(re.findall(r"f32\[2,32,128,128\]\{[^}]*\}", text))
+    assert states and all(s.split("{")[1].startswith(("3,2,1,0:T(8,128)",
+                                                      "3,2,1,0}"))
+                          for s in states), states
+    step = [ln for ln in text.splitlines() if "decode_step" in ln]
+    assert step
+    carried = r"(?:bf16\[2,(?:32896|2056),256\]|f32\[2,32,128,128\])"
+    moved = [ln for ln in step if re.search(
+        r"= %s\S* (copy|transpose|copy-start)\(" % carried, ln)]
+    assert not moved, moved[0][:300]
+    assert any(re.search(r"f32\[2,32,128,128\]\S* (fusion|dynamic-update-slice)"
+                         r"\(", ln) for ln in step)
+    for kernel, calls in (("sparse_fwd", 1), ("lightning_fwd", 2)):
+        found = [ln for ln in text.splitlines() if re.search(
+            r"%%\S*%s\S* = .*tpu_custom_call" % kernel, ln)]
+        assert len(found) == calls, (kernel, len(found))
+        for ln in found:
+            operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", ln)
+            for shape in re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}",
+                                    operands.group(1)):
+                wide = _minor_dim(shape)
+                assert wide is None or wide % 128 == 0 or shape.startswith(
+                    ("s32", "f32[32]")), (shape, ln[:200])
+
+
+def test_sala_generator_fits_one_v5e(chip, monkeypatch):
+    """The whole stage (4 sparse + 12 lightning layers, the whole
+    vocabulary): 10.08 GB of arguments and 1.64 GB of temporaries (the
+    carried state, a chunk's activations through a 16,384-wide FFN, the
+    scorer's float32 scores), under 14.5 GB together, so ISSUE 33's 16-layer
+    cut stands; the configuration file's ``memory`` group records what this
+    compile said."""
+    cell_config, compiled = _sala_generator(chip, monkeypatch, layers=16)
+    m = compiled.memory_analysis()
+    assert 10.0e9 < m.argument_size_in_bytes < 10.15e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9
+    recorded = cell_config["memory"]
+    assert abs(recorded["generator_weights_bytes"]
+               - m.argument_size_in_bytes) < 1e6
+    assert abs(recorded["generator_rows_2_temporaries_bytes"]
+               - m.temp_size_in_bytes) < 0.2e9
+
+
 def _minor_dim(shape):
     """The minor dimension's size of ``bf16[32,1024,16,64]{3,1,2,0:T(8,128)}``
     (the first index in the braces names it), or None for a scalar."""
