@@ -185,8 +185,9 @@ def kernel_case(name: str, shape, causal: bool, mask: str, seed: int,
           f"{name}: flash differs from the dense f32 reference by "
           f"{max(errs.values()):.2e} > {BF16_TOL}")
     if on_tpu():
-        check(calls >= 3, f"{name}: the lowered program holds {calls} "
-                          f"tpu_custom_call, expected fwd + dq + dkv")
+        # forward and one backward kernel (two where a sequence streams)
+        check(calls >= 2, f"{name}: the lowered program holds {calls} "
+                          f"tpu_custom_call, expected fwd + backward")
     return errs
 
 
@@ -331,8 +332,8 @@ def train_phase(cfg, batch: int, seq: int, seed: int, steps: int = 12,
         + (f"flash kernel ({calls} tpu_custom_call)" if calls
            else "no Pallas kernel call (dense, or interpreted off-chip)"))
     if on_tpu():
-        check(calls >= 3, f"the train step holds {calls} tpu_custom_call: "
-                          f"flash fwd + dq + dkv are not all in it")
+        check(calls >= 2, f"the train step holds {calls} tpu_custom_call: "
+                          f"flash fwd + backward are not both in it")
 
     loss0, t_first = blocked_step(trainer, feeds[0])
     say("train", f"first step, compile included: {t_first:.2f} s "
@@ -467,7 +468,7 @@ def four_chip_phase(cfg, batch: int, seq: int, seed: int, devices,
     calls = debugger.step_kernel_calls(trainer, feeds[0])
     say("4chip", f"tpu_custom_call in the sharded train step: {calls}")
     if on_tpu():
-        check(calls >= 3, "the sharded step does not hold the flash kernels")
+        check(calls >= 2, "the sharded step does not hold the flash kernels")
     report = debugger.collective_report(trainer, feeds[0])
     kinds = {k: v["count"] for k, v in report["collectives"].items()}
     say("4chip", f"collectives in the compiled step: {json.dumps(kinds)}")

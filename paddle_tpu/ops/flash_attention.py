@@ -4,11 +4,28 @@ New first-class component per SURVEY §5/§7: the reference has no
 attention kernels at all (attention was composed from mul/softmax ops in
 models, e.g. benchmark/fluid/models/machine_translation.py), and no
 answer to long sequences beyond LoD ragged batching. This supplies
-O(seq) -memory attention on TPU: a forward kernel and two backward
-kernels (dq; dk/dv) that recompute probabilities from the saved
-logsumexp — the standard flash-attention-2 decomposition.
+O(seq) -memory attention on TPU: a forward kernel and a backward that
+recomputes probabilities from the saved logsumexp, the
+flash-attention-2 decomposition. The backward is one kernel or two, by
+the plan (``FlashPlan.backward``, :func:`_backward`):
 
-The tile walk, shared by the three kernels:
+- ``fused``, where both sequences sit in one grid step (up to
+  ``RESIDENT`` rows, within ``FUSED_BYTES`` of VMEM): ``flash_bwd`` walks
+  q and dO chunks past a still K/V tile, computes the tile's scores,
+  probabilities and ``ds`` once, carries dk and dv, and adds
+  ``ds^T k`` into a float32 scratch that holds the step's whole dq. Five
+  products a tile and one exponential.
+- ``split``, where a sequence streams: dq is a sum over key blocks and
+  dk / dv are sums over query blocks, and those arrive in different grid
+  steps, so each sum gets a kernel whose grid ends on its own axis
+  (``flash_dq``; ``flash_dkv``, the same body as ``flash_bwd`` without the
+  dq scratch) and the scores, ``dp`` and ``ds`` are computed in both:
+  seven products a tile and two exponentials. A whole dq of a streamed
+  call does not fit VMEM (a float32 row a query, all heads of a step),
+  and writing partial sums to HBM for every key block would cost more
+  than the two products.
+
+The tile walk, shared by the kernels:
 
 - A grid step brings in ``block_q`` rows of Q (with dO, lse, delta in
   the backward) and ``block_k`` rows of K/V for ``heads`` heads: whole
@@ -71,7 +88,8 @@ layout follows the call's shape, one tile walk for both:
   form does not fit (192 / 128 wide scores and values, an odd count of
   64-wide heads) are transposed to ``bhsd`` inside the call.
 
-``flash.plan`` records ``layout`` and ``lane_heads`` for every call traced.
+``flash.plan`` records ``layout``, ``lane_heads`` and ``backward`` for every
+call traced.
 
 Masking: causal (bottom-right aligned), an additive per-key bias
 [b, s_k] (padding), and segment ids (the LoD ragged-batch equivalent,
@@ -117,6 +135,11 @@ STREAM_BLOCK = 1024
 STEP_SCORES = 2 << 20
 STEP_BYTES = 6 << 20
 UNROLL = 8
+# FUSED_BYTES: the blocks and accumulators one backward kernel may hold a
+# grid step (``_backward``). A compile for a described v5e takes 10.0 MB
+# at every shape tried (2048 x 128 and two heads of 1024 x 64 in bfloat16)
+# and first refuses at 12.8 (float32, 1536 x 128, packed).
+FUSED_BYTES = 12 << 20
 
 NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
@@ -160,7 +183,7 @@ def default_interpret() -> bool:
 
 
 class FlashPlan(NamedTuple):
-    """What one attention call's three kernels run with."""
+    """What one attention call's kernels run with."""
     sq: int          # the call's query and key lengths
     sk: int
     d: int
@@ -178,6 +201,7 @@ class FlashPlan(NamedTuple):
     dv: int = 0      # width of a value and of an output row (plan_blocks sets it)
     layout: str = "bhsd"  # the operands' layout: ``bhsd``, or ``bsd`` (packed)
     lane_heads: int = 0   # bsd: heads a 128-lane group of the minor dimension
+    backward: str = "split"   # ``fused``: one backward kernel (:func:`_backward`)
 
 
 def _round_up(n, m):
@@ -218,6 +242,12 @@ def _clip(v, lo, hi):
     return max(lo, min(v, hi))
 
 
+def _where(cond, a, b):
+    """``where`` on a python bool or on a traced one."""
+    return jnp.where(cond, a, b) if isinstance(cond, jax.Array) else (
+        a if cond else b)
+
+
 def _chunk_bounds(r0, tile_q, c_base, n_chunks, tile_k, *, causal, offset,
                   sk, sk_p):
     """Of the ``n_chunks`` key chunks that start at column ``c_base``,
@@ -234,6 +264,20 @@ def _chunk_bounds(r0, tile_q, c_base, n_chunks, tile_k, *, causal, offset,
     if sk != sk_p:  # chunks before the first padded key
         n_plain = _clip(n_plain, 0, _clip((sk - c_base) // tile_k, 0, n_chunks))
     return n_plain, n_end
+
+
+def _backward(p: FlashPlan, itemsize) -> str:
+    """``fused`` or ``split`` (the module's docstring) for a call with plan
+    ``p`` and operands of ``itemsize`` bytes: one kernel where a grid step
+    holds both sequences and its blocks (q, dO, dq and k, v, dk, dv, each
+    double-buffered, and the three float32 accumulators) leave the
+    compiler room in VMEM. They do not for heads 256 wide beyond 1,024
+    rows, nor for float32 operands at bfloat16's longest shapes."""
+    lanes = p.heads * (p.d if p.layout == "bsd" else _round_up(p.d, 128))
+    held = lanes * (p.sq_p * (3 * 2 * itemsize + 4)
+                    + p.sk_p * (4 * 2 * itemsize + 2 * 4))
+    resident = (p.sq_p, p.sk_p) == (p.block_q, p.block_k)
+    return "fused" if resident and held <= FUSED_BYTES else "split"
 
 
 def lane_heads(d, dv, num_heads) -> int:
@@ -262,9 +306,10 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     computes ``STEP_SCORES`` scores. Explicit ``block_q``/``block_k`` win
     (the compute tile then follows the block). ``have_bias``/``have_seg`` do
     not change the blocks today; they are part of what a plan may depend
-    on. ``d`` is the width the scores contract over and ``dv`` that of a
-    value (latent attention: 192 and 128); ``scale`` is the softmax scale
-    where it is not ``d ** -0.5``.
+    on, and ``dtype`` decides with the shape whether the backward is one
+    kernel (:func:`_backward`). ``d`` is the width the scores contract
+    over and ``dv`` that of a value (latent attention: 192 and 128);
+    ``scale`` is the softmax scale where it is not ``d ** -0.5``.
 
     ``num_heads`` says the call's operands are ``[b, s, num_heads * d]``,
     as a projection leaves them. The plan keeps that layout (``bsd``)
@@ -272,7 +317,7 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     groups (:func:`lane_heads`); a step then holds whole groups of one
     batch row. Else the call is laid out ``[b, h, s, d]`` first
     (``bhsd``), which is also what a rank-4 call is."""
-    del dtype, have_bias, have_seg
+    del have_bias, have_seg
     dv = d if dv is None else dv
     packed = lane_heads(d, dv, num_heads)
     sq_p, block_q, tile_q = _axis_plan(sq, block_q, 128)
@@ -306,16 +351,18 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                             g * step_scores <= STEP_SCORES
                             and g * step_bytes <= STEP_BYTES
                             and g * (tiles_all if written else 1) <= UNROLL)))
-        return FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
-                         sq_p, sk_p, causal, fold, run, tiles_all, dv,
-                         "bsd", packed)
-    step_bytes = (6 * max(block_q, block_k)
-                  * (_round_up(d, 128) + _round_up(dv, 128)) * 2)
-    heads = max(g for g in range(1, bh + 1) if bh % g == 0 and (
-        g == 1 or (g * step_scores <= STEP_SCORES
-                   and g * step_bytes <= STEP_BYTES)))
-    return FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
-                     sq_p, sk_p, causal, fold, run, tiles_all, dv)
+        layout = ("bsd", packed)
+    else:
+        step_bytes = (6 * max(block_q, block_k)
+                      * (_round_up(d, 128) + _round_up(dv, 128)) * 2)
+        heads = max(g for g in range(1, bh + 1) if bh % g == 0 and (
+            g == 1 or (g * step_scores <= STEP_SCORES
+                       and g * step_bytes <= STEP_BYTES)))
+        layout = ()
+    plan = FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
+                     sq_p, sk_p, causal, fold, run, tiles_all, dv, *layout)
+    return plan._replace(
+        backward=_backward(plan, jnp.dtype(dtype).itemsize))
 
 
 def _record_plan(p: FlashPlan):
@@ -328,11 +375,11 @@ def _record_plan(p: FlashPlan):
         block_q=p.block_q, block_k=p.block_k, tile_q=p.tile_q,
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
-        lane_heads=p.lane_heads)
+        lane_heads=p.lane_heads, backward=p.backward)
 
 
 # ---------------------------------------------------------------------------
-# what the three kernels share
+# what the kernels share
 
 
 class _Walk(NamedTuple):
@@ -473,7 +520,7 @@ def _scores(keys, queries, r0, c0, w: _Walk, *, masked, bias_col, segq_row,
     """One tile of scores, transposed: ``[tile_k, tile_q]`` f32 from
     ``keys [tile_k, d]`` and ``queries [tile_q, d]`` in their input dtype
     (bf16 in: one MXU-native pass with f32 accumulation). One definition
-    for the three kernels, so they can never desynchronize. ``masked``
+    for every kernel, so they can never desynchronize. ``masked``
     (static) is whether this tile can cross the causal diagonal or hold
     padded keys; bias and segment masks apply whenever the operand is
     there."""
@@ -915,7 +962,8 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
 
 
 # ---------------------------------------------------------------------------
-# backward (two pallas passes, flash-attention-2 style)
+# backward (flash-attention-2 style: one pallas pass where both sequences
+# are a grid step's, two where one streams; ``FlashPlan.backward``)
 
 
 def _probs(s, lse_row):
@@ -989,18 +1037,29 @@ def _dq_kernel(*refs, w: _Walk):
 
 
 def _dkv_kernel(*refs, w: _Walk):
+    """dk and dv of a step's K/V block: a K/V tile held still, the q and
+    dO chunks that see it walked past it. Where the plan's backward is
+    ``fused`` the step holds every query too, so the chunk's ``ds`` also
+    goes into dq, which the step keeps whole in scratch (``flash_bwd``:
+    each tile's scores, probabilities and ``ds`` computed once); where a
+    sequence streams, :func:`_dq_kernel` computes them again for dq."""
+    fused = w.plan.backward == "fused"
     (q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
-     (g_ref, lse_ref, delta_ref), (dk_ref, dv_ref),
-     (dk_scr, dv_scr)) = _split_refs(refs, w, 3, 2)
+     (g_ref, lse_ref, delta_ref), outs, scr) = _split_refs(
+         refs, w, 3, 3 if fused else 2)
+    dq_ref, dk_ref, dv_ref = outs if fused else [None] + outs
+    dq_scr, dk_scr, dv_scr = scr if fused else [None] + scr
     p = w.plan
     kb_i, qb = _grid_index(1, w.nk), _grid_index(2, w.nq)
     last_q = w.nq - 1
     tq, tk = p.tile_q, p.tile_k
+    # the still K carries a folded scale into dq; else dq takes it here
+    dq_scale = None if p.fold_scale else w.scale
 
     @pl.when(qb == 0)
     def _init():
-        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
-        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        for ref in scr:
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
 
     r_base = qb * p.block_q
 
@@ -1012,17 +1071,22 @@ def _dkv_kernel(*refs, w: _Walk):
         mg = w.mask_row(g)
         ks = _fold_scale(w.still(k_ref, *rows), w)
         vb = w.still(v_ref, *rows)
+        k_own = w.own_lanes(ks, g) if fused else None   # for dq, cut once
         bias_col = _col(bias_ref, mg, kt) if w.have_bias else None
         segk_col = _col(segk_ref, mg, kt) if w.have_seg else None
         # query chunks of this block, ascending: those before n_lo see
         # none of the tile's keys, [n_lo, n_plain) cross the diagonal,
-        # [n_plain, nqt) see all of them. Padded keys need no mask here:
-        # they only fill their own (sliced-off) dk/dv rows.
+        # [n_plain, nqt) see all of them. Padded keys need no mask for
+        # dk / dv: they only fill their own (sliced-off) rows. dq sums
+        # over keys, so there a tile that holds padded keys masks them
+        # in every chunk.
         n_lo = n_plain = 0
         if p.causal:
             n_lo = _clip((c0 - w.offset - r_base) // tq, 0, w.nqt)
             n_plain = _clip(
                 (c0 + tk - 1 - w.offset - r_base + tq - 1) // tq, 0, w.nqt)
+        if fused and p.sk != p.sk_p:
+            n_plain = _where(c0 + tk > p.sk, w.nqt, n_plain)
 
         def chunk(masked):
             def body(i, carry):
@@ -1042,10 +1106,16 @@ def _dkv_kernel(*refs, w: _Walk):
                     preferred_element_type=jnp.float32)
                 dp = jax.lax.dot_general(vb, do, _NT,
                                          preferred_element_type=jnp.float32)
-                ds = prob * (dp - delta_ref[row])
+                # ds rounded to the input dtype once, for both products
+                ds = (prob * (dp - delta_ref[row])).astype(qc.dtype)
                 dk = dk + jax.lax.dot_general(
-                    ds.astype(qc.dtype), qc, _NN,
-                    preferred_element_type=jnp.float32)
+                    ds, qc, _NN, preferred_element_type=jnp.float32)
+                if fused:
+                    # k.T @ ds = (ds.T @ k).T, [d, tq], into the head's
+                    # rows of its lane group's accumulator
+                    dq_at = w.acc_at(g, i)
+                    dq_scr[dq_at] = dq_scr[dq_at] + jax.lax.dot_general(
+                        k_own, ds, _TN, preferred_element_type=jnp.float32)
                 return dk, dv
             return body
 
@@ -1058,9 +1128,17 @@ def _dkv_kernel(*refs, w: _Walk):
             dk_ref[at] = w.own_lanes(dk * w.scale, g).astype(dk_ref.dtype)
             dv_ref[at] = w.own_lanes(dv, g).astype(dv_ref.dtype)
 
+    def dq_tile(g, qt):
+        dq_t = dq_scr[g, qt] if dq_scale is None else dq_scr[g, qt] * dq_scale
+        dq_ref[w.head(g, pl.ds(_at(qt, tq), tq))] = dq_t.T.astype(dq_ref.dtype)
+
     @pl.when(_block_runs(qb, kb_i, w) | (qb == last_q))
     def _step():
         _over_tiles(w.nkt, k_tile, w)
+        if fused and w.shared_lanes:
+            w.write_groups(dq_scr, dq_ref, w.nqt, tq, dq_scale)
+        elif fused:
+            _over_tiles(w.nqt, dq_tile, w)
 
 
 def _head_sums(x, h, exact: bool):
@@ -1095,11 +1173,11 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
     ``[b, s, num_heads * d]`` each, also where q, k and v came fused in
     ``q``); ``lse`` and ``delta`` are ``[b, h, s_q]``."""
     if num_heads is None and v.shape[-1] != q.shape[-1]:
-        # the dq and dkv kernels hold dO, K and V in blocks of one width
+        # the backward kernels hold dO, K and V in blocks of one width
         raise NotImplementedError(
             f"flash_attention: no backward pass for values {v.shape[-1]} "
             f"wide under scores that contract over {q.shape[-1]}; the "
-            f"forward takes unequal widths, the dq and dkv kernels do not yet")
+            f"forward takes unequal widths, the backward kernels do not yet")
     if delta is None:
         delta = out.astype(jnp.float32) * g.astype(jnp.float32)
         delta = (jnp.sum(delta, axis=-1) if num_heads is None
@@ -1116,37 +1194,36 @@ def _flash_bwd(q, k, v, bias, seg_q, seg_k, causal, out, lse, g,
     lse_r = _rows(_pad_seq(lse, p.sq_p, 2, -NEG_INF), bh, nq, w.nqt, p.tile_q)
     delta_r = _rows(_pad_seq(delta, p.sq_p, 2), bh, nq, w.nqt, p.tile_q)
 
-    # ---- dq pass: grid (bh/heads, nq, nk), K/V on the inner dim; causal
-    # steps past the diagonal re-request the same block so their DMA is
-    # skipped (see _kj_clamp)
-    sp = _specs(ops, p, w, h)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, w=w),
-        name="flash_dq",
-        grid=(bh // hg, nq, nk),
-        in_specs=[sp.q, sp.k, sp.v] + sp.masks + [sp.o, sp.qrow, sp.qrow],
-        out_specs=sp.o,
-        out_shape=jax.ShapeDtypeStruct(g_r.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)],
-        interpret=interpret,
-    )(ops.q, ops.k, ops.v, *sp.mask_args, g_r, lse_r, delta_r)
-
-    # ---- dk/dv pass: grid (bh/heads, nk, nq), Q/dO on the inner dim;
-    # causal steps before a key block's first useful q block re-request
-    # that first block (DMA skipped, see _qi_clamp)
-    sp = _specs(ops, p, w, h, dkv=True)
     acc_lanes = 128 if w.shared_lanes else d    # dk, dv of a whole lane group
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, w=w),
-        name="flash_dkv",
-        grid=(bh // hg, nk, nq),
-        in_specs=[sp.q, sp.k, sp.v] + sp.masks + [sp.o, sp.qrow, sp.qrow],
-        out_specs=[sp.dk, sp.dk],
-        out_shape=[jax.ShapeDtypeStruct(kv_shape, q.dtype)] * 2,
-        scratch_shapes=[pltpu.VMEM((hg, p.block_k, acc_lanes), jnp.float32),
-                        pltpu.VMEM((hg, p.block_k, acc_lanes), jnp.float32)],
-        interpret=interpret,
-    )(ops.q, ops.k, ops.v, *sp.mask_args, g_r, lse_r, delta_r)
+    kv_scratch = [pltpu.VMEM((hg, p.block_k, acc_lanes), jnp.float32)] * 2
+    dq_scratch = [pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)]
+
+    def call(kernel, name, grid, sp, out_specs, out_shapes, scratch):
+        return pl.pallas_call(
+            functools.partial(kernel, w=w), name=name, grid=grid,
+            in_specs=[sp.q, sp.k, sp.v] + sp.masks + [sp.o, sp.qrow, sp.qrow],
+            out_specs=out_specs,
+            out_shape=[jax.ShapeDtypeStruct(s, q.dtype) for s in out_shapes],
+            scratch_shapes=scratch, interpret=interpret,
+        )(ops.q, ops.k, ops.v, *sp.mask_args, g_r, lse_r, delta_r)
+
+    # dk/dv: grid (bh/heads, nk, nq), Q/dO on the inner dim; causal steps
+    # before a key block's first useful q block re-request that first
+    # block (DMA skipped, see _qi_clamp). dq: grid (bh/heads, nq, nk), K/V
+    # on the inner dim; steps past the diagonal re-request the same block
+    # (see _kj_clamp). Where both sequences are one step's, the dk/dv
+    # pass leaves dq as well and there is no other
+    sp = _specs(ops, p, w, h, dkv=True)
+    if p.backward == "fused":
+        dq, dk, dv = call(_dkv_kernel, "flash_bwd", (bh // hg, 1, 1), sp,
+                          [sp.o, sp.dk, sp.dk], [g_r.shape] + [kv_shape] * 2,
+                          dq_scratch + kv_scratch)
+    else:
+        sp_dq = _specs(ops, p, w, h)
+        dq, = call(_dq_kernel, "flash_dq", (bh // hg, nq, nk), sp_dq,
+                   [sp_dq.o], [g_r.shape], dq_scratch)
+        dk, dv = call(_dkv_kernel, "flash_dkv", (bh // hg, nk, nq), sp,
+                      [sp.dk, sp.dk], [kv_shape] * 2, kv_scratch)
 
     return (_user_form(dq, p.sq, q, p), _user_form(dk, p.sk, q, p),
             _user_form(dv, p.sk, q, p))
@@ -1206,6 +1283,12 @@ def _flash_core_fused_bwd(causal, block_q, block_k, interpret, scale,
     grads = _flash_bwd(qkv, None, None, bias, seg_q, seg_k, causal, out, lse,
                        g, block_q, block_k, interpret, scale=scale,
                        num_heads=num_heads)
+    # each gradient behind a barrier of its own: the compiler then fuses
+    # the concatenation into the projection's three backward products, as
+    # it did when dq and dk / dv came from two calls. Three results of one
+    # call it lays into a ``[b, s, 3 * h*d]`` buffer first, by three
+    # update-slice fusions (0.32 ms a layer of the one-chip train step)
+    grads = [jax.lax.optimization_barrier(x) for x in grads]
     return jnp.concatenate(grads, axis=-1), None, None, None
 
 

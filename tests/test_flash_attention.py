@@ -520,6 +520,8 @@ def _ref_masked(q, k, v, causal, key_bias=None, seg=None):
     if seg is not None:
         keep = keep & (seg[:, None, :, None] == seg[:, None, None, :])
     p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+    # a query with every key masked attends to nothing, as in the kernels
+    p = jnp.where(keep.any(axis=-1, keepdims=True), p, 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 

@@ -99,6 +99,15 @@ SHAPES = {
     "gpt2m_train_packed": (_packed(16), (32, 1024, 1024), None),
     "gpt2l_train_shard_packed": (_packed(10), (16, 1024, 640), None),
     "gpt2m_prefill_packed": (_packed(16), (16, 896, 1024), None),
+    # the longest calls that get the one backward kernel (``RESIDENT``
+    # rows: q, k, v, dO, dq, dk, dv and three float32 accumulators of a
+    # step in VMEM at once), 128-wide heads in both layouts, and no mask
+    "resident_2k_d128": (_attention(True), (2, 8, 2048, 128), None),
+    "resident_2k_d128_packed": (_packed(8), (2, 2048, 1024), None),
+    "resident_2k_no_mask": (_attention(False), (2, 4, 2048, 64), None),
+    # and the first the plan leaves to the pair: 256-wide heads at 1,536
+    # rows, whose one kernel the compiler refuses for VMEM
+    "resident_d256_split": (_attention(True), (4, 1, 1536, 256), None),
     # k25-serve-batch's prefill: latent attention scores over 192 (128
     # nope + 64 rope) and sums values 128 wide, under YaRN's softmax scale
     "k25_prefill": (lambda q, k, v: fa.flash_attention(
@@ -127,8 +136,11 @@ def test_flash_compiles_for_v5e(chip, name, grad):
         fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                       argnums=(0, 1, 2))
     text = jax.jit(fn).lower(*args).compile().as_text()
-    # forward alone is one kernel; its gradient adds the dq and dkv passes
-    assert text.count("tpu_custom_call") == (3 if grad else 1)
+    # forward alone is one kernel; its gradient adds one backward kernel
+    # where the sequence is resident, the dq and dkv passes where it streams
+    rows = shape[2] if len(shape) == 4 else shape[1]
+    backward = 2 if rows > fa.RESIDENT or name.endswith("_split") else 1
+    assert text.count("tpu_custom_call") == (1 + backward if grad else 1)
     if name.endswith("_packed"):
         _assert_kernel_operands_lane_dense(text)
 
@@ -372,7 +384,8 @@ def _assert_kernel_operands_lane_dense(text):
     """Every operand of a ``flash_*`` kernel call has a minor dimension of
     whole 128-lane registers: the chip holds none of them padded."""
     calls = [ln for ln in text.splitlines()
-             if re.search(r"%\S*flash_(fwd|dq|dkv)\S* = .*tpu_custom_call", ln)]
+             if re.search(r"%\S*flash_(fwd|bwd|dq|dkv)\S* = .*tpu_custom_call",
+                          ln)]
     assert calls
     for ln in calls:
         operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", ln)
@@ -403,16 +416,15 @@ def _assert_no_64_minor_copies_in_loops(text):
                         assert _minor_dim(shape) != 64, ln[:300]
 
 
-def test_stacked_train_block_has_no_padded_head_transposes_for_v5e(
-        chip, monkeypatch):
-    """Two scan-stacked layers at gpt2-medium's widths (32 x 1024 tokens,
-    d 1024, 16 heads of 64, remat, ``jax.grad``, bfloat16): the fused
-    projection's output goes into the flash kernels as it lies and their
-    output into the out-projection, so neither loop body copies or
-    transposes an array with a 64-wide minor dimension (the parent's had
-    twelve a layer, each held at twice its size), every operand of the
-    four kernel calls a layer is lane-dense, and the temporaries are
-    1.17 GB where the parent's were 1.55."""
+_COMPILED = {}   # what two tests read of one compile (a file runs on one worker)
+
+
+def _one_chip_block(chip, monkeypatch):
+    """(compiled text, temporaries' bytes) of ``jax.grad`` over two
+    scan-stacked layers at gpt2-medium's widths (32 x 1024 tokens, d 1024,
+    16 heads of 64, remat, bfloat16) on one described chip."""
+    if "one_chip" in _COMPILED:
+        return _COMPILED["one_chip"]
     d, inner, heads, batch, seq, layers = 1024, 4096, 16, 32, 1024, 2
 
     def net(x):
@@ -438,12 +450,54 @@ def test_stacked_train_block_has_no_padded_head_transposes_for_v5e(
         ).lower(params, x).compile()
     finally:
         config.set_flag("default_compute_dtype", before)
-    text = compiled.as_text()
-    # forward; remat's second forward, dq and dk/dv in the backward body
-    assert text.count("tpu_custom_call") == 4
+    _COMPILED["one_chip"] = (compiled.as_text(),
+                             compiled.memory_analysis().temp_size_in_bytes)
+    return _COMPILED["one_chip"]
+
+
+def test_stacked_train_block_has_no_padded_head_transposes_for_v5e(
+        chip, monkeypatch):
+    """Two scan-stacked layers at gpt2-medium's widths (32 x 1024 tokens,
+    d 1024, 16 heads of 64, remat, ``jax.grad``, bfloat16): the fused
+    projection's output goes into the flash kernels as it lies and their
+    output into the out-projection, so neither loop body copies or
+    transposes an array with a 64-wide minor dimension (the parent's had
+    twelve a layer, each held at twice its size), every operand of the
+    three kernel calls a layer is lane-dense, and the temporaries are
+    1.17 GB where the parent's were 1.55."""
+    text, temporaries = _one_chip_block(chip, monkeypatch)
+    # forward; remat's second forward and the one backward kernel in the
+    # backward body
+    assert text.count("tpu_custom_call") == 3
     _assert_kernel_operands_lane_dense(text)
     _assert_no_64_minor_copies_in_loops(text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+    assert temporaries < 1.3e9
+
+
+@pytest.mark.parametrize("step", ["one_chip", "dp2tp2"])
+def test_backward_body_runs_one_flash_bwd_for_v5e(step, chips, chip,
+                                                  monkeypatch):
+    """The train cells' sequence (1,024 rows) is resident, so the scan's
+    backward body, on one chip and inside the dp2 x tp2 ``shard_map``,
+    holds remat's ``flash_fwd`` and one ``flash_bwd``; neither ``flash_dq``
+    nor ``flash_dkv`` is in the program, and the loops still copy no array
+    with a 64-wide minor dimension."""
+    text = (_one_chip_block(chip, monkeypatch)[0] if step == "one_chip"
+            else _dp2tp2_block_text(chips, monkeypatch, batch=32))
+    comps = _computations(text)
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+    kernels = sorted(
+        sorted(m.group(1) for ln in comps[name] for m in [re.search(
+            r"%\S*(flash_[a-z]+)\S* = .*tpu_custom_call", ln)] if m)
+        for name in bodies)
+    assert kernels == [["flash_bwd", "flash_fwd"], ["flash_fwd"]], kernels
+    assert "flash_dq" not in text and "flash_dkv" not in text
+    _assert_no_64_minor_copies_in_loops(text)
+    # dq, dk and dv go into the projection's backward products as three
+    # operands: no body lays them side by side in a buffer first
+    laid = [ln for name in bodies for ln in comps[name] if re.search(
+        r"dynamic-update-slice\S* = bf16\[\d+,1024,(3072|1920)\]", ln)]
+    assert not laid, laid[0][:200]
 
 
 def _computations(text):
@@ -464,6 +518,8 @@ def _dp2tp2_block_text(chips, monkeypatch, batch):
     gpt2-large's widths (d 1280, inner 5120, 20 heads, ``batch`` x 1024,
     remat, bfloat16) on a dp2 x tp2 mesh of the described chips, the
     parameters sharded by the rule table."""
+    if ("dp2tp2", batch) in _COMPILED:
+        return _COMPILED["dp2tp2", batch]
     d, inner, heads, seq, layers = 1280, 5120, 20, 1024, 2
     mesh = Mesh(np.array(chips).reshape(2, 2), ("dp", "tp"))
 
@@ -494,9 +550,10 @@ def _dp2tp2_block_text(chips, monkeypatch, batch):
             with mesh_mode(mesh):
                 return prog.apply(p, {}, training=True, x=x)[0]["loss"]
 
-        return jax.jit(jax.grad(loss), out_shardings={
+        _COMPILED["dp2tp2", batch] = jax.jit(jax.grad(loss), out_shardings={
             k: v.sharding for k, v in params.items()}
         ).lower(params, x).compile().as_text()
+        return _COMPILED["dp2tp2", batch]
     finally:
         config.set_flag("default_compute_dtype", before)
 
@@ -514,7 +571,7 @@ def test_partitioned_tp_block_gathers_no_weight_for_v5e(chips, monkeypatch):
     kinds = set(re.findall(r" (all-gather|all-reduce|collective-permute|"
                            r"all-to-all|reduce-scatter)(?:-start)?\(", text))
     assert kinds == {"all-reduce"}, kinds
-    assert text.count("tpu_custom_call") == 4
+    assert text.count("tpu_custom_call") == 3
     _assert_kernel_operands_lane_dense(text)
 
 

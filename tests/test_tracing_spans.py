@@ -245,14 +245,22 @@ def test_the_compiled_step_names_its_operations_by_scope():
     assert seen == SCOPES
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
-def test_the_flash_kernels_are_named(kernel):
-    from paddle_tpu.ops.flash_attention import flash_attention
+@pytest.mark.parametrize("seq,kernel", [
+    ("resident", "flash_fwd"), ("resident", "flash_bwd"),
+    ("streamed", "flash_dq"), ("streamed", "flash_dkv")])
+def test_the_flash_kernels_are_named(seq, kernel):
+    """A call whose sequences sit in VMEM whole runs one backward kernel,
+    ``flash_bwd``; one that streams keeps the pair, each by its name."""
+    from paddle_tpu.ops import flash_attention as fa
 
-    q = jnp.ones((1, 2, 128, 64), jnp.float32)
+    rows = 128 if seq == "resident" else fa.RESIDENT + 128
+    q = jnp.ones((1, 2, rows, 64), jnp.float32)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).sum()
+        return fa.flash_attention(q, k, v, causal=True).sum()
 
     jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
     assert kernel in jaxpr
+    names = {"flash_bwd"} if seq == "resident" else {"flash_dq", "flash_dkv"}
+    assert {n for n in ("flash_bwd", "flash_dq", "flash_dkv")
+            if n in jaxpr} == names

@@ -1,4 +1,4 @@
-"""Flash-attention kernel microbench: device time of each of the three
+"""Flash-attention kernel microbench: device time of each of the flash
 kernels at the shapes the benchmark's cells run, over a sweep of the
 plan's compute tiles, beside JAX's own Pallas kernel as the yardstick.
 
@@ -11,10 +11,13 @@ For every shape and every largest compute tile of ``--tiles`` (``plan``: the
 ``TILE`` that ``ops/flash_attention.plan_blocks`` uses itself) it runs forward +
 backward (forward alone for the prefill shape) ``--iters`` times under the
 profiler and reads each kernel's device time from the trace, by the
-kernel's name (``flash_fwd`` / ``flash_dq`` / ``flash_dkv``): milliseconds a
-call and TFLOP/s of the matmuls the algorithm needs (2 / 3 / 4 of
+kernel's name (``flash_fwd``, and ``flash_bwd`` where the plan's backward is
+``fused``, ``flash_dq`` / ``flash_dkv`` where it is ``split``): milliseconds a
+call and TFLOP/s of the matmuls the algorithm needs (2, and 5, or 3 / 4 of
 ``2 * sq * sk * d`` a head, halved under causal) against the bf16 peak of
-``benchmarks/peaks.json``. At head_dim 64 the MXU's ceiling for these
+``benchmarks/peaks.json``. A shape whose backward is fused is timed a second
+time with the split pair in its place (``"backward": "split"`` in the row),
+so that one process gives both. At head_dim 64 the MXU's ceiling for these
 kernels is about half the peak: the contraction (q k^T) or the output
 width (p v) fills 64 of its 128 columns. ``--reference 1`` times
 ``jax.experimental.pallas.ops.tpu.flash_attention`` the same way over a few
@@ -37,6 +40,7 @@ where there is no TPU (a CPU time is not a device time).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -57,7 +61,7 @@ SHAPES = {
     "long_32k": ((1, 8, 32768, 64), True),
 }
 # matmuls of 2 * sq * sk * d flops a head that each kernel needs
-MATMULS = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}
+MATMULS = {"flash_fwd": 2, "flash_bwd": 5, "flash_dq": 3, "flash_dkv": 4}
 
 
 def kernel_flops(kernel, shape, causal=True):
@@ -89,7 +93,7 @@ def traced_kernel_ms(fn, args, iters, busy=None):
         if trace.is_kernel(label):
             # jvp_flash_fwd_.3 [custom-call] -> flash_fwd; other names whole
             name = re.sub(r"\.\d+$", "", label.split(" ")[0])
-            ours = re.search(r"flash_(fwd|dq|dkv)", name)
+            ours = re.search(r"flash_(fwd|bwd|dq|dkv)", name)
             name = ours.group(0) if ours else name
             ms, n = by_name.get(name, (0.0, 0))
             by_name[name] = (ms + dur / 1e6, n + 1)
@@ -105,12 +109,15 @@ def main():
                          "TILE), 'plan' = its own")
     ap.add_argument("--layouts", default="bhsd",
                     help="comma list of bhsd, projection, bsd, fused")
+    ap.add_argument("--causal", type=int, default=1,
+                    help="0: the whole score rectangle, no mask")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--reference", type=int, default=1,
                     help="also time the jax pallas reference kernel")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "flash_microbench.jsonl"))
     args = ap.parse_args()
+    causal = bool(args.causal)
 
     import jax
     import jax.numpy as jnp
@@ -129,22 +136,57 @@ def main():
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
 
     def record(row):
-        row["device"] = dev.device_kind
+        row["device"], row["causal"] = dev.device_kind, causal
         with open(args.out, "a") as f:
             f.write(json.dumps(row) + "\n")
         print(json.dumps(row), flush=True)
 
     def rates(shape, ms_by_kernel, names):
         """ms and TFLOP/s per kernel; ``names`` maps the trace's kernel
-        names onto flash_fwd / flash_dq / flash_dkv."""
+        names onto flash_fwd / flash_bwd / flash_dq / flash_dkv."""
         out = {}
         for traced, (ms, calls) in ms_by_kernel.items():
             kernel = names(traced)
-            tf = kernel_flops(kernel, shape) / (ms / 1e3) / 1e12
+            tf = kernel_flops(kernel, shape, causal) / (ms / 1e3) / 1e12
             out[kernel] = {"ms": round(ms, 4), "calls": calls,
                            "tflops": round(tf, 2),
                            "of_peak": round(tf * 1e12 / peak, 4)}
         return out
+
+    @contextlib.contextmanager
+    def split_backward():
+        """Calls traced inside run the streaming pair on a shape whose
+        plan would fuse the backward: the tool steers the plan as it
+        steers ``TILE``, the program has no switch."""
+        own = fa.plan_blocks
+        fa.plan_blocks = lambda *a, **kw: own(*a, **kw)._replace(
+            backward="split")
+        try:
+            yield
+        finally:
+            fa.plan_blocks = own
+
+    def time_call(shape, base, plan, make_fn, operands, grad, note_best):
+        """One row for the call as planned and, where its backward is
+        fused, one more with the split pair forced onto the same operands;
+        ``note_best(row)`` sees the planned one."""
+        forced = (False, True) if grad and plan.backward == "fused" else (
+            False,)
+        for split in forced:
+            row = dict(base, backward="split" if split else plan.backward,
+                       plan=plan._asdict())
+            try:
+                with split_backward() if split else contextlib.nullcontext():
+                    row["kernels"] = rates(shape, traced_kernel_ms(
+                        make_fn(), operands, args.iters, busy=row),
+                        lambda n: n)
+                row["ms_all"] = round(sum(
+                    v["ms"] for v in row["kernels"].values()), 4)
+                if not split:
+                    note_best(row)
+            except Exception as e:  # e.g. a tile the compiler refuses
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            record(row)
 
     def step_fn(attn, grad):
         if not grad:
@@ -162,14 +204,14 @@ def main():
         if layout == "projection":
             def attn(q, k, v):
                 o = fa.flash_attention(*(heads_apart(x, h) for x in (q, k, v)),
-                                       causal=True)
+                                       causal=causal)
                 return o.transpose(0, 2, 1, 3).reshape(q.shape)
             return attn, 3
         if layout == "bsd":
             return (lambda q, k, v: fa.flash_attention(
-                q, k, v, causal=True, num_heads=h)), 3
+                q, k, v, causal=causal, num_heads=h)), 3
         return (lambda qkv: fa.flash_attention(
-            qkv, causal=True, num_heads=h)), 1
+            qkv, causal=causal, num_heads=h)), 1
 
     plan_tile = fa.TILE
     layouts = args.layouts.split(",")
@@ -182,43 +224,35 @@ def main():
             attn, n = layout_call(layout, h)
             ops = [jnp.asarray(rng.randn(b, s, (3 if n == 1 else 1) * h * d),
                                jnp.bfloat16) for _ in range(n)]
-            row = {"kernel": "repo", "shape": name, "layout": layout,
-                   "plan": fa.plan_blocks(
-                       s, s, d, jnp.bfloat16, True, bh=b * h,
-                       num_heads=None if layout == "projection" else h
-                   )._asdict()}
-            try:
-                fn = jax.jit(jax.grad(
+            plan = fa.plan_blocks(
+                s, s, d, jnp.bfloat16, causal, bh=b * h,
+                num_heads=None if layout == "projection" else h)
+
+            def make_fn(attn=attn, n=n):
+                return jax.jit(jax.grad(
                     lambda *a: attn(*a).astype(jnp.float32).sum(),
                     argnums=tuple(range(n)))) if grad else jax.jit(attn)
-                row["kernels"] = rates(shape, traced_kernel_ms(
-                    fn, ops, args.iters, busy=row), lambda n: n)
-                row["ms_all"] = round(sum(
-                    v["ms"] for v in row["kernels"].values()), 4)
+
+            def note_best(row, layout=layout):
                 best[name, "repo " + layout] = (row["ms_call"], "ms_call")
-            except Exception as e:
-                row["error"] = f"{type(e).__name__}: {e}"[:300]
-            record(row)
+
+            time_call(shape, {"kernel": "repo", "shape": name,
+                              "layout": layout},
+                      plan, make_fn, ops, grad, note_best)
             del ops
         qkv = [jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(3)]
         for spec in args.tiles.split(",") if "bhsd" in layouts else ():
             fa.TILE = plan_tile if spec == "plan" else int(spec)
-            plan = fa.plan_blocks(s, s, d, jnp.bfloat16, True, bh=b * h)
-            row = {"kernel": "repo", "shape": name, "layout": "bhsd",
-                   "tiles": spec, "plan": plan._asdict()}
-            try:
-                fn = step_fn(lambda q, k, v: fa.flash_attention(
-                    q, k, v, causal=True), grad)
-                row["kernels"] = rates(shape, traced_kernel_ms(
-                    fn, qkv, args.iters, busy=row), lambda n: n)
-                row["ms_all"] = round(sum(
-                    v["ms"] for v in row["kernels"].values()), 4)
-                if row["ms_all"] < best.get((name, "repo"),
-                                            (math.inf,))[0]:
+            plan = fa.plan_blocks(s, s, d, jnp.bfloat16, causal, bh=b * h)
+
+            def note_best(row, spec=spec):
+                if row["ms_all"] < best.get((name, "repo"), (math.inf,))[0]:
                     best[name, "repo"] = (row["ms_all"], spec)
-            except Exception as e:  # a tile the compiler refuses
-                row["error"] = f"{type(e).__name__}: {e}"[:300]
-            record(row)
+
+            time_call(shape, {"kernel": "repo", "shape": name,
+                              "layout": "bhsd", "tiles": spec},
+                      plan, lambda: step_fn(lambda q, k, v: fa.flash_attention(
+                          q, k, v, causal=causal), grad), qkv, grad, note_best)
         fa.TILE = plan_tile
 
         if not args.reference:
@@ -242,7 +276,7 @@ def main():
                    "blocks": [bq, bkm, bk]}
             try:
                 fn = step_fn(lambda q, k, v: jfa.flash_attention(
-                    q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d),
+                    q, k, v, causal=causal, sm_scale=1.0 / math.sqrt(d),
                     block_sizes=sizes), grad)
                 by_kernel = traced_kernel_ms(fn, qkv, args.iters)
                 row["traced_names"] = list(by_kernel)
