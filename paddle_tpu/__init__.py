@@ -16,6 +16,7 @@ from .resilience import (CheckpointCorrupt, GuardPolicy, PreemptionHandler,
                          ReshardError, reshard_restore)
 from .serving import PredictorServer
 from .core import CPUPlace, CUDAPlace, Place, TPUPlace, default_place
+from .core import profiler  # fluid.profiler: pt.profiler.profiler(trace_dir)
 from .executor import CheckpointConfig, Event, Executor, Inferencer, Scope, Trainer, fit
 from .framework import (
     LayerHelper,

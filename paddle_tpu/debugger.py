@@ -155,8 +155,6 @@ _COLLECTIVE_RE = _re.compile(
     r"=\s+(\(.*?\)|" + _HLO_SHAPE + r")\s+"
     r"(all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all|collective-broadcast)(-start)?\(")
-_GROUP_RE = _re.compile(r"replica_groups=\{?\{([0-9,]+)\}")
-_IOTA_GROUP_RE = _re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _SHAPE_ELEM_RE = _re.compile(r"(\w+)\[([0-9,]*)\]")
 
 
@@ -185,8 +183,11 @@ def _parse_hlo_collectives(hlo_text: str, fallback_group_size: int = 0):
     largest element — the result — is taken instead of the sum.
 
     Group size comes from ``replica_groups`` in either the explicit
-    ``{{0,1},{2,3}}`` or the iota ``[G,S]<=[N]`` form; an empty ``{}``
-    (all devices) falls back to ``fallback_group_size``."""
+    ``{{0,1},{2,3}}`` or the iota ``[G,S]<=[N]`` form
+    (``profiling.fusion.replica_groups``); an empty ``{}`` (all devices)
+    falls back to ``fallback_group_size``."""
+    from .profiling.fusion import replica_groups
+
     out = []
     for line in hlo_text.splitlines():
         m = _COLLECTIVE_RE.search(line)
@@ -198,12 +199,8 @@ def _parse_hlo_collectives(hlo_text: str, fallback_group_size: int = 0):
             payload = max(sizes, default=0)
         else:
             payload = sum(sizes)
-        g = _GROUP_RE.search(line)
-        if g:
-            gsize = len(g.group(1).split(","))
-        else:
-            gi = _IOTA_GROUP_RE.search(line)
-            gsize = int(gi.group(2)) if gi else fallback_group_size
+        groups = replica_groups(line)
+        gsize = len(groups[0]) if groups else fallback_group_size
         out.append((kind, payload, gsize))
     return out
 

@@ -292,6 +292,7 @@ class Trainer:
         # runs at trace time inside jit/scan); tests pin no-retrace
         # guarantees on this counter staying flat
         self._trace_count = 0
+        self._traces_registered = 0  # _trace_count at the last registration
         self.global_step = 0
         self.lint_report = None  # set by startup(lint=...)
         # NaN/Inf guard: guard=True -> default GuardPolicy; None ->
@@ -1314,18 +1315,18 @@ class Trainer:
         ls = getattr(self.scope, "loss_scale_state", None) or {}
         base_step = self.global_step
         t0 = _time.perf_counter()
+        args = (self.scope.params, self.scope.opt_state, self.scope.state,
+                rng, feed, ls)
         with profiler.record_event("trainer.step", step=base_step, steps=1,
                                    inst=self.telemetry_inst):
             if self._quant_ef:
-                p, o, s, out, new_ls, new_qr = self._step_fn(
-                    self.scope.params, self.scope.opt_state,
-                    self.scope.state, rng, feed, ls,
-                    self.scope.quant_resid)
+                args += (self.scope.quant_resid,)
+                p, o, s, out, new_ls, new_qr = self._step_fn(*args)
                 self.scope.quant_resid = new_qr
             else:
-                p, o, s, out, new_ls = self._step_fn(
-                    self.scope.params, self.scope.opt_state,
-                    self.scope.state, rng, feed, ls)
+                p, o, s, out, new_ls = self._step_fn(*args)
+        if self._trace_count != self._traces_registered:
+            self._register_program("_step_fn", args)
         self.step_timer.record_dispatch(t0, _time.perf_counter(), 1, "step",
                                         span=span, base_step=base_step)
         self._log_compile_cache("train step")
@@ -1376,18 +1377,18 @@ class Trainer:
         ls = getattr(self.scope, "loss_scale_state", None) or {}
         step0 = np.int32(self.global_step)
         t0 = _time.perf_counter()
+        args = (self.scope.params, self.scope.opt_state, self.scope.state,
+                rng, step0, feed, ls)
         with profiler.record_event("trainer.run_steps", step=int(step0),
                                    steps=k, inst=self.telemetry_inst):
             if self._quant_ef:
-                p, o, s, outs, new_ls, new_qr = self._multi_step_fn(
-                    self.scope.params, self.scope.opt_state,
-                    self.scope.state, rng, step0, feed, ls,
-                    self.scope.quant_resid)
+                args += (self.scope.quant_resid,)
+                p, o, s, outs, new_ls, new_qr = self._multi_step_fn(*args)
                 self.scope.quant_resid = new_qr
             else:
-                p, o, s, outs, new_ls = self._multi_step_fn(
-                    self.scope.params, self.scope.opt_state,
-                    self.scope.state, rng, step0, feed, ls)
+                p, o, s, outs, new_ls = self._multi_step_fn(*args)
+        if self._trace_count != self._traces_registered:
+            self._register_program("_multi_step_fn", args)
         self.step_timer.record_dispatch(t0, _time.perf_counter(), k,
                                         "run_steps", span=span,
                                         base_step=int(step0))
@@ -1403,6 +1404,54 @@ class Trainer:
         else:
             self._warn_inert_nan_flag()
         return outs
+
+    # what _build_step, the step it traces and _loss_and_aux read of their
+    # Trainer: all a registered program's stand-in is given
+    _STEP_READS = ("program", "optimizer", "loss_name", "fetch_list", "mesh",
+                   "sharding_rules", "strategy", "donate", "loss_scaler",
+                   "guard_policy", "_guard_opt_out", "feed_wire",
+                   "feed_augment", "_zero", "_pp_perm")
+
+    def _register_program(self, attr: str, args) -> None:
+        """The dispatch just made traced its program (a first ``step`` /
+        ``run_steps`` of a feed shape): register it with ``core.profiler``
+        so a device trace's operations can be put under this program's
+        scopes. The registry outlives this Trainer, and the step's
+        closures hold theirs, so what is kept is a stand-in: a bare
+        Trainer with the ``_STEP_READS`` of this one and a scope that
+        holds the arguments' shapes, dtypes and shardings (donated arrays
+        still tell them) in place of arrays; not the dataset cache, a
+        pending guard readback, the feeder or the telemetry. Asked for the
+        text, the stand-in builds its own step and lowers it at those
+        shapes: the program that ran, so the compile cache serves it."""
+        self._traces_registered = self._trace_count
+
+        def struct(a):
+            # an uncommitted array (the rng key) or a host scalar goes
+            # where the program is, as it did in the call
+            sh = a.sharding if getattr(a, "_committed", False) else None
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sh)
+
+        structs = jax.tree.map(struct, args)
+        shadow = object.__new__(type(self))
+        for name in self._STEP_READS:
+            setattr(shadow, name, getattr(self, name, None))
+        shadow._trace_count = 0
+        shadow.scope = Scope()
+        (shadow.scope.params, shadow.scope.opt_state,
+         shadow.scope.state) = structs[:3]
+
+        def text() -> str:
+            shadow._build_step()
+            return getattr(shadow, attr).lower(*structs).compile().as_text()
+
+        fn = getattr(self, attr)
+        profiler.register_program(
+            "jit_" + getattr(fn, "__name__", attr), text,
+            mesh_axes=tuple(self.mesh.shape.items()) if self.mesh is not None
+            else (),
+            key=(attr, jax.tree.structure(structs),
+                 tuple(jax.tree.leaves(structs))))
 
     def _warn_inert_nan_flag(self):
         """The check_nan_inf flag is compiled into the step at

@@ -1185,6 +1185,10 @@ def _aot_compile(exported):
     args, kwargs = jax.tree.unflatten(exported.in_tree, flat)
     compiled = jax.jit(exported.call).lower(*args, **kwargs).compile()
     _aot_compiles += 1
+    # the module a device trace will show is ``jit_call(n)``: its text is
+    # asked of the executable only if someone joins a trace to it
+    profiler.register_program("jit_" + exported.call.__name__,
+                              compiled.as_text)
     return compiled
 
 

@@ -86,6 +86,7 @@ def rms_norm(x, g, eps: float = 1e-5):
     return (x32 * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("ffn")
 def gated_ffn(x, w_gate, w_up, w_down):
     """``W_down(silu(W_gate x) * (W_up x))``; products accumulate in
     float32, the gate is taken in float32 and rounded once."""

@@ -191,7 +191,8 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
             return S.prefill_block(a, lp, cfg.num_heads, cfg.use_flash)
 
         with jax.named_scope("prefill"):
-            x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
+            with jax.named_scope("tok"):
+                x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
             x, (ks, vs) = jax.lax.scan(pre, x, stack)
             logp0 = head(x[:, -1])  # first generated token comes from here
 
@@ -213,27 +214,29 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
         enforce(cfg.kv_cache_dtype in ("compute", "int8"),
                 f"kv_cache_dtype={cfg.kv_cache_dtype!r} (compute|int8)")
         int8_kv = cfg.kv_cache_dtype == "int8"
-        if int8_kv:
-            # quantize the prefix BEFORE growing: padded tail positions
-            # get int8 zeros with zero scales (dequantize to exact 0)
-            kq, ksc = zip(*(S.quantize_kv(ks[i], cfg.num_heads)
-                            for i in range(L)))
-            vq, vsc = zip(*(S.quantize_kv(vs[i], cfg.num_heads)
-                            for i in range(L)))
-            state0 = {"kq": [grow(a) for a in kq],
-                      "ks": [grow(a) for a in ksc],
-                      "vq": [grow(a) for a in vq],
-                      "vs": [grow(a) for a in vsc]}
-        else:
-            state0 = {"k": [grow(ks[i]) for i in range(L)],
-                      "v": [grow(vs[i]) for i in range(L)]}
+        with jax.named_scope("cache_init"):
+            if int8_kv:
+                # quantize the prefix BEFORE growing: padded tail positions
+                # get int8 zeros with zero scales (dequantize to exact 0)
+                kq, ksc = zip(*(S.quantize_kv(ks[i], cfg.num_heads)
+                                for i in range(L)))
+                vq, vsc = zip(*(S.quantize_kv(vs[i], cfg.num_heads)
+                                for i in range(L)))
+                state0 = {"kq": [grow(a) for a in kq],
+                          "ks": [grow(a) for a in ksc],
+                          "vq": [grow(a) for a in vq],
+                          "vs": [grow(a) for a in vsc]}
+            else:
+                state0 = {"k": [grow(ks[i]) for i in range(L)],
+                          "v": [grow(vs[i]) for i in range(L)]}
         _record_decode_plan(cfg, state0)
         state0.update(
             index=jnp.asarray(p, jnp.int32),
             logp0=jnp.repeat(logp0, K, axis=0) if K > 1 else logp0,
             first=jnp.asarray(True))
-        layer_params = [jax.tree.map(lambda a, i=i: a[i], stack)
-                        for i in range(L)]
+        with jax.named_scope("stack_slice"):
+            layer_params = [jax.tree.map(lambda a, i=i: a[i], stack)
+                            for i in range(L)]
         cache_keys = ("kq", "ks", "vq", "vs") if int8_kv else ("k", "v")
 
         def step_fn(tokens, state):
@@ -241,8 +244,9 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
             # afterwards embed the chosen token and run the cached stack
             @jax.named_scope("decode_step")
             def incremental(_):
-                xt = cast_compute(w_emb[tokens][:, None, :]
-                                  + pe[state["index"]][None, None])
+                with jax.named_scope("tok"):
+                    xt = cast_compute(w_emb[tokens][:, None, :]
+                                      + pe[state["index"]][None, None])
                 new = tuple([] for _ in cache_keys)
                 for i, lp in enumerate(layer_params):
                     caches = tuple(state[k][i] for k in cache_keys)

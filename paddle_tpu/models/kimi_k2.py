@@ -240,7 +240,8 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
         return _expert_ffn(cfg, x, lp, banks, layer), cache
 
     with jax.named_scope("prefill"):
-        x = w_emb[prompt_ids]
+        with jax.named_scope("tok"):
+            x = w_emb[prompt_ids]
         x, (c0, r0) = jax.lax.scan(pre_dense, x, dense)
         x, (c1, r1) = jax.lax.scan(pre_expert, x, (sliced, layer_ids))
         logp0 = head(x[:, -1])
@@ -266,12 +267,14 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
 
         @jax.named_scope("decode_step")
         def incremental(_):
-            x = w_emb[tokens][:, None, :]
+            with jax.named_scope("tok"):
+                x = w_emb[tokens][:, None, :]
             c, r = list(state["c"]), list(state["r"])
             for i in range(n_layers):
                 j = i - n_dense
-                lp = jax.tree.map(lambda a: a[i if j < 0 else j],
-                                  dense if j < 0 else sliced)
+                with jax.named_scope("stack_slice"):
+                    lp = jax.tree.map(lambda a: a[i if j < 0 else j],
+                                      dense if j < 0 else sliced)
                 x, c[i], r[i] = M.mla_decode(x, lp, c[i], r[i], index, dims,
                                              yarn)
                 x = (M.ffn_block(x, lp, cfg.rms_norm_eps) if j < 0

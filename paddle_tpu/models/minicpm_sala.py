@@ -183,7 +183,9 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
                  for l, k in zip(indices, kinds) if k == LIGHTNING]
 
     def embed(ids):
-        return (w_emb[ids].astype(jnp.float32) * cfg.scale_emb).astype(dtype)
+        with jax.named_scope("tok"):
+            return (w_emb[ids].astype(jnp.float32)
+                    * cfg.scale_emb).astype(dtype)
 
     def head(x_last):   # [rows, d] -> log-probs
         with jax.named_scope("head"):

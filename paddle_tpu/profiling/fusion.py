@@ -38,7 +38,7 @@ from __future__ import annotations
 import dataclasses
 import re
 from collections import Counter
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -56,11 +56,11 @@ _SHAPE_ELEM_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
 # arg list is matched greedily up to the "->"
 _COMP_HEAD_RE = re.compile(
     r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*\S.*\{\s*$")
-_INSTR_RE = re.compile(
-    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"
-    r"(\((?:[^()]|\([^()]*\))*\)|\w+\[[^\]]*\](?:\{[^}]*\})?)\s*"
-    r"([\w\-]+)\(")
+_INSTR_HEAD_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_ARRAY_SHAPE_RE = re.compile(r"\w+\[[^\]]*\](?:\{[^}]*\})?")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
 _OPERAND_SHAPE_RE = re.compile(r"(\w+\[[0-9,]*\])(?:\{[^}]*\})?\s+%")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _CALLS_RE = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _BODY_RE = re.compile(
     r"(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)")
@@ -160,6 +160,7 @@ class Instruction:
     operand_shapes: List[str]
     attrs: str                    # text after the operand parens
     op_name: str = ""             # metadata op_name (source mapping)
+    operands: Tuple[str, ...] = ()  # operand instruction names
 
     @property
     def out_bytes(self) -> int:
@@ -206,6 +207,35 @@ class Unit:
     shape_sig: str = ""
 
 
+def _match_instruction(line: str):
+    """``(name, result shape, opcode, end of "opcode(")`` of an instruction
+    line, or None. A tuple-typed result may nest to any depth (an
+    asynchronous ``slice-start`` gives ``((f32[..]{..T(8,128)}), f32[..],
+    s32[])``), so its parentheses are counted, not matched by pattern."""
+    head = _INSTR_HEAD_RE.match(line)
+    if not head:
+        return None
+    at = head.end()
+    if line.startswith("(", at):
+        depth, end = 0, at
+        for end in range(at, len(line)):
+            depth += {"(": 1, ")": -1}.get(line[end], 0)
+            if depth == 0:
+                break
+        else:
+            return None
+        end += 1
+    else:
+        shape = _ARRAY_SHAPE_RE.match(line, at)
+        if not shape:
+            return None
+        end = shape.end()
+    op = _OPCODE_RE.match(line, end)
+    if not op:
+        return None
+    return head.group(1), line[at:end], op.group(1), op.end()
+
+
 def parse_hlo_module(text: str) -> Dict[str, Computation]:
     """Parse optimized-HLO text into ``{name: Computation}``."""
     comps: Dict[str, Computation] = {}
@@ -222,20 +252,21 @@ def parse_hlo_module(text: str) -> Dict[str, Computation]:
             comps[cur.name] = cur
             cur = None
             continue
-        m = _INSTR_RE.match(line)
+        m = _match_instruction(line)
         if not m:
             continue
-        name, shape, opcode = m.group(1), m.group(2), m.group(3)
-        seg = _operand_segment(line, m.end())
+        name, shape, opcode, end = m
+        seg = _operand_segment(line, end)
         operands = [s.group(1) for s in _OPERAND_SHAPE_RE.finditer(seg)]
-        attrs = line[m.end() + len(seg):]
+        attrs = line[end + len(seg):]
         op_name = ""
         nm = _OP_NAME_RE.search(line)
         if nm:
             op_name = nm.group(1)
         cur.instructions.append(Instruction(
             name=name, opcode=opcode, shape=shape,
-            operand_shapes=operands, attrs=attrs, op_name=op_name))
+            operand_shapes=operands, attrs=attrs, op_name=op_name,
+            operands=tuple(_OPERAND_NAME_RE.findall(seg))))
     if cur is not None:  # unterminated tail (defensive)
         comps[cur.name] = cur
     return comps
@@ -357,11 +388,12 @@ def _comp_metrics(comps: Dict[str, Computation]):
     return total
 
 
-def module_units(comps: Dict[str, Computation]) -> List[Unit]:
-    """Flatten a parsed module into cost units: instructions of the
-    entry computation plus while bodies/conditions and conditional
-    branches (tagged ``in_loop`` when under a while), with absorbed
-    fusion/reducer computations folded into their calling unit."""
+def executed_computations(comps: Dict[str, Computation]) -> Dict[str, bool]:
+    """``{computation: in_loop}`` of the computations executed in place:
+    the entry, while bodies and conditions, conditional branches and
+    ``call`` targets, walked from the entry so nested whiles inherit loop
+    membership. Computations absorbed into a caller (``calls=`` fusions,
+    ``to_apply=`` reducers) are not among them."""
     absorbed = set()
     control: Dict[str, bool] = {}    # name -> in_loop
     for comp in comps.values():
@@ -386,6 +418,15 @@ def module_units(comps: Dict[str, Computation]) -> List[Unit]:
             is_while = ins.opcode == "while"
             for sub in _referenced(ins, "control"):
                 stack.append((sub, in_loop or is_while))
+    return control
+
+
+def module_units(comps: Dict[str, Computation]) -> List[Unit]:
+    """Flatten a parsed module into cost units: instructions of the
+    entry computation plus while bodies/conditions and conditional
+    branches (tagged ``in_loop`` when under a while), with absorbed
+    fusion/reducer computations folded into their calling unit."""
+    control = executed_computations(comps)
     total = _comp_metrics(comps)
     units: List[Unit] = []
     for name, in_loop in control.items():
@@ -535,3 +576,234 @@ def fusion_report(trainer, feed, top_k: int = 8) -> Dict[str, Any]:
     if ma is not None:
         rep["temp_mb"] = ma.temp_size_in_bytes / 1e6
     return rep
+
+
+# -- instruction name -> the program's own scope --------------------------------
+
+# name-stack components that say how an operation was reached, not which
+# layer it belongs to
+_STRUCTURAL = frozenset({
+    "call_exported", "closed_call", "while", "cond", "checkpoint",
+    "rematted_computation", "shard_map", "pjit", "custom_jvp_call",
+    "custom_vjp_call", "custom_vjp_call_jaxpr", "core_call", "remat",
+})
+_BRANCH_N_RE = re.compile(r"branch_\d+(_fun)?$")
+_UNPLACED_OPS = ("parameter", "constant")   # arguments and literals: no scope
+_SCOPE_NAME_RE = re.compile(r"[\w.\-]+$")
+_WRAPPED_RE = re.compile(r"^(\w+)\((.*)\)$")
+_GROUPS_RE = re.compile(
+    r"(replica_groups|source_target_pairs)=\{((?:\{[0-9,]*\},?)*)\}")
+_IOTA_GROUPS_RE = re.compile(
+    r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
+
+
+@dataclasses.dataclass(frozen=True)
+class ScopeRow:
+    """Where one instruction of an executed computation belongs."""
+    path: Tuple[str, ...]         # the named_scope components, in order
+    remat: bool                   # of a checkpoint's second forward
+    backward: bool                # reached through transpose(...)
+    axes: Optional[str]           # a collective's mesh axes ("dp", "dp,tp",
+                                  # "" without a mesh); None: no collective
+    opcode: str
+    op_name: str                  # its own, or its donor's where inherited
+    inherited: bool = False       # no name stack of its own: placed where
+                                  # the instruction it feeds (or is fed by) is
+
+
+def _split_top(s: str, sep: str = "/") -> List[str]:
+    """Split at ``sep`` outside parentheses."""
+    out, depth, at = [], 0, 0
+    for i, c in enumerate(s):
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+        elif c == sep and depth == 0:
+            out.append(s[at:i])
+            at = i + 1
+    out.append(s[at:])
+    return out
+
+
+def scope_path(op_name: str) -> Tuple[str, ...]:
+    """The ``named_scope`` components of an ``op_name``, in order: the
+    primitive (the last component), ``jit(...)`` / ``pjit(...)`` wrappers
+    and the structural words are taken out, ``jvp(...)`` / ``transpose(...)``
+    / ``vmap(...)`` are opened. ``jit(step)/transpose(jvp(gpt))/while/body/
+    checkpoint/rematted_computation/attn/dot_general`` -> ``("gpt", "attn")``."""
+    path: List[str] = []
+    before = ""
+    # XLA joins the names of merged instructions with ";": the first
+    op_name = op_name.split(";", 1)[0]
+    if "[" in op_name:      # an argument's own name (``params['gpt/h/w']``)
+        return ()
+    for part in _split_top(op_name)[:-1]:
+        in_while, before = before == "while", part
+        if in_while and part in ("body", "cond"):
+            continue
+        m = _WRAPPED_RE.match(part)
+        while m:
+            if m.group(1) in ("jit", "pjit"):
+                part = ""
+                break
+            part = m.group(2)
+            m = _WRAPPED_RE.match(part)
+        path += [p for p in part.split("/")
+                 if _SCOPE_NAME_RE.match(p) and p not in _STRUCTURAL
+                 and not _BRANCH_N_RE.match(p)]
+    return tuple(path)
+
+
+def replica_groups(attrs: str, pairs: bool = False
+                   ) -> Optional[List[List[int]]]:
+    """The device groups of a collective's ``replica_groups``, explicit
+    (``{{0,1},{2,3}}``) or iota (``[2,2]<=[4]``, ``[2,2]<=[2,2]T(1,0)``);
+    ``[]`` for the empty ``{}`` that means every device; with ``pairs``
+    also a ``collective-permute``'s ``source_target_pairs``. None where
+    the text has none."""
+    m = _IOTA_GROUPS_RE.search(attrs)
+    if m:
+        dims = [int(x) for x in m.group(3).split(",")]
+        ids = np.arange(int(np.prod(dims))).reshape(dims)
+        if m.group(4):
+            ids = ids.transpose([int(x) for x in m.group(4).split(",")])
+        return ids.reshape(int(m.group(1)), int(m.group(2))).tolist()
+    for m in _GROUPS_RE.finditer(attrs):
+        if pairs or m.group(1) == "replica_groups":
+            return [[int(x) for x in g.split(",") if x]
+                    for g in re.findall(r"\{([0-9,]*)\}", m.group(2))]
+    return None
+
+
+def collective_axes(attrs: str, mesh_axes: Sequence[Tuple[str, int]]
+                    ) -> Optional[str]:
+    """The mesh axes a collective's groups (``replica_groups`` with
+    ``pairs``) run over: a member's number is its place in the mesh's
+    devices, row-major, so its coordinates are ``unravel_index``; an axis
+    counts where two members of one group differ along it. ``mesh_axes``:
+    ``((name, size), ...)`` in the mesh's order; empty gives ``""``. An
+    empty ``replica_groups={}`` is every device: every axis of size > 1."""
+    if not mesh_axes:
+        return ""
+    names = [n for n, _ in mesh_axes]
+    shape = tuple(s for _, s in mesh_axes)
+    n = int(np.prod(shape))
+    groups = replica_groups(attrs, pairs=True)
+    if groups is None:
+        return None
+    varies = np.zeros(len(shape), bool)
+    for g in groups or [list(range(n))]:
+        if g and max(g) >= n:
+            return None             # not this mesh's numbering
+        coords = np.asarray(np.unravel_index(np.asarray(g, int), shape)).T
+        if len(coords):
+            varies |= (coords != coords[0]).any(axis=0)
+    return ",".join(nm for nm, v in zip(names, varies) if v)
+
+
+def scope_table(text: str, mesh_axes: Sequence[Tuple[str, int]] = ()
+                ) -> Dict[str, ScopeRow]:
+    """``{instruction name: ScopeRow}`` over every instruction of an
+    executed computation of the optimized HLO ``text``. An instruction
+    whose ``op_name`` is a name stack is placed by it; a fusion without
+    one takes the (path, remat, backward) that most of its fused
+    instructions carry, weighed by the roofline cost ``attribute_units``
+    ranks by; any other instruction without one (the compiler's own
+    copies, asynchronous slices and kernels) takes the place of the first
+    instruction it feeds, else of one that feeds it: its row says
+    ``inherited`` and carries the donor's ``op_name``. A fusion is indivisible:
+    what XLA merged across two scopes lies whole under the one its
+    metadata names. A collective (also an asynchronous ``-done``, through
+    its ``-start``, and a fusion that holds one) gets the mesh axes its
+    groups run over."""
+    comps = parse_hlo_module(text)
+    by_name = {i.name: i for c in comps.values() for i in c.instructions}
+
+    def tags(op_name: str):
+        return (scope_path(op_name), "rematted_computation" in op_name,
+                "transpose(" in op_name)
+
+    def fused(name: str, seen=()) -> List[Instruction]:
+        if name not in comps or name in seen:
+            return []
+        out = []
+        for ins in comps[name].instructions:
+            out.append(ins)
+            for sub in _referenced(ins, "absorb"):
+                out += fused(sub, seen + (name,))
+        return out
+
+    def vote(ins: Instruction):
+        """((path, remat, backward), op_name) of the costliest tags among
+        the instructions fused into ``ins``, the op_name that of the
+        costliest single one; ``((), False, False), ""`` without any."""
+        cost_of: Dict[tuple, float] = {}
+        top: Dict[tuple, Tuple[float, str]] = {}
+        for sub in _referenced(ins, "absorb"):
+            for f in fused(sub):
+                if "/" in f.op_name and f.opcode not in _UNPLACED_OPS:
+                    cost = max(_instr_flops(f) / _FALLBACK_PEAK,
+                               (f.operand_bytes + f.out_bytes) / _FALLBACK_BW)
+                    t = tags(f.op_name)
+                    cost_of[t] = cost_of.get(t, 0.0) + cost
+                    top[t] = max(top.get(t, (-1.0, "")), (cost, f.op_name))
+        if not cost_of:
+            return ((), False, False), ""
+        best = max(cost_of, key=lambda t: (cost_of[t], t))
+        return best, top[best][1]
+
+    def axes_of(ins: Instruction, depth: int = 0) -> Optional[str]:
+        op = ins.opcode
+        base = op[:-6] if op.endswith("-start") else \
+            op[:-5] if op.endswith("-done") else op
+        if base in _COLLECTIVE_OPS:
+            if op.endswith("-done") and depth < 4:
+                for o in ins.operands:
+                    if o in by_name:
+                        return axes_of(by_name[o], depth + 1)
+            return collective_axes(ins.attrs, mesh_axes)
+        if op == "fusion":
+            found = [collective_axes(f.attrs, mesh_axes)
+                     for sub in _referenced(ins, "absorb") for f in fused(sub)
+                     if f.opcode.split("-start")[0] in _COLLECTIVE_OPS]
+            found = [a for a in found if a is not None]
+            if found:
+                return ",".join(dict.fromkeys(
+                    x for a in found for x in a.split(",") if x))
+        return None
+
+    table: Dict[str, ScopeRow] = {}
+    for comp in executed_computations(comps):
+        instrs = comps[comp].instructions
+        placed: Dict[str, tuple] = {}     # name -> ((path, remat, backward), src)
+        inherited = set()
+        users: Dict[str, List[str]] = {}
+        for ins in instrs:
+            for o in ins.operands:
+                users.setdefault(o, []).append(ins.name)
+            if "/" in ins.op_name:        # a name stack: jit(f)/.../primitive
+                placed[ins.name] = (tags(ins.op_name), ins.op_name)
+            elif ins.opcode not in _UNPLACED_OPS:
+                got = vote(ins)
+                if got[1]:
+                    placed[ins.name] = got
+        # what the compiler made itself carries no name stack (a layout
+        # copy, an asynchronous slice, a kernel XLA wrote for ragged-dot):
+        # it lies with the first instruction it feeds, or else with what
+        # feeds it; the text is in schedule order, operands first
+        for order, near in ((reversed(instrs), lambda i: users.get(i.name, ())),
+                            (instrs, lambda i: i.operands)):
+            for ins in order:
+                if ins.name not in placed and ins.opcode not in _UNPLACED_OPS:
+                    donor = next((n for n in near(ins) if n in placed), None)
+                    if donor is not None:
+                        placed[ins.name] = placed[donor]
+                        inherited.add(ins.name)
+        for ins in instrs:
+            (path, remat, backward), src = placed.get(
+                ins.name, (((), False, False), ins.op_name))
+            table[ins.name] = ScopeRow(path, remat, backward, axes_of(ins),
+                                       ins.opcode, src,
+                                       ins.name in inherited)
+    return table

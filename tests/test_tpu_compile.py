@@ -636,3 +636,39 @@ def test_tp_block_exchanges_run_under_matmuls_for_v5e(chips, monkeypatch):
           if "f32[%d,%d,3,%d]" % (layers, d, d // 2) in ln]
     assert len(dp) == 1 and "replica_groups={{0,2},{1,3}}" in dp[0], dp
     assert "bf16[" not in dp[0].split(" all-reduce(")[0]
+
+
+def test_scope_table_places_the_tp_block_and_its_exchanges_for_v5e(
+        chips, monkeypatch):
+    """The same compiled text through ``profiling.fusion.scope_table`` with
+    the mesh's axes: every instruction line of the chip's text is parsed
+    (an asynchronous pair's result is a tuple nested three deep), the 11
+    exchanges of a layer, halves of a pair and all, run over ``tp`` and lie
+    under ``attn`` or ``ffn``, three of them in remat's second forward; the
+    gradient all-reduce runs over ``dp``; and the three kernel calls lie
+    under ``attn`` by their own names."""
+    from paddle_tpu.profiling.fusion import parse_hlo_module, scope_table
+
+    text = _dp2tp2_block_text(chips, monkeypatch, batch=32)
+    lines = [ln for ln in text.splitlines()
+             if re.match(r"^\s+(ROOT\s+)?%?[\w.\-]+ = ", ln)]
+    parsed = parse_hlo_module(text)
+    assert sum(len(c.instructions) for c in parsed.values()) == len(lines)
+
+    table = scope_table(text, (("dp", 2), ("tp", 2)))
+    hops = [r for r in table.values()
+            if r.opcode == "collective-permute-done"]
+    assert len(hops) == 11 and {r.axes for r in hops} == {"tp"}
+    assert {r.path[-1] for r in hops} <= {"attn", "ffn"}
+    assert sum(r.remat for r in hops) == 3
+    assert sum(r.backward and not r.remat for r in hops) == 4
+    starts = [r for r in table.values()
+              if r.opcode == "collective-permute-start"]
+    assert len(starts) == 11 and {r.axes for r in starts} == {"tp"}
+    reduces = {r.axes for r in table.values() if r.opcode == "all-reduce"}
+    assert "dp" in reduces and reduces <= {"dp", "dp,tp"}
+    kernels = {name.split(".")[0]: r for name, r in table.items()
+               if r.opcode == "custom-call" and "flash" in name}
+    assert set(kernels) == {"flash_fwd", "flash_bwd"}
+    assert all("attn" in r.path for r in kernels.values())
+    assert kernels["flash_bwd"].backward and not kernels["flash_bwd"].remat
