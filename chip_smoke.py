@@ -229,6 +229,44 @@ def sparse_case(name: str, seed: int, rows=2, kv_heads=2, group=16, d=128,
         check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
 
 
+def select_case(name: str, seed: int, rows=2, kv_heads=2, group=16, d=128,
+                total=8192, queries=512, p0=7680, window_blocks=32,
+                topk=64) -> None:
+    """``select_fwd`` at the published head shapes (the 31 highest of the
+    blocks before a window of 32, scored by 16 grouped heads of 128 against
+    the compressed keys) against the plain scorer, ``select_blocks``: the
+    same counts, and the same block in 99.9% of the seats that count (two
+    blocks whose scores tie to float32 rounding may swap)."""
+    from paddle_tpu.layers import sala
+    from paddle_tpu.ops.block_select import block_select
+
+    dims = sala.SparseDims(1, kv_heads * group, kv_heads, d, 1e-6, 32, 16, 64,
+                           1, window_blocks * 64, topk, 0)
+    kq, kk = jax.random.split(jax.random.PRNGKey(seed))
+    q = 4.0 * jax.random.normal(kq, (rows, queries, dims.heads, d), jnp.float32)
+    ck = 0.2 * jax.random.normal(kk, (rows, total // 16, kv_heads * d),
+                                 jnp.float32)
+    q, ck = q.astype(jnp.bfloat16), ck.astype(jnp.bfloat16)
+    kernel = jax.jit(lambda q, ck, p0: block_select(
+        q.reshape(rows, queries, -1), ck, p0, group=group, head_dim=d,
+        kernel_size=32, stride=16, block=64, init_blocks=1,
+        window_blocks=window_blocks, n_sel=dims.n_sel, scale=dims.scale)[0])
+    args = (q, ck, jnp.int32(p0))
+    calls = kernel.lower(*args).as_text().count("tpu_custom_call")
+    got = np.asarray(kernel(*args))
+    want = np.asarray(jax.jit(lambda q, ck, p0: sala.select_blocks(
+        q, ck, p0 + jnp.arange(queries), dims))(*args))
+    live = np.arange(dims.n_sel) < want[..., -1:]
+    same = float((got[..., :-1] == want[..., :-1])[live].mean())
+    say("kernel", f"{name} q{q.shape} compressed keys{ck.shape} p0={p0}: "
+        f"tpu_custom_call in the lowered call: {calls}; seats equal to the "
+        f"plain scorer's: {same:.5f} of {int(live.sum())}")
+    check((got[..., -1] == want[..., -1]).all() and same >= 0.999,
+          f"{name}: select_fwd seats {same:.5f} of the plain scorer's blocks")
+    if on_tpu():
+        check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
+
+
 def lightning_case(name: str, seed: int, rows=2, heads=32, d=128,
                    seq=1024) -> None:
     """``lightning_fwd`` at the published head shapes (32 heads of 128,
@@ -267,8 +305,9 @@ def lightning_case(name: str, seed: int, rows=2, heads=32, d=128,
 
 def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
                  transformer_shape=(32, 8, 256, 64), sala=None) -> None:
-    with timed("kernel", "seven cases, compiles included"):
+    with timed("kernel", "eight cases, compiles included"):
         sparse_case("sala_sparse", seed + 5, **(sala or {}).get("sparse", {}))
+        select_case("sala_select", seed + 7, **(sala or {}).get("select", {}))
         lightning_case("sala_lightning", seed + 6,
                        **(sala or {}).get("lightning", {}))
         kernel_case("gpt", gpt_shape, True, "none", seed)

@@ -16,7 +16,9 @@ every ``kernel_stride``), sums a group's heads, pools the kernels that touch
 a block into the block's score (the largest), and takes the highest blocks
 beside those always read (the first ``init_blocks`` and the window ending
 with the query's own) up to ``topk`` in all: :func:`select_blocks`, one
-selection a group. The attention over the selection is
+selection a group; a prefill's chunk takes it as ``ops/block_select.py``'s
+kernel, which writes the indices alone, a step's one query a row in the
+plain form. The attention over the selection is
 ``ops/sparse_attention.py``'s kernel in the prefill and a gather in a step.
 The layer carries three lane-dense slabs: keys and values ``[rows, T,
 kv_heads * 128]`` and the compressed keys ``[rows, T / stride, kv_heads *
@@ -42,6 +44,7 @@ import jax.numpy as jnp
 
 from ..core.errors import enforce
 from ..framework import LayerHelper
+from ..ops.block_select import block_select
 from ..ops.flash_attention import NEG_INF, flash_attention
 from ..ops.lightning_attention import lightning_attention
 from ..ops.sparse_attention import record_plan as _record_sparse_plan
@@ -183,12 +186,22 @@ def _stride_means(k, stride: int):
 
 
 def select_blocks(q, ck_cache, positions, dims: SparseDims):
-    """The scorer. ``q [b, s, heads, hd]`` at ``positions [s]``,
-    ``ck_cache [b, J, kv * hd]`` -> ``sel [b, kv, s, n_sel + 1]`` int32: a
-    query's chosen blocks, highest score first, and how many of them
-    count (fewer than ``n_sel`` while fewer blocks lie before the window).
-    Compressed key ``j`` exists for a query at ``i`` when its last key does
-    (``stride * j + kernel <= i + 1``)."""
+    """The scorer, in its plain form (a step's one query a row; a prefill's
+    chunk takes ``ops/block_select.py``'s kernel, whose oracle this is).
+    ``q [b, s, heads, hd]`` at ``positions [s]``, ``ck_cache [b, J, kv *
+    hd]`` -> ``sel [b, kv, s, n_sel + 1]`` int32: a query's chosen blocks,
+    highest score first, and how many of them count (fewer than ``n_sel``
+    while fewer blocks lie before the window)."""
+    top, idx = _highest(block_scores(q, ck_cache, positions, dims), dims.n_sel)
+    count = jnp.sum(top >= 0.0, axis=-1, keepdims=True)
+    return jnp.concatenate([idx, count], axis=-1).astype(jnp.int32)
+
+
+def block_scores(q, ck_cache, positions, dims: SparseDims):
+    """``[b, kv, s, blocks]`` float32: a block's score for a query, -1
+    where the block is not the query's to choose (the first blocks, the
+    window's, those after it). Compressed key ``j`` exists for a query at
+    ``i`` when its last key does (``stride * j + kernel <= i + 1``)."""
     b, s, _, hd = q.shape
     J = ck_cache.shape[1]
     per = dims.block_size // dims.kernel_stride          # kernels a block starts
@@ -212,9 +225,7 @@ def select_blocks(q, ck_cache, positions, dims: SparseDims):
     blocks = jnp.arange(n_blocks)
     free = ((blocks[None, :] >= dims.init_blocks)
             & (blocks[None, :] <= (own - dims.window_blocks)[:, None]))
-    top, idx = _highest(jnp.where(free, block_score, -1.0), dims.n_sel)
-    count = jnp.sum(top >= 0.0, axis=-1, keepdims=True)
-    return jnp.concatenate([idx, count], axis=-1).astype(jnp.int32)
+    return jnp.where(free, block_score, -1.0)
 
 
 def _highest(scores, k: int):
@@ -233,11 +244,6 @@ def _highest(scores, k: int):
     stack = lambda parts: (jnp.stack(parts, axis=-1) if parts else
                            jnp.zeros(scores.shape[:-1] + (0,), scores.dtype))
     return stack(top), stack(idx).astype(jnp.int32)
-
-
-# queries the scorer takes at once in a prefill: its scores are float32
-# [rows, heads, queries, T / stride]
-SCORER_QUERIES = 512
 
 
 def sparse_prefill(x, p, dims: SparseDims, cache, p0, selected: bool, a: float):
@@ -276,25 +282,23 @@ def sparse_prefill(x, p, dims: SparseDims, cache, p0, selected: bool, a: float):
             enforce(s % dims.block_size == 0,
                     f"sparse_prefill: a chunk of {s} in blocks of "
                     f"{dims.block_size}")
+            # a chunk is whole query tiles: the scorer is the kernel (a
+            # step's one query a row takes select_blocks, its plain form)
             with jax.named_scope("sparse_select"):
-                n = min(SCORER_QUERIES, s)
-                enforce(s % n == 0, f"sparse_prefill: a chunk of {s} in "
-                        f"scorer runs of {n}")
-                starts = jnp.arange(s // n) * n
-                sel = jax.lax.map(
-                    lambda at: select_blocks(
-                        jax.lax.dynamic_slice_in_dim(q, at, n, axis=1),
-                        ck_cache, p0 + at + jnp.arange(n), dims), starts)
-                # [runs, b, kv, n, :] -> [b, kv, s, :]
-                sel = jnp.moveaxis(sel, 0, 2).reshape(
-                    b, dims.kv_heads, s, dims.n_sel + 1)
+                sel, scorer = block_select(
+                    q.reshape(b, s, -1), ck_cache, p0, group=dims.group,
+                    head_dim=dims.head_dim, kernel_size=dims.kernel_size,
+                    stride=stride, block=dims.block_size,
+                    init_blocks=dims.init_blocks,
+                    window_blocks=dims.window_blocks, n_sel=dims.n_sel,
+                    scale=dims.scale)
             qt = q.reshape(b, s, dims.kv_heads, dims.group, dims.head_dim)
             qt = qt.transpose(0, 2, 1, 3, 4).reshape(
                 b, dims.kv_heads, s * dims.group, dims.head_dim)
             o = sparse_attention(
                 qt, k_cache, v_cache, sel, p0, group=dims.group,
                 block=dims.block_size, window_blocks=dims.window_blocks,
-                init_blocks=dims.init_blocks, scale=dims.scale)
+                init_blocks=dims.init_blocks, scale=dims.scale, scorer=scorer)
             o = o.reshape(b, dims.kv_heads, s, dims.group, dims.head_dim)
             o = o.transpose(0, 2, 1, 3, 4).reshape(b, s, -1)
         else:

@@ -172,27 +172,38 @@ def _kernel(p0_ref, sel_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     jax.lax.fori_loop(0, block, query, 0)
 
 
-def record_plan(context, total, n_blocks, topk, forced, block, tile, form):
+def record_plan(context, total, n_blocks, topk, forced, block, tile, form,
+                scorer=None):
     """One zero-length ``sparse.plan`` span for each sparse attention traced:
     its context, blocks and which form it took (``selected``: this kernel;
-    ``dense``: the flash kernel or a plain product, ``layers/sala.py``)."""
+    ``dense``: the flash kernel or a plain product, ``layers/sala.py``), and
+    the form of the scorer that chose the blocks: ``kernel``
+    (``ops/block_select.py``, with its query tile, the compressed keys a
+    step of its walk and all of them: ``scorer``, its plan), ``jnp`` (the
+    plain scorer, one query a row) or ``none`` (no query selects)."""
     from ..core import profiler
 
+    if scorer is None:
+        scorer = {"scorer": "none" if form == "dense" else "jnp"}
+    else:
+        scorer = {"scorer": "kernel", **{f"scorer_{k}": v
+                                         for k, v in scorer.items()}}
     profiler.record_span(
         "sparse.plan", time.time_ns(), 0, context=context, cache_len=total,
         blocks=n_blocks, topk=topk, forced_blocks=forced, block=block,
-        row_tile=tile[0], key_tile=tile[1], form=form)
+        row_tile=tile[0], key_tile=tile[1], form=form, **scorer)
 
 
 def sparse_attention(q, k_cache, v_cache, sel, p0, *, group: int, block: int,
                      window_blocks: int, init_blocks: int, scale: float,
-                     interpret=None):
+                     scorer=None, interpret=None):
     """``q [b, n_kv, queries * group, d]`` (a query's ``group`` heads on
     consecutive rows; the queries are positions ``p0 ..``, ``p0`` a traced
     multiple of ``block``), the cache ``k_cache, v_cache [b, T, n_kv * d]``
     filled at least up to the last query, ``sel [b, n_kv, queries, n_sel +
     1]`` int32 (a query's chosen blocks, the ones that count first, then
-    their number) -> ``o`` like ``q``."""
+    their number) -> ``o`` like ``q``. ``scorer``: the plan of the kernel
+    that made ``sel``, for the record."""
     b, n_kv, rows, d = q.shape
     total = k_cache.shape[1]
     n_sel = sel.shape[-1] - 1
@@ -215,7 +226,7 @@ def sparse_attention(q, k_cache, v_cache, sel, p0, *, group: int, block: int,
     record_plan(total, total, total // block,
                  n_sel + window_blocks + init_blocks,
                  window_blocks + init_blocks, block, (row_tile, key_tile),
-                 "selected")
+                 "selected", scorer)
     kv = pl.BlockSpec((1, total, d), lambda bi, c, i, p: (bi, 0, c))
     qo = pl.BlockSpec((1, 1, block * group, d), lambda bi, c, i, p: (bi, c, i, 0))
     chosen = pl.BlockSpec((1, 1, block, n_sel + 1),

@@ -39,6 +39,8 @@ def test_kernel_phase_rehearsal():
         0, gpt_shape=(1, 2, 128, 64), transformer_shape=(1, 2, 64, 64),
         sala={"sparse": dict(rows=1, kv_heads=1, group=2, d=32, total=512,
                              queries=64, p0=448, window_blocks=2, n_sel=3),
+              "select": dict(rows=1, kv_heads=1, group=2, d=32, total=1024,
+                             queries=128, p0=896, window_blocks=2, topk=6),
               "lightning": dict(rows=1, heads=2, d=32, seq=512)})
 
 

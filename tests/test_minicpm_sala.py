@@ -366,7 +366,8 @@ def test_bfloat16_weights_are_held_in_bfloat16():
 def test_generator_emits_the_scorer_s_argmax_and_records_its_plans(highest):
     """Greedy ids are the argmax of the scorer's distributions under those
     ids; the trace leaves one ``decode.plan`` of two kinds of entry, a
-    ``prefill.plan``, and ``sparse.plan`` / ``lightning.plan`` a layer."""
+    ``prefill.plan``, and ``sparse.plan`` / ``lightning.plan`` a layer; a
+    ``sparse.plan`` says which form of the scorer chose its blocks."""
     prompt = np.random.RandomState(4).randint(3, VOCAB, (2, 384)).astype(np.int32)
     cfg = family.program_config(TINY)
     gen = pt.build(minicpm_sala.make_generator(cfg, max_new_tokens=5))
@@ -385,8 +386,19 @@ def test_generator_emits_the_scorer_s_argmax_and_records_its_plans(highest):
                                    + plan["state_bytes"])
     (pre,) = [s[4] for s in spans if s[0] == "prefill.plan"]
     assert (pre["chunk"], pre["chunks"]) == (128, 3)
-    forms = {s[4]["form"] for s in spans if s[0] == "sparse.plan"}
-    assert forms == {"selected"}
+    plans = [s[4] for s in spans if s[0] == "sparse.plan"]
+    assert {p["form"] for p in plans} == {"selected"}
+    # the scorer's form: a chunk's whole query tiles take the kernel (its
+    # plan says a tile of 128 queries and a walk of 32 of the 28 compressed
+    # keys a step), a step's one query a row the plain form
+    prefill = [p for p in plans if p["row_tile"]]
+    steps = [p for p in plans if not p["row_tile"]]
+    assert len(prefill) == len(steps) == 2      # a record a sparse layer
+    assert {p["scorer"] for p in prefill} == {"kernel"}
+    assert {(p["scorer_tile"], p["scorer_key_step"], p["scorer_keys"])
+            for p in prefill} == {(128, 32, 28)}
+    assert {p["scorer"] for p in steps} == {"jnp"}
+    assert not any(k.startswith("scorer_") for p in steps for k in p)
     assert {s[4]["state_dtype"] for s in spans
             if s[0] == "lightning.plan"} == {"float32"}
     logp, _ = scored(TINY, prompt, ids[:, :-1], params)

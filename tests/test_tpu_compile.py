@@ -288,7 +288,8 @@ def _sala_generator(chip, monkeypatch, layers):
         sys.path.insert(0, root)
     from benchmarks.families import minicpm_sala as family
     from paddle_tpu.models import minicpm_sala
-    from paddle_tpu.ops import lightning_attention, sparse_attention
+    from paddle_tpu.ops import block_select, lightning_attention
+    from paddle_tpu.ops import sparse_attention
 
     with open(os.path.join(root, "benchmarks", "configs",
                            "minicpm-sala.json")) as f:
@@ -298,7 +299,7 @@ def _sala_generator(chip, monkeypatch, layers):
     rows, prompt, new = 2, 32768, 128
     prog = pt.build(minicpm_sala.make_generator(
         family.program_config(cell_config), max_new_tokens=new))
-    for module in (fa, sparse_attention, lightning_attention):
+    for module in (fa, sparse_attention, lightning_attention, block_select):
         monkeypatch.setattr(module, "default_interpret", lambda: False)
     one_row = np.zeros((1, prompt), np.int32)
     shapes = jax.eval_shape(lambda key: prog.init(key, prompt_ids=one_row)[0],
@@ -339,7 +340,10 @@ def test_sala_carried_state_is_lane_dense_and_in_place_for_v5e(chip, monkeypatch
     assert not moved, moved[0][:300]
     assert any(re.search(r"f32\[2,32,128,128\]\S* (fusion|dynamic-update-slice)"
                          r"\(", ln) for ln in step)
-    for kernel, calls in (("sparse_fwd", 1), ("lightning_fwd", 2)):
+    # (the benchmark's readers pick a kernel's calls by the name's prefix:
+    # the scorer's kernel must not read as a ``sparse_fwd``)
+    for kernel, calls in (("sparse_fwd", 1), ("lightning_fwd", 2),
+                          ("select_fwd", 1)):
         found = [ln for ln in text.splitlines() if re.search(
             r"%%\S*%s\S* = .*tpu_custom_call" % kernel, ln)]
         assert len(found) == calls, (kernel, len(found))
@@ -350,6 +354,40 @@ def test_sala_carried_state_is_lane_dense_and_in_place_for_v5e(chip, monkeypatch
                 wide = _minor_dim(shape)
                 assert wide is None or wide % 128 == 0 or shape.startswith(
                     ("s32", "f32[32]")), (shape, ln[:200])
+
+
+    # the scorer's kernel writes indices alone: no float32 array with the
+    # compressed keys (2,056, or 2,560 reordered and padded) as its minor
+    # axis holds more than a step's one query a row and head has (the
+    # parent's prefill held f32[2,2,16,512,2056] in a loop body)
+    scores = {m.group(0) for m in re.finditer(r"f32\[([\d,]*),(?:2056|2560)\]",
+                                              text)
+              if np.prod([int(x) for x in m.group(1).split(",")]) > 2 * 32}
+    assert not scores, scores
+
+
+@pytest.mark.parametrize("context", [32896, 131200], ids=["32k", "128k"])
+def test_select_kernel_compiles_for_v5e(chip, context):
+    """``select_fwd`` alone at the cell's shapes (a chunk of 2 x 4,096
+    queries of 32 heads against 2,056 compressed keys, the 31 highest of 514
+    blocks) and at four times the context: a tile's score and accumulator
+    scratch and a key head's reordered keys within the VMEM a kernel may
+    ask for, every slice on the (8, 128) tiling."""
+    from paddle_tpu.ops import block_select
+
+    rows, s, heads, d, kv = 2, 4096, 32, 128, 2
+    q = jax.ShapeDtypeStruct((rows, s, heads * d), jnp.bfloat16, sharding=chip)
+    ck = jax.ShapeDtypeStruct((rows, context // 16, kv * d), jnp.bfloat16,
+                              sharding=chip)
+    p0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda q, ck, p0: block_select.block_select(
+        q, ck, p0, group=heads // kv, head_dim=d, kernel_size=32, stride=16,
+        block=64, init_blocks=1, window_blocks=32, n_sel=31, scale=d ** -0.5,
+        interpret=False)[0]).lower(q, ck, p0).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"select_fwd\S* = .*tpu_custom_call", text)) == 1
+    assert f"s32[{rows},{kv},32,{s}]" in text     # indices, queries on lanes
+    assert not re.search(r"f32\[[\d,]*,%d\]" % (context // 16), text)
 
 
 def test_sala_generator_fits_one_v5e(chip, monkeypatch):
