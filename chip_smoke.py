@@ -303,9 +303,65 @@ def lightning_case(name: str, seed: int, rows=2, heads=32, d=128,
         check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
 
 
+def retention_case(name: str, seed: int, rows=1, heads=10, kv_heads=2, d=128,
+                   seq=576) -> None:
+    """``retention_fwd`` and ``retention_step`` at the published head shapes
+    (5 query heads of 128 to a key head, 8,704 products held a head) from a
+    random float32 state, against their ``jnp`` forms: two whole chunks and
+    a tail, then one token."""
+    from paddle_tpu.ops import power_retention as pr
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(keys[0], (rows, seq, heads * d), jnp.bfloat16)
+    q = (q.astype(jnp.float32) * d ** -0.5).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(key, (rows, seq, kv_heads * d), jnp.bfloat16)
+            for key in keys[1:3])
+    log_gamma = jax.nn.log_sigmoid(
+        4.85 + 0.7 * jax.random.normal(keys[3], (rows, seq, kv_heads)))
+    # a state as some hundred earlier tokens leave it (positive key sums)
+    split = lambda a, n: a.reshape(rows, -1, n, d)
+    early = [jax.random.normal(key, (rows, 128, kv_heads * d), jnp.bfloat16)
+             for key in keys[4:6]]
+    _, state = jax.jit(pr.retention_chunk)(
+        split(q[:, :128], heads), split(early[0], kv_heads),
+        split(early[1], kv_heads), log_gamma[:, :128],
+        pr.empty_state(rows, kv_heads, d))
+    kernel = jax.jit(lambda *a: pr.retention(*a, heads, kv_heads))
+    step = jax.jit(lambda *a: pr.retention_step(*a, heads, kv_heads))
+    args = (q, k, v, log_gamma, state)
+    one = tuple(a[:, 0] for a in args[:4]) + (state,)
+    calls = (kernel.lower(*args).as_text().count("tpu_custom_call"),
+             step.lower(*one).as_text().count("tpu_custom_call"))
+    got, left = kernel(*args)
+    want, want_left = jax.jit(pr.retention_chunk)(
+        split(q, heads), split(k, kv_heads), split(v, kv_heads), log_gamma, state)
+    got1, left1 = step(*one)
+    want1, want_left1 = jax.jit(pr.retention_step_plain)(
+        split(q, heads)[:, 0], split(k, kv_heads)[:, 0], split(v, kv_heads)[:, 0],
+        log_gamma[:, 0], state)
+    errs = {}
+    for label, a, r in (("out", got, want.reshape(rows, seq, -1)),
+                        ("state", left, want_left),
+                        ("step out", got1, want1.reshape(rows, -1)),
+                        ("step state", left1, want_left1)):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        check(np.isfinite(a).all(), f"{name}: {label} is not finite")
+        errs[label] = float(np.abs(a - r).max() / np.abs(r).max())
+    say("kernel", f"{name} {q.shape} heads={heads} over {kv_heads}: "
+        f"tpu_custom_call in the lowered calls: {calls}; error over largest "
+        f"reference value: " + ", ".join(f"{k} {v:.2e}" for k, v in errs.items()))
+    check(max(errs.values()) <= BF16_TOL,
+          f"{name}: the retention kernels differ from their jnp forms by "
+          f"{max(errs.values()):.2e}")
+    if on_tpu():
+        check(calls == (1, 1), f"{name}: {calls} tpu_custom_call, expected one each")
+
+
 def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
-                 transformer_shape=(32, 8, 256, 64), sala=None) -> None:
-    with timed("kernel", "eight cases, compiles included"):
+                 transformer_shape=(32, 8, 256, 64), sala=None,
+                 brumby=None) -> None:
+    with timed("kernel", "nine cases, compiles included"):
+        retention_case("brumby_retention", seed + 8, **(brumby or {}))
         sparse_case("sala_sparse", seed + 5, **(sala or {}).get("sparse", {}))
         select_case("sala_select", seed + 7, **(sala or {}).get("select", {}))
         lightning_case("sala_lightning", seed + 6,

@@ -92,8 +92,9 @@ def beam_search(
 
 
 def greedy_search(step_fn, init_state, batch_size: int, max_len: int,
-                  bos_id: int = 1, eos_id: int = 2):
-    """Greedy decode (beam_size=1 fast path)."""
+                  bos_id: int = 1, eos_id: int = 2, with_state: bool = False):
+    """Greedy decode (beam_size=1 fast path). ``with_state``: return
+    ``(seqs, the state the last step left)``."""
     tokens0 = jnp.full((batch_size,), bos_id, jnp.int32)
     finished0 = jnp.zeros((batch_size,), jnp.bool_)
     seqs0 = jnp.zeros((batch_size, max_len), jnp.int32)
@@ -107,9 +108,9 @@ def greedy_search(step_fn, init_state, batch_size: int, max_len: int,
         finished = finished | (nxt == eos_id)
         return (nxt, finished, seqs, new_state), None
 
-    (tokens, finished, seqs, _), _ = jax.lax.scan(
+    (tokens, finished, seqs, state), _ = jax.lax.scan(
         step, (tokens0, finished0, seqs0, init_state), jnp.arange(max_len))
-    return seqs
+    return (seqs, state) if with_state else seqs
 
 
 def beam_search_decode(step_ids, step_parents, end_id: int = 2, name=None):

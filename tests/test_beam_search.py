@@ -51,6 +51,27 @@ def test_greedy_matches_beam1():
     np.testing.assert_array_equal(np.asarray(g), np.asarray(b)[:, 0])
 
 
+def test_greedy_returns_the_last_state_on_request():
+    """``with_state``: the same ids, and the state the last step left."""
+    vocab = 6
+    rng = np.random.RandomState(1)
+    table = jnp.asarray(jax.nn.log_softmax(
+        jnp.asarray(rng.randn(vocab, vocab).astype(np.float32)), axis=-1))
+
+    def step_fn(tokens, state):
+        return jnp.take(table, tokens, axis=0), {"n": state["n"] + 1,
+                                                 "last": tokens}
+
+    state0 = {"n": jnp.zeros((), jnp.int32), "last": jnp.zeros((3,), jnp.int32)}
+    want = greedy_search(step_fn, state0, batch_size=3, max_len=5)
+    got, state = greedy_search(step_fn, state0, batch_size=3, max_len=5,
+                               with_state=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(state["n"]) == 5
+    np.testing.assert_array_equal(np.asarray(state["last"]),
+                                  np.asarray(got)[:, -2])
+
+
 def _train_tiny_copy_model(max_steps=400, target_loss=0.35):
     cfg = transformer.base_config(src_vocab=12, trg_vocab=12, d_model=32,
                                   d_inner=64, num_heads=4, num_encoder_layers=1,

@@ -41,7 +41,8 @@ def test_kernel_phase_rehearsal():
                              queries=64, p0=448, window_blocks=2, n_sel=3),
               "select": dict(rows=1, kv_heads=1, group=2, d=32, total=1024,
                              queries=128, p0=896, window_blocks=2, topk=6),
-              "lightning": dict(rows=1, heads=2, d=32, seq=512)})
+              "lightning": dict(rows=1, heads=2, d=32, seq=512)},
+        brumby=dict(rows=1, heads=4, kv_heads=2, d=16, seq=300))
 
 
 def test_a_failed_check_raises(monkeypatch):

@@ -408,6 +408,80 @@ def test_sala_generator_fits_one_v5e(chip, monkeypatch):
                - m.temp_size_in_bytes) < 0.2e9
 
 
+def test_brumby_generator_fits_one_v5e_and_carries_its_states_in_place(
+        chip, monkeypatch):
+    """The ``brumby-serve-decode`` generator (16 rows, prompt 1,024 + 256
+    new, bfloat16, the benchmark's configuration file: 8 layers, the whole
+    vocabulary) compiled for one described chip: 8.40 GB of arguments and
+    5.32 GB of temporaries, 4.85 GB of them the eight states
+    ``f32[16,8,136,8704]`` (8,704 products held a head, the key sum on the
+    sublane behind a value's 128), under 14.5 GB together, so ISSUE 39's 16
+    rows stand; the configuration file's ``memory`` group records what this
+    compile said. Every state is tiled (8, 128) with nothing padded, both
+    kernels are there once a layer, and neither loop, nor the audit that
+    reads a slice of one state behind them, copies or transposes a state: a
+    second 4.85 GB would not fit. A one-row step walks every FFN matrix in
+    whole rows (no float32 ``[16, 17408]`` product, whose walk in strips of
+    512 columns ran at a speed fixed by the process: PERF.md section 6,
+    PR 39)."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import brumby as family
+    from paddle_tpu.models import brumby
+    from paddle_tpu.ops import power_retention
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "brumby-14b-pp5.json")) as f:
+        cell_config = json.load(f)
+    rows, prompt, new = 16, 1024, 256
+    prog = pt.build(brumby.make_generator(
+        family.program_config(cell_config), max_new_tokens=new))
+    monkeypatch.setattr(power_retention, "default_interpret", lambda: False)
+    one_row = np.zeros((1, prompt), np.int32)
+    shapes = jax.eval_shape(lambda key: prog.init(key, prompt_ids=one_row)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
+    ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]
+                       ).lower(params, ids).compile()
+    m = compiled.memory_analysis()
+    # ids and the audit of one key head: 16 x 1,279 positions of k and v
+    assert 11e6 < m.output_size_in_bytes < 12e6
+    assert 8.39e9 < m.argument_size_in_bytes < 8.41e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9
+    recorded = cell_config["memory"]
+    assert abs(recorded["generator_weights_bytes"]
+               - m.argument_size_in_bytes) < 1e6
+    assert abs(recorded["generator_rows_16_temporaries_bytes"]
+               - m.temp_size_in_bytes) < 0.2e9
+    held = 8 * rows * 8 * 136 * 8704 * 4
+    assert recorded["state_bytes_as_held"] == held < m.temp_size_in_bytes
+    text = compiled.as_text()
+    carried = r"f32\[16,8,136,8704\]"
+    # arrays are what computations outside fusions hold (inside one, a
+    # "copy" under the audit's slice is an index map)
+    fused = set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", text))
+    outside = [ln for name, body in _computations(text).items()
+               if name not in fused for ln in body]
+    states = set(re.findall(carried + r"\{[^}]*\}", "\n".join(outside)))
+    assert states and all(s.split("{")[1].startswith(("3,2,1,0:T(8,128)",
+                                                      "3,2,1,0}"))
+                          for s in states), states
+    moved = [ln for ln in outside if re.search(
+        r"= %s\S* (copy|transpose|copy-start)\(" % carried, ln)]
+    assert not moved, moved[0][:300]
+    for kernel in ("retention_fwd", "retention_step"):
+        found = re.findall(r"%%\S*%s\S* = .*tpu_custom_call" % kernel, text)
+        assert len(found) == 8, (kernel, len(found))
+    products = re.findall(r"= (\w+)\[16,17408\]\S* fusion\(.*decode_step/ffn/", text)
+    assert len(products) >= 16 and set(products) == {"bf16"}, products
+
+
 def _minor_dim(shape):
     """The minor dimension's size of ``bf16[32,1024,16,64]{3,1,2,0:T(8,128)}``
     (the first index in the braces names it), or None for a scalar."""
