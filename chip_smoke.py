@@ -368,10 +368,109 @@ def retention_case(name: str, seed: int, rows=1, heads=10, kv_heads=2, d=128,
         check(calls == (1, 1), f"{name}: {calls} tpu_custom_call, expected one each")
 
 
+def mamba_case(name: str, seed: int, rows=2, d_inner=5120, d_state=16,
+               seq=150) -> None:
+    """``mamba_fwd`` at the published widths from a random float32 state
+    against the token-by-token ``lax.scan``: two whole blocks and a tail."""
+    from paddle_tpu.ops import selective_scan as ss
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    delta = jax.nn.softplus(jax.random.normal(keys[0], (rows, seq, d_inner)) - 4)
+    u = delta * jax.random.normal(keys[1], (rows, seq, d_inner))
+    b, c = (jax.random.normal(key, (rows, seq, d_state)) for key in keys[2:4])
+    a = -jnp.broadcast_to(jnp.arange(1.0, d_state + 1)[:, None],
+                          (d_state, d_inner))
+    state = jax.random.normal(keys[4], (rows, d_state, d_inner))
+    kernel = jax.jit(ss.selective_scan)
+    args = (delta, u, b, c, a, state)
+    calls = kernel.lower(*args).as_text().count("tpu_custom_call")
+    errs = {}
+    for label, got, want in zip(("out", "state"), kernel(*args),
+                                jax.jit(ss.mamba_scan)(*args)):
+        got, want = np.asarray(got), np.asarray(want)
+        check(np.isfinite(got).all(), f"{name}: {label} is not finite")
+        errs[label] = float(np.abs(got - want).max() / np.abs(want).max())
+    say("kernel", f"{name} {delta.shape} x {d_state}: tpu_custom_call in the "
+        f"lowered call: {calls}; error over largest reference value: "
+        + ", ".join(f"{k} {v:.2e}" for k, v in errs.items()))
+    check(max(errs.values()) <= 1e-4,
+          f"{name}: mamba_fwd differs from the scan by {max(errs.values()):.2e}")
+    if on_tpu():
+        check(calls == 1, f"{name}: {calls} tpu_custom_call, expected one")
+
+
+def window_case(name: str, seed: int, shape=(2, 8, 512, 64), window=512) -> None:
+    """The windowed ``flash_fwd`` as a prefill piece calls it (``window``
+    keys held before the piece's own, a value twice a score's width, the
+    first keys not there yet) against dense masked attention."""
+    b, h, s, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    k = jax.random.normal(keys[1], (b, h, window + s, d), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (b, h, window + s, 2 * d), jnp.bfloat16)
+    bias = jnp.broadcast_to(jnp.where(jnp.arange(window + s) < window // 2,
+                                      -1e9, 0.0)[None], (b, window + s))
+    got = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, key_bias=bias, window=window))(q, k, v)
+    row = jnp.arange(s)[:, None] + window
+    col = jnp.arange(window + s)[None, :]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    scores = jnp.where((col <= row) & (col > row - window), scores + bias[:, None, None],
+                       -1e30)
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1),
+                      v.astype(jnp.float32))
+    err = float(jnp.abs(got.astype(jnp.float32) - want).max()
+                / jnp.abs(want).max())
+    say("kernel", f"{name} q {q.shape} k {k.shape} window {window}: error over "
+        f"largest reference value {err:.2e}")
+    check(np.isfinite(np.asarray(got, np.float32)).all() and err <= BF16_TOL,
+          f"{name}: the windowed flash_fwd differs from dense attention by "
+          f"{err:.2e}")
+
+
+def phi4_flash_case(name: str, seed: int, hidden=512, heads=8, window=128,
+                    vocab=1024, rows=2, prompt=600, new=8, chunk=256) -> None:
+    """The ``models/phi4_flash.py`` generator at a small width, eight
+    layers (two periods of the pattern), bfloat16: a prompt of two pieces
+    and a tail, past the window; the audited state against its definition
+    from the audit's own inputs."""
+    from paddle_tpu.models import phi4_flash
+
+    cfg = phi4_flash.base_config(
+        vocab_size=vocab, hidden_size=hidden, num_hidden_layers=8,
+        num_attention_heads=heads, num_key_value_heads=heads // 2,
+        intermediate_size=2 * hidden, sliding_window=window,
+        prefill_chunk=chunk)
+    prog = pt.build(phi4_flash.make_generator(cfg, max_new_tokens=new))
+    ids = jax.random.randint(jax.random.PRNGKey(seed), (rows, prompt), 3, vocab)
+    params, _ = prog.init(jax.random.PRNGKey(seed + 1), prompt_ids=ids)
+    out = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0])(params, ids)
+    out = {k: np.asarray(v, np.float64) for k, v in out.items()}
+    check(out["ids"].shape == (rows, new) and (out["ids"] >= 0).all()
+          and (out["ids"] < vocab).all(), f"{name}: ids {out['ids']}")
+    # as created, A = -(1 .. d_state) for every channel
+    delta, u, b = out["audit_delta"], out["audit_u"], out["audit_b"]
+    after = np.cumsum(delta[:, ::-1], axis=1)[:, ::-1] - delta
+    a = -np.arange(1.0, b.shape[-1] + 1)
+    want = np.einsum("rtnc,rtc,rtn->rnc",
+                     np.exp(a[:, None] * after[:, :, None, :]), u, b)
+    err = float(np.abs(out["audit_state"] - want).max() / np.abs(want).max())
+    say("kernel", f"{name}: {rows} x ({prompt} + {new}) through 8 layers, "
+        f"audited state over {delta.shape[1]} positions: error over largest "
+        f"reference value {err:.2e}")
+    check(np.isfinite(err) and err <= 1e-4,
+          f"{name}: the carried state differs from its definition by {err:.2e}")
+
+
 def kernel_phase(seed: int, gpt_shape=(BATCH, 12, SEQ, 64),
                  transformer_shape=(32, 8, 256, 64), sala=None,
-                 brumby=None) -> None:
-    with timed("kernel", "nine cases, compiles included"):
+                 brumby=None, phi4=None) -> None:
+    with timed("kernel", "twelve cases, compiles included"):
+        mamba_case("phi4_mamba", seed + 9, **(phi4 or {}).get("mamba", {}))
+        window_case("phi4_window", seed + 10, **(phi4 or {}).get("window", {}))
+        phi4_flash_case("phi4_generator", seed + 11,
+                        **(phi4 or {}).get("generator", {}))
         retention_case("brumby_retention", seed + 8, **(brumby or {}))
         sparse_case("sala_sparse", seed + 5, **(sala or {}).get("sparse", {}))
         select_case("sala_select", seed + 7, **(sala or {}).get("select", {}))

@@ -202,6 +202,7 @@ class FlashPlan(NamedTuple):
     layout: str = "bhsd"  # the operands' layout: ``bhsd``, or ``bsd`` (packed)
     lane_heads: int = 0   # bsd: heads a 128-lane group of the minor dimension
     backward: str = "split"   # ``fused``: one backward kernel (:func:`_backward`)
+    window: int = 0       # keys a causal query sees, itself included; 0: all
 
 
 def _round_up(n, m):
@@ -266,6 +267,24 @@ def _chunk_bounds(r0, tile_q, c_base, n_chunks, tile_k, *, causal, offset,
     return n_plain, n_end
 
 
+def _window_bounds(r0, tile_q, c_base, n_chunks, tile_k, bounds, *, offset,
+                   window):
+    """:func:`_chunk_bounds` under a sliding window (a causal row ``r``
+    sees keys ``r + offset - window < c <= r + offset``): ``(n_start,
+    n_lead, n_plain, n_end)``. Chunks before ``n_start`` lie wholly behind
+    the first row's window and are not visited, ``[n_start, n_lead)`` are
+    cut by the window's edge and ``[n_plain, n_end)`` by the diagonal (or
+    hold padded keys): both take the mask; ``[n_lead, n_plain)`` take
+    none."""
+    n_plain, n_end = bounds
+    first = r0 + offset - window + 1 - c_base    # first key of the first row
+    n_start = _clip(first // tile_k, 0, n_chunks)
+    n_lead = _clip((first + tile_q - 1 + tile_k - 1) // tile_k, 0, n_chunks)
+    n_end = _clip(n_end, n_start, n_chunks)
+    n_plain = _clip(n_plain, n_start, n_end)
+    return n_start, _clip(n_lead, n_start, n_plain), n_plain, n_end
+
+
 def _backward(p: FlashPlan, itemsize) -> str:
     """``fused`` or ``split`` (the module's docstring) for a call with plan
     ``p`` and operands of ``itemsize`` bytes: one kernel where a grid step
@@ -296,7 +315,8 @@ def lane_heads(d, dv, num_heads) -> int:
 
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
-                bh=1, dv=None, scale=None, num_heads=None) -> FlashPlan:
+                bh=1, dv=None, scale=None, num_heads=None,
+                window=0) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -316,7 +336,12 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     where a step's block of the minor dimension can be whole 128-lane
     groups (:func:`lane_heads`); a step then holds whole groups of one
     batch row. Else the call is laid out ``[b, h, s, d]`` first
-    (``bhsd``), which is also what a rank-4 call is."""
+    (``bhsd``), which is also what a rank-4 call is.
+
+    ``window`` (causal calls, forward only): a query sees its own key and
+    the ``window - 1`` before it. The walk skips the key tiles that lie
+    wholly behind a query tile's window and masks the tile its edge cuts;
+    ``tiles_run`` counts what is left. 0: no window."""
     del have_bias, have_seg
     dv = d if dv is None else dv
     packed = lane_heads(d, dv, num_heads)
@@ -329,10 +354,15 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     offset = sk - sq
     run = 0
     for r0 in range(0, sq_p, tile_q):
-        _, n_end = _chunk_bounds(r0, tile_q, 0, sk_p // tile_k, tile_k,
-                                 causal=causal, offset=offset, sk=sk,
-                                 sk_p=sk_p)
-        run += n_end
+        bounds = _chunk_bounds(r0, tile_q, 0, sk_p // tile_k, tile_k,
+                               causal=causal, offset=offset, sk=sk,
+                               sk_p=sk_p)
+        n_start, n_end = 0, bounds[1]
+        if window:
+            n_start, _, _, n_end = _window_bounds(
+                r0, tile_q, 0, sk_p // tile_k, tile_k, bounds, offset=offset,
+                window=window)
+        run += n_end - n_start
     tiles_all = (sq_p // tile_q) * (sk_p // tile_k)
 
     # heads a step: until the step computes STEP_SCORES scores, inside
@@ -362,7 +392,7 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     plan = FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
                      sq_p, sk_p, causal, fold, run, tiles_all, dv, *layout)
     return plan._replace(
-        backward=_backward(plan, jnp.dtype(dtype).itemsize))
+        backward=_backward(plan, jnp.dtype(dtype).itemsize), window=window)
 
 
 def _record_plan(p: FlashPlan):
@@ -375,7 +405,7 @@ def _record_plan(p: FlashPlan):
         block_q=p.block_q, block_k=p.block_k, tile_q=p.tile_q,
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
-        lane_heads=p.lane_heads, backward=p.backward)
+        lane_heads=p.lane_heads, backward=p.backward, window=p.window)
 
 
 # ---------------------------------------------------------------------------
@@ -536,6 +566,8 @@ def _scores(keys, queries, r0, c0, w: _Walk, *, masked, bias_col, segq_row,
         if w.plan.causal:
             r = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             keep = r + w.offset >= c
+            if w.plan.window:
+                keep &= r + w.offset - w.plan.window < c
         if w.plan.sk != w.plan.sk_p:
             pad = c < w.plan.sk
             keep = pad if keep is None else keep & pad
@@ -596,23 +628,33 @@ def _block_runs(qb, kb, w: _Walk):
     """Does q block ``qb`` see any key of key block ``kb``?"""
     if not w.plan.causal:
         return True
-    return kb * w.plan.block_k < (qb + 1) * w.plan.block_q + w.offset
+    runs = kb * w.plan.block_k < (qb + 1) * w.plan.block_q + w.offset
+    if w.plan.window:   # the block's last key is in the first row's window
+        runs &= ((kb + 1) * w.plan.block_k
+                 > qb * w.plan.block_q + w.offset - w.plan.window + 1)
+    return runs
 
 
-def _kj_clamp(causal, block_q, block_k, nk, offset):
+def _kj_clamp(causal, block_q, block_k, nk, offset, window=0):
     """Index clamp for K/V-side blocks in causal kernels: grid steps
     past a q block's last useful key block keep requesting the SAME
     block index, and Pallas's pipelining skips the HBM→VMEM DMA when the
     index does not change — the compute for those steps is already
     gated off, so without this the skipped upper-triangle blocks still
     paid their K/V fetch bandwidth. Last useful kj for q block qi:
-    floor(((qi+1)·bq + offset − 1)/bk), clamped to [0, nk−1]."""
+    floor(((qi+1)·bq + offset − 1)/bk), clamped to [0, nk−1]. Under a
+    ``window`` the steps before the first useful key block, the one that
+    holds key qi·bq + offset − window + 1, request that block."""
     if not causal:
         return lambda kk, j: kk
 
     def clamp(kk, j):
         last = ((j + 1) * block_q + offset - 1) // block_k
-        return jnp.minimum(kk, jnp.clip(last, 0, nk - 1))
+        kk = jnp.minimum(kk, jnp.clip(last, 0, nk - 1))
+        if window:
+            first = (j * block_q + offset - window + 1) // block_k
+            kk = jnp.maximum(kk, jnp.clip(first, 0, nk - 1))
+        return kk
     return clamp
 
 
@@ -819,8 +861,16 @@ def _fwd_kernel(*refs, w: _Walk):
             return body
 
         row, at = (g, pl.ds(qt, 1)), w.acc_at(g, qt)
-        m, l, acc = _two_loops(
-            n_plain, n_end, chunk, (m_scr[row], l_scr[row], acc_scr[at]), w)
+        carry = (m_scr[row], l_scr[row], acc_scr[at])
+        if p.window:
+            n_start, n_lead, n_plain, n_end = _window_bounds(
+                r0, tq, c_base, w.nkt, tk, (n_plain, n_end), offset=w.offset,
+                window=p.window)
+            carry = _loop(n_start, n_lead, chunk(True), carry)
+            carry = _loop(n_lead, n_plain, chunk(False), carry)
+            m, l, acc = _loop(n_plain, n_end, chunk(True), carry)
+        else:
+            m, l, acc = _two_loops(n_plain, n_end, chunk, carry, w)
         m_scr[row], l_scr[row], acc_scr[at] = m, l, acc
 
         @pl.when(kv == last_kv)
@@ -874,7 +924,8 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
         cq = _qi_clamp(p.causal, p.block_q, p.block_k, w.nq, w.offset)
         q_at, k_at = (lambda j, kk: cq(kk, j)), (lambda j, kk: j)
     else:
-        ck = _kj_clamp(p.causal, p.block_q, p.block_k, w.nk, w.offset)
+        ck = _kj_clamp(p.causal, p.block_q, p.block_k, w.nk, w.offset,
+                       p.window)
         q_at, k_at = (lambda j, kk: j), (lambda j, kk: ck(kk, j))
 
     def block(rows, width, at, nth=0):
@@ -903,7 +954,7 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
 
 
 def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-             scale=None, num_heads=None):
+             scale=None, num_heads=None, window=0):
     """(plan, walk, operands, b, h) of one call: ``[b, h, s, d]`` operands,
     ``[b, s, num_heads * d]`` ones that :func:`lane_heads` admits, or,
     with ``k`` and ``v`` None, ``q`` as the three of them fused,
@@ -920,7 +971,7 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
     scale = 1.0 / math.sqrt(d) if scale is None else scale
     p = plan_blocks(sq, sk, d, q.dtype, causal, bias is not None,
                     seg_q is not None, block_q, block_k, bh=b * h,
-                    dv=dv, scale=scale, num_heads=num_heads)
+                    dv=dv, scale=scale, num_heads=num_heads, window=window)
     w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
     return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h), b, h
 
@@ -928,14 +979,14 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
                interpret: bool, scale: Optional[float] = None,
-               num_heads: Optional[int] = None):
+               num_heads: Optional[int] = None, window: int = 0):
     """``q`` and ``k`` are ``[b, h, s, d]`` and ``v`` ``[b, h, s_k, dv]``:
     the scores contract over ``d``, the output rows are ``dv`` wide. With
     ``num_heads`` they are ``[b, s, num_heads * d]`` (or fused in ``q``,
     :func:`_planned`) and so is the output; lse is ``[b, h, s_q]`` for
     both."""
     p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                               block_k, scale, num_heads)
+                               block_k, scale, num_heads, window)
     _record_plan(p)
     bh, g, nq, nk, dv = b * h, p.heads, w.nq, w.nk, p.dv
     sp = _specs(ops, p, w, h)
@@ -1318,6 +1369,7 @@ def flash_attention(
     return_lse: bool = False,
     scale: Optional[float] = None,
     num_heads: Optional[int] = None,
+    window: int = 0,
 ):
     """Flash attention over ``q``, ``k`` [b, h, s, d] and ``v``
     [b, h, s_k, dv]; the output is [b, h, s_q, dv]. ``dv`` may differ
@@ -1347,8 +1399,26 @@ def flash_attention(
     - ``return_lse``: also return the per-query logsumexp [b, h, s_q]
       (forward only — used by ring attention to merge shards).
     - ``scale``: the softmax scale; None is ``d ** -0.5``.
+    - ``window``: with ``causal``, a query sees its own key and the
+      ``window - 1`` before it (a sliding window; key tiles wholly behind
+      it are skipped, :func:`plan_blocks`). 0 is no window. Rank-4
+      operands and the forward only: no backward kernel takes one (ROADMAP
+      Reach).
     """
     from ..core.errors import enforce
+
+    if window:
+        enforce(causal and q.ndim == 4 and k is not None and attn_mask is None
+                and segment_ids is None,
+                "flash_attention: a window takes causal [b, h, s, d] q, k, v "
+                "and at most a key bias")
+        block_q, block_k = resolve_block_shapes(block_q, block_k)
+        out = _flash_fwd(
+            q, k, v, None if key_bias is None else key_bias.astype(jnp.float32),
+            None, None, True, block_q, block_k,
+            default_interpret() if interpret is None else interpret, scale,
+            None, window)
+        return out if return_lse else out[0]
 
     fused = k is None
     if fused:

@@ -42,7 +42,11 @@ def test_kernel_phase_rehearsal():
               "select": dict(rows=1, kv_heads=1, group=2, d=32, total=1024,
                              queries=128, p0=896, window_blocks=2, topk=6),
               "lightning": dict(rows=1, heads=2, d=32, seq=512)},
-        brumby=dict(rows=1, heads=4, kv_heads=2, d=16, seq=300))
+        brumby=dict(rows=1, heads=4, kv_heads=2, d=16, seq=300),
+        phi4={"mamba": dict(rows=1, d_inner=256, seq=150),
+              "window": dict(shape=(1, 2, 128, 32), window=96),
+              "generator": dict(hidden=64, heads=4, window=24, vocab=503,
+                                rows=1, prompt=200, new=4, chunk=80)})
 
 
 def test_a_failed_check_raises(monkeypatch):

@@ -797,3 +797,30 @@ def test_projection_layout_lse_and_dense_mask(shape):
         masked4 = fa.flash_attention(*apart, attn_mask=dense)
     np.testing.assert_allclose(np.asarray(masked),
                                np.asarray(_heads_together(masked4)), atol=1e-6)
+
+
+# the plans PR 40's tree gave these calls (the GPT cells' fused projection at
+# both widths, the serve cells' prefill, latent attention's 192 / 128, a
+# streamed call), field for field: ``window`` is a new last field, 0 for them
+@pytest.mark.parametrize("kw,want", [
+    (dict(sq=1024, sk=1024, d=64, causal=True, bh=32 * 16, num_heads=16),
+     (1024, 1024, 64, 1024, 1024, 512, 512, 2, 1024, 1024, True, True, 3, 4,
+      64, "bsd", 2, "fused")),
+    (dict(sq=1024, sk=1024, d=64, causal=True, bh=16 * 10, num_heads=10),
+     (1024, 1024, 64, 1024, 1024, 512, 512, 2, 1024, 1024, True, True, 3, 4,
+      64, "bsd", 2, "fused")),
+    (dict(sq=896, sk=896, d=64, causal=True, bh=16 * 16, num_heads=16),
+     (896, 896, 64, 896, 896, 896, 448, 2, 896, 896, True, True, 2, 2, 64,
+      "bsd", 2, "fused")),
+    (dict(sq=1984, sk=1984, d=192, dv=128, causal=True, bh=8 * 64, scale=0.1),
+     (1984, 1984, 192, 2048, 2048, 512, 512, 1, 2048, 2048, True, False, 10,
+      16, 128, "bhsd", 0, "split")),
+    (dict(sq=4096, sk=4096, d=128, causal=True, bh=8),
+     (4096, 4096, 128, 1024, 1024, 512, 512, 2, 4096, 4096, True, False, 36,
+      64, 128, "bhsd", 0, "split"))],
+    ids=["gpt2m_train", "gpt2l_train", "gpt2m_prefill", "k25_prefill",
+         "streamed"])
+def test_a_call_without_a_window_plans_as_it_did(kw, want):
+    plan = fa.plan_blocks(dtype=jnp.bfloat16, **kw)
+    assert tuple(plan) == want + (0,)
+    assert fa.FlashPlan._fields[-1] == "window"

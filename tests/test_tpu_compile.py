@@ -512,6 +512,115 @@ def test_brumby_generator_fits_one_v5e_and_carries_its_states_in_place(
     assert len(products) >= 16 and set(products) == {"bf16"}, products
 
 
+def test_the_window_and_the_scan_compile_for_v5e_at_the_cells_shapes(chip):
+    """``phi4flash-serve-reason``'s two kernels alone at a prefill piece's
+    shapes (32 rows x 512 tokens): the windowed ``flash_fwd`` over the 512
+    keys held and the piece's own, 40 heads scoring over 64 and summing 128
+    wide under a key bias, and ``mamba_fwd`` over 5,120 channels x 16 states;
+    one ``tpu_custom_call`` each, by its name."""
+    from paddle_tpu.ops import selective_scan as ss
+
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=chip)
+    f32 = jnp.float32
+    text = jax.jit(lambda q, k, v, bias: fa.flash_attention(
+        q, k, v, causal=True, key_bias=bias, window=512, interpret=False)
+    ).lower(shape(32, 40, 512, 64), shape(32, 40, 1024, 64),
+            shape(32, 40, 1024, 128), shape(32, 1024, dtype=f32)
+            ).compile().as_text()
+    assert len(re.findall(r"%\S*flash_fwd\S* = .*tpu_custom_call", text)) == 1
+    text = jax.jit(lambda *a: ss.selective_scan(*a, interpret=False)).lower(
+        shape(32, 512, 5120, dtype=f32), shape(32, 512, 5120, dtype=f32),
+        shape(32, 512, 16, dtype=f32), shape(32, 512, 16, dtype=f32),
+        shape(16, 5120, dtype=f32), shape(32, 16, 5120, dtype=f32)
+    ).compile().as_text()
+    assert len(re.findall(r"%\S*mamba_fwd\S* = .*tpu_custom_call", text)) == 1
+
+
+def test_phi4_flash_generator_fits_one_v5e_and_its_steps_copy_no_cache(
+        chip, monkeypatch):
+    """The ``phi4flash-serve-reason`` generator (32 rows, prompt 2,048 + 256
+    new, bfloat16, the benchmark's configuration file: all 32 layers, the
+    whole vocabulary) compiled for one described chip: 7.71 GB of arguments
+    and 3.89 GB of temporaries, under 14.5 GB together, so ISSUE 41's 32
+    rows stand; the configuration file's ``memory`` group records what this
+    compile said. Both kernels are there once a layer that has them (the
+    prefill's scan holds them; a step runs neither). The decode loop copies
+    or transposes none of what it carries (states ``f32[32,16,5120]``, rings
+    ``bf16[32,512,1280]``, the shared ``bf16[32,2304,1280]``): each is tiled
+    (8, 128) with nothing padded and written in place. No one-row product of
+    a step is walked in narrow strided strips (a float32 result's
+    ``kernel_window_bounds`` of the contraction's eighth by a handful of
+    lane groups: PERF.md section 6, PR 39; section 7, "After PR 39" (2))."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import phi4_flash as family
+    from paddle_tpu.models import phi4_flash
+    from paddle_tpu.ops import selective_scan as ss
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "phi4-mini-flash.json")) as f:
+        cell_config = json.load(f)
+    rows, prompt, new = 32, 2048, 256
+    prog = pt.build(phi4_flash.make_generator(
+        family.program_config(cell_config), max_new_tokens=new))
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    monkeypatch.setattr(ss, "default_interpret", lambda: False)
+    params = {name: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+              for name, s in family.parameter_table(cell_config).items()}
+    ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]
+                       ).lower(params, ids).compile()
+    m = compiled.memory_analysis()
+    assert 7.70e9 < m.argument_size_in_bytes < 7.72e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9
+    recorded = cell_config["memory"]
+    assert abs(recorded["generator_weights_bytes"]
+               - m.argument_size_in_bytes) < 1e6
+    assert abs(recorded["generator_rows_32_temporaries_bytes"]
+               - m.temp_size_in_bytes) < 0.2e9
+    assert abs(recorded["generator_outputs_bytes"] - m.output_size_in_bytes) < 1e6
+    carried = (recorded["state_bytes"] + recorded["window_kv_bytes"]
+               + recorded["shared_kv_bytes"])
+    assert 1.1e9 < carried < m.temp_size_in_bytes
+    text = compiled.as_text()
+    calls = {kernel: len(re.findall(r"%%\S*%s\S* = .*tpu_custom_call" % kernel,
+                                    text))
+             for kernel in ("mamba_fwd", "flash_fwd")}
+    assert calls == {"mamba_fwd": 9, "flash_fwd": 8}
+    comps = _computations(text)
+    fused = set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", text))
+    loops = re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text)
+    # the decode loop is the one that holds the step's conditional
+    (decode,) = [name for name in loops
+                 if any(" conditional(" in ln for ln in comps[name])]
+    assert not any("tpu_custom_call" in ln for ln in comps[decode])
+    held = r"(f32\[32,16,5120\]|bf16\[32,512,1280\]|bf16\[32,2304,1280\])"
+    moved = [ln for ln in comps[decode] if re.search(
+        r"= %s\S* (copy|transpose|copy-start)\(" % held, ln)]
+    assert not moved, moved[0][:300]
+    layouts = set(re.findall(held + r"(\{[^}]*\})", "\n".join(comps[decode])))
+    assert layouts and all(
+        layout.startswith("{2,1,0:T(8,128)") for _, layout in layouts), layouts
+    # the step's one-row products: none walked in strips of under eight
+    # lane groups of a whole contraction (2,560 / 5,120 / 10,240 rows are
+    # 320 / 640 / 1,280 sublane groups)
+    walked = 0
+    for ln in text.splitlines():
+        if "decode_step/" in ln and "dot_general" in ln and " fusion(" in ln:
+            m = re.search(r'"kernel_window_bounds":\["(\d+)","(\d+)"\]', ln)
+            walked += bool(m)
+            if m and int(m.group(1)) in (320, 640, 1280):
+                assert int(m.group(2)) >= 8, ln[:300]
+    assert walked >= 100
+    gates = re.findall(r"= (\w+)\[32,10240\]\S* fusion\(.*decode_step/ffn/", text)
+    assert len(gates) >= 32 and set(gates) == {"bf16"}, gates
+
+
 def _minor_dim(shape):
     """The minor dimension's size of ``bf16[32,1024,16,64]{3,1,2,0:T(8,128)}``
     (the first index in the braces names it), or None for a scalar."""
