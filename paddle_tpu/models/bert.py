@@ -34,7 +34,7 @@ class BertConfig:
     # involuntary-remat resharding XLA's partitioner hits on the dense
     # head's scatter-grad under fsdp
     fused_ce: bool = False
-    ce_chunk: int = 4096
+    ce_chunk: int = 4096         # rows of a chunk, the whole vocabulary each
     # per-block jax.checkpoint over encoder layers (memory_optimize analog)
     remat: bool = False
     dtype: str = "float32"
@@ -92,12 +92,11 @@ def make_pretrain_model(cfg: BertConfig):
         bias = helper.create_parameter("b", (cfg.vocab_size,), dtype,
                                        initializer=init.Constant(0.0))
         if cfg.fused_ce:
-            from ..ops.fused_ce import chunked_softmax_cross_entropy
-            m = h.shape[1]
-            ce = chunked_softmax_cross_entropy(
-                h.reshape(b * m, cfg.d_model), w, bias,
-                mlm_labels.reshape(-1).astype(jnp.int32), 0.0, cfg.ce_chunk)
-            mlm_loss = jnp.mean(ce)
+            from ..ops.fused_ce import softmax_cross_entropy_sum
+            rows = b * h.shape[1]
+            mlm_loss = softmax_cross_entropy_sum(
+                h.reshape(rows, cfg.d_model), w, bias, mlm_labels.reshape(-1),
+                jnp.full((rows,), 1.0 / rows), 0.0, cfg.ce_chunk)
         else:
             mlm_logits = jnp.matmul(h, w) + bias
             mlm_loss = L.mean(L.softmax_with_cross_entropy(mlm_logits, mlm_labels))

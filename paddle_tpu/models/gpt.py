@@ -44,7 +44,7 @@ class GPTConfig:
     num_layers: int = 12
     use_flash: bool = True
     fused_ce: bool = True
-    ce_chunk: int = 4096
+    ce_chunk: int = 4096         # rows of a chunk of the fused head
     remat: bool = False
     # residual/softmax/ffn dropout inside the stacked blocks (per-layer
     # rng via framework.rng_fold; rate > 0 disables the flash kernel the

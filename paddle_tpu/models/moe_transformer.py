@@ -42,7 +42,7 @@ class MoeTransformerConfig:
     dropout: float = 0.0
     use_flash: bool = False
     fused_ce: bool = True
-    ce_chunk: int = 4096
+    ce_chunk: int = 4096         # rows of a chunk of the fused head
     dtype: str = "float32"
 
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from .. import layers as L
 from ..framework import LayerHelper, maybe_remat, name_scope
 from ..layers import attention as A
-from ..ops.fused_ce import chunked_softmax_cross_entropy
+from ..ops.fused_ce import softmax_cross_entropy_sum
 from .. import initializer as init
 
 
@@ -39,7 +39,8 @@ class TransformerConfig:
     # one [d,3,d] (self) / [d,2,d] (cross K/V) projection matmul per
     # attention instead of three — see layers/attention.py fuse_qkv
     fuse_qkv: bool = False
-    # chunked logits-free CE (ops/fused_ce.py); chunk = vocab tile width
+    # chunked logits-free CE (ops/fused_ce.py); ce_chunk = rows of a chunk
+    # (the whole vocabulary a chunk)
     fused_ce: bool = False
     ce_chunk: int = 4096
     # per-block jax.checkpoint: drop intra-layer activations, recompute
@@ -249,10 +250,9 @@ def make_model(cfg: TransformerConfig):
             # logits (ops/fused_ce.py) — the LM-head HBM hot spot.
             x, w = decode_hidden(trg_ids, enc_out, src_mask, cfg)
             b, t, d = x.shape
-            ce = chunked_softmax_cross_entropy(
-                x.reshape(b * t, d), w, None, lab.reshape(-1), eps,
-                cfg.ce_chunk).reshape(b, t)
-            loss = jnp.sum(ce * nonpad) / token_count
+            loss = softmax_cross_entropy_sum(
+                x.reshape(b * t, d), w, None, lab.reshape(-1),
+                (nonpad / token_count).reshape(-1), eps, cfg.ce_chunk)
             return {"loss": loss, "token_count": token_count}
         logits = decode(trg_ids, enc_out, src_mask, cfg)
         # Label-smoothed CE without materializing a [b,t,vocab] one-hot:

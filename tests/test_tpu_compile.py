@@ -621,6 +621,43 @@ def test_phi4_flash_generator_fits_one_v5e_and_its_steps_copy_no_cache(
     assert len(gates) >= 32 and set(gates) == {"bf16"}, gates
 
 
+def test_loss_head_makes_three_products_a_chunk_for_v5e(chip):
+    """gpt2-medium's loss head at the train cell's shape (32 x 1,024 rows,
+    50,257 columns, bfloat16) under ``jax.value_and_grad``: the loop over
+    its 8 chunks of 4,096 rows holds three products, each over 50,304
+    columns (the vocabulary to the next 128) and each under scope ``ce``;
+    the program makes no fourth (the backward pass scales what the forward
+    made), holds no logits of all the rows at once, and its temporaries are
+    1.34 GB (a chunk's float32 logits, 824 MB, and what the products keep
+    beside them)."""
+    from paddle_tpu.ops.fused_ce import softmax_cross_entropy_sum
+    from paddle_tpu.profiling.fusion import scope_path
+
+    rows, d, vocab, chunk = 32 * 1024, 1024, 50257, 4096
+
+    def loss(h, w, labels):
+        nonpad = (labels != 0).astype(jnp.float32)
+        return softmax_cross_entropy_sum(
+            h, w, None, labels, nonpad / jnp.maximum(nonpad.sum(), 1.0),
+            0.0, chunk)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=chip),
+        jax.ShapeDtypeStruct((d, vocab), jnp.bfloat16, sharding=chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=chip)).compile()
+    text = compiled.as_text()
+    products = [ln for ln in text.splitlines() if " convolution(" in ln]
+    shapes = sorted(re.search(r"= (f32\[\d+,\d+\])", ln).group(1)
+                    for ln in products)
+    assert shapes == ["f32[1024,50304]", "f32[4096,1024]",
+                      "f32[4096,50304]"], shapes
+    for ln in products:
+        op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "ce" in scope_path(op_name) and "/while/body/" in op_name, ln[:300]
+    assert not re.search(r"f32\[%d,\d" % rows, text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def _minor_dim(shape):
     """The minor dimension's size of ``bf16[32,1024,16,64]{3,1,2,0:T(8,128)}``
     (the first index in the braces names it), or None for a scalar."""
