@@ -11,10 +11,16 @@ linear in the latent. That gives the block two forms, which agree
 (``tests/test_kimi_k2.py``):
 
 - **expanded** (:func:`mla_prefill`): per head ``[k_nope | v] = c_kv W_kvb``,
-  scores over ``[q_nope | q_rope] . [k_nope | k_rope]`` (192 wide), values
-  128 wide, through the flash kernel (``ops/flash_attention.py`` takes the
-  value width from ``v``). ``k_rope`` is copied to every head for the
-  kernel: one 192-wide product a tile, not a 128-wide and a 64-wide one.
+  scores ``q_nope . k_nope + q_rope . k_rope`` (128 + 64 wide), values 128
+  wide, through the flash kernel. The two parts of a score travel apart
+  from the projection to the softmax: ``q_nope``, ``k_nope``, ``v`` and the
+  output are ``[b, s, H * 128]`` as their matmuls leave and take them,
+  ``q_rope`` is ``[b, s, H * 64]`` and ``k_rope`` ``[b, s, 64]`` once, the
+  kernel's second score operand (``ops/flash_attention.py``, ``q_rot`` /
+  ``k_rot``). No array is 192 wide: a head of 192 lies astride the
+  128-lane groups the TPU tiles a minor dimension in, so every array that
+  held one was kept at 256 lanes and copied to get there. The kernel puts
+  a tile's two parts side by side in VMEM, where that costs nothing.
 - **absorbed** (:func:`mla_decode`): ``W_kvb`` moves onto the query and the
   output (``q' = q_nope W_k^T``, 512 a head; ``o = (P c_kv) W_v``), so a
   cached step reads 576 numbers a token and layer, whatever the head count,
@@ -25,6 +31,12 @@ The cache is what the absorbed form reads, stored so that the TPU's
 128 lanes) and the rotary keys transposed, ``[rows, 64, T]`` (positions on
 lanes; ``q_rope @ that`` is the rotary part of every score with no
 transposition). One ``[rows, T, 576]`` slab would be held at 640 lanes.
+
+The parameter table keeps the published layouts (``q_b/w [q_lora, H *
+(nope + rope)]``, a head's 128 and 64 side by side). :func:`regrouped`
+makes of it what both forms read, once, outside a generator's scans and
+its loop: ``q_b``'s columns as every head's ``nope`` and every head's
+``rope``, and ``kv_b``'s halves flat for the prefill's products.
 
 Rotary pairs are ``(2i, 2i + 1)``; the published code de-interleaves and
 rotates halves, the same map up to one fixed permutation of the 64 rotary
@@ -42,7 +54,7 @@ import jax.numpy as jnp
 
 from .. import initializer as init
 from ..framework import LayerHelper
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, padded_rows
 from .stacked import NEG_INF, StackedInit
 
 
@@ -205,25 +217,70 @@ def gated_ffn_params(d_model: int, width: int, dtype,
 # -- the block's two forms ----------------------------------------------------------
 
 
+def regrouped(p: Dict[str, jax.Array], dims: MLADims) -> Dict[str, jax.Array]:
+    """``p`` (one block's parameters, or a stack's: any leading axes) as
+    both forms read it. ``q_b/w [.., q_lora, H * (nope + rope)]`` becomes
+    ``q_b/w_nope [.., q_lora, H * nope]`` and ``q_b/w_rope [.., q_lora, H *
+    rope]``, so that each product's result splits into heads on whole lane
+    groups and a one-token step reads the weight as it is held (a view of
+    the published columns a head at a time made the compiler lay the whole
+    weight out anew, every layer of every step). ``kv_b_k/w`` and
+    ``kv_b_v/w`` gain their flat forms ``[.., kv_lora, H * nope]`` and
+    ``[.., kv_lora, H * v]`` beside them, for the prefill's keys and values
+    as ``[b, s, H * 128]``. A copy, so a generator makes it once a request,
+    outside its scans and its loop; a ``p`` that has been through here comes
+    back as it is."""
+    if "q_b/w" not in p:
+        return p
+    h = dims.heads
+    p = dict(p)
+    w = p.pop("q_b/w")
+    lead = w.shape[:-1]
+    w = w.reshape(lead + (h, dims.qk))
+    p["q_b/w_nope"] = w[..., :dims.nope].reshape(lead + (h * dims.nope,))
+    p["q_b/w_rope"] = w[..., dims.nope:].reshape(lead + (h * dims.rope,))
+    k, v = p["kv_b_k/w"], p["kv_b_v/w"]     # [.., H, nope, c], [.., H, c, v]
+    lead = k.shape[:-3]
+    p["kv_b_k/w_flat"] = jnp.moveaxis(k, -1, -3).reshape(
+        lead + (dims.kv_lora, h * dims.nope))
+    p["kv_b_v/w_flat"] = jnp.moveaxis(v, -3, -2).reshape(
+        lead + (dims.kv_lora, h * dims.v))
+    return p
+
+
 def _record_plan(dims: MLADims, form: str, seq: int, scale: float):
     """One zero-length span in the program's ring for each attention
-    traced: its widths and which form it took."""
+    traced: its widths and which form it took. ``score_parts``: the widths
+    a score's products contract over, ``[nope, rope]`` apart in both forms
+    (``[nope + rope]`` would say one head is built; no caller does)."""
     from ..core import profiler
 
     profiler.record_span(
         "mla.plan", time.time_ns(), 0, heads=dims.heads, q_lora=dims.q_lora,
         kv_lora=dims.kv_lora, rope_dim=dims.rope, nope_dim=dims.nope,
-        v_dim=dims.v, form=form, seq=seq, softmax_scale=scale)
+        v_dim=dims.v, form=form, seq=seq, softmax_scale=scale,
+        score_parts=[dims.nope, dims.rope])
 
 
-def _queries(h, p, dims: MLADims, positions, freqs, cs_scale):
-    """``(q_nope [b, s, H, nope], q_rope [b, s, H, rope])`` of the normed
-    input ``h [b, s, d]``, the rotary part rotated."""
-    b, s, _ = h.shape
-    c_q = rms_norm(jnp.matmul(h, p["q_a/w"]), p["q_norm/g"], dims.eps)
-    q = jnp.matmul(c_q, p["q_b/w"]).reshape(b, s, dims.heads, dims.qk)
-    return q[..., :dims.nope], rope(q[..., dims.nope:], positions, freqs,
-                                    cs_scale, head_axis=True)
+def _queries(c_q, p, dims: MLADims, positions, freqs, cs_scale):
+    """``(q_nope [b, s, H * nope], q_rope [b, s, H * rope])`` of the query
+    latents ``c_q [b, s, q_lora]`` (:func:`_query_latents`), heads side by
+    side as the two products leave them, the rotary part rotated; ``p`` as
+    :func:`regrouped` leaves it."""
+    b, s, _ = c_q.shape
+    # the flat product as it is, behind a barrier: left free, the compiler
+    # moves the views below (a head's 64, a pair's 2) onto the weight, and a
+    # step copies every layer's ``[H, rope / 2, 2, q_lora]`` to get them
+    q_rope = jax.lax.optimization_barrier(jnp.matmul(c_q, p["q_b/w_rope"]))
+    q_rope = rope(q_rope.reshape(b, s, dims.heads, dims.rope), positions,
+                  freqs, cs_scale, head_axis=True)
+    return (jnp.matmul(c_q, p["q_b/w_nope"]),
+            q_rope.reshape(b, s, dims.heads * dims.rope))
+
+
+def _query_latents(h, p, dims: MLADims):
+    """``c_q [b, s, q_lora]`` of the normed input ``h``, after its norm."""
+    return rms_norm(jnp.matmul(h, p["q_a/w"]), p["q_norm/g"], dims.eps)
 
 
 def _latents(h, p, dims: MLADims, positions, freqs, cs_scale):
@@ -238,23 +295,30 @@ def mla_prefill(x, p, dims: MLADims, y: Yarn):
     """The expanded form over a whole prompt: ``x [b, s, d]`` ->
     ``(x + attention, (c_kv [b, s, kv_lora], k_rope [b, rope, s]))``, the
     pair being this layer's cache entries in the cache's own layout."""
-    b, s, _ = x.shape
-    positions, freqs = jnp.arange(s), yarn_frequencies(dims.rope, y)
+    s = x.shape[1]
+    # the rows the kernel pads a call of s to. The latents are padded to
+    # them here, 2,112 numbers a token, and the products leave q, k and v
+    # that long: padded inside the call they were 20,544 a token, copied
+    rows = padded_rows(s)
+    positions, freqs = jnp.arange(rows), yarn_frequencies(dims.rope, y)
     scale, cs = softmax_scale(dims, y), yarn_cos_sin_scale(y)
     _record_plan(dims, "expanded", s, scale)
+    p = regrouped(p, dims)
+
+    def grown(a):
+        return jnp.pad(a, ((0, 0), (0, rows - s), (0, 0)))
+
     with jax.named_scope("mla"):
         h = rms_norm(x, p["attn_norm/g"], dims.eps)
-        q_nope, q_rope = _queries(h, p, dims, positions, freqs, cs)
-        c_kv, k_rope = _latents(h, p, dims, positions, freqs, cs)
-        k_nope = jnp.einsum("bsc,hnc->bhsn", c_kv, p["kv_b_k/w"])
-        v = jnp.einsum("bsc,hcv->bhsv", c_kv, p["kv_b_v/w"])
-        q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, None], (b, dims.heads, s,
-                                                        dims.rope))], axis=-1)
-        o = flash_attention(q, k, v, causal=True, scale=scale)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, dims.heads * dims.v)
-        x = x + jnp.matmul(o, p["o/w"])
+        c_kv, k_rope = _latents(h, p, dims, positions[:s], freqs, cs)
+        q_nope, q_rope = _queries(grown(_query_latents(h, p, dims)), p, dims,
+                                  positions, freqs, cs)
+        c_long = grown(c_kv)
+        o = flash_attention(
+            q_nope, jnp.matmul(c_long, p["kv_b_k/w_flat"]),
+            jnp.matmul(c_long, p["kv_b_v/w_flat"]), num_heads=dims.heads,
+            causal=True, scale=scale, q_rot=q_rope, k_rot=grown(k_rope))
+        x = x + jnp.matmul(o[:, :s], p["o/w"])
     return x, (c_kv, k_rope.transpose(0, 2, 1))
 
 
@@ -264,30 +328,33 @@ def mla_decode(x, p, c_cache, r_cache, index, dims: MLADims, y: Yarn):
     [rows, rope, T]`` written in place at ``index`` and read as stored;
     positions ``<= index`` attended. Returns ``(x + attention, c_cache,
     r_cache)``."""
-    T = c_cache.shape[1]
+    rows, T = x.shape[0], c_cache.shape[1]
     positions, freqs = index[None], yarn_frequencies(dims.rope, y)
     scale, cs = softmax_scale(dims, y), yarn_cos_sin_scale(y)
     _record_plan(dims, "absorbed", T, scale)
+    p = regrouped(p, dims)
     with jax.named_scope("mla"):
         h = rms_norm(x, p["attn_norm/g"], dims.eps)
-        q_nope, q_rope = _queries(h, p, dims, positions, freqs, cs)
+        q_nope, q_rope = (
+            q.reshape(rows, dims.heads, -1)
+            for q in _queries(_query_latents(h, p, dims), p, dims, positions,
+                              freqs, cs))
         c_new, r_new = _latents(h, p, dims, positions, freqs, cs)
         c_cache = jax.lax.dynamic_update_slice(
             c_cache, c_new.astype(c_cache.dtype), (0, index, 0))
         r_cache = jax.lax.dynamic_update_slice(
             r_cache, r_new.transpose(0, 2, 1).astype(r_cache.dtype),
             (0, 0, index))
-        q_lat = jnp.einsum("rhn,hnc->rhc", q_nope[:, 0], p["kv_b_k/w"])
+        q_lat = jnp.einsum("rhn,hnc->rhc", q_nope, p["kv_b_k/w"])
         s = (jnp.einsum("rhc,rtc->rht", q_lat, c_cache,
                         preferred_element_type=jnp.float32)
-             + jnp.einsum("rhe,ret->rht", q_rope[:, 0], r_cache,
+             + jnp.einsum("rhe,ret->rht", q_rope, r_cache,
                           preferred_element_type=jnp.float32)) * scale
         live = jnp.arange(T)[None, None, :] <= index
         probs = jax.nn.softmax(jnp.where(live, s, NEG_INF), axis=-1)
         o_lat = jnp.einsum("rht,rtc->rhc", probs.astype(x.dtype), c_cache)
         o = jnp.einsum("rhc,hcv->rhv", o_lat, p["kv_b_v/w"])
-        x = x + jnp.matmul(o.reshape(x.shape[0], 1, dims.heads * dims.v),
-                           p["o/w"])
+        x = x + jnp.matmul(o.reshape(rows, 1, dims.heads * dims.v), p["o/w"])
     return x, c_cache, r_cache
 
 
@@ -298,5 +365,5 @@ def ffn_block(x, p, eps: float = 1e-5):
 
 
 __all__ = ["MLADims", "Yarn", "ffn_block", "gated_ffn", "gated_ffn_params",
-           "mla_decode", "mla_params", "mla_prefill", "rms_norm", "rope",
-           "softmax_scale", "yarn_cos_sin_scale", "yarn_frequencies"]
+           "mla_decode", "mla_params", "mla_prefill", "regrouped", "rms_norm",
+           "rope", "softmax_scale", "yarn_cos_sin_scale", "yarn_frequencies"]

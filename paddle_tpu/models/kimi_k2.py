@@ -34,7 +34,12 @@ are one stack of ``[L - dense, ...]`` parameters: under ``lax.scan`` in the
 prefill, written out in the step (a scanned step copied the whole cache at
 every layer to change its layout: the compile for a described v5e, PERF.md
 section 6). The routed experts' banks are never sliced: every layer reads
-its experts in place (``moe_held``'s ``bank_offset``).
+its experts in place (``moe_held``'s ``bank_offset``). The attention's
+``q_b`` and ``kv_b`` are regrouped once a request, before the scans and the
+loop (``layers/latent.regrouped``: a head's 128 apart from its rotary 64),
+and the step takes each layer's two halves of ``q_b`` as arrays of their
+own: a view of the published columns, or a slice of a stack, cost a copy of
+the weight in every layer of every step.
 """
 
 from __future__ import annotations
@@ -169,10 +174,12 @@ def _expert_ffn(cfg: KimiK2Config, x, p, banks, layer):
             + routed.reshape(b, s, d)).astype(x.dtype)
 
 
-def _record_decode_plan(cfg: KimiK2Config, c, r):
+def _record_decode_plan(cfg: KimiK2Config, c, r, regrouped):
     """The latent cache the decode loop carries, beside GPT's
     ``decode.plan``: ``lane_width`` is the minor dimension of a latent
-    slab as stored (a rotary slab's is the context length)."""
+    slab as stored (a rotary slab's is the context length);
+    ``q_b_regrouped_bytes`` what the request's one regrouping of ``q_b``
+    wrote (``regrouped``: the stacks that hold its halves)."""
     from ..core import profiler
 
     rows, max_len, lane_width = c[0].shape
@@ -181,7 +188,10 @@ def _record_decode_plan(cfg: KimiK2Config, c, r):
         heads=cfg.num_attention_heads, layers=len(c), cache_kind="latent",
         cache_dtype=str(c[0].dtype), lane_width=lane_width,
         rope_lane_width=r[0].shape[-1],
-        cache_bytes=sum(a.size * a.dtype.itemsize for a in c + r))
+        cache_bytes=sum(a.size * a.dtype.itemsize for a in c + r),
+        q_b_regrouped_bytes=sum(
+            t[k].size * t[k].dtype.itemsize for t in regrouped
+            for k in ("q_b/w_nope", "q_b/w_rope")))
 
 
 def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
@@ -222,6 +232,15 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
                   for k in _BANKS)
     sliced = {k: v for k, v in stack.items() if k not in _BANKS}
     layer_ids = jnp.arange(n_exp)
+    # the attention's projections as both forms read them, once a request:
+    # a copy here, outside the scans and the loop, and none in them
+    with jax.named_scope("regroup"):
+        dense, sliced = M.regrouped(dense, dims), M.regrouped(sliced, dims)
+        # a step's q_b a layer, cut out of the stacks here as well: the
+        # written-out step then reads each half as it is held
+        step_q_b = [{k: t[k][i] for k in ("q_b/w_nope", "q_b/w_rope")}
+                    for t, n in ((dense, n_dense), (sliced, n_exp))
+                    for i in range(n)]
 
     def head(x_last):   # [rows, d] -> log-probs over the held rows
         with jax.named_scope("head"):
@@ -255,7 +274,7 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
         n_layers = cfg.num_hidden_layers
         c = [grow(a, 1) for a in list(c0) + list(c1)]   # [rows, total, kv_lora]
         r = [grow(a, 2) for a in list(r0) + list(r1)]   # [rows, rope, total]
-    _record_decode_plan(cfg, c, r)
+    _record_decode_plan(cfg, c, r, (dense, sliced))
     state0 = {"c": c, "r": r, "index": jnp.asarray(p_len, jnp.int32),
               "logp0": logp0, "first": jnp.asarray(True)}
 
@@ -275,6 +294,7 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
                 with jax.named_scope("stack_slice"):
                     lp = jax.tree.map(lambda a: a[i if j < 0 else j],
                                       dense if j < 0 else sliced)
+                    lp.update(step_q_b[i])
                 x, c[i], r[i] = M.mla_decode(x, lp, c[i], r[i], index, dims,
                                              yarn)
                 x = (M.ffn_block(x, lp, cfg.rms_norm_eps) if j < 0

@@ -67,9 +67,9 @@ projections leave q, k and v as ``[b, s, h*d]``, so every call on
 arrays (twelve a layer of a remat train step: PERF.md, PR 32). So the
 layout follows the call's shape, one tile walk for both:
 
-- rank-4 ``[b, h, s, d]`` operands (ring attention, Ulysses, latent
-  attention): ``bhsd``. A grid step's block is ``(heads, rows, d)`` of
-  the flattened ``(b*h, s, d)`` array and a head is a leading index.
+- rank-4 ``[b, h, s, d]`` operands (ring attention, Ulysses, a windowed
+  call): ``bhsd``. A grid step's block is ``(heads, rows, d)`` of the
+  flattened ``(b*h, s, d)`` array and a head is a leading index.
 - rank-3 ``[b, s, h*d]`` operands with their head count: ``bsd``, where
   :func:`lane_heads` finds whole 128-lane groups (a head 128-multiple
   wide, or ``128 // d`` narrower ones dividing the head count). A step's
@@ -85,11 +85,25 @@ layout follows the call's shape, one tile walk for both:
   and delta stay a head's. A fused self-attention projection ``[b, s, 3*h*d]``
   goes in as ``q`` alone and is passed three times with lane-block
   offsets, because three slices of it would be three copies. Shapes the
-  form does not fit (192 / 128 wide scores and values, an odd count of
-  64-wide heads) are transposed to ``bhsd`` inside the call.
+  form does not fit (one 192-wide head of scores over 128-wide values, an
+  odd count of 64-wide heads) are transposed to ``bhsd`` inside the call.
+- a ``bsd`` call with a rotary pair (latent attention's prefill, forward
+  only): scores that contract over 128 + 64 are not one 192-wide head,
+  which lies astride the lane groups, but two operands. ``q``, ``k``, ``v``
+  and the output are ``[b, s, h * 128]``, one head a lane group, as their
+  projections leave and take them; ``q_rot [b, s, h * 64]`` holds two
+  heads' rotary parts a group and ``k_rot [b, s, 64]`` has no head at all:
+  its block is a batch row's and stays while the heads walk past. The two
+  parts meet in VMEM: a step lays each head's keys and the row's ``k_rot``
+  side by side in scratch once, a query tile's two parts are joined when
+  it is taken (the head's rotary part cut out of its lane group,
+  :meth:`_Walk.rot_still`), and a tile's scores are one product over 128
+  + 64 whose sum forms in the MXU's float32 accumulator, two passes of the
+  128 x 128 array as before. In HBM nothing is concatenated, broadcast to
+  heads or transposed round the call.
 
-``flash.plan`` records ``layout``, ``lane_heads`` and ``backward`` for every
-call traced.
+``flash.plan`` records ``layout``, ``lane_heads``, ``backward``, ``window``
+and ``rot`` for every call traced.
 
 Masking: causal (bottom-right aligned), an additive per-key bias
 [b, s_k] (padding), and segment ids (the LoD ragged-batch equivalent,
@@ -203,6 +217,7 @@ class FlashPlan(NamedTuple):
     lane_heads: int = 0   # bsd: heads a 128-lane group of the minor dimension
     backward: str = "split"   # ``fused``: one backward kernel (:func:`_backward`)
     window: int = 0       # keys a causal query sees, itself included; 0: all
+    rot: int = 0          # width of a second score operand (q_rot, k_rot); 0: none
 
 
 def _round_up(n, m):
@@ -233,6 +248,15 @@ def _axis_plan(s, block, unit):
     tile = max(t for t in range(unit, min(TILE, s_p) + 1, unit)
                if s_p % t == 0)
     return s_p, s_p, tile if 2 * tile >= min(TILE, s_p) else s_p
+
+
+def padded_rows(s: int) -> int:
+    """The length a self-attention call of ``s`` rows is padded to inside
+    (queries pad furthest: whole lanes, then whole tiles). A caller whose
+    operands come out of products can make them that long by padding what
+    goes into the products, which may be far narrower, and cut the
+    output; rows past ``s`` are zeros, which causal queries never see."""
+    return _axis_plan(s, None, 128)[0]
 
 
 def _clip(v, lo, hi):
@@ -303,9 +327,11 @@ def lane_heads(d, dv, num_heads) -> int:
     """Heads a 128-lane group of ``[b, s, num_heads * d]`` operands, or 0
     where the kernels cannot read that layout: a head 128-multiple wide
     is its own group, ``128 // d`` narrower ones fill one where they
-    divide the head count, and anything else (192 / 128 wide scores and
-    values, an odd count of 64-wide heads) has a head astride a group's
-    edge."""
+    divide the head count, and anything else (one 192-wide head of scores
+    over 128-wide values, an odd count of 64-wide heads) has a head
+    astride a group's edge. Latent attention's 128 + 64 is no such shape
+    when its parts come apart: 128-wide heads here, their own groups, and
+    the 64 as the call's rotary pair (:func:`rot_lane_heads`)."""
     if num_heads is None or dv != d:
         return 0
     if d % 128 == 0:
@@ -313,10 +339,27 @@ def lane_heads(d, dv, num_heads) -> int:
     return 128 // d if 128 % d == 0 and num_heads % (128 // d) == 0 else 0
 
 
+def _rot_group(rot) -> int:
+    """Heads whose rotary parts fill a 128-lane group of ``q_rot`` (1 where
+    a part is whole groups itself)."""
+    return max(128 // rot, 1)
+
+
+def rot_lane_heads(d, dv, num_heads, rot) -> int:
+    """Heads a 128-lane group of a rotary operand ``q_rot [b, s, num_heads
+    * rot]``, or 0 where the kernel cannot take the pair in place: the
+    other operands' heads are their own lane groups (:func:`lane_heads` 1)
+    and the rotary parts fill whole groups by the same rule, two a group
+    (64 wide) or a part whole groups itself."""
+    if lane_heads(d, dv, num_heads) != 1 or rot % 64:
+        return 0
+    return lane_heads(rot, rot, num_heads)
+
+
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
                 bh=1, dv=None, scale=None, num_heads=None,
-                window=0) -> FlashPlan:
+                window=0, rot=0) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -328,7 +371,7 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     not change the blocks today; they are part of what a plan may depend
     on, and ``dtype`` decides with the shape whether the backward is one
     kernel (:func:`_backward`). ``d`` is the width the scores contract
-    over and ``dv`` that of a value (latent attention: 192 and 128);
+    over and ``dv`` that of a value (they may differ: 192 and 128);
     ``scale`` is the softmax scale where it is not ``d ** -0.5``.
 
     ``num_heads`` says the call's operands are ``[b, s, num_heads * d]``,
@@ -341,7 +384,14 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     ``window`` (causal calls, forward only): a query sees its own key and
     the ``window - 1`` before it. The walk skips the key tiles that lie
     wholly behind a query tile's window and masks the tile its edge cuts;
-    ``tiles_run`` counts what is left. 0: no window."""
+    ``tiles_run`` counts what is left. 0: no window.
+
+    ``rot`` (``bsd`` calls, forward only): the width of a second score
+    operand, ``q_rot [b, s, num_heads * rot]`` against one ``k_rot [b, s,
+    rot]`` that every head shares (latent attention's rotary 64 beside its
+    128). The walk and the tiles are the call's without it; a step holds
+    one head, or as many as start a lane group of ``q_rot``
+    (:func:`rot_lane_heads`). 0: none."""
     del have_bias, have_seg
     dv = d if dv is None else dv
     packed = lane_heads(d, dv, num_heads)
@@ -374,11 +424,13 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
         # out (a lane offset is static): as many as keep the step's code
         # within UNROLL tile bodies, or head bodies where tiles loop
         written = (sq_p, sk_p) == (block_q, block_k) and tiles_all <= UNROLL
-        step_bytes = 6 * max(block_q, block_k) * (d + dv) * 2
+        step_bytes = 6 * max(block_q, block_k) * (d + dv + rot) * 2
+        rot_heads = _rot_group(rot) if rot else 1
         heads = max(g for g in range(packed, num_heads + 1, packed)
                     if num_heads % g == 0 and (
                         g == packed or (
-                            g * step_scores <= STEP_SCORES
+                            g % rot_heads == 0
+                            and g * step_scores <= STEP_SCORES
                             and g * step_bytes <= STEP_BYTES
                             and g * (tiles_all if written else 1) <= UNROLL)))
         layout = ("bsd", packed)
@@ -392,7 +444,8 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     plan = FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
                      sq_p, sk_p, causal, fold, run, tiles_all, dv, *layout)
     return plan._replace(
-        backward=_backward(plan, jnp.dtype(dtype).itemsize), window=window)
+        backward=_backward(plan, jnp.dtype(dtype).itemsize), window=window,
+        rot=rot)
 
 
 def _record_plan(p: FlashPlan):
@@ -405,7 +458,8 @@ def _record_plan(p: FlashPlan):
         block_q=p.block_q, block_k=p.block_k, tile_q=p.tile_q,
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
-        lane_heads=p.lane_heads, backward=p.backward, window=p.window)
+        lane_heads=p.lane_heads, backward=p.backward, window=p.window,
+        rot=p.rot)
 
 
 # ---------------------------------------------------------------------------
@@ -543,6 +597,28 @@ class _Walk(NamedTuple):
         """Head ``g``'s leading index in a block of key bias or segment
         ids: they are a batch row's, which a packed step has one of."""
         return 0 if self.packed else g
+
+    @property
+    def rot_group(self):
+        """Heads a 128-lane group of ``q_rot`` holds where that is more
+        than the step's: the step's block is then the whole group and the
+        grid index says which part is its own (:meth:`rot_still`). Else 0:
+        the step's heads start a group and a head's lanes are static."""
+        n = _rot_group(self.plan.rot)
+        return 0 if self.plan.heads % n == 0 else n
+
+    def rot_still(self, ref, g, rows, step):
+        """Head ``g``'s ``rows`` of the step's block of ``q_rot``, ``[rows,
+        rot]``, cut out of its lane group once a query tile to stand
+        beside the head's ``q``. ``step``: the grid's first index, asked
+        for only under :attr:`rot_group`."""
+        r, n = self.plan.rot, self.rot_group
+        if not n:
+            return ref[0, rows, pl.ds(g * r, r)]
+        group = ref[0, rows, :]      # two heads' parts: n is 2
+        part = (step * self.plan.heads + g) % n
+        first = group[:, :r]
+        return jnp.where(part == 1, group[:, r:], first)
 
 
 def _scores(keys, queries, r0, c0, w: _Walk, *, masked, bias_col, segq_row,
@@ -695,6 +771,7 @@ class _Operands(NamedTuple):
     bias: Optional[jax.Array]
     segq: Optional[jax.Array]
     segk: Optional[jax.Array]
+    rot: tuple = ()     # (q_rot, k_rot) where the call has the pair
 
 
 def _kernel_form(x, s_p, p: FlashPlan):
@@ -715,7 +792,7 @@ def _user_form(x, s, like, p: FlashPlan):
     return x.reshape(like.shape[:2] + x.shape[1:])[:, :, :s]
 
 
-def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan, b, h):
+def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan, b, h, rot=()):
     """Pad the sequence axes to the plan and put the operands in kernel
     form. Padded keys are masked by their index inside the kernels (no
     bias is invented); padded q/k segment ids get distinct negative ids
@@ -748,7 +825,9 @@ def _prepare(q, k, v, bias, seg_q, seg_k, p: FlashPlan, b, h):
                       lead, nq, nqt, p.tile_q)
         seg_k = _rows(per_head(seg_k, p.sk_p, -2, jnp.int32),
                       lead, nk, nkt, p.tile_k)
-    return _Operands(q, k, v, bias, seg_q, seg_k)
+    rot = tuple(_kernel_form(x, s_p, p)
+                for x, s_p in zip(rot, (p.sq_p, p.sk_p)))
+    return _Operands(q, k, v, bias, seg_q, seg_k, rot)
 
 
 def _mask_specs(ops: _Operands, p: FlashPlan, q_map, k_map):
@@ -811,9 +890,12 @@ def _over_tiles(n_tiles, tile_body, w: _Walk):
 
 
 def _fwd_kernel(*refs, w: _Walk):
-    (q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, _,
-     (o_ref, lse_ref), (m_scr, l_scr, acc_scr)) = _split_refs(refs, w, 0, 2)
     p = w.plan
+    (q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, rot_refs,
+     (o_ref, lse_ref), (m_scr, l_scr, acc_scr, *rest)) = _split_refs(
+         refs, w, 2 if p.rot else 0, 2)
+    (qrot_ref, krot_ref), (keys_scr,) = (rot_refs, rest) if p.rot else (
+        (None, None), (None,))
     qb, kv = _grid_index(1, w.nq), _grid_index(2, w.nk)
     last_kv = w.nk - 1
     tq, tk = p.tile_q, p.tile_k
@@ -825,12 +907,17 @@ def _fwd_kernel(*refs, w: _Walk):
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     c_base = kv * p.block_k
+    step = pl.program_id(0) if p.rot and w.rot_group else None
 
     def q_tile(g, qt):
         q0 = _at(qt, tq)
         r0 = qb * p.block_q + q0
         mg = w.mask_row(g)
-        q = _fold_scale(w.still(q_ref, g, pl.ds(q0, tq)), w)
+        q = w.still(q_ref, g, pl.ds(q0, tq))
+        if p.rot:       # the tile's two score operands side by side, in VMEM
+            q = jnp.concatenate(
+                [q, w.rot_still(qrot_ref, g, pl.ds(q0, tq), step)], axis=1)
+        q = _fold_scale(q, w)
         segq = segq_ref[mg, 0, pl.ds(qt, 1), :] if w.have_seg else None
         n_plain, n_end = _chunk_bounds(r0, tq, c_base, w.nkt, tk,
                                        causal=p.causal, offset=w.offset,
@@ -842,8 +929,9 @@ def _fwd_kernel(*refs, w: _Walk):
                 k0 = _at(j, tk)
                 vb = w.moving(v_ref, g, pl.ds(k0, tk))
                 s = _scores(
-                    w.moving(k_ref, g, pl.ds(k0, tk)), q, r0, c_base + k0, w,
-                    masked=masked,
+                    (keys_scr[g, pl.ds(k0, tk), :] if p.rot
+                     else w.moving(k_ref, g, pl.ds(k0, tk))),
+                    q, r0, c_base + k0, w, masked=masked,
                     bias_col=_col(bias_ref, mg, j) if w.have_bias else None,
                     segq_row=segq,
                     segk_col=_col(segk_ref, mg, j) if w.have_seg else None)
@@ -887,6 +975,14 @@ def _fwd_kernel(*refs, w: _Walk):
     # entered to write the result, if it is the last
     @pl.when(_block_runs(qb, kv, w) | (kv == last_kv))
     def _step():
+        if p.rot:
+            # each head's keys with the batch row's one k_rot beside them,
+            # once a step: a tile's scores are then one product over d +
+            # rot, the sum of the two parts formed in the MXU's float32
+            # accumulator as a [.., d + rot] operand in HBM had it formed
+            for g in range(p.heads):
+                keys_scr[g, :, pl.ds(0, p.d)] = k_ref[w.head(g, slice(None))]
+                keys_scr[g, :, pl.ds(p.d, p.rot)] = krot_ref[0]
         _over_tiles(w.nqt, q_tile, w)
         if w.shared_lanes:
             pl.when(kv == last_kv)(
@@ -903,6 +999,7 @@ class _Specs(NamedTuple):
     qrow: pl.BlockSpec    # lse, delta: a row a head
     masks: list           # specs and arrays of bias, segq, segk
     mask_args: list
+    rot: list             # specs of q_rot and k_rot, or none
 
 
 def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
@@ -916,7 +1013,11 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
     ``i % per_row``; where q, k and v are one fused ``[b, s, 3 * h * d]``
     array (a projection's output, passed three times), k's and v's blocks
     lie ``per_row`` and ``2 * per_row`` lane blocks further on. The
-    per-query rows (lse, delta) are a head's in both layouts."""
+    per-query rows (lse, delta) are a head's in both layouts. A rotary
+    pair's ``q_rot [b, s, h * rot]`` goes by whole lane groups, the one a
+    step's heads start or, where they are fewer than a group's, lie in;
+    ``k_rot [b, s, rot]`` is a batch row's and stays while its heads walk
+    past (a block whose index does not change is not fetched again)."""
     g, packed = p.heads, p.layout == "bsd"
     per_row = h // g
     fused = packed and ops.q.shape[-1] == 3 * h * p.d
@@ -944,21 +1045,30 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
         return lambda i, j, kk: (i, at(j, kk), 0, 0)
 
     masks, mask_args = _mask_specs(ops, p, mask(q_at), mask(k_at))
+    rot = []
+    if p.rot:
+        span = max(g, _rot_group(p.rot))    # heads the q_rot block holds
+        rot = [pl.BlockSpec((1, p.block_q, span * p.rot),
+                            lambda i, j, kk: (i // per_row, q_at(j, kk),
+                                              i % per_row * g // span)),
+               pl.BlockSpec((1, p.block_k, p.rot),
+                            lambda i, j, kk: (i // per_row, k_at(j, kk), 0))]
     return _Specs(
         q=block(p.block_q, p.d, q_at), k=block(p.block_k, p.d, k_at, 1),
         v=block(p.block_k, p.dv, k_at, 2), o=block(p.block_q, p.dv, q_at),
         dk=block(p.block_k, p.d, k_at),
         qrow=pl.BlockSpec((g, 1, w.nqt, p.tile_q),
                           lambda i, j, kk: (i, q_at(j, kk), 0, 0)),
-        masks=masks, mask_args=mask_args)
+        masks=masks, mask_args=mask_args, rot=rot)
 
 
 def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-             scale=None, num_heads=None, window=0):
+             scale=None, num_heads=None, window=0, rot=()):
     """(plan, walk, operands, b, h) of one call: ``[b, h, s, d]`` operands,
     ``[b, s, num_heads * d]`` ones that :func:`lane_heads` admits, or,
     with ``k`` and ``v`` None, ``q`` as the three of them fused,
-    ``[b, s, 3 * num_heads * d]``."""
+    ``[b, s, 3 * num_heads * d]``; ``rot``: the ``(q_rot, k_rot)`` of a
+    ``[b, s, num_heads * d]`` call that :func:`rot_lane_heads` admits."""
     if num_heads is None:
         b, h, sq, d = q.shape
         sk, dv = k.shape[2], v.shape[-1]
@@ -971,22 +1081,24 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
     scale = 1.0 / math.sqrt(d) if scale is None else scale
     p = plan_blocks(sq, sk, d, q.dtype, causal, bias is not None,
                     seg_q is not None, block_q, block_k, bh=b * h,
-                    dv=dv, scale=scale, num_heads=num_heads, window=window)
+                    dv=dv, scale=scale, num_heads=num_heads, window=window,
+                    rot=rot[1].shape[-1] if rot else 0)
     w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
-    return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h), b, h
+    return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h, rot), b, h
 
 
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
                interpret: bool, scale: Optional[float] = None,
-               num_heads: Optional[int] = None, window: int = 0):
+               num_heads: Optional[int] = None, window: int = 0, rot=()):
     """``q`` and ``k`` are ``[b, h, s, d]`` and ``v`` ``[b, h, s_k, dv]``:
     the scores contract over ``d``, the output rows are ``dv`` wide. With
     ``num_heads`` they are ``[b, s, num_heads * d]`` (or fused in ``q``,
     :func:`_planned`) and so is the output; lse is ``[b, h, s_q]`` for
-    both."""
+    both. ``rot``: such a call's ``(q_rot [b, s, num_heads * rot], k_rot
+    [b, s_k, rot])``, the scores' further ``rot`` columns."""
     p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                               block_k, scale, num_heads, window)
+                               block_k, scale, num_heads, window, rot)
     _record_plan(p)
     bh, g, nq, nk, dv = b * h, p.heads, w.nq, w.nk, p.dv
     sp = _specs(ops, p, w, h)
@@ -997,16 +1109,18 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
         functools.partial(_fwd_kernel, w=w),
         name="flash_fwd",
         grid=(bh // g, nq, nk),
-        in_specs=[sp.q, sp.k, sp.v] + sp.masks,
+        in_specs=[sp.q, sp.k, sp.v] + sp.masks + sp.rot,
         out_specs=[sp.o, sp.qrow],
         out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype),
                    jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
                         pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
-                        pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)],
+                        pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)]
+        + ([pltpu.VMEM((g, p.block_k, p.d + p.rot), q.dtype)]
+           if p.rot else []),
         interpret=interpret,
-    )(ops.q, ops.k, ops.v, *sp.mask_args)
+    )(ops.q, ops.k, ops.v, *sp.mask_args, *ops.rot)
     out = _user_form(out, p.sq, q, p)
     lse = lse.reshape(b, h, p.sq_p)[:, :, :p.sq]
     return out, lse
@@ -1346,6 +1460,30 @@ def _flash_core_fused_bwd(causal, block_q, block_k, interpret, scale,
 _flash_core_fused.defvjp(_flash_core_fused_fwd, _flash_core_fused_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_core_rot(q, k, v, q_rot, k_rot, causal, block_q, block_k,
+                    interpret, scale, num_heads):
+    """The forward of a ``[b, s, num_heads * d]`` call whose scores run
+    over the rotary pair as well. Forward only, as a window is: the
+    backward kernels take no second score operand."""
+    return _flash_fwd(q, k, v, None, None, None, causal, block_q, block_k,
+                      interpret, scale, num_heads, rot=(q_rot, k_rot))[0]
+
+
+def _flash_core_rot_fwd(*args):
+    return _flash_core_rot.fun(*args), None
+
+
+def _flash_core_rot_bwd(*args):
+    raise NotImplementedError(
+        "flash_attention: no backward pass for a call with a rotary pair "
+        "(q_rot, k_rot); the forward adds its product to the scores, the "
+        "backward kernels do not yet")
+
+
+_flash_core_rot.defvjp(_flash_core_rot_fwd, _flash_core_rot_bwd)
+
+
 def _split_heads(x, h):
     b, s, width = x.shape
     return x.reshape(b, s, h, width // h).transpose(0, 2, 1, 3)
@@ -1370,11 +1508,14 @@ def flash_attention(
     scale: Optional[float] = None,
     num_heads: Optional[int] = None,
     window: int = 0,
+    q_rot: Optional[jax.Array] = None,
+    k_rot: Optional[jax.Array] = None,
 ):
     """Flash attention over ``q``, ``k`` [b, h, s, d] and ``v``
     [b, h, s_k, dv]; the output is [b, h, s_q, dv]. ``dv`` may differ
-    from ``d`` in the forward pass (latent attention scores over 192 and
-    sums values 128 wide); the backward pass raises for unequal widths.
+    from ``d`` in the forward pass (scores over 192, values 128 wide: what
+    latent attention was before its parts came apart, ``q_rot`` below);
+    the backward pass raises for unequal widths.
 
     Rank-3 ``q``, ``k``, ``v`` [b, s, num_heads * d], heads side by side
     as a projection leaves them, give the output in that layout too, and
@@ -1404,8 +1545,46 @@ def flash_attention(
       it are skipped, :func:`plan_blocks`). 0 is no window. Rank-4
       operands and the forward only: no backward kernel takes one (ROADMAP
       Reach).
+    - ``q_rot`` [b, s_q, num_heads * r] and ``k_rot`` [b, s_k, r], with
+      rank-3 ``q``, ``k``, ``v``: a second score operand. Head ``h``'s
+      scores are ``q_h . k_h + q_rot_h . k_rot``, one sum over ``d + r``
+      accumulated in float32, before the scale, the mask and the softmax;
+      ``scale`` None is ``(d + r) ** -0.5``. ``k_rot`` has no head: every
+      head's rotary part meets the same keys (latent attention's rotary 64
+      beside its 128: no head of 192 is built and ``k_rot`` is copied to
+      no head). Where :func:`rot_lane_heads` admits the shape the kernel
+      reads the pair in place; else this call builds the ``[b, h, s, d +
+      r]`` operands its caller would have had to. Forward only, with
+      ``causal`` as the one mask.
     """
     from ..core.errors import enforce
+
+    if q_rot is not None or k_rot is not None:
+        enforce(q_rot is not None and k_rot is not None and q.ndim == 3
+                and k is not None and num_heads is not None and not window
+                and attn_mask is None and key_bias is None
+                and segment_ids is None and not return_lse
+                and q_rot.shape[-1] == num_heads * k_rot.shape[-1],
+                "flash_attention: a rotary pair is q_rot [b, s, h * r] and "
+                "k_rot [b, s_k, r] beside [b, s, h * d] q, k, v with "
+                "num_heads, under at most the causal mask")
+        d, dv, r = (q.shape[-1] // num_heads, v.shape[-1] // num_heads,
+                    k_rot.shape[-1])
+        scale = (d + r) ** -0.5 if scale is None else scale
+        if not rot_lane_heads(d, dv, num_heads, r):
+            q, k, v, q_rot = (_split_heads(x, num_heads)
+                              for x in (q, k, v, q_rot))
+            k_rot = jnp.broadcast_to(k_rot[:, None], k.shape[:3] + (r,))
+            return _merge_heads(flash_attention(
+                jnp.concatenate([q, q_rot], -1),
+                jnp.concatenate([k, k_rot], -1), v, causal=causal,
+                block_q=block_q, block_k=block_k, interpret=interpret,
+                scale=scale))
+        block_q, block_k = resolve_block_shapes(block_q, block_k)
+        return _flash_core_rot(
+            q, k, v, q_rot, k_rot, causal, block_q, block_k,
+            default_interpret() if interpret is None else interpret, scale,
+            num_heads)
 
     if window:
         enforce(causal and q.ndim == 4 and k is not None and attn_mask is None

@@ -821,6 +821,100 @@ def test_projection_layout_lse_and_dense_mask(shape):
     ids=["gpt2m_train", "gpt2l_train", "gpt2m_prefill", "k25_prefill",
          "streamed"])
 def test_a_call_without_a_window_plans_as_it_did(kw, want):
+    """... and ``rot`` (PR 43) is the newest last field, 0 for them too."""
     plan = fa.plan_blocks(dtype=jnp.bfloat16, **kw)
-    assert tuple(plan) == want + (0,)
-    assert fa.FlashPlan._fields[-1] == "window"
+    assert tuple(plan) == want + (0, 0)
+    assert fa.FlashPlan._fields[-2:] == ("window", "rot")
+
+
+# -- a rotary pair: a second score operand in the projections' layout (PR 43) --------
+
+
+def _rot_case(rows, heads, dtype, d=128, r=64, b=1, seed=0):
+    """``q, k, v [b, rows, heads * d]``, ``q_rot [b, rows, heads * r]`` and
+    ``k_rot [b, rows, r]`` in ``dtype``, and the dense float64 attention
+    over them: head ``h``'s scores are ``q_h . k_h + q_rot_h . k_rot``."""
+    rng = np.random.RandomState(seed)
+    q, k, v, q_rot, k_rot = (
+        jnp.asarray(rng.randn(b, rows, w).astype(np.float32)).astype(dtype)
+        for w in (heads * d, heads * d, heads * d, heads * r, r))
+    scale = 0.1147                              # k25's: not a power of two
+
+    def apart(x, width):        # [b, h, rows, width]
+        return np.asarray(x, np.float64).reshape(
+            b, rows, -1, width).transpose(0, 2, 1, 3)
+
+    qh, kh, vh, qrh = apart(q, d), apart(k, d), apart(v, d), apart(q_rot, r)
+    kr = np.asarray(k_rot, np.float64).transpose(0, 2, 1)
+    seen = np.tril(np.ones((rows, rows), bool))
+    want = np.empty((b, rows, heads, d))
+    for h in range(heads):      # a head at a time: [rows, rows] of float64
+        s = (qh[:, h] @ kh[:, h].transpose(0, 2, 1) + qrh[:, h] @ kr) * scale
+        s = np.where(seen, s, -np.inf)
+        prob = np.exp(s - s.max(-1, keepdims=True))
+        want[:, :, h] = (prob / prob.sum(-1, keepdims=True)) @ vh[:, h]
+    return (q, k, v, q_rot, k_rot), scale, want.reshape(b, rows, heads * d)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("heads", [4, 64])
+@pytest.mark.parametrize("rows", [200, 512, 1984])
+def test_forward_with_a_rotary_pair_matches_dense(rows, heads, dtype):
+    """Latent attention's widths, 128 + 64 over 128-wide values, causal,
+    in the projections' own layout: a ragged sequence, whole tiles and the
+    cell's 1,984 rows (loops traced, one head a step, the rotary part
+    picked out of its lane group by the grid index); 4 heads a step where
+    the plan groups them."""
+    from paddle_tpu.core import profiler
+
+    (q, k, v, q_rot, k_rot), scale, want = _rot_case(rows, heads, dtype)
+    since = profiler.time.time_ns()
+    got = fa.flash_attention(q, k, v, causal=True, scale=scale,
+                             num_heads=heads, q_rot=q_rot, k_rot=k_rot)
+    assert got.shape == q.shape and got.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
+    plan = [s[4] for s in profiler.spans(since) if s[0] == "flash.plan"][-1]
+    assert (plan["layout"], plan["rot"], plan["d"], plan["dv"]) == (
+        "bsd", 64, 128, 128)
+
+
+def test_rotary_pair_plan_backward_and_fallback():
+    """The cell's call (8 x 64 heads x 1,984, bfloat16) plans as the
+    192-wide call did (the same blocks, tiles and causal skip), in ``bsd``
+    with one head a step and ``rot`` 64; the backward raises; widths that
+    fill no lane group go through the ``[b, h, s, d + r]`` form and agree
+    with the dense reference."""
+    plan = fa.plan_blocks(1984, 1984, 128, jnp.bfloat16, causal=True,
+                          bh=8 * 64, scale=0.1147, num_heads=64, rot=64)
+    old = fa.plan_blocks(1984, 1984, 192, jnp.bfloat16, causal=True,
+                         bh=8 * 64, dv=128, scale=0.1147)
+    assert (plan.layout, plan.lane_heads, plan.rot, plan.heads) == (
+        "bsd", 1, 64, 1)
+    assert (plan.tiles_run, plan.tiles_all) == (10, 16)
+    assert plan[3:14] == old[3:14] and (old.layout, old.rot) == ("bhsd", 0)
+    # the rows such a call is padded to inside, for a caller that can pad
+    # what its products take instead (layers/latent.mla_prefill)
+    assert [fa.padded_rows(s) for s in (37, 200, 1984, 2048, 2100)] == [
+        40, 256, 2048, 2048, 3072]
+    assert fa.rot_lane_heads(128, 128, 64, 64) == 2
+    assert fa.rot_lane_heads(128, 128, 3, 64) == 0     # an odd count of 64s
+    assert fa.rot_lane_heads(64, 64, 64, 32) == 0      # heads that share lanes
+
+    (q, k, v, q_rot, k_rot), scale, _ = _rot_case(128, 2, jnp.float32)
+    with pytest.raises(NotImplementedError, match="rotary pair"):
+        jax.grad(lambda q: fa.flash_attention(
+            q, k, v, causal=True, scale=scale, num_heads=2, q_rot=q_rot,
+            k_rot=k_rot).sum())(q)
+
+    from paddle_tpu.core import profiler
+    (q, k, v, q_rot, k_rot), scale, want = _rot_case(37, 3, jnp.float32,
+                                                     d=8, r=8)
+    since = profiler.time.time_ns()
+    with jax.default_matmul_precision("highest"):
+        got = fa.flash_attention(q, k, v, causal=True, scale=scale,
+                                 num_heads=3, q_rot=q_rot, k_rot=k_rot)
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=2e-5)
+    plan = [s[4] for s in profiler.spans(since) if s[0] == "flash.plan"][-1]
+    assert (plan["layout"], plan["rot"], plan["d"]) == ("bhsd", 0, 16)

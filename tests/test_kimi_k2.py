@@ -209,6 +209,59 @@ def test_mla_absorbed_step_agrees_with_expanded_at_every_position(highest):
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_all), atol=1e-6)
 
 
+@pytest.mark.parametrize("dims", [DIMS, latent.MLADims(32, 2, 24, 16, 128,
+                                                        64, 128)],
+                         ids=["tiny", "lane_groups"])
+def test_regrouped_projections_are_the_published_columns(highest, dims):
+    """``regrouped``'s two halves of ``q_b`` give, column for column, what
+    the published layout's one product gives a head's 128 and 64 of, and
+    the flat halves of ``kv_b`` the per-head keys and values; a stack goes
+    through as its layers do; what has been through comes back as it is.
+    At widths that fill lane groups the expanded form then hands the
+    kernel the parts apart (``flash.plan``: ``bsd``, ``rot``) and still
+    agrees with the absorbed step at every position."""
+    prog = pt.build(lambda x: latent.mla_params(dims, jnp.float32))
+    p, _ = prog.init(jax.random.PRNGKey(3), x=np.zeros(1))
+    p = {k.split("mla/", 1)[1]: v for k, v in p.items()}
+    g = latent.regrouped(p, dims)
+    assert "q_b/w" not in g and latent.regrouped(g, dims) is g
+    h, c_q, c_kv = dims.heads, rand(5, 2, 7, dims.q_lora), rand(6, 2, 7, dims.kv_lora)
+    q = jnp.matmul(c_q, p["q_b/w"]).reshape(2, 7, h, dims.qk)
+    for got, want in (
+            (jnp.matmul(c_q, g["q_b/w_nope"]), q[..., :dims.nope]),
+            (jnp.matmul(c_q, g["q_b/w_rope"]), q[..., dims.nope:]),
+            (jnp.matmul(c_kv, g["kv_b_k/w_flat"]),
+             jnp.einsum("bsc,hnc->bshn", c_kv, p["kv_b_k/w"])),
+            (jnp.matmul(c_kv, g["kv_b_v/w_flat"]),
+             jnp.einsum("bsc,hcv->bshv", c_kv, p["kv_b_v/w"]))):
+        np.testing.assert_allclose(np.asarray(got.reshape(want.shape)),
+                                   np.asarray(want), atol=1e-6)
+    stacked = latent.regrouped(
+        {k: jnp.stack([v, 2 * v]) for k, v in p.items()}, dims)
+    for k, v in g.items():
+        np.testing.assert_array_equal(np.asarray(stacked[k][0]), np.asarray(v))
+        np.testing.assert_array_equal(np.asarray(stacked[k][1]),
+                                      np.asarray(2 * v))
+    if dims.nope % 128:
+        return
+    s = 6
+    x = rand(9, 2, s, dims.d_model)
+    since = profiler.time.time_ns()
+    want, (c_all, r_all) = latent.mla_prefill(x, g, dims, YARN)
+    plan = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"][-1]
+    assert (plan["layout"], plan["rot"], plan["d"]) == ("bsd", 64, 128)
+    parts = [sp[4]["score_parts"] for sp in profiler.spans(since)
+             if sp[0] == "mla.plan"]
+    assert parts == [[128, 64]]
+    c, r = jnp.zeros((2, s, dims.kv_lora)), jnp.zeros((2, dims.rope, s))
+    for i in range(s):
+        got, c, r = latent.mla_decode(x[:, i:i + 1], p, c, r,
+                                      jnp.asarray(i, jnp.int32), dims, YARN)
+        np.testing.assert_allclose(np.asarray(got[:, 0]),
+                                   np.asarray(want[:, i]), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(r), np.asarray(r_all), atol=1e-6)
+
+
 # -- routing and the held share ---------------------------------------------------------
 
 

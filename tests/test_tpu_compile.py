@@ -204,10 +204,13 @@ def test_generator_cache_is_lane_dense_for_v5e(chip, monkeypatch):
     assert not moved, moved[0][:300]
 
 
-def _k25_generator(chip, monkeypatch, layers):
+def _k25_generator(chip, monkeypatch, layers, served=False):
     """The ``k25-serve-batch`` generator (8 rows, prompt 1984 + 64 new,
     bfloat16, the benchmark's configuration file at ``layers`` of its
-    depth) compiled for one described chip."""
+    depth) compiled for one described chip. ``served``: the parameters in
+    the row-major layout a served array arrives in; left free, a described
+    compile chooses each parameter's layout itself, and a copy the chip
+    makes in the decode loop moves out of sight into that choice."""
     import json
     import sys
 
@@ -226,8 +229,16 @@ def _k25_generator(chip, monkeypatch, layers):
     one_row = np.zeros((1, prompt), np.int32)
     shapes = jax.eval_shape(lambda key: prog.init(key, prompt_ids=one_row)[0],
                             jax.random.PRNGKey(0))
+
+    def held(s):
+        if not served:
+            return chip
+        from jax.experimental.layout import Format, Layout
+        return Format(Layout(major_to_minor=tuple(range(len(s.shape)))), chip)
+
     params = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=held(s)),
+        shapes)
     ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
     compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]["ids"]
                        ).lower(params, ids).compile()
@@ -244,7 +255,11 @@ def test_k25_latent_cache_is_lane_dense_for_v5e(chip, monkeypatch):
     product's group sizes); flash and the grouped products are kernels."""
     _, compiled = _k25_generator(chip, monkeypatch, layers=3)
     text = compiled.as_text()
-    slabs = set(re.findall(r"bf16\[8,(?:2048,512|64,2048)\]\{[^}]*\}", text))
+    # (the loop's own lines and its carried tuple: since PR 43 the prefill
+    # pads a prompt's latents to 2,048 rows, which is a slab's shape too)
+    loop = "\n".join(ln for ln in text.splitlines()
+                     if "decode_step" in ln or " while(" in ln)
+    slabs = set(re.findall(r"bf16\[8,(?:2048,512|64,2048)\]\{[^}]*\}", loop))
     assert slabs and all(s.split("{")[1].startswith("2,1,0:T(8,128)(2,1)")
                          for s in slabs), slabs
     assert not re.search(r"bf16\[8,2048,(576|640)\]", text)
@@ -260,11 +275,93 @@ def test_k25_latent_cache_is_lane_dense_for_v5e(chip, monkeypatch):
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 6
 
 
+def _array_bytes(shape_text):
+    """Bytes of the largest array in an instruction's result type
+    (``bf16[1,1536,12288]{...}``, or a tuple of such)."""
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    return max([width.get(t, 4) * int(np.prod([int(n) for n in dims.split(",")
+                                              if n]))
+                for t, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape_text)]
+               or [0])
+
+
+def _copies_in(text, scope, least):
+    """The instructions under ``scope`` (a component of the ``op_name``)
+    that write an array of ``least`` bytes or more: copies and transposes,
+    and fusions that are more than a view (a fusion of bitcasts alone
+    writes nothing; one of slices copies what it cuts, as the parent's
+    ``fusion.738`` did with every layer's ``q_b``; a ``reshape`` the
+    compiler left standing changes tiles, as the ``[H, 32, 2, q_lora]``
+    view of the rotary half did). Plain slices of a stack's leading axis
+    and bitcasts are views and are not listed."""
+    bodies = {name: body for name, body in re.findall(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    fused = set(re.findall(r" fusion\(.*?calls=%([^,\s)]+)", text))
+
+    def view(called):
+        ops = re.findall(r"= \S+ ([\w-]+)\(", bodies.get(called, ""))
+        return bool(ops) and set(ops) <= {"parameter", "bitcast"}
+
+    found = []
+    for inside, body in bodies.items():
+        # a fusion's own instructions write nothing; a computation is
+        # under the scope if any of its instructions names it, and then so
+        # are those of its instructions that carry no name at all
+        if inside in fused or scope not in body:
+            continue
+        for ln in body.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", ln)
+            if not m or _array_bytes(m.group(2)) < least or (
+                    scope not in ln and "op_name=" in ln):
+                continue
+            name, result, op = m.groups()
+            called = re.search(r"calls=%([^,\s)]+)", ln)
+            if op in ("copy", "transpose", "reshape") or (
+                    op == "fusion" and not view(called.group(1))):
+                found.append((name, op, result[:60]))
+    return found
+
+
+def test_k25_step_reads_q_b_as_held_and_prefill_builds_no_192_for_v5e(
+        chip, monkeypatch):
+    """With the parameters as a served array arrives (the free compile
+    would pass on the parent too): in the decode loop nothing of 12 MB or
+    more is written but the latent slabs' in-place updates, so each of
+    ``q_b``'s regrouped halves (25.2 and 12.6 MB a layer) is read as it is
+    held and the rotary half's 64-wide head view brings no copy back. In
+    the prefill nothing is 192 wide (or 256 from it), ``flash_fwd`` takes
+    ``[b, s, H * 128]`` three times, ``[b, s, H * 64]`` and ``[b, s, 64]``
+    and writes ``[b, s, H * 128]``, and ``k_rope`` is broadcast to no
+    head."""
+    _, compiled = _k25_generator(chip, monkeypatch, layers=3, served=True)
+    text = compiled.as_text()
+    written = [c for c in _copies_in(text, "decode_step", 12e6)
+               if "bf16[8,2048,512]" not in c[2]]
+    assert not written, written[:4]
+    assert _copies_in(text, "regroup", 12e6)      # made once, outside
+    # no activation (8 rows lead) is 192 wide; the one view of ``q_b``'s
+    # published columns a head at a time is the regrouping's, once
+    assert not re.search(r"\[8,[\d,]*,(192|256)\]", text)
+    assert all("regroup" in ln or "op_name" not in ln
+               for ln in text.splitlines() if ",64,192]" in ln)
+    assert not re.search(r"bf16\[8,64,(1984|2048),64\]", text)
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "flash_fwd" in ln]
+    assert len(calls) == 2      # the dense layer's and the scan body's
+    for ln in calls:
+        taken = ln.split("operand_layout_constraints={")[1].split("}}")[0]
+        assert re.findall(r"\w+\[[\d,]*\]", taken) == [
+            "bf16[8,2048,8192]"] * 3 + ["bf16[8,2048,4096]", "bf16[8,2048,64]"]
+        assert re.search(r"= \(bf16\[8,2048,8192\]\{", ln)
+
+
 def test_k25_generator_fits_one_v5e(chip, monkeypatch):
     """The whole cut (1 dense + 5 expert layers, 12 experts a layer, 20480
     rows of the vocabulary): 8.37 GB of arguments and 2.47 GB of
     temporaries, under 14.5 GB together; the configuration file's ``memory``
-    group records what this compile said."""
+    group records what this compile said (2.51 GB of temporaries since
+    PR 43: 0.33 GB of regrouped projections come, the prefill's 537 MB
+    operands go; within the 0.15 GB the file's figure is held to)."""
     cell_config, compiled = _k25_generator(chip, monkeypatch, layers=6)
     m = compiled.memory_analysis()
     assert 8.3e9 < m.argument_size_in_bytes < 8.45e9
