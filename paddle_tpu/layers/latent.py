@@ -1,9 +1,10 @@
-"""Latent attention (MLA) blocks and what stands round them in the
-DeepseekV3 decoder: RMSNorm, a gated SiLU FFN, rotary positions with
-YaRN's frequency blend. A sibling of ``layers/stacked.py``, written once in
-the stacked form: parameters carry a leading ``[num_layers, ...]`` axis,
-blocks are pure functions of ``(activation, layer_params)`` with no
-``LayerHelper`` call inside, so they trace under ``lax.scan``.
+"""Latent attention (MLA) blocks of the DeepseekV3 decoder and YaRN's
+frequency blend for their rotary positions; the norm, the rotary map and the
+FFN that stand round them are ``layers/blocks.py``'s. A sibling of
+``layers/stacked.py``, written once in the stacked form: parameters carry a
+leading ``[num_layers, ...]`` axis, blocks are pure functions of
+``(activation, layer_params)`` with no ``LayerHelper`` call inside, so they
+trace under ``lax.scan``.
 
 Latent attention projects a token to one 512-wide latent ``c_kv`` and one
 64-wide rotary key ``k_rope`` shared by all heads; keys and values are
@@ -52,10 +53,10 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .. import initializer as init
 from ..framework import LayerHelper
 from ..ops.flash_attention import flash_attention, padded_rows
-from .stacked import NEG_INF, StackedInit
+from .blocks import params, rms_norm, rope
+from .stacked import NEG_INF
 
 
 class MLADims(NamedTuple):
@@ -86,25 +87,7 @@ class Yarn(NamedTuple):
     mscale_all_dim: float = 0.0
 
 
-# -- norm, FFN, rotary ---------------------------------------------------------
-
-
-@jax.named_scope("rms")
-def rms_norm(x, g, eps: float = 1e-5):
-    """``x * rsqrt(mean(x^2) + eps) * g``, statistics and scale in float32,
-    result in ``x``'s dtype."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32)).astype(x.dtype)
-
-
-@jax.named_scope("ffn")
-def gated_ffn(x, w_gate, w_up, w_down):
-    """``W_down(silu(W_gate x) * (W_up x))``; products accumulate in
-    float32, the gate is taken in float32 and rounded once."""
-    gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
-    up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
-    return jnp.matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down)
+# -- rotary frequencies --------------------------------------------------------
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -145,41 +128,7 @@ def softmax_scale(dims: MLADims, y: Yarn) -> float:
     return dims.qk ** -0.5 * m * m
 
 
-def rope(x, positions, freqs, scale: float = 1.0, head_axis: bool = False):
-    """Rotate the pairs ``(2i, 2i + 1)`` of ``x [..., s, dim]`` (with
-    ``head_axis``: ``[..., s, H, dim]``) by ``positions[s] * freqs[i]``;
-    angles, cos and sin in float32."""
-    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    if head_axis:
-        ang = ang[:, None, :]
-    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    even, odd = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
 # -- parameters ------------------------------------------------------------------
-
-
-def _normal(fan_in: int, layers: Optional[int]):
-    base = init.Normal(0.0, fan_in ** -0.5)
-    return base if layers is None else StackedInit(base)
-
-
-def _params(helper: LayerHelper, shapes, layers: Optional[int], dtype):
-    """``name -> (shape, fan_in)``, fan_in ``None`` for a norm's scale
-    (ones, float32); with ``layers`` every shape gains the leading axis."""
-    out = {}
-    for name, (shape, fan_in) in shapes.items():
-        full = shape if layers is None else (layers,) + shape
-        if fan_in is None:
-            out[name] = helper.create_parameter(
-                name, full, jnp.float32, initializer=init.Constant(1.0))
-        else:
-            out[name] = helper.create_parameter(
-                name, full, dtype, initializer=_normal(fan_in, layers))
-    return out
 
 
 def mla_params(dims: MLADims, dtype, layers: Optional[int] = None,
@@ -190,7 +139,7 @@ def mla_params(dims: MLADims, dtype, layers: Optional[int] = None,
     ``q_nope @ kv_b_k`` is the absorbed query) and ``kv_b_v [H, kv_lora,
     v]``."""
     d, h = dims.d_model, dims.heads
-    return _params(LayerHelper(name, name=name), {
+    return params(LayerHelper(name, name=name), {
         "attn_norm/g": ((d,), None),
         "q_a/w": ((d, dims.q_lora), d),
         "q_norm/g": ((dims.q_lora,), None),
@@ -201,17 +150,6 @@ def mla_params(dims: MLADims, dtype, layers: Optional[int] = None,
         "kv_b_v/w": ((h, dims.kv_lora, dims.v), dims.kv_lora),
         "o/w": ((h * dims.v, d), h * dims.v),
     }, layers, dtype)
-
-
-def gated_ffn_params(d_model: int, width: int, dtype,
-                     layers: Optional[int] = None, name: str = "ffn",
-                     with_norm: bool = True) -> Dict[str, jax.Array]:
-    shapes = {"gate/w": ((d_model, width), d_model),
-              "up/w": ((d_model, width), d_model),
-              "down/w": ((width, d_model), width)}
-    if with_norm:
-        shapes["ffn_norm/g"] = ((d_model,), None)
-    return _params(LayerHelper(name, name=name), shapes, layers, dtype)
 
 
 # -- the block's two forms ----------------------------------------------------------
@@ -358,12 +296,6 @@ def mla_decode(x, p, c_cache, r_cache, index, dims: MLADims, y: Yarn):
     return x, c_cache, r_cache
 
 
-def ffn_block(x, p, eps: float = 1e-5):
-    """``x + FFN(RMSNorm(x))`` with the block's own norm."""
-    h = rms_norm(x, p["ffn_norm/g"], eps)
-    return x + gated_ffn(h, p["gate/w"], p["up/w"], p["down/w"])
-
-
-__all__ = ["MLADims", "Yarn", "ffn_block", "gated_ffn", "gated_ffn_params",
-           "mla_decode", "mla_params", "mla_prefill", "regrouped", "rms_norm",
-           "rope", "softmax_scale", "yarn_cos_sin_scale", "yarn_frequencies"]
+__all__ = ["MLADims", "Yarn", "mla_decode", "mla_params", "mla_prefill",
+           "regrouped", "softmax_scale", "yarn_cos_sin_scale",
+           "yarn_frequencies"]

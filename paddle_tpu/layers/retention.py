@@ -2,8 +2,8 @@
 an RMSNorm on every head of q and k, rotary positions, a gate computed from
 the token, and ``ops/power_retention.py`` in place of attention. A sibling of
 ``layers/sala.py`` and written as it is: pure functions of ``(activation,
-layer_params, carried state)``; ``layers/latent.py``'s ``rms_norm``, ``rope``,
-``ffn_block`` and ``_params`` are the ones used here.
+layer_params, carried state)``; the norm, the rotary map and the parameter
+maker are ``layers/blocks.py``'s.
 
 ``heads`` query heads over ``kv_heads`` key heads (a *group* of ``heads /
 kv_heads`` reads one key head's state)::
@@ -31,10 +31,9 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .. import initializer as init
 from ..framework import LayerHelper
 from ..ops.power_retention import retention, retention_step, window_append
-from . import latent as M
+from .blocks import params, rms_norm, rope
 
 
 class RetentionDims(NamedTuple):
@@ -56,16 +55,13 @@ def retention_params(dims: RetentionDims, dtype) -> Dict[str, jax.Array]:
     q, k and v are one matrix ``[out, in]``, q's rows first."""
     d, hd = dims.d_model, dims.head_dim
     wide, kv = dims.heads * hd, dims.kv_heads * hd
-    helper = LayerHelper("mixer", name="mixer")
-    p = M._params(helper, {
+    return params(LayerHelper("mixer", name="mixer"), {
         "attn_norm/g": ((d,), None),
         "qkv/w": ((wide + 2 * kv, d), d),
         "q_norm/g": ((hd,), None), "k_norm/g": ((hd,), None),
-        "gate/w": ((d, dims.kv_heads), d), "o/w": ((wide, d), wide),
+        "gate/w": ((d, dims.kv_heads), d), "gate/b": ((dims.kv_heads,), 0.0),
+        "o/w": ((wide, d), wide),
     }, None, dtype)
-    p["gate/b"] = helper.create_parameter(
-        "gate/b", (dims.kv_heads,), jnp.float32, initializer=init.Constant(0.0))
-    return p
 
 
 def _qkv(u, p, dims: RetentionDims, positions):
@@ -77,7 +73,7 @@ def _qkv(u, p, dims: RetentionDims, positions):
     qkv = jnp.einsum("bsd,od->bso", u, p["qkv/w"])
     i = jnp.arange(hd // 2, dtype=jnp.float32)
     freqs = dims.theta ** (-2.0 * i / hd)
-    turn = lambda t, g: M.rope(M.rms_norm(t.reshape(b, s, -1, hd), g, dims.eps),
+    turn = lambda t, g: rope(rms_norm(t.reshape(b, s, -1, hd), g, dims.eps),
                                positions, freqs, head_axis=True)
     q = turn(qkv[..., :wide], p["q_norm/g"])
     k = turn(qkv[..., wide:wide + kv], p["k_norm/g"])
@@ -98,7 +94,7 @@ def retention_prefill(x, p, dims: RetentionDims, state, p0):
     hd], v, log gamma [b, s, kv]))``: the last is what the recurrence was
     given."""
     with jax.named_scope("retention"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _qkv(u, p, dims, p0 + jnp.arange(x.shape[1]))
         log_gamma = log_gate(u, p)
         o, state = retention(q, k, v, log_gamma, state, dims.heads,
@@ -122,7 +118,7 @@ def retention_decode(x, p, dims: RetentionDims, carried, index, write):
     ``given`` as :func:`retention_prefill`'s, ``s = 1``."""
     state, window = carried
     with jax.named_scope("retention"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _qkv(u, p, dims, index[None])
         k = jnp.where(write, k, 0)
         log_gamma = jnp.where(write, log_gate(u, p), 0.0)

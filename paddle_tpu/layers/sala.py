@@ -4,7 +4,8 @@ state the layer carries, and a one-token form. A sibling of
 ``layers/latent.py`` and written as it is: pure functions of ``(activation,
 layer_params, carried state)``, parameter tables that take a stack's
 leading axis or none (``models/minicpm_sala.py`` gives every layer its
-own); its ``rms_norm``, ``rope`` and ``_params`` are the ones used here.
+own); the norm, the rotary map, the residual sum and the parameter maker
+are ``layers/blocks.py``'s.
 
 **Sparse mixer** (``minicpm4``, InfLLM-V2): ``heads`` query heads over
 ``kv_heads`` key/value heads (a *group* of ``heads / kv_heads`` shares one),
@@ -49,7 +50,7 @@ from ..ops.flash_attention import NEG_INF, flash_attention
 from ..ops.lightning_attention import lightning_attention
 from ..ops.sparse_attention import record_plan as _record_sparse_plan
 from ..ops.sparse_attention import sparse_attention
-from . import latent as M
+from .blocks import params, residual, rms_norm, rope
 
 
 class SparseDims(NamedTuple):
@@ -121,7 +122,7 @@ def sparse_params(dims: SparseDims, dtype, layers: Optional[int] = None,
                   name: str = "mixer") -> Dict[str, jax.Array]:
     d, hd = dims.d_model, dims.head_dim
     q, kv = dims.heads * hd, dims.kv_heads * hd
-    return M._params(LayerHelper(name, name=name), {
+    return params(LayerHelper(name, name=name), {
         "attn_norm/g": ((d,), None),
         "qkv/w": ((q + 2 * kv, d), d),
         "q_norm/g": ((hd,), None), "k_norm/g": ((hd,), None),
@@ -133,7 +134,7 @@ def lightning_params(dims: LightningDims, dtype, layers: Optional[int] = None,
                      name: str = "mixer") -> Dict[str, jax.Array]:
     d, hd = dims.d_model, dims.head_dim
     w = dims.heads * hd
-    return M._params(LayerHelper(name, name=name), {
+    return params(LayerHelper(name, name=name), {
         "attn_norm/g": ((d,), None),
         "qkv/w": ((3 * w, d), d),
         "q_norm/g": ((hd,), None), "k_norm/g": ((hd,), None),
@@ -146,11 +147,6 @@ def _project(u, w):
     """``u [b, s, d] @ w^T``: q, k and v of ``u`` side by side from the one
     matrix ``qkv/w``, stored ``[out, in]``."""
     return jnp.einsum("bsd,od->bso", u, w)
-
-
-def residual(x, y, a: float):
-    """``x + a * y`` summed in float32, in ``x``'s dtype."""
-    return (x.astype(jnp.float32) + a * y.astype(jnp.float32)).astype(x.dtype)
 
 
 def _gated_out(o, u, p):
@@ -169,9 +165,9 @@ def _sparse_qkv(u, p, dims: SparseDims):
     b, s, _ = u.shape
     wide, kv = dims.heads * dims.head_dim, dims.kv_heads * dims.head_dim
     qkv = _project(u, p["qkv/w"])
-    q = M.rms_norm(qkv[..., :wide].reshape(b, s, dims.heads, dims.head_dim),
+    q = rms_norm(qkv[..., :wide].reshape(b, s, dims.heads, dims.head_dim),
                    p["q_norm/g"], dims.eps)
-    k = M.rms_norm(qkv[..., wide:wide + kv].reshape(b, s, dims.kv_heads,
+    k = rms_norm(qkv[..., wide:wide + kv].reshape(b, s, dims.kv_heads,
                                                     dims.head_dim),
                    p["k_norm/g"], dims.eps)
     return q, k.reshape(b, s, -1), qkv[..., wide + kv:]
@@ -257,7 +253,7 @@ def sparse_prefill(x, p, dims: SparseDims, cache, p0, selected: bool, a: float):
     k_cache, v_cache, ck_cache = cache
     stride = dims.kernel_stride
     with jax.named_scope("sparse"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _sparse_qkv(u, p, dims)
         k = k.astype(k_cache.dtype)
         # compressed keys: the one astride the chunk's start first (where
@@ -394,7 +390,7 @@ def sparse_decode(x, p, dims: SparseDims, cache, index, prompt_len: int,
                         "selected" if prompt_len >= dims.dense_len
                         else "dense+selected")
     with jax.named_scope("sparse"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _sparse_qkv(u, p, dims)
         k_cache = jax.lax.dynamic_update_slice_in_dim(
             k_cache, k.astype(k_cache.dtype), index, axis=1)
@@ -443,9 +439,9 @@ def _lightning_qkv(u, p, dims: LightningDims, positions):
     qkv = _project(u, p["qkv/w"]).reshape(b, s, 3, dims.heads, dims.head_dim)
     i = jnp.arange(dims.head_dim // 2, dtype=jnp.float32)
     freqs = dims.theta ** (-2.0 * i / dims.head_dim)
-    q = M.rope(M.rms_norm(qkv[:, :, 0], p["q_norm/g"], dims.eps), positions,
+    q = rope(rms_norm(qkv[:, :, 0], p["q_norm/g"], dims.eps), positions,
                freqs, head_axis=True)
-    k = M.rope(M.rms_norm(qkv[:, :, 1], p["k_norm/g"], dims.eps), positions,
+    k = rope(rms_norm(qkv[:, :, 1], p["k_norm/g"], dims.eps), positions,
                freqs, head_axis=True)
     q = (q.astype(jnp.float32) * dims.scale).astype(q.dtype)
     return q, k, qkv[:, :, 2]
@@ -457,12 +453,12 @@ def lightning_prefill(x, p, dims: LightningDims, state, log_decay, p0, a: float)
     a * mixer, state)``."""
     b, s, _ = x.shape
     with jax.named_scope("lightning"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _lightning_qkv(u, p, dims, p0 + jnp.arange(s))
         flat = lambda t: t.reshape(b, s, -1)
         o, state = lightning_attention(flat(q), flat(k), flat(v), log_decay,
                                        state, dims.heads)
-        o = M.rms_norm(o.reshape(b, s, dims.heads, dims.head_dim),
+        o = rms_norm(o.reshape(b, s, dims.heads, dims.head_dim),
                        p["o_norm/g"], dims.eps)
         x = residual(x, _gated_out(flat(o), u, p), a)
     return x, state
@@ -474,24 +470,17 @@ def lightning_decode(x, p, dims: LightningDims, state, log_decay, index,
     S``, the state read and written once, in float32."""
     f32 = jnp.float32
     with jax.named_scope("lightning"):
-        u = M.rms_norm(x, p["attn_norm/g"], dims.eps)
+        u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _lightning_qkv(u, p, dims, index[None])
         state = (state * jnp.exp(log_decay.astype(f32))[None, :, None, None]
                  + jnp.einsum("rhd,rhe->rhde", k[:, 0].astype(f32),
                               v[:, 0].astype(f32)))
         o = jnp.einsum("rhd,rhde->rhe", q[:, 0].astype(f32), state)
-        o = M.rms_norm(o.astype(x.dtype), p["o_norm/g"], dims.eps)
+        o = rms_norm(o.astype(x.dtype), p["o_norm/g"], dims.eps)
         x = residual(x, _gated_out(o.reshape(x.shape[0], 1, -1), u, p), a)
     return x, state
 
 
-def ffn_block(x, p, eps: float, a: float):
-    """``x + a * FFN(RMSNorm(x))`` with the block's own norm."""
-    h = M.rms_norm(x, p["ffn_norm/g"], eps)
-    return residual(x, M.gated_ffn(h, p["gate/w"], p["up/w"], p["down/w"]), a)
-
-
-__all__ = ["LightningDims", "SparseDims", "ffn_block", "lightning_decode",
+__all__ = ["LightningDims", "SparseDims", "lightning_decode",
            "lightning_log_decay", "lightning_params", "lightning_prefill",
-           "residual", "select_blocks", "sparse_decode", "sparse_params",
-           "sparse_prefill"]
+           "select_blocks", "sparse_decode", "sparse_params", "sparse_prefill"]

@@ -6,8 +6,8 @@ and ``layers/retention.py`` and written as they are: pure functions of
 ``(activation, layer_params, carried state)``, parameters created by
 ``*_params`` under ``mixer/`` in the caller's scope.
 
-Every block is ``x + Mixer(LayerNorm(x))`` (scale and bias, statistics in
-float32). What a mixer carries:
+Every block is ``x + Mixer(LayerNorm(x))`` (``layers/blocks.layer_norm``:
+scale and bias, statistics in float32). What a mixer carries:
 
 - **Mamba** (:func:`mamba_prefill`, :func:`mamba_decode`): the last ``d_conv
   - 1`` inputs of the causal convolution ``[rows, 3, d_inner]`` and the
@@ -55,7 +55,7 @@ from .. import initializer as init
 from ..framework import LayerHelper
 from ..ops.flash_attention import flash_attention
 from ..ops.selective_scan import mamba_step, selective_scan
-from . import latent as M
+from .blocks import gated_ffn_params, layer_norm, params
 from .stacked import NEG_INF
 
 
@@ -82,34 +82,12 @@ def lambda_init(layer: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * layer)
 
 
-@jax.named_scope("ln")
-def layer_norm(x, g, b, eps: float):
-    """LayerNorm with scale and bias; statistics, scale and bias in
-    float32, the result in ``x``'s dtype."""
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
-    return ((x32 - mean) * jax.lax.rsqrt(var + eps) * g + b).astype(x.dtype)
-
-
 # -- parameters ------------------------------------------------------------------
 
 
 def _create(shapes, dtype) -> Dict[str, jax.Array]:
-    """``name -> (shape, fan_in | constant | initializer)``: a matrix ``N(0,
-    1 / fan_in)`` in ``dtype`` where an int is given, else a float32 array
-    of the constant or from the initializer."""
-    helper = LayerHelper("mixer", name="mixer")
-    out = {}
-    for name, (shape, how) in shapes.items():
-        if isinstance(how, int):
-            out[name] = helper.create_parameter(
-                name, shape, dtype, initializer=init.Normal(0.0, how ** -0.5))
-        else:
-            out[name] = helper.create_parameter(
-                name, shape, jnp.float32,
-                initializer=init.Constant(how) if isinstance(how, float) else how)
-    return out
+    """``layers/blocks.params`` under ``mixer/`` in the caller's scope."""
+    return params(LayerHelper("mixer", name="mixer"), shapes, None, dtype)
 
 
 def _norm(d):
@@ -161,23 +139,11 @@ def gmu_params(dims: SambaDims, dtype) -> Dict[str, jax.Array]:
 
 def ffn_params(dims: SambaDims, width: int, dtype) -> Dict[str, jax.Array]:
     """The published ``gate_up_proj`` as its two halves."""
-    p = M.gated_ffn_params(dims.d_model, width, dtype)
+    p = gated_ffn_params(dims.d_model, width, dtype)
     p["ffn_norm/b"] = LayerHelper("ffn", name="ffn").create_parameter(
         "ffn_norm/b", (dims.d_model,), jnp.float32,
         initializer=init.Constant(0.0))
     return p
-
-
-def ffn_block(x, p, eps: float):
-    """``x + FFN(LayerNorm(x))``, the gate product taken in ``x``'s dtype
-    before the SiLU in float32 (``models/brumby._ffn_block`` says why a
-    one-row step wants that)."""
-    h = layer_norm(x, p["ffn_norm/g"], p["ffn_norm/b"], eps)
-    with jax.named_scope("ffn"):
-        gate = jnp.matmul(h, p["gate/w"]).astype(jnp.float32)
-        up = jnp.matmul(h, p["up/w"], preferred_element_type=jnp.float32)
-        return x + jnp.matmul((jax.nn.silu(gate) * up).astype(x.dtype),
-                              p["down/w"])
 
 
 # -- Mamba ---------------------------------------------------------------------------
@@ -429,7 +395,7 @@ def cross_decode(x, p, dims: SambaDims, shared, index, layer: int):
 
 
 __all__ = ["SambaDims", "attention_params", "cache_attention", "cross_decode",
-           "cross_params", "ffn_block", "ffn_params", "gmu", "gmu_params",
-           "lambda_init", "layer_norm", "mamba_decode", "mamba_params",
+           "cross_params", "ffn_params", "gmu", "gmu_params",
+           "lambda_init", "mamba_decode", "mamba_params",
            "mamba_prefill", "ring_of", "shared_decode", "shared_kv",
            "window_decode", "window_prefill"]

@@ -7,12 +7,12 @@ pipeline stage holds a run of the published stack; every layer is of one
 kind, so the index only names the parameters); the rest of the depth lies on
 further chips. Nothing stands in for it.
 
-This module serves only: :func:`make_generator`, the contract of
-``gpt.make_generator`` (``prompt_ids [b, p] -> {"ids": [b, new], ...}``
-through ``greedy_search``). There is no ``make_model``: no cut of this model
-trains on one chip, and neither kernel has a backward (ROADMAP R5). Matrices
-are created and held in ``cfg.dtype``; norm scales and the gate's bias are
-float32.
+This module serves only: :func:`make_generator`, through the contract of
+``layers/decoding.py`` (``prompt_ids [b, p] -> {"ids": [b, new], ...}``, the
+first step with its write switch). There is no ``make_model``: no cut of
+this model trains on one chip, and neither kernel has a backward (ROADMAP
+R5). Matrices are created and held in ``cfg.dtype``; norm scales and the
+gate's bias are float32.
 
 The carried state has **no key/value slab**: for each layer one float32
 array ``[rows, kv_heads, head_dim + 8, 8704]``, the state with its key sum
@@ -28,11 +28,10 @@ through both loops: the kernels write into the buffer they read, the loops
 alias their carry.
 
 The prefill walks the prompt a chunk of the recurrence
-(``ops/power_retention.CHUNK`` tokens) at a time under one ``lax.scan`` with
-no conditional in it (a tail shorter than a chunk follows the scan as one
-more piece); the layers are written out, each with its own parameters
-(``layer_<published index>/...``), as ``models/minicpm_sala.py`` writes its
-own and for its reason.
+(``ops/power_retention.CHUNK`` tokens) at a time (``decoding.chunked_walk``:
+a scan, then a tail shorter than a chunk as one more piece); the layers are
+written out, each with its own parameters (``layer_<published index>/...``),
+as ``models/minicpm_sala.py`` writes its own and for its reason.
 
 **What a request reports of its state.** A state is never returned (0.6 GB a
 layer), and a few hundred greedy tokens show little of how it was carried.
@@ -50,16 +49,16 @@ at 16 rows x 1,279 positions, left on the device unless a caller fetches it.
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import initializer as init
 from ..core.errors import enforce
-from ..framework import LayerHelper, name_scope
-from ..layers import latent as M
+from ..framework import name_scope
+from ..layers import blocks as B
+from ..layers import decoding
 from ..layers import retention as R
 from ..ops import power_retention
 
@@ -100,81 +99,28 @@ def base_config(**kw) -> BrumbyConfig:
 AUDIT_LAYER, AUDIT_HEAD = 0, 0      # the held layer and key head a request audits
 
 
-def _ffn_block(x, p, eps: float):
-    """``x + FFN(RMSNorm(x))`` as ``layers/latent.ffn_block`` computes it but
-    for one rounding: the gate product's result is taken in ``x``'s dtype
-    (as the published bfloat16 inference takes it) before the SiLU in
-    float32. With a float32 result the compiler walks a one-row step's
-    ``[d, width]`` matrix in strips of 512 columns, 16 KB pieces half a
-    megabyte apart, and on the chip that walk took 260, 274 or 293 us by a
-    level fixed for a process's life; with this result it walks whole rows,
-    as it walks ``up`` and ``down``, in 237 us in every process (PERF.md
-    section 6, PR 39; ``tests/test_tpu_compile.py`` holds the walk)."""
-    h = M.rms_norm(x, p["ffn_norm/g"], eps)
-    with jax.named_scope("ffn"):
-        gate = jnp.matmul(h, p["gate/w"]).astype(jnp.float32)
-        up = jnp.matmul(h, p["up/w"], preferred_element_type=jnp.float32)
-        return x + jnp.matmul((jax.nn.silu(gate) * up).astype(x.dtype),
-                              p["down/w"])
-
-
-def _record_plans(cfg: BrumbyConfig, states, windows, rows, max_len, chunk,
-                  chunks):
-    """``decode.plan`` beside the other generators', with what is new here:
-    a carried state, a window of tokens beside it and nothing else, and
-    how much of the state a step writes. ``prefill.plan``: how the prompt
-    is walked."""
-    from ..core import profiler
-
-    high = cfg.head_dim + power_retention.NORM_ROWS
-    size = lambda arrays: sum(a.size * a.dtype.itemsize for a in arrays)
-    held = size(states)
-    # a step writes back one state of every ``WINDOW`` (a last short run's too)
-    pairs = rows * cfg.num_key_value_heads
-    runs = -(-pairs // power_retention.WINDOW)
-    profiler.record_span(
-        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
-        heads=cfg.num_attention_heads, layers=cfg.num_hidden_layers,
-        cache_kind="state", cache_dtype="float32", lane_width=0,
-        cache_bytes=held, kv_bytes=0, state_bytes=held * cfg.head_dim // high,
-        norm_bytes=held * power_retention.NORM_ROWS // high,
-        state_layers=len(states), state_dtype="float32",
-        state_write_bytes=held * runs // pairs,
-        window_bytes=size(a for w in windows for a in w))
-    profiler.record_span("prefill.plan", time.time_ns(), 0, chunk=chunk,
-                         chunks=chunks, rows=rows)
-
-
 def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
-    """``(state0, step_fn, audit)`` for ``layers/beam_search``: the
-    parameters (created or fetched here, once, by name), the chunked prefill
-    of ``prompt_ids``, the one-token step that follows it, and what the
-    generator returns of the last state."""
+    """``(state0, step_fn, audit)``, the contract of ``layers/decoding.py``:
+    the parameters (created or fetched here, once, by name), the chunked
+    prefill of ``prompt_ids``, the one-token step that follows it, and what
+    the generator returns of the last state."""
     indices = cfg.indices
     enforce(len(indices) == cfg.num_hidden_layers,
             f"brumby: {cfg.num_hidden_layers} layers, published indices "
             f"{indices}")
     dims, dtype = cfg.retention, jnp.dtype(cfg.dtype)
     rows, p_len = prompt_ids.shape
-    enforce(p_len + max_new_tokens <= cfg.max_position_embeddings,
-            f"prompt {p_len} + max_new {max_new_tokens} exceeds "
-            f"max_position_embeddings {cfg.max_position_embeddings}")
+    decoding.check_length(p_len, max_new_tokens, cfg.max_position_embeddings)
     d, eps = cfg.hidden_size, cfg.rms_norm_eps
 
-    with name_scope("tok"):
-        w_emb = LayerHelper("embedding").create_parameter(
-            "w", (cfg.vocab_size, d), dtype, initializer=init.Normal(0.0, 1.0))
+    w_emb = decoding.token_embedding(cfg.vocab_size, d, dtype)
     per_layer = []
     for index in indices:
         with name_scope(f"layer_{index}"):
             per_layer.append((R.retention_params(dims, dtype),
-                              M.gated_ffn_params(d, cfg.intermediate_size,
+                              B.gated_ffn_params(d, cfg.intermediate_size,
                                                  dtype)))
-    final_g = LayerHelper("final_norm").create_parameter(
-        "g", (d,), jnp.float32, initializer=init.Constant(1.0))
-    w_head = LayerHelper("lm_head").create_parameter(
-        "w", (d, cfg.vocab_size), dtype,
-        initializer=init.Normal(0.0, d ** -0.5))
+    final_g, w_head = decoding.untied_head(cfg.vocab_size, d, dtype)
 
     def embed(ids):
         with jax.named_scope("tok"):
@@ -182,18 +128,29 @@ def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
 
     def head(x_last):   # [rows, d] -> log-probs
         with jax.named_scope("head"):
-            return jax.nn.log_softmax(jnp.matmul(
-                M.rms_norm(x_last, final_g, eps), w_head,
-                preferred_element_type=jnp.float32), axis=-1)
+            return decoding.log_probs(B.rms_norm(x_last, final_g, eps), w_head)
 
     states = [power_retention.empty_state(rows, dims.kv_heads, dims.head_dim)
               ] * len(per_layer)
     windows = [power_retention.empty_window(rows, dims.kv_heads, dims.head_dim,
                                             dtype)] * len(per_layer)
     chunk = min(power_retention.CHUNK, p_len)
-    whole = p_len // chunk
-    _record_plans(cfg, states, windows, rows, p_len + max_new_tokens, chunk,
-                  whole)
+    # a carried state, a window of tokens beside it and nothing else: of what
+    # is held, a value's rows and the key sum's; a step writes back one state
+    # of every ``WINDOW`` (a last short run's too)
+    held = decoding.nbytes(states)
+    high = dims.head_dim + power_retention.NORM_ROWS
+    pairs = rows * cfg.num_key_value_heads
+    runs = -(-pairs // power_retention.WINDOW)
+    decoding.record_plans(
+        "state", rows, p_len + max_new_tokens, cfg.num_attention_heads,
+        cfg.num_hidden_layers, "float32", 0,
+        {"kv": 0, "state": held * dims.head_dim // high,
+         "norm": held * power_retention.NORM_ROWS // high},
+        prefill={"chunk": chunk, "chunks": p_len // chunk},
+        state_layers=len(states), state_dtype="float32",
+        state_write_bytes=held * runs // pairs,
+        window_bytes=decoding.nbytes(windows))
     hd = dims.head_dim
     at = slice(AUDIT_HEAD * hd, (AUDIT_HEAD + 1) * hd)
 
@@ -207,7 +164,7 @@ def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
             x, carried[i], (k, v, log_gamma) = mix(x, lp, carried[i])
             if i == AUDIT_LAYER:
                 given = (k[..., at], v[..., at], log_gamma[..., AUDIT_HEAD])
-            x = _ffn_block(x, ffn, eps)
+            x = B.ffn_block(x, ffn, eps, gate_dtype=dtype, sum_in_scope=True)
         return x, carried, given
 
     # ---- prefill: the prompt a chunk at a time
@@ -219,62 +176,29 @@ def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
         return states, (x[:, -1], given)
 
     with jax.named_scope("prefill"):
-        if whole == 1:
-            states, (x_last, given) = prefill_piece(states, 0, chunk)
-            seen = [given]
-        else:
-            states, (lasts, given) = jax.lax.scan(
-                lambda s, p0: prefill_piece(s, p0, chunk), states,
-                jnp.arange(whole, dtype=jnp.int32) * chunk)
-            x_last = lasts[-1]
-            # [pieces, rows, chunk, ...] -> [rows, pieces * chunk, ...]
-            seen = [jax.tree.map(lambda a: jnp.moveaxis(a, 0, 1).reshape(
-                (rows, whole * chunk) + a.shape[3:]), given)]
-        if p_len > whole * chunk:
-            states, (x_last, given) = prefill_piece(states, whole * chunk,
-                                                    p_len - whole * chunk)
-            seen.append(given)
-        logp0 = head(x_last)
-    # the steps that consume a token: all but the first, which takes logp0
-    steps = max(max_new_tokens - 1, 1)
+        states, x_last, seen = decoding.chunked_walk(prefill_piece, states,
+                                                     p_len, chunk)
+        first_logp = head(x_last)
     # a layer carries on what the prefill left and an empty window beside it
-    state0 = {"s": list(zip(states, windows)),
-              "index": jnp.asarray(p_len, jnp.int32),
-              "logp0": logp0, "first": jnp.asarray(True),
-              "given": (jnp.zeros((rows, steps, hd), dtype),
-                        jnp.zeros((rows, steps, hd), dtype),
-                        jnp.zeros((rows, steps), jnp.float32))}
+    state0 = decoding.start(
+        {"s": list(zip(states, windows))}, p_len, first_logp,
+        decoding.audit_log(rows, max_new_tokens, [
+            ((hd,), dtype), ((hd,), dtype), ((), jnp.float32)]))
 
-    # ---- one step: each layer's one-token form over its own state and window
-    def step_fn(tokens, state):
-        index, first = state["index"], state["first"]
-        # the first step consumes the prefill's distribution and must write
-        # nothing (position p holds the first generated token): the layers
-        # run with the state's update switched off, outside the conditional
-        # (see ``retention_decode``), and the conditional holds the head
-        # alone. What that step leaves in the audit's first place, the next
-        # step writes over: both stand at position p.
-        with jax.named_scope("decode_step"):
-            x, new, given = through(
-                embed(tokens)[:, None, :], state["s"],
-                lambda x, lp, sw: R.retention_decode(x, lp, dims, sw, index,
-                                                     ~first))
-            logp = jax.lax.cond(first, lambda _: state["logp0"],
-                                lambda _: head(x[:, 0]), operand=None)
-            kept = jax.tree.map(
-                lambda log, a: jax.lax.dynamic_update_slice_in_dim(
-                    log, a, index - p_len, axis=1), state["given"], given)
-        return logp, {"s": new, "logp0": state["logp0"], "given": kept,
-                      "index": jnp.where(first, index, index + 1),
-                      "first": jnp.asarray(False)}
+    # ---- one step: each layer's one-token form over its own state and
+    # window, the state's update switched off in the first
+    # (``retention_decode``)
+    def layers(tokens, carried, index, first):
+        x, new, given = through(
+            embed(tokens)[:, None, :], carried["s"],
+            lambda x, lp, sw: R.retention_decode(x, lp, dims, sw, index,
+                                                 ~first))
+        return x, {"s": new}, given
 
     def audit(state):
         """The generator's ``audit_*`` outputs from the loop's last state."""
         with jax.named_scope("audit"):
-            k, v, log_gamma = (
-                jnp.concatenate(parts[:-1] + (parts[-1][:, :max_new_tokens - 1],),
-                                axis=1)
-                for parts in zip(*seen, state["given"]))
+            k, v, log_gamma = decoding.audit_join(seen, state, max_new_tokens)
             return {"audit_k": k, "audit_v": v, "audit_log_gamma": log_gamma,
                     # (the last token went in at ``index - 1``; what the
                     # head's window still holds of it and those before is
@@ -283,25 +207,16 @@ def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
                         *state["s"][AUDIT_LAYER], state["index"] - 1,
                         AUDIT_HEAD)}
 
-    return state0, step_fn, audit
+    return (state0, decoding.step_with_write_switch(layers, head, p_len),
+            audit)
 
 
-def make_generator(cfg: BrumbyConfig, max_new_tokens: int, bos_id: int = 1,
-                   eos_id: int = 2):
-    """Greedy incremental generation over the carried states. Returns a
-    program fn: ``(prompt_ids [b, p]) -> {"ids": [b, max_new_tokens],
-    "audit_k", "audit_v", "audit_log_gamma", "audit_sums"}`` (the module's
-    docstring says what the audit holds)."""
-    from ..layers.beam_search import greedy_search
-
-    def generate(prompt_ids):
-        state0, step_fn, audit = _decoder(cfg, prompt_ids, max_new_tokens)
-        ids, state = greedy_search(
-            step_fn, state0, prompt_ids.shape[0], max_new_tokens,
-            bos_id=bos_id, eos_id=eos_id, with_state=True)
-        return {"ids": ids, **audit(state)}
-
-    return generate
+# ``make_generator(cfg, max_new_tokens, bos_id=1, eos_id=2)``: greedy
+# incremental generation over the carried states, a program fn ``(prompt_ids
+# [b, p]) -> {"ids": [b, max_new_tokens], "audit_k", "audit_v",
+# "audit_log_gamma", "audit_sums"}`` (the module's docstring says what the
+# audit holds)
+make_generator = functools.partial(decoding.make_generator, _decoder)
 
 
 __all__ = ["BrumbyConfig", "base_config", "make_generator"]

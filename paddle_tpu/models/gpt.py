@@ -20,7 +20,6 @@ TRAINING PATH:
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +29,7 @@ from .. import layers as L
 from ..core.errors import enforce
 from ..framework import LayerHelper, cast_compute, name_scope, sp_config
 from ..layers import attention as A
+from ..layers import decoding
 from ..layers import stacked as S
 from .lm_head import lm_head_loss
 
@@ -120,21 +120,116 @@ def make_model(cfg: GPTConfig):
     return gpt
 
 
-def _record_decode_plan(cfg: GPTConfig, state):
-    """One zero-length span in the program's ring for each generator
-    traced: the cache the decode loop carries, as held. ``lane_width`` is
-    the minor dimension of a stored slab; a multiple of 128 means the
-    chip's tiling pads nothing."""
-    from ..core import profiler
+def _decoder(cfg: GPTConfig, prompt_ids, max_new_tokens: int,
+             beam_size: int = 1):
+    """``(state0, step_fn, audit)``, the contract of ``layers/decoding.py``:
+    the parameters (created or fetched here, once, with the exact names the
+    train program uses, so trained params load directly), the prefill of
+    ``prompt_ids``, its rows repeated ``beam_size`` times, and the cached
+    step that follows it; no audit."""
+    dtype = jnp.dtype(cfg.dtype)
+    b, p = prompt_ids.shape
+    total = p + max_new_tokens
+    decoding.check_length(p, max_new_tokens, cfg.max_len, "max_len")
+    pe = A.positional_encoding(cfg.max_len, cfg.d_model, dtype)
 
-    slabs = jax.tree.leaves(state)
-    rows, max_len, lane_width = slabs[0].shape
-    profiler.record_span(
-        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
-        heads=cfg.num_heads, head_dim=cfg.d_model // cfg.num_heads,
-        layers=cfg.num_layers, cache_dtype=str(slabs[0].dtype),
-        lane_width=lane_width,
-        cache_bytes=sum(a.size * a.dtype.itemsize for a in slabs))
+    # the decode loop closes over the arrays (no LayerHelper calls inside
+    # scan — nothing to re-resolve)
+    with name_scope("tok"):
+        w_emb = LayerHelper("embedding").create_parameter(
+            "w", (cfg.vocab_size, cfg.d_model), dtype,
+            initializer=init.Xavier())
+    with name_scope("gpt"):
+        stack = S.encoder_stack_params(cfg.num_layers, cfg.d_model,
+                                       cfg.d_inner)
+        ln = LayerHelper("layer_norm")
+        ln_scale = ln.create_parameter("scale", (cfg.d_model,), jnp.float32,
+                                       initializer=init.Constant(1.0))
+        ln_bias = ln.create_parameter("bias", (cfg.d_model,), jnp.float32,
+                                      initializer=init.Constant(0.0))
+    w_head = LayerHelper("lm_head").create_parameter(
+        "w", (cfg.d_model, cfg.vocab_size), dtype,
+        initializer=init.Xavier())
+
+    def head(x_last):  # [rows, d] -> log-probs [rows, vocab]
+        with jax.named_scope("head"):
+            h = S._ln(x_last[:, None, :], ln_scale, ln_bias)[:, 0]
+            return jax.nn.log_softmax(
+                jnp.matmul(h, w_head).astype(jnp.float32), axis=-1)
+
+    # ---- prefill: run the prompt causally, capture per-layer k/v
+    # (cast_compute keeps the scan carry dtype consistent with the
+    # blocks' compute dtype regardless of cfg.dtype)
+    def pre(a, lp):
+        return S.prefill_block(a, lp, cfg.num_heads, cfg.use_flash)
+
+    with jax.named_scope("prefill"):
+        with jax.named_scope("tok"):
+            x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
+        x, (ks, vs) = jax.lax.scan(pre, x, stack)
+        first_logp = head(x[:, -1])  # first generated token comes from here
+
+    K = beam_size
+    rows = b * K
+    L = cfg.num_layers
+
+    def grow(a):  # [b, p, ...] -> [rows, total, ...]
+        a = jnp.repeat(a, K, axis=0) if K > 1 else a
+        pad = jnp.zeros((rows, total - p) + a.shape[2:], a.dtype)
+        return jnp.concatenate([a, pad], axis=1)
+
+    # caches are PER-LAYER lists of lane-dense [rows, total, h*hd]
+    # arrays (layers/stacked.py: heads side by side in the minor
+    # dimension, so the TPU's (8, 128) tiling pads nothing) —
+    # beam_search reorders state leaves whose leading dim is
+    # batch*beam, so the layer axis must NOT lead (the transformer
+    # decoder's contract, layers/beam_search.py _gather_beams)
+    enforce(cfg.kv_cache_dtype in ("compute", "int8"),
+            f"kv_cache_dtype={cfg.kv_cache_dtype!r} (compute|int8)")
+    int8_kv = cfg.kv_cache_dtype == "int8"
+    with jax.named_scope("cache_init"):
+        if int8_kv:
+            # quantize the prefix BEFORE growing: padded tail positions
+            # get int8 zeros with zero scales (dequantize to exact 0)
+            kq, ksc = zip(*(S.quantize_kv(ks[i], cfg.num_heads)
+                            for i in range(L)))
+            vq, vsc = zip(*(S.quantize_kv(vs[i], cfg.num_heads)
+                            for i in range(L)))
+            caches = {"kq": [grow(a) for a in kq],
+                      "ks": [grow(a) for a in ksc],
+                      "vq": [grow(a) for a in vq],
+                      "vs": [grow(a) for a in vsc]}
+        else:
+            caches = {"k": [grow(ks[i]) for i in range(L)],
+                      "v": [grow(vs[i]) for i in range(L)]}
+    slab = jax.tree.leaves(caches)[0]
+    decoding.record_plans(
+        "kv", rows, total, cfg.num_heads, L, str(slab.dtype), slab.shape[2],
+        {"cache": caches}, head_dim=cfg.d_model // cfg.num_heads)
+    state0 = decoding.start(
+        caches, p, jnp.repeat(first_logp, K, axis=0) if K > 1 else first_logp)
+    with jax.named_scope("stack_slice"):
+        layer_params = [jax.tree.map(lambda a, i=i: a[i], stack)
+                        for i in range(L)]
+    block = S.decode_block_q8 if int8_kv else S.decode_block
+    cache_keys = tuple(caches)      # the order a block takes and returns them
+
+    # the prefill already produced the first step's distribution;
+    # afterwards embed the chosen token and run the cached stack
+    def layers(tokens, carried, index):
+        with jax.named_scope("tok"):
+            xt = cast_compute(w_emb[tokens][:, None, :]
+                              + pe[index][None, None])
+        new = {k: [] for k in cache_keys}
+        for i, lp in enumerate(layer_params):
+            xt, *layer = block(xt, lp, *(carried[k][i] for k in cache_keys),
+                               index, cfg.num_heads)
+            for k, c in zip(cache_keys, layer):
+                new[k].append(c)
+        return xt, new
+
+    return (state0, decoding.step_in_conditional(layers, head),
+            decoding.no_audit)
 
 
 def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
@@ -148,139 +243,5 @@ def make_generator(cfg: GPTConfig, max_new_tokens: int, beam_size: int = 1,
     Returns a program fn: (prompt_ids [b, p]) -> {"ids": [b, max_new]}
     (greedy) or {"ids": [b, beam, max_new], "scores": [b, beam]} (beam).
     """
-    from ..layers.beam_search import beam_search, greedy_search
-
-    def generate(prompt_ids):
-        dtype = jnp.dtype(cfg.dtype)
-        b, p = prompt_ids.shape
-        total = p + max_new_tokens
-        enforce(total <= cfg.max_len,
-                f"prompt {p} + max_new {max_new_tokens} exceeds max_len "
-                f"{cfg.max_len}")
-        pe = A.positional_encoding(cfg.max_len, cfg.d_model, dtype)
-
-        # ---- create/fetch every parameter ONCE, with the exact names the
-        # train program uses; the decode loop then closes over the arrays
-        # (no LayerHelper calls inside scan — nothing to re-resolve)
-        with name_scope("tok"):
-            w_emb = LayerHelper("embedding").create_parameter(
-                "w", (cfg.vocab_size, cfg.d_model), dtype,
-                initializer=init.Xavier())
-        with name_scope("gpt"):
-            stack = S.encoder_stack_params(cfg.num_layers, cfg.d_model,
-                                           cfg.d_inner)
-            ln = LayerHelper("layer_norm")
-            ln_scale = ln.create_parameter("scale", (cfg.d_model,), jnp.float32,
-                                           initializer=init.Constant(1.0))
-            ln_bias = ln.create_parameter("bias", (cfg.d_model,), jnp.float32,
-                                          initializer=init.Constant(0.0))
-        w_head = LayerHelper("lm_head").create_parameter(
-            "w", (cfg.d_model, cfg.vocab_size), dtype,
-            initializer=init.Xavier())
-
-        def head(x_last):  # [rows, d] -> log-probs [rows, vocab]
-            with jax.named_scope("head"):
-                h = S._ln(x_last[:, None, :], ln_scale, ln_bias)[:, 0]
-                return jax.nn.log_softmax(
-                    jnp.matmul(h, w_head).astype(jnp.float32), axis=-1)
-
-        # ---- prefill: run the prompt causally, capture per-layer k/v
-        # (cast_compute keeps the scan carry dtype consistent with the
-        # blocks' compute dtype regardless of cfg.dtype)
-        def pre(a, lp):
-            return S.prefill_block(a, lp, cfg.num_heads, cfg.use_flash)
-
-        with jax.named_scope("prefill"):
-            with jax.named_scope("tok"):
-                x = cast_compute(w_emb[prompt_ids] + pe[:p][None])
-            x, (ks, vs) = jax.lax.scan(pre, x, stack)
-            logp0 = head(x[:, -1])  # first generated token comes from here
-
-        K = beam_size
-        rows = b * K
-        L = cfg.num_layers
-
-        def grow(a):  # [b, p, ...] -> [rows, total, ...]
-            a = jnp.repeat(a, K, axis=0) if K > 1 else a
-            pad = jnp.zeros((rows, total - p) + a.shape[2:], a.dtype)
-            return jnp.concatenate([a, pad], axis=1)
-
-        # caches are PER-LAYER lists of lane-dense [rows, total, h*hd]
-        # arrays (layers/stacked.py: heads side by side in the minor
-        # dimension, so the TPU's (8, 128) tiling pads nothing) —
-        # beam_search reorders state leaves whose leading dim is
-        # batch*beam, so the layer axis must NOT lead (the transformer
-        # decoder's contract, layers/beam_search.py _gather_beams)
-        enforce(cfg.kv_cache_dtype in ("compute", "int8"),
-                f"kv_cache_dtype={cfg.kv_cache_dtype!r} (compute|int8)")
-        int8_kv = cfg.kv_cache_dtype == "int8"
-        with jax.named_scope("cache_init"):
-            if int8_kv:
-                # quantize the prefix BEFORE growing: padded tail positions
-                # get int8 zeros with zero scales (dequantize to exact 0)
-                kq, ksc = zip(*(S.quantize_kv(ks[i], cfg.num_heads)
-                                for i in range(L)))
-                vq, vsc = zip(*(S.quantize_kv(vs[i], cfg.num_heads)
-                                for i in range(L)))
-                state0 = {"kq": [grow(a) for a in kq],
-                          "ks": [grow(a) for a in ksc],
-                          "vq": [grow(a) for a in vq],
-                          "vs": [grow(a) for a in vsc]}
-            else:
-                state0 = {"k": [grow(ks[i]) for i in range(L)],
-                          "v": [grow(vs[i]) for i in range(L)]}
-        _record_decode_plan(cfg, state0)
-        state0.update(
-            index=jnp.asarray(p, jnp.int32),
-            logp0=jnp.repeat(logp0, K, axis=0) if K > 1 else logp0,
-            first=jnp.asarray(True))
-        with jax.named_scope("stack_slice"):
-            layer_params = [jax.tree.map(lambda a, i=i: a[i], stack)
-                            for i in range(L)]
-        cache_keys = ("kq", "ks", "vq", "vs") if int8_kv else ("k", "v")
-
-        def step_fn(tokens, state):
-            # the prefill already produced the first step's distribution;
-            # afterwards embed the chosen token and run the cached stack
-            @jax.named_scope("decode_step")
-            def incremental(_):
-                with jax.named_scope("tok"):
-                    xt = cast_compute(w_emb[tokens][:, None, :]
-                                      + pe[state["index"]][None, None])
-                new = tuple([] for _ in cache_keys)
-                for i, lp in enumerate(layer_params):
-                    caches = tuple(state[k][i] for k in cache_keys)
-                    if int8_kv:
-                        xt, *caches = S.decode_block_q8(
-                            xt, lp, *caches, state["index"], cfg.num_heads)
-                    else:
-                        xt, *caches = S.decode_block(
-                            xt, lp, *caches, state["index"], cfg.num_heads)
-                    for dst, c in zip(new, caches):
-                        dst.append(c)
-                return (head(xt[:, 0]),) + new
-
-            logp, *new = jax.lax.cond(
-                state["first"],
-                lambda _: ((state["logp0"],)
-                           + tuple(state[k] for k in cache_keys)),
-                incremental, operand=None)
-            # the first step consumes the prefill's distribution without
-            # writing a token; the index advances only once a generated
-            # token has actually been cached (position p holds token 1)
-            new_state = dict(zip(cache_keys, new))
-            new_state.update(
-                index=jnp.where(state["first"], state["index"],
-                                state["index"] + 1),
-                logp0=state["logp0"], first=jnp.asarray(False))
-            return logp, new_state
-
-        if K > 1:
-            seqs, scores = beam_search(step_fn, state0, b, K, max_new_tokens,
-                                       bos_id=bos_id, eos_id=eos_id,
-                                       length_penalty_alpha=length_penalty_alpha)
-            return {"ids": seqs, "scores": scores}
-        return {"ids": greedy_search(step_fn, state0, rows, max_new_tokens,
-                                     bos_id=bos_id, eos_id=eos_id)}
-
-    return generate
+    return decoding.make_generator(_decoder, cfg, max_new_tokens, bos_id,
+                                   eos_id, beam_size, length_penalty_alpha)

@@ -17,9 +17,9 @@ argmax is over the slice), and ``num_hidden_layers`` of the depth (the rest
 lie on further chips as pipeline stages). Nothing stands in for the absent
 chips or their exchange.
 
-This module serves only: :func:`make_generator`, the contract of
-``gpt.make_generator`` (``prompt_ids [b, p] -> {"ids": [b, new]}`` through
-``greedy_search``). There is no ``make_model``: no cut of this model
+This module serves only: :func:`make_generator`, through the contract of
+``layers/decoding.py`` (``prompt_ids [b, p] -> {"ids": [b, new]}``, the
+first step's plain form). There is no ``make_model``: no cut of this model
 trains on one chip, and the flash backward does not take unequal widths yet
 (ROADMAP R1). Every matrix is created in ``cfg.dtype`` and held in it (no
 float32 master copy: the weights are the chip's memory); norm scales and the
@@ -45,7 +45,7 @@ the weight in every layer of every step.
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,8 @@ import jax.numpy as jnp
 from .. import initializer as init
 from ..core.errors import enforce
 from ..framework import LayerHelper, name_scope
+from ..layers import blocks as B
+from ..layers import decoding
 from ..layers import latent as M
 from ..layers.stacked import StackedInit
 from ..parallel import moe
@@ -128,7 +130,7 @@ def _expert_stack(cfg: KimiK2Config, dtype):
     L, d, f = cfg.expert_layers, cfg.hidden_size, cfg.moe_intermediate_size
     E, held = cfg.n_routed_experts, cfg.experts_held
     p = M.mla_params(cfg.mla, dtype, L)
-    shared = M.gated_ffn_params(d, f * cfg.n_shared_experts, dtype, L,
+    shared = B.gated_ffn_params(d, f * cfg.n_shared_experts, dtype, L,
                                 name="shared")
     p.update({"shared/" + k: v for k, v in shared.items()})
     helper = LayerHelper("experts", name="experts")
@@ -158,7 +160,7 @@ def _expert_ffn(cfg: KimiK2Config, x, p, banks, layer):
     experts flattened to ``[layers * held, ...]`` and ``layer`` this one's
     index in it."""
     b, s, d = x.shape
-    h = M.rms_norm(x, p["shared/ffn_norm/g"], cfg.rms_norm_eps)
+    h = B.rms_norm(x, p["shared/ffn_norm/g"], cfg.rms_norm_eps)
     flat = h.reshape(b * s, d)
     experts, weights = moe.sigmoid_topk_route(
         flat, p["router/w"], p["router/select_bias"],
@@ -168,36 +170,16 @@ def _expert_ffn(cfg: KimiK2Config, x, p, banks, layer):
         experts_held=cfg.experts_held, experts_total=cfg.n_routed_experts,
         bank_offset=layer * cfg.experts_held)
     with jax.named_scope("shared"):
-        shared = M.gated_ffn(h, p["shared/gate/w"], p["shared/up/w"],
+        shared = B.gated_ffn(h, p["shared/gate/w"], p["shared/up/w"],
                              p["shared/down/w"])
     return (x.astype(jnp.float32) + shared.astype(jnp.float32)
             + routed.reshape(b, s, d)).astype(x.dtype)
 
 
-def _record_decode_plan(cfg: KimiK2Config, c, r, regrouped):
-    """The latent cache the decode loop carries, beside GPT's
-    ``decode.plan``: ``lane_width`` is the minor dimension of a latent
-    slab as stored (a rotary slab's is the context length);
-    ``q_b_regrouped_bytes`` what the request's one regrouping of ``q_b``
-    wrote (``regrouped``: the stacks that hold its halves)."""
-    from ..core import profiler
-
-    rows, max_len, lane_width = c[0].shape
-    profiler.record_span(
-        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
-        heads=cfg.num_attention_heads, layers=len(c), cache_kind="latent",
-        cache_dtype=str(c[0].dtype), lane_width=lane_width,
-        rope_lane_width=r[0].shape[-1],
-        cache_bytes=sum(a.size * a.dtype.itemsize for a in c + r),
-        q_b_regrouped_bytes=sum(
-            t[k].size * t[k].dtype.itemsize for t in regrouped
-            for k in ("q_b/w_nope", "q_b/w_rope")))
-
-
 def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
-    """``(state0, step_fn)`` for ``layers/beam_search``: the parameters
-    (created or fetched here, once, by name), the prefill of ``prompt_ids``
-    and the cached step that follows it."""
+    """``(state0, step_fn, audit)``, the contract of ``layers/decoding.py``:
+    the parameters (created or fetched here, once, by name), the prefill of
+    ``prompt_ids`` and the cached step that follows it; no audit."""
     enforce(0 < cfg.first_k_dense_replace < cfg.num_hidden_layers,
             "kimi_k2: dense layers lead and expert layers follow")
     enforce(0 <= cfg.first_expert
@@ -206,27 +188,19 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
             f"{cfg.first_expert + cfg.experts_held} of {cfg.n_routed_experts}")
     dims, yarn, dtype = cfg.mla, cfg.yarn, jnp.dtype(cfg.dtype)
     rows, p_len = prompt_ids.shape
-    enforce(p_len + max_new_tokens <= cfg.max_position_embeddings,
-            f"prompt {p_len} + max_new {max_new_tokens} exceeds "
-            f"max_position_embeddings {cfg.max_position_embeddings}")
+    decoding.check_length(p_len, max_new_tokens, cfg.max_position_embeddings)
     d, n_dense, n_exp = (cfg.hidden_size, cfg.first_k_dense_replace,
                          cfg.expert_layers)
 
     # every parameter once, by name; the loops close over the arrays
-    with name_scope("tok"):
-        w_emb = LayerHelper("embedding").create_parameter(
-            "w", (cfg.vocab_size, d), dtype, initializer=init.Normal(0.0, 1.0))
+    w_emb = decoding.token_embedding(cfg.vocab_size, d, dtype)
     with name_scope("dense"):
         dense = M.mla_params(dims, dtype, n_dense)
-        dense.update(M.gated_ffn_params(d, cfg.intermediate_size, dtype,
+        dense.update(B.gated_ffn_params(d, cfg.intermediate_size, dtype,
                                         n_dense))
     with name_scope("moe"):
         stack = _expert_stack(cfg, dtype)
-    final_g = LayerHelper("final_norm").create_parameter(
-        "g", (d,), jnp.float32, initializer=init.Constant(1.0))
-    w_head = LayerHelper("lm_head").create_parameter(
-        "w", (d, cfg.vocab_size), dtype,
-        initializer=init.Normal(0.0, d ** -0.5))
+    final_g, w_head = decoding.untied_head(cfg.vocab_size, d, dtype)
 
     banks = tuple(stack[k].reshape((-1,) + stack[k].shape[2:])
                   for k in _BANKS)
@@ -244,14 +218,13 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
 
     def head(x_last):   # [rows, d] -> log-probs over the held rows
         with jax.named_scope("head"):
-            h = M.rms_norm(x_last, final_g, cfg.rms_norm_eps)
-            return jax.nn.log_softmax(jnp.matmul(
-                h, w_head, preferred_element_type=jnp.float32), axis=-1)
+            return decoding.log_probs(
+                B.rms_norm(x_last, final_g, cfg.rms_norm_eps), w_head)
 
     # ---- prefill: the prompt through the expanded form
     def pre_dense(x, lp):
         x, cache = M.mla_prefill(x, lp, dims, yarn)
-        return M.ffn_block(x, lp, cfg.rms_norm_eps), cache
+        return B.ffn_block(x, lp, cfg.rms_norm_eps), cache
 
     def pre_expert(x, xs):
         lp, layer = xs
@@ -263,7 +236,7 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
             x = w_emb[prompt_ids]
         x, (c0, r0) = jax.lax.scan(pre_dense, x, dense)
         x, (c1, r1) = jax.lax.scan(pre_expert, x, (sliced, layer_ids))
-        logp0 = head(x[:, -1])
+        first_logp = head(x[:, -1])
 
         def grow(a, axis):      # [b, p, ...] -> [b, total, ...] on ``axis``
             pad = [(0, 0)] * a.ndim
@@ -274,59 +247,43 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
         n_layers = cfg.num_hidden_layers
         c = [grow(a, 1) for a in list(c0) + list(c1)]   # [rows, total, kv_lora]
         r = [grow(a, 2) for a in list(r0) + list(r1)]   # [rows, rope, total]
-    _record_decode_plan(cfg, c, r, (dense, sliced))
-    state0 = {"c": c, "r": r, "index": jnp.asarray(p_len, jnp.int32),
-              "logp0": logp0, "first": jnp.asarray(True)}
+    # ``lane_width``: the minor dimension of a latent slab as stored (a
+    # rotary slab's is the context length); ``q_b_regrouped_bytes``: what the
+    # request's one regrouping of ``q_b`` wrote
+    decoding.record_plans(
+        "latent", rows, c[0].shape[1], cfg.num_attention_heads, n_layers,
+        str(c[0].dtype), c[0].shape[2], {"cache": c + r},
+        rope_lane_width=r[0].shape[-1],
+        q_b_regrouped_bytes=decoding.nbytes(
+            [t[k] for t in (dense, sliced)
+             for k in ("q_b/w_nope", "q_b/w_rope")]))
 
     # ---- one cached step: the absorbed form, the layers written out, each
     # with its own slabs (written at one row, read in place) and its slice
     # of the stack taken where it is used
-    def step_fn(tokens, state):
-        index = state["index"]
+    def layers(tokens, carried, index):
+        with jax.named_scope("tok"):
+            x = w_emb[tokens][:, None, :]
+        c, r = list(carried["c"]), list(carried["r"])
+        for i in range(n_layers):
+            j = i - n_dense
+            with jax.named_scope("stack_slice"):
+                lp = jax.tree.map(lambda a: a[i if j < 0 else j],
+                                  dense if j < 0 else sliced)
+                lp.update(step_q_b[i])
+            x, c[i], r[i] = M.mla_decode(x, lp, c[i], r[i], index, dims, yarn)
+            x = (B.ffn_block(x, lp, cfg.rms_norm_eps) if j < 0
+                 else _expert_ffn(cfg, x, lp, banks, j))
+        return x, {"c": c, "r": r}
 
-        @jax.named_scope("decode_step")
-        def incremental(_):
-            with jax.named_scope("tok"):
-                x = w_emb[tokens][:, None, :]
-            c, r = list(state["c"]), list(state["r"])
-            for i in range(n_layers):
-                j = i - n_dense
-                with jax.named_scope("stack_slice"):
-                    lp = jax.tree.map(lambda a: a[i if j < 0 else j],
-                                      dense if j < 0 else sliced)
-                    lp.update(step_q_b[i])
-                x, c[i], r[i] = M.mla_decode(x, lp, c[i], r[i], index, dims,
-                                             yarn)
-                x = (M.ffn_block(x, lp, cfg.rms_norm_eps) if j < 0
-                     else _expert_ffn(cfg, x, lp, banks, j))
-            return head(x[:, 0]), c, r
-
-        # the first step consumes the prefill's distribution and writes
-        # nothing; position p holds the first generated token
-        logp, c, r = jax.lax.cond(
-            state["first"],
-            lambda _: (state["logp0"], state["c"], state["r"]),
-            incremental, operand=None)
-        return logp, {"c": c, "r": r, "logp0": state["logp0"],
-                      "index": jnp.where(state["first"], index, index + 1),
-                      "first": jnp.asarray(False)}
-
-    return state0, step_fn
+    return (decoding.start({"c": c, "r": r}, p_len, first_logp),
+            decoding.step_in_conditional(layers, head), decoding.no_audit)
 
 
-def make_generator(cfg: KimiK2Config, max_new_tokens: int, bos_id: int = 1,
-                   eos_id: int = 2):
-    """Greedy incremental generation over the latent cache. Returns a
-    program fn: ``(prompt_ids [b, p]) -> {"ids": [b, max_new_tokens]}``."""
-    from ..layers.beam_search import greedy_search
-
-    def generate(prompt_ids):
-        state0, step_fn = _decoder(cfg, prompt_ids, max_new_tokens)
-        return {"ids": greedy_search(step_fn, state0, prompt_ids.shape[0],
-                                     max_new_tokens, bos_id=bos_id,
-                                     eos_id=eos_id)}
-
-    return generate
+# ``make_generator(cfg, max_new_tokens, bos_id=1, eos_id=2)``: greedy
+# incremental generation over the latent cache, a program fn ``(prompt_ids
+# [b, p]) -> {"ids": [b, max_new_tokens]}``
+make_generator = functools.partial(decoding.make_generator, _decoder)
 
 
 __all__ = ["KimiK2Config", "base_config", "make_generator"]

@@ -13,11 +13,11 @@ the published stack; a lightning layer's decay follows its published
 index, ``published_layers`` deep); the rest of the depth lies on further
 chips. Nothing stands in for it.
 
-This module serves only: :func:`make_generator`, the contract of
-``gpt.make_generator`` (``prompt_ids [b, p] -> {"ids": [b, new]}`` through
-``greedy_search``). There is no ``make_model``: no cut of this model trains
-on one chip, and neither kernel has a backward (ROADMAP R5, R15). Matrices
-are created and held in ``cfg.dtype``; norm scales are float32.
+This module serves only: :func:`make_generator`, through the contract of
+``layers/decoding.py`` (``prompt_ids [b, p] -> {"ids": [b, new]}``, the
+first step's plain form). There is no ``make_model``: no cut of this model
+trains on one chip, and neither kernel has a backward (ROADMAP R5, R15).
+Matrices are created and held in ``cfg.dtype``; norm scales are float32.
 
 The carried state has two kinds of entry, in per-layer lists: for each
 sparse layer a key slab, a value slab and a compressed-key slab, lane-dense
@@ -41,17 +41,17 @@ PERF.md section 6, PR 33).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-import time
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import initializer as init
 from ..core.errors import enforce
-from ..framework import LayerHelper, name_scope
-from ..layers import latent as M
+from ..framework import name_scope
+from ..layers import blocks as B
+from ..layers import decoding
 from ..layers import sala as S
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
@@ -122,31 +122,11 @@ def base_config(**kw) -> MiniCPMSALAConfig:
     return MiniCPMSALAConfig(**kw)
 
 
-def _record_plans(cfg: MiniCPMSALAConfig, kv, ck, states, rows, max_len,
-                  chunk, chunks):
-    """``decode.plan`` beside GPT's and Kimi-K2's, with what is new here:
-    the carried state by kind. ``prefill.plan``: how the prompt is walked."""
-    from ..core import profiler
-
-    nbytes = lambda arrays: sum(a.size * a.dtype.itemsize for a in arrays)
-    kv_bytes, index_bytes, state_bytes = nbytes(kv), nbytes(ck), nbytes(states)
-    profiler.record_span(
-        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
-        heads=cfg.num_attention_heads, layers=cfg.num_hidden_layers,
-        cache_kind="kv+state", cache_dtype=cfg.dtype,
-        lane_width=kv[0].shape[-1] if kv else 0,
-        cache_bytes=kv_bytes + index_bytes + state_bytes, kv_bytes=kv_bytes,
-        index_bytes=index_bytes, state_bytes=state_bytes,
-        sparse_layers=len(ck), state_layers=len(states),
-        state_dtype="float32")
-    profiler.record_span("prefill.plan", time.time_ns(), 0, chunk=chunk,
-                         chunks=chunks, rows=rows)
-
-
 def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
-    """``(state0, step_fn)`` for ``layers/beam_search``: the parameters
-    (created or fetched here, once, by name), the chunked prefill of
-    ``prompt_ids`` and the one-token step that follows it."""
+    """``(state0, step_fn, audit)``, the contract of ``layers/decoding.py``:
+    the parameters (created or fetched here, once, by name), the chunked
+    prefill of ``prompt_ids`` and the one-token step that follows it; no
+    audit."""
     kinds, indices = tuple(cfg.mixer_types), cfg.indices
     enforce(len(kinds) == len(indices) == cfg.num_hidden_layers
             and set(kinds) <= {SPARSE, LIGHTNING},
@@ -154,9 +134,7 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
             f"published indices {indices}")
     sp, li, dtype = cfg.sparse, cfg.lightning, jnp.dtype(cfg.dtype)
     rows, p_len = prompt_ids.shape
-    enforce(p_len + max_new_tokens <= cfg.max_position_embeddings,
-            f"prompt {p_len} + max_new {max_new_tokens} exceeds "
-            f"max_position_embeddings {cfg.max_position_embeddings}")
+    decoding.check_length(p_len, max_new_tokens, cfg.max_position_embeddings)
     d, eps, a = cfg.hidden_size, cfg.rms_norm_eps, cfg.branch_scale
     n_sparse, n_light = kinds.count(SPARSE), kinds.count(LIGHTNING)
     # which of its kind's entries of the carried state a layer has
@@ -164,21 +142,15 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
 
     # every parameter once, by name, a layer under its published index; the
     # loops close over the arrays
-    with name_scope("tok"):
-        w_emb = LayerHelper("embedding").create_parameter(
-            "w", (cfg.vocab_size, d), dtype, initializer=init.Normal(0.0, 1.0))
+    w_emb = decoding.token_embedding(cfg.vocab_size, d, dtype)
     per_layer = []
     for kind, index in zip(kinds, indices):
         with name_scope(f"layer_{index}"):
             mixer = (S.sparse_params(sp, dtype) if kind == SPARSE
                      else S.lightning_params(li, dtype))
-            per_layer.append((mixer, M.gated_ffn_params(
+            per_layer.append((mixer, B.gated_ffn_params(
                 d, cfg.intermediate_size, dtype)))
-    final_g = LayerHelper("final_norm").create_parameter(
-        "g", (d,), jnp.float32, initializer=init.Constant(1.0))
-    w_head = LayerHelper("lm_head").create_parameter(
-        "w", (d, cfg.vocab_size), dtype,
-        initializer=init.Normal(0.0, d ** -0.5))
+    final_g, w_head = decoding.untied_head(cfg.vocab_size, d, dtype)
     log_decay = [S.lightning_log_decay(li.heads, l, cfg.published_layers)
                  for l, k in zip(indices, kinds) if k == LIGHTNING]
 
@@ -189,11 +161,10 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
 
     def head(x_last):   # [rows, d] -> log-probs
         with jax.named_scope("head"):
-            h = M.rms_norm(x_last, final_g, eps)
+            h = B.rms_norm(x_last, final_g, eps)
             h = (h.astype(jnp.float32)
                  / (cfg.hidden_size / cfg.dim_model_base)).astype(dtype)
-            return jax.nn.log_softmax(jnp.matmul(
-                h, w_head, preferred_element_type=jnp.float32), axis=-1)
+            return decoding.log_probs(h, w_head)
 
     # ---- the carried state: slabs a sparse layer, a state a lightning layer
     blk = sp.block_size
@@ -215,8 +186,13 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
         * n_sparse,
         "s": [jnp.zeros((rows, li.heads, li.head_dim, li.head_dim),
                         jnp.float32)] * n_light}
-    _record_plans(cfg, carried["k"] + carried["v"], carried["ck"],
-                  carried["s"], rows, total, chunk, p_len // chunk)
+    decoding.record_plans(
+        "kv+state", rows, total, cfg.num_attention_heads,
+        cfg.num_hidden_layers, cfg.dtype, width if n_sparse else 0,
+        {"kv": carried["k"] + carried["v"], "index": carried["ck"],
+         "state": carried["s"]},
+        prefill={"chunk": chunk, "chunks": p_len // chunk},
+        sparse_layers=n_sparse, state_layers=n_light, state_dtype="float32")
 
     def through(x, carried, mix_sparse, mix_light):
         """``x`` through the layers held, each with its own entries of the
@@ -232,70 +208,39 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
             else:
                 x, carried["s"][j] = mix_light(x, lp, carried["s"][j],
                                                log_decay[j])
-            x = S.ffn_block(x, ffn, eps, a)
+            x = B.ffn_block(x, ffn, eps, scale=a)
         return x, carried
 
-    # ---- prefill: the prompt a chunk at a time
-    def prefill_chunk(carried, p0):
-        ids = jax.lax.dynamic_slice_in_dim(prompt_ids, p0, chunk, axis=1)
+    # ---- prefill: the prompt a chunk at a time (whole chunks: no tail)
+    def prefill_piece(carried, p0, length):
+        ids = jax.lax.dynamic_slice_in_dim(prompt_ids, p0, length, axis=1)
         x, carried = through(
             embed(ids), carried,
             lambda x, lp, c: S.sparse_prefill(x, lp, sp, c, p0, selected, a),
             lambda x, lp, s, ld: S.lightning_prefill(x, lp, li, s, ld, p0, a))
-        return carried, x[:, -1]
+        return carried, (x[:, -1], ())
 
     with jax.named_scope("prefill"):
-        if p_len == chunk:
-            carried, x_last = prefill_chunk(carried, 0)
-        else:
-            carried, lasts = jax.lax.scan(
-                prefill_chunk, carried,
-                jnp.arange(p_len // chunk, dtype=jnp.int32) * chunk)
-            x_last = lasts[-1]
-        logp0 = head(x_last)
-    state0 = {**carried, "index": jnp.asarray(p_len, jnp.int32),
-              "logp0": logp0, "first": jnp.asarray(True)}
+        carried, x_last, _ = decoding.chunked_walk(prefill_piece, carried,
+                                                   p_len, chunk)
+        first_logp = head(x_last)
 
     # ---- one step: each layer's one-token form over its own entries
-    def step_fn(tokens, state):
-        index = state["index"]
-        carried = {k: state[k] for k in ("k", "v", "ck", "s")}
+    def layers(tokens, carried, index):
+        return through(
+            embed(tokens)[:, None, :], carried,
+            lambda x, lp, c: S.sparse_decode(x, lp, sp, c, index, p_len, a),
+            lambda x, lp, s, ld: S.lightning_decode(x, lp, li, s, ld, index,
+                                                    a))
 
-        @jax.named_scope("decode_step")
-        def incremental(_):
-            x, new = through(
-                embed(tokens)[:, None, :], carried,
-                lambda x, lp, c: S.sparse_decode(x, lp, sp, c, index, p_len, a),
-                lambda x, lp, s, ld: S.lightning_decode(x, lp, li, s, ld,
-                                                        index, a))
-            return head(x[:, 0]), new
-
-        # the first step consumes the prefill's distribution and writes
-        # nothing; position p holds the first generated token
-        logp, new = jax.lax.cond(
-            state["first"], lambda _: (state["logp0"], carried), incremental,
-            operand=None)
-        return logp, {**new, "logp0": state["logp0"],
-                      "index": jnp.where(state["first"], index, index + 1),
-                      "first": jnp.asarray(False)}
-
-    return state0, step_fn
+    return (decoding.start(carried, p_len, first_logp),
+            decoding.step_in_conditional(layers, head), decoding.no_audit)
 
 
-def make_generator(cfg: MiniCPMSALAConfig, max_new_tokens: int,
-                   bos_id: int = 1, eos_id: int = 2):
-    """Greedy incremental generation over the two-kind carried state.
-    Returns a program fn: ``(prompt_ids [b, p]) -> {"ids": [b,
-    max_new_tokens]}``."""
-    from ..layers.beam_search import greedy_search
-
-    def generate(prompt_ids):
-        state0, step_fn = _decoder(cfg, prompt_ids, max_new_tokens)
-        return {"ids": greedy_search(step_fn, state0, prompt_ids.shape[0],
-                                     max_new_tokens, bos_id=bos_id,
-                                     eos_id=eos_id)}
-
-    return generate
+# ``make_generator(cfg, max_new_tokens, bos_id=1, eos_id=2)``: greedy
+# incremental generation over the two-kind carried state, a program fn
+# ``(prompt_ids [b, p]) -> {"ids": [b, max_new_tokens]}``
+make_generator = functools.partial(decoding.make_generator, _decoder)
 
 
 __all__ = ["LIGHTNING", "MiniCPMSALAConfig", "PUBLISHED_MIXERS", "SPARSE",

@@ -12,10 +12,11 @@ Which mixer a layer has follows from its index ``l`` of ``num_hidden_layers
 ``l < L / 2 + 1`` window attention, ``l = L / 2 + 1`` full attention, then
 even ``l`` a gated memory unit and odd ``l`` cross-attention.
 
-This module serves only: :func:`make_generator`, the contract of
-``gpt.make_generator`` (``prompt_ids [b, p] -> {"ids": [b, new], ...}``
-through ``greedy_search``). No ``make_model``: ``ops/selective_scan.py`` and
-the windowed flash call have no backward (ROADMAP Reach).
+This module serves only: :func:`make_generator`, through the contract of
+``layers/decoding.py`` (``prompt_ids [b, p] -> {"ids": [b, new], ...}``, the
+first step with its write switch). No ``make_model``:
+``ops/selective_scan.py`` and the windowed flash call have no backward
+(ROADMAP Reach).
 
 **What is carried**, three kinds side by side and a fourth that is none:
 a Mamba layer's convolution tail ``[rows, 3, d_inner]`` and float32 state
@@ -26,9 +27,9 @@ read by that layer and by every cross layer; the gated memory units and the
 cross layers carry nothing. ``decode.plan`` says how much each is.
 
 **The prefill** walks the prompt a piece of ``cfg.prefill_chunk`` tokens at
-a time under one ``lax.scan`` with no conditional in it (a shorter tail
-follows as one more piece) through the layers below the full-attention
-layer, and writes that layer's keys and values. Everything above reads
+a time (``decoding.chunked_walk``: a scan, then a shorter tail as one more
+piece) through the layers below the full-attention layer, and writes that
+layer's keys and values. Everything above reads
 nothing of a prompt position but those keys and values, and the first token
 needs the last position's output only: so the full-attention layer's own
 query and FFN and the whole cross-decoder run at the last prompt position
@@ -53,7 +54,7 @@ carried across pieces and steps (``benchmarks/families/phi4_flash.py``).
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 from typing import Tuple
 
 import jax
@@ -62,6 +63,8 @@ import jax.numpy as jnp
 from .. import initializer as init
 from ..core.errors import enforce
 from ..framework import LayerHelper, name_scope
+from ..layers import blocks as B
+from ..layers import decoding
 from ..layers import sambay as S
 
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
@@ -118,38 +121,9 @@ _PARAMS = {MAMBA: S.mamba_params, WINDOW: S.attention_params,
            FULL: S.attention_params, GMU: S.gmu_params, CROSS: S.cross_params}
 
 
-def _record_plans(cfg, mamba, rings, shared, rows, max_len, chunk, pieces,
-                  kinds):
-    """``decode.plan`` beside the other generators', with the three kinds
-    of carry apart; ``prefill.plan``: how the prompt is walked and what the
-    skip leaves out."""
-    from ..core import profiler
-
-    size = lambda arrays: sum(a.size * a.dtype.itemsize for a in arrays)
-    state = size(a for pair in mamba for a in pair)
-    window_kv = size(a for pair in rings for a in pair)
-    shared_kv = size(shared)
-    profiler.record_span(
-        "decode.plan", time.time_ns(), 0, rows=rows, max_len=max_len,
-        heads=cfg.num_attention_heads, layers=cfg.num_hidden_layers,
-        cache_kind="state+window+shared", cache_dtype=cfg.dtype,
-        lane_width=cfg.dims.kv_width, state_bytes=state,
-        state_layers=len(mamba), state_dtype="float32",
-        window_kv_bytes=window_kv, window_layers=len(rings),
-        shared_kv_bytes=shared_kv,
-        shared_kv_readers=1 + kinds.count(CROSS),
-        carry_free_layers=kinds.count(GMU) + kinds.count(CROSS),
-        kv_bytes=window_kv + shared_kv,
-        cache_bytes=state + window_kv + shared_kv)
-    profiler.record_span(
-        "prefill.plan", time.time_ns(), 0, chunk=chunk, pieces=pieces,
-        rows=rows, self_layers=kinds.index(FULL), kv_layers=1,
-        cross_layers=len(kinds) - kinds.index(FULL), cross_positions=1)
-
-
 def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
-    """``(state0, step_fn, audit)`` for ``layers/beam_search``: the
-    parameters (created or fetched here, once, by name), the prefill of
+    """``(state0, step_fn, audit)``, the contract of ``layers/decoding.py``:
+    the parameters (created or fetched here, once, by name), the prefill of
     ``prompt_ids``, the one-token step that follows it, and what the
     generator returns of the last state."""
     kinds = mixer_kinds(cfg.num_hidden_layers)
@@ -162,14 +136,10 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
             f"heads over {dims.kv_heads} key heads, two to one")
     rows, p_len = prompt_ids.shape
     max_len = p_len + max_new_tokens
-    enforce(max_len <= cfg.max_position_embeddings,
-            f"prompt {p_len} + max_new {max_new_tokens} exceeds "
-            f"max_position_embeddings {cfg.max_position_embeddings}")
+    decoding.check_length(p_len, max_new_tokens, cfg.max_position_embeddings)
     d, eps, full = cfg.hidden_size, cfg.layer_norm_eps, kinds.index(FULL)
 
-    with name_scope("tok"):
-        w_emb = LayerHelper("embedding").create_parameter(
-            "w", (cfg.vocab_size, d), dtype, initializer=init.Normal(0.0, 1.0))
+    w_emb = decoding.token_embedding(cfg.vocab_size, d, dtype)
     per_layer = []
     for l, kind in enumerate(kinds):
         with name_scope(f"layer_{l}"):
@@ -187,8 +157,11 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
     def head(x_last):   # [rows, d] -> log-probs; the head is the embedding
         with jax.named_scope("head"):
             return jax.nn.log_softmax(jnp.einsum(
-                "rd,vd->rv", S.layer_norm(x_last, *final, eps), w_emb,
+                "rd,vd->rv", B.layer_norm(x_last, *final, eps), w_emb,
                 preferred_element_type=jnp.float32), axis=-1)
+
+    ffn_of = functools.partial(B.ffn_block, eps=eps, norm="layer",
+                               gate_dtype=dtype, sum_in_scope=True)
 
     # ---- what is carried
     n_mamba, n_window = kinds.count(MAMBA), kinds.count(WINDOW)
@@ -201,9 +174,19 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
             ] * n_window
     shared = (jnp.zeros((rows, max_len, dims.kv_width), dtype),) * 2
     chunk = min(cfg.prefill_chunk, p_len)
-    whole = p_len // chunk
-    _record_plans(cfg, mamba, held, shared, rows, max_len, chunk,
-                  whole + (p_len > whole * chunk), kinds)
+    # the three kinds of carry apart; how the prompt is walked and what the
+    # skip leaves out
+    decoding.record_plans(
+        "state+window+shared", rows, max_len, cfg.num_attention_heads,
+        cfg.num_hidden_layers, cfg.dtype, dims.kv_width,
+        {"state": mamba, "window_kv": held, "shared_kv": shared},
+        prefill={"chunk": chunk, "pieces": -(-p_len // chunk),
+                 "self_layers": full, "kv_layers": 1,
+                 "cross_layers": len(kinds) - full, "cross_positions": 1},
+        state_layers=n_mamba, state_dtype="float32", window_layers=n_window,
+        shared_kv_readers=1 + kinds.count(CROSS),
+        carry_free_layers=kinds.count(GMU) + kinds.count(CROSS),
+        kv_bytes=decoding.nbytes((held, shared)))
     at = slice(0, min(AUDIT_CHANNELS, dims.d_inner))
     audit_slot = [l for l, k in enumerate(kinds) if k == MAMBA][AUDIT_LAYER]
 
@@ -226,9 +209,9 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
                     given = audited(handed)
             else:
                 x, held[j] = S.window_prefill(x, lp, dims, held[j], p0, l)
-            x = S.ffn_block(x, ffn, eps)
+            x = ffn_of(x, ffn)
         shared = S.shared_kv(x, per_layer[full][0], dims, shared, p0)
-        return (mamba, held, tuple(shared)), (x[:, -1], memory[:, -1], given)
+        return (mamba, held, tuple(shared)), ((x[:, -1], memory[:, -1]), given)
 
     # ---- everything from the full-attention layer up, for one position
     def upper(x, memory, shared, index):
@@ -242,104 +225,60 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
                 x = S.gmu(x, lp, dims, memory)
             else:
                 x = S.cross_decode(x, lp, dims, shared, index, l)
-            x = S.ffn_block(x, ffn, eps)
+            x = ffn_of(x, ffn)
         return x, shared
 
     with jax.named_scope("prefill"):
-        carried = (mamba, held, shared)
-        if whole == 1:
-            carried, (x_last, m_last, given) = prefill_piece(carried, 0, chunk)
-            seen = [given]
-        else:
-            carried, (x_lasts, m_lasts, given) = jax.lax.scan(
-                lambda c, p0: prefill_piece(c, p0, chunk), carried,
-                jnp.arange(whole, dtype=jnp.int32) * chunk)
-            x_last, m_last = x_lasts[-1], m_lasts[-1]
-            # [pieces, rows, chunk, ...] -> [rows, pieces * chunk, ...]
-            seen = [jax.tree.map(lambda a: jnp.moveaxis(a, 0, 1).reshape(
-                (rows, whole * chunk) + a.shape[3:]), given)]
-        if p_len > whole * chunk:
-            carried, (x_last, m_last, given) = prefill_piece(
-                carried, whole * chunk, p_len - whole * chunk)
-            seen.append(given)
-        mamba, held, shared = carried
+        (mamba, held, shared), (x_last, m_last), seen = decoding.chunked_walk(
+            prefill_piece, (mamba, held, shared), p_len, chunk)
         x_last, shared = upper(x_last[:, None], m_last[:, None], shared,
                                jnp.asarray(p_len - 1, jnp.int32))
-        logp0 = head(x_last[:, 0])
-    steps = max(max_new_tokens - 1, 1)
+        first_logp = head(x_last[:, 0])
     width = at.stop
-    state0 = {"mamba": list(mamba),
-              "ring": [S.ring_of(h, p_len, dims) for h in held],
-              "shared": shared, "index": jnp.asarray(p_len, jnp.int32),
-              "logp0": logp0, "first": jnp.asarray(True),
-              "given": (jnp.zeros((rows, steps, width), jnp.float32),
-                        jnp.zeros((rows, steps, width), jnp.float32),
-                        jnp.zeros((rows, steps, dims.d_state), jnp.float32))}
+    ring = [S.ring_of(h, p_len, dims) for h in held]
+    state0 = decoding.start(
+        {"mamba": list(mamba), "ring": ring, "shared": shared}, p_len,
+        first_logp, decoding.audit_log(
+            rows, max_new_tokens,
+            [((width,), jnp.float32)] * 2 + [((dims.d_state,), jnp.float32)]))
 
-    # ---- one step: each layer's one-token form over what it carries
-    def step_fn(tokens, state):
-        index, first = state["index"], state["first"]
-        mamba, ring = list(state["mamba"]), list(state["ring"])
-        # the first step consumes the prefill's distribution and must leave
-        # nothing: position p holds the first generated token. The layers
-        # run all the same, outside the conditional (a conditional round
-        # arrays written in place makes the compiler copy them on both of
-        # its sides, PERF.md section 6, PR 39): a Mamba layer keeps its
-        # state (``write``), and what the attention layers put at position
-        # p the next step writes over, since it stands at p too.
-        with jax.named_scope("decode_step"):
-            x = embed(tokens)[:, None, :]
-            for l in range(full):
-                lp, ffn = per_layer[l]
-                j = slot[l]
-                if kinds[l] == MAMBA:
-                    x, mamba[j], memory, handed = S.mamba_decode(
-                        x, lp, dims, mamba[j], ~first)
-                    if l == audit_slot:
-                        given = audited(handed)
-                else:
-                    x, ring[j] = S.window_decode(x, lp, dims, ring[j], index, l)
-                x = S.ffn_block(x, ffn, eps)
-            x, shared = upper(x, memory, state["shared"], index)
-            logp = jax.lax.cond(first, lambda _: state["logp0"],
-                                lambda _: head(x[:, 0]), operand=None)
-            kept = jax.tree.map(
-                lambda log, a: jax.lax.dynamic_update_slice_in_dim(
-                    log, a, index - p_len, axis=1), state["given"], given)
-        return logp, {"mamba": mamba, "ring": ring, "shared": shared,
-                      "logp0": state["logp0"], "given": kept,
-                      "index": jnp.where(first, index, index + 1),
-                      "first": jnp.asarray(False)}
+    # ---- one step: each layer's one-token form over what it carries. In the
+    # first a Mamba layer keeps its state (``write``); what the attention
+    # layers put at position p the next step writes over
+    def layers(tokens, carried, index, first):
+        mamba, ring = list(carried["mamba"]), list(carried["ring"])
+        x = embed(tokens)[:, None, :]
+        for l in range(full):
+            lp, ffn = per_layer[l]
+            j = slot[l]
+            if kinds[l] == MAMBA:
+                x, mamba[j], memory, handed = S.mamba_decode(
+                    x, lp, dims, mamba[j], ~first)
+                if l == audit_slot:
+                    given = audited(handed)
+            else:
+                x, ring[j] = S.window_decode(x, lp, dims, ring[j], index, l)
+            x = ffn_of(x, ffn)
+        x, shared = upper(x, memory, carried["shared"], index)
+        return x, {"mamba": mamba, "ring": ring, "shared": shared}, given
 
     def audit(state):
         """The generator's ``audit_*`` outputs from the loop's last state."""
         with jax.named_scope("audit"):
-            delta, u, b = (
-                jnp.concatenate(parts[:-1] + (parts[-1][:, :max_new_tokens - 1],),
-                                axis=1)
-                for parts in zip(*seen, state["given"]))
+            delta, u, b = decoding.audit_join(seen, state, max_new_tokens)
             return {"audit_delta": delta, "audit_u": u, "audit_b": b,
                     "audit_state": state["mamba"][AUDIT_LAYER][1][..., at]}
 
-    return state0, step_fn, audit
+    return (state0, decoding.step_with_write_switch(layers, head, p_len),
+            audit)
 
 
-def make_generator(cfg: Phi4FlashConfig, max_new_tokens: int, bos_id: int = 1,
-                   eos_id: int = 2):
-    """Greedy incremental generation over the carried states and caches.
-    Returns a program fn: ``(prompt_ids [b, p]) -> {"ids": [b,
-    max_new_tokens], "audit_delta", "audit_u", "audit_b", "audit_state"}``
-    (the module's docstring says what the audit holds)."""
-    from ..layers.beam_search import greedy_search
-
-    def generate(prompt_ids):
-        state0, step_fn, audit = _decoder(cfg, prompt_ids, max_new_tokens)
-        ids, state = greedy_search(
-            step_fn, state0, prompt_ids.shape[0], max_new_tokens,
-            bos_id=bos_id, eos_id=eos_id, with_state=True)
-        return {"ids": ids, **audit(state)}
-
-    return generate
+# ``make_generator(cfg, max_new_tokens, bos_id=1, eos_id=2)``: greedy
+# incremental generation over the carried states and caches, a program fn
+# ``(prompt_ids [b, p]) -> {"ids": [b, max_new_tokens], "audit_delta",
+# "audit_u", "audit_b", "audit_state"}`` (the module's docstring says what the
+# audit holds)
+make_generator = functools.partial(decoding.make_generator, _decoder)
 
 
 __all__ = ["CROSS", "FULL", "GMU", "MAMBA", "Phi4FlashConfig", "WINDOW",

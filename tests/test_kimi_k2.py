@@ -23,7 +23,7 @@ import paddle_tpu as pt
 from benchmarks.families import kimi_k2 as family
 from benchmarks.reference import kimi_k2 as reference
 from paddle_tpu.core import profiler
-from paddle_tpu.layers import latent
+from paddle_tpu.layers import blocks, decoding, latent
 from paddle_tpu.models import kimi_k2
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.parallel import moe
@@ -67,32 +67,9 @@ def highest():
         yield
 
 
-def make_scorer(cfg):
-    """The generator's distributions under given continuations: teacher
-    forcing through the generator's own prefill, cache and step
-    (``kimi_k2._decoder``). A program fn ``(prompt_ids [b, p], next_ids
-    [b, n]) -> {"logp": [b, n + 1, vocab]}``: row ``j`` is the distribution
-    after ``j`` of ``next_ids``."""
-
-    def score(prompt_ids, next_ids):
-        state0, step_fn = kimi_k2._decoder(cfg, prompt_ids,
-                                           next_ids.shape[1] + 1)
-        # the step takes the token chosen before it; the first ignores its
-        tokens = jnp.concatenate([next_ids[:, :1], next_ids], axis=1).T
-
-        def step(state, tok):
-            logp, state = step_fn(tok, state)
-            return state, logp
-
-        _, logp = jax.lax.scan(step, state0, tokens)
-        return {"logp": logp.transpose(1, 0, 2)}
-
-    return score
-
-
 def init_params(config, prompt, new=4, scorer=False):
     cfg = family.program_config(config)
-    prog = pt.build(make_scorer(cfg) if scorer
+    prog = pt.build(decoding.make_scorer(kimi_k2._decoder, cfg) if scorer
                     else kimi_k2.make_generator(cfg, max_new_tokens=new))
     feed = {"prompt_ids": prompt}
     if scorer:
@@ -111,14 +88,14 @@ def init_params(config, prompt, new=4, scorer=False):
 def _rms():
     x, g = rand(0, 3, 5, 32), rand(1, 32)
     want = x / np.sqrt(np.mean(np.square(x), -1, keepdims=True) + 1e-5) * g
-    return latent.rms_norm(x, g), want
+    return blocks.rms_norm(x, g), want
 
 
 def _ffn():
     x, wg, wu, wd = rand(0, 5, 32), rand(1, 32, 48), rand(2, 32, 48), rand(3, 48, 32)
     g = np.asarray(x @ wg, np.float64)
     want = (g / (1 + np.exp(-g)) * np.asarray(x @ wu)) @ np.asarray(wd)
-    return latent.gated_ffn(x, wg, wu, wd), want
+    return blocks.gated_ffn(x, wg, wu, wd), want
 
 
 def _yarn():
@@ -137,7 +114,7 @@ def _rope():
     ang = np.asarray(pos)[:, None] * np.asarray(f)[None]
     z = (np.asarray(x)[..., 0::2] + 1j * np.asarray(x)[..., 1::2]) * np.exp(1j * ang)
     want = np.stack([z.real, z.imag], -1).reshape(x.shape)
-    return latent.rope(x, pos, f), want
+    return blocks.rope(x, pos, f), want
 
 
 @pytest.mark.parametrize("piece", [_rms, _ffn, _yarn, _rope],
@@ -349,9 +326,9 @@ def test_the_shares_add_up(highest):
               "shared_down": rand(52, 16, 32, scale=16 ** -0.5)}
     x = h[None]                                           # [1, t, d]
     norm = 1 + rand(53, 32, scale=0.1)
-    hn = latent.rms_norm(x, norm)[0]
+    hn = blocks.rms_norm(x, norm)[0]
     parts = sum(held_part(hn, w_r, bias, banks, rank, 4) for rank in range(4))
-    got = x[0] + parts + latent.gated_ffn(
+    got = x[0] + parts + blocks.gated_ffn(
         hn, shared["shared_gate"], shared["shared_up"], shared["shared_down"])
     whole = SHAPE._replace(held=16, rank=0)
     want = reference.ffn_part(x, {"ffn_norm": norm, "router": w_r,
@@ -433,7 +410,8 @@ def test_generator_emits_the_scorer_s_argmax(highest):
     (plan,) = [s[4] for s in profiler.spans(since) if s[0] == "decode.plan"]
     assert plan["cache_kind"] == "latent" and plan["lane_width"] == 16
     assert plan["cache_bytes"] == 3 * 2 * 14 * (16 + 8) * 4
-    scorer = pt.build(make_scorer(family.program_config(TINY)))
+    scorer = pt.build(decoding.make_scorer(kimi_k2._decoder,
+                                           family.program_config(TINY)))
     logp = scorer.apply(params, {}, training=False, prompt_ids=prompt,
                         next_ids=ids[:, :-1])[0]["logp"]
     ended = np.cumsum(ids == 2, axis=1) - (ids == 2) > 0

@@ -30,7 +30,7 @@ import paddle_tpu as pt
 from benchmarks.families import minicpm_sala as family
 from benchmarks.reference import minicpm_sala as reference
 from paddle_tpu.core import profiler
-from paddle_tpu.layers import sala
+from paddle_tpu.layers import blocks, decoding, sala
 from paddle_tpu.models import minicpm_sala
 from paddle_tpu.ops import lightning_attention as la
 from paddle_tpu.ops import sparse_attention as sa
@@ -81,29 +81,9 @@ def highest():
         yield
 
 
-def make_scorer(cfg):
-    """The generator's log-probabilities under given continuations: teacher
-    forcing through the generator's own prefill, carried state and step
-    (``minicpm_sala._decoder``). ``(prompt_ids [b, p], next_ids [b, n]) ->
-    {"logp": [b, n + 1, vocab]}``."""
-
-    def score(prompt_ids, next_ids):
-        state0, step_fn = minicpm_sala._decoder(cfg, prompt_ids,
-                                                next_ids.shape[1] + 1)
-        tokens = jnp.concatenate([next_ids[:, :1], next_ids], axis=1).T
-
-        def step(state, tok):
-            logp, state = step_fn(tok, state)
-            return state, logp
-
-        _, logp = jax.lax.scan(step, state0, tokens)
-        return {"logp": logp.transpose(1, 0, 2)}
-
-    return score
-
-
 def scored(config, prompt, nxt, params=None):
-    prog = pt.build(make_scorer(family.program_config(config)))
+    prog = pt.build(decoding.make_scorer(minicpm_sala._decoder,
+                                         family.program_config(config)))
     if params is None:
         params, _ = prog.init(jax.random.PRNGKey(5), prompt_ids=prompt,
                               next_ids=nxt)
@@ -179,7 +159,7 @@ def test_lightning_prefill_against_reference(highest):
                                     reference.LIGHTNING, 10, 300)
         np.testing.assert_allclose(np.asarray(got[row]), np.asarray(want),
                                    atol=MIXER_TOL)
-    u = sala.M.rms_norm(x, p["attn_norm/g"], 1e-6)
+    u = blocks.rms_norm(x, p["attn_norm/g"], 1e-6)
     _, k, v = sala._lightning_qkv(u, p, LIGHT, jnp.arange(300))
     lam = np.exp(np.asarray(decay))[None, :, None, None]
     want_state = np.zeros((2, 4, 16, 16))

@@ -24,7 +24,7 @@ import paddle_tpu as pt
 from benchmarks.families import phi4_flash as family
 from benchmarks.reference import phi4_flash as reference
 from paddle_tpu.core import profiler
-from paddle_tpu.layers import sambay
+from paddle_tpu.layers import blocks, sambay
 from paddle_tpu.models import phi4_flash
 from paddle_tpu.ops import selective_scan as ss
 from paddle_tpu.ops.flash_attention import flash_attention, plan_blocks
@@ -348,7 +348,7 @@ def test_the_ring_wraps_and_the_steps_see_the_window(prefilled):
                                rtol=2e-4, atol=2e-4)
     # the ring holds the last 24 positions, position t at slot t % 24
     with jax.default_matmul_precision("highest"):
-        u = sambay.layer_norm(x, p["norm/g"], p["norm/b"], DIMS.eps)
+        u = blocks.layer_norm(x, p["norm/g"], p["norm/b"], DIMS.eps)
         _, k_all, _ = sambay._qkv(u, p, DIMS)
     for t in range(total - 24, total):
         np.testing.assert_allclose(ring[0][:, t % 24], k_all[:, t],
