@@ -1,9 +1,9 @@
 """What stands round a mixer in every served decoder, once: the norms, the
 rotary map, the gated SiLU FFN with its parameters, the residual sum and the
 FFN block. ``layers/latent.py`` (latent attention), ``layers/sala.py``
-(sparse and lightning attention), ``layers/retention.py`` (power retention)
-and ``layers/sambay.py`` (Mamba, differential attention) hold mixers only and
-take these from here; the models pass :func:`ffn_block` the arguments that
+(sparse and lightning attention), ``layers/retention.py`` (power retention),
+``layers/sambay.py`` (Mamba, differential attention) and ``layers/gqa.py``
+(gated grouped-query attention) hold mixers only and take these from here; the models pass :func:`ffn_block` the arguments that
 give the expression each computes.
 
 Pure functions of arrays, no ``LayerHelper`` call outside the two parameter

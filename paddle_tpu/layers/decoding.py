@@ -2,7 +2,7 @@
 its side of it from.
 
 Every served model (``models/gpt.py``, ``kimi_k2.py``, ``minicpm_sala.py``,
-``brumby.py``, ``phi4_flash.py``) has one function::
+``brumby.py``, ``phi4_flash.py``, ``trinity.py``) has one function::
 
     _decoder(cfg, prompt_ids [rows, p], max_new_tokens) -> (state0, step_fn, audit)
 

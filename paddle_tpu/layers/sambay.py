@@ -18,8 +18,8 @@ scale and bias, statistics in float32). What a mixer carries:
   last ``window`` keys and values, ``[rows, window, kv_heads * head_dim]``
   each, lane-dense as a projection leaves them. A prefill holds them in
   order of position (a piece attends to them and to its own keys through
-  ``flash_attention(window=)``); :func:`ring_of` turns that into the ring
-  the steps write, a key at slot ``position % window``.
+  ``flash_attention(window=)``); ``layers/kv_ring.ring_of`` turns that into
+  the ring the steps write, a key at slot ``position % window``.
 - **full attention** (:func:`shared_kv`, :func:`shared_decode`): its keys
   and values ``[rows, max_len, kv_heads * head_dim]``, which its own query
   and every cross layer's read (:func:`cross_decode`): stored once.
@@ -55,6 +55,7 @@ from .. import initializer as init
 from ..framework import LayerHelper
 from ..ops.flash_attention import flash_attention
 from ..ops.selective_scan import mamba_step, selective_scan
+from . import kv_ring
 from .blocks import gated_ffn_params, layer_norm, params
 from .stacked import NEG_INF
 
@@ -288,8 +289,9 @@ def cache_attention(q, k_cache, v_cache, live, dims: SambaDims, lam):
 def _flash_window(q, k, v, valid_from, dims: SambaDims, lam):
     """A piece's differential attention over a window through the flash
     kernel: ``q [b, s, heads * hd]``, ``k, v [b, window + s, kv_heads *
-    hd]`` (what the window held before the piece, then the piece's own);
-    keys before index ``valid_from`` (traced) hold nothing yet. Returns
+    hd]`` (what the window held before the piece, then the piece's own:
+    ``layers/kv_ring.joined``); keys before index ``valid_from`` (traced)
+    hold nothing yet. Returns
     ``[b, s, pairs, 2 hd]`` float32."""
     b, s, _ = q.shape
     hd, h, sk = dims.head_dim, dims.heads, k.shape[1]
@@ -299,7 +301,7 @@ def _flash_window(q, k, v, valid_from, dims: SambaDims, lam):
                          (b, sk, h // 4, 2, 2, hd))
     v = jnp.broadcast_to(v.reshape(b, sk, h // 4, 1, 2 * hd),
                          (b, sk, h // 4, 4, 2 * hd))
-    bias = jnp.where(jnp.arange(sk) < valid_from, NEG_INF, 0.0)
+    bias = kv_ring.empty_bias(sk, valid_from)
     o = flash_attention(
         q, k.reshape(b, sk, h, hd).transpose(0, 2, 1, 3),
         v.reshape(b, sk, h, 2 * hd).transpose(0, 2, 1, 3), causal=True,
@@ -315,22 +317,10 @@ def window_prefill(x, p, dims: SambaDims, held, p0, layer: int):
     with jax.named_scope("swa"):
         u = layer_norm(x, p["norm/g"], p["norm/b"], dims.eps)
         q, k, v = _qkv(u, p, dims)
-        k = jnp.concatenate([held[0], k], axis=1)
-        v = jnp.concatenate([held[1], v], axis=1)
+        k, v = kv_ring.joined(held[0], k), kv_ring.joined(held[1], v)
         o = _flash_window(q, k, v, dims.window - p0, dims, _lambda(p, layer))
         x = _attn_out(x, p, o, dims, layer)
-    return x, (k[:, -dims.window:], v[:, -dims.window:])
-
-
-def ring_of(held, p_len: int, dims: SambaDims):
-    """What a prefill of ``p_len`` positions left in order of position, as
-    the ring the steps write: position ``t`` at slot ``t % window``."""
-    return tuple(jnp.roll(a, p_len % dims.window, axis=1) for a in held)
-
-
-def _write(cache, row, at):
-    return jax.lax.dynamic_update_slice_in_dim(
-        cache, row[:, None, :].astype(cache.dtype), at, axis=1)
+    return x, (kv_ring.kept(k, dims.window), kv_ring.kept(v, dims.window))
 
 
 def window_decode(x, p, dims: SambaDims, ring, index, layer: int):
@@ -343,8 +333,9 @@ def window_decode(x, p, dims: SambaDims, ring, index, layer: int):
         u = layer_norm(x, p["norm/g"], p["norm/b"], dims.eps)
         q, k, v = _qkv(u[:, 0], p, dims)
         slot = index % dims.window
-        ring = (_write(ring[0], k, slot), _write(ring[1], v, slot))
-        o = cache_attention(q, *ring, jnp.arange(dims.window) <= index, dims,
+        ring = (kv_ring.write(ring[0], k, slot),
+                kv_ring.write(ring[1], v, slot))
+        o = cache_attention(q, *ring, kv_ring.live(dims.window, index), dims,
                             _lambda(p, layer))
         x = _attn_out(x, p, o[:, None], dims, layer)
     return x, ring
@@ -373,7 +364,8 @@ def shared_decode(x, p, dims: SambaDims, shared, index, layer: int):
     with jax.named_scope("full_attn"):
         u = layer_norm(x, p["norm/g"], p["norm/b"], dims.eps)
         q, k, v = _qkv(u[:, 0], p, dims)
-        shared = (_write(shared[0], k, index), _write(shared[1], v, index))
+        shared = (kv_ring.write(shared[0], k, index),
+                  kv_ring.write(shared[1], v, index))
         o = cache_attention(q, *shared,
                             jnp.arange(shared[0].shape[1]) <= index, dims,
                             _lambda(p, layer))
@@ -397,5 +389,5 @@ def cross_decode(x, p, dims: SambaDims, shared, index, layer: int):
 __all__ = ["SambaDims", "attention_params", "cache_attention", "cross_decode",
            "cross_params", "ffn_params", "gmu", "gmu_params",
            "lambda_init", "mamba_decode", "mamba_params",
-           "mamba_prefill", "ring_of", "shared_decode", "shared_kv",
+           "mamba_prefill", "shared_decode", "shared_kv",
            "window_decode", "window_prefill"]

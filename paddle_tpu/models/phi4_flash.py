@@ -64,7 +64,7 @@ from .. import initializer as init
 from ..core.errors import enforce
 from ..framework import LayerHelper, name_scope
 from ..layers import blocks as B
-from ..layers import decoding
+from ..layers import decoding, kv_ring
 from ..layers import sambay as S
 
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
@@ -235,7 +235,7 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
                                jnp.asarray(p_len - 1, jnp.int32))
         first_logp = head(x_last[:, 0])
     width = at.stop
-    ring = [S.ring_of(h, p_len, dims) for h in held]
+    ring = [kv_ring.ring_of(h, p_len, dims.window) for h in held]
     state0 = decoding.start(
         {"mamba": list(mamba), "ring": ring, "shared": shared}, p_len,
         first_logp, decoding.audit_log(

@@ -102,8 +102,21 @@ layout follows the call's shape, one tile walk for both:
   128 x 128 array as before. In HBM nothing is concatenated, broadcast to
   heads or transposed round the call.
 
-``flash.plan`` records ``layout``, ``lane_heads``, ``backward``, ``window``
-and ``rot`` for every call traced.
+- grouped heads (``kv_heads``, forward only): ``k`` and ``v`` hold one head
+  for every ``group`` of ``q``'s, in either layout, and are never repeated
+  to ``q``'s count: a step's query heads lie in one group, or are whole
+  groups, and its block of ``k`` and ``v`` is the key heads they read, picked
+  by the index map where the cache holds them (:func:`_specs`); inside, head
+  ``g`` reads head ``g // group`` of that block. A ``bsd`` call needs a head
+  to be its own lane group (128 wide).
+- a query offset (``q_offset``, forward only): the causal diagonal lies
+  where a traced scalar says, not at ``sk - sq``: a later piece of a prompt
+  against the whole cache. The scalar reaches the index maps (the clamp
+  that keeps blocks past the diagonal from being fetched) and the kernel
+  (the tile bounds and the mask) through SMEM.
+
+``flash.plan`` records ``layout``, ``lane_heads``, ``backward``, ``window``,
+``rot``, ``kv_heads`` and ``q_offset`` for every call traced.
 
 Masking: causal (bottom-right aligned), an additive per-key bias
 [b, s_k] (padding), and segment ids (the LoD ragged-batch equivalent,
@@ -218,6 +231,7 @@ class FlashPlan(NamedTuple):
     backward: str = "split"   # ``fused``: one backward kernel (:func:`_backward`)
     window: int = 0       # keys a causal query sees, itself included; 0: all
     rot: int = 0          # width of a second score operand (q_rot, k_rot); 0: none
+    group: int = 1        # query heads that read one key/value head
 
 
 def _round_up(n, m):
@@ -257,6 +271,15 @@ def padded_rows(s: int) -> int:
     goes into the products, which may be far narrower, and cut the
     output; rows past ``s`` are zeros, which causal queries never see."""
     return _axis_plan(s, None, 128)[0]
+
+
+def padded_keys(s: int) -> int:
+    """The length the keys of a call over ``s`` keys are padded to inside
+    (whole registers of 16, whole tiles, whole blocks once they stream). A
+    cache allocated that long goes into the kernel as it is: no padded
+    copy of it is made for a call, and a causal query never sees the rows
+    past its own."""
+    return _axis_plan(s, None, 16)[0]
 
 
 def _clip(v, lo, hi):
@@ -339,6 +362,12 @@ def lane_heads(d, dv, num_heads) -> int:
     return 128 // d if 128 % d == 0 and num_heads % (128 // d) == 0 else 0
 
 
+def _in_groups(heads, group) -> bool:
+    """Do ``heads`` consecutive query heads (from a multiple of ``heads``)
+    lie in one group of ``group``, or make whole groups?"""
+    return group % heads == 0 or heads % group == 0
+
+
 def _rot_group(rot) -> int:
     """Heads whose rotary parts fill a 128-lane group of ``q_rot`` (1 where
     a part is whole groups itself)."""
@@ -359,7 +388,7 @@ def rot_lane_heads(d, dv, num_heads, rot) -> int:
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
                 bh=1, dv=None, scale=None, num_heads=None,
-                window=0, rot=0) -> FlashPlan:
+                window=0, rot=0, group=1) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -391,7 +420,14 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     rot]`` that every head shares (latent attention's rotary 64 beside its
     128). The walk and the tiles are the call's without it; a step holds
     one head, or as many as start a lane group of ``q_rot``
-    (:func:`rot_lane_heads`). 0: none."""
+    (:func:`rot_lane_heads`). 0: none.
+
+    ``group`` (forward only): query heads that read one key/value head
+    (grouped-query attention; ``k`` and ``v`` hold ``1 / group`` of the
+    heads, head ``h`` reads ``h // group``). A step's heads then lie in one
+    group or are whole groups, so that its block of ``k`` and ``v`` is the
+    key heads they read, as the cache holds them; a ``bsd`` call needs a
+    head to be its own lane group. 1: every head its own."""
     del have_bias, have_seg
     dv = d if dv is None else dv
     packed = lane_heads(d, dv, num_heads)
@@ -429,7 +465,7 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
         heads = max(g for g in range(packed, num_heads + 1, packed)
                     if num_heads % g == 0 and (
                         g == packed or (
-                            g % rot_heads == 0
+                            g % rot_heads == 0 and _in_groups(g, group)
                             and g * step_scores <= STEP_SCORES
                             and g * step_bytes <= STEP_BYTES
                             and g * (tiles_all if written else 1) <= UNROLL)))
@@ -438,19 +474,21 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
         step_bytes = (6 * max(block_q, block_k)
                       * (_round_up(d, 128) + _round_up(dv, 128)) * 2)
         heads = max(g for g in range(1, bh + 1) if bh % g == 0 and (
-            g == 1 or (g * step_scores <= STEP_SCORES
+            g == 1 or (_in_groups(g, group)
+                       and g * step_scores <= STEP_SCORES
                        and g * step_bytes <= STEP_BYTES)))
         layout = ()
     plan = FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
                      sq_p, sk_p, causal, fold, run, tiles_all, dv, *layout)
     return plan._replace(
         backward=_backward(plan, jnp.dtype(dtype).itemsize), window=window,
-        rot=rot)
+        rot=rot, group=group)
 
 
-def _record_plan(p: FlashPlan):
+def _record_plan(p: FlashPlan, h: int, q_offset: bool = False):
     """One span in the program's ring for each attention traced: which
-    plan the call got, and how far the causal skip engages."""
+    plan the call got, and how far the causal skip engages (under a traced
+    ``q_offset`` the count is the last piece's, the most a call walks)."""
     from ..core import profiler
 
     profiler.record_span(
@@ -459,7 +497,7 @@ def _record_plan(p: FlashPlan):
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
         lane_heads=p.lane_heads, backward=p.backward, window=p.window,
-        rot=p.rot)
+        rot=p.rot, kv_heads=h // p.group, q_offset=q_offset)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +508,8 @@ class _Walk(NamedTuple):
     """The static half of a kernel: the plan plus what the call masks."""
     plan: FlashPlan
     scale: float
-    offset: int       # sk - sq: causal is bottom-right aligned
+    offset: int       # sk - sq: causal is bottom-right aligned; or the
+                      # call's ``q_offset``, read from SMEM inside a kernel
     have_bias: bool
     have_seg: bool
 
@@ -500,6 +539,12 @@ class _Walk(NamedTuple):
     @property
     def packed(self):
         return self.plan.layout == "bsd"
+
+    def kv(self, g):
+        """The head of the step's ``k`` and ``v`` blocks that query head
+        ``g`` of the step reads (grouped heads: ``g // group``; a step
+        within one group holds that group's one key head)."""
+        return g if self.plan.group == 1 else g // self.plan.group
 
     def head(self, g, rows):
         """Where head ``g`` of the step has ``rows`` in a block of q, k,
@@ -923,14 +968,16 @@ def _fwd_kernel(*refs, w: _Walk):
                                        causal=p.causal, offset=w.offset,
                                        sk=p.sk, sk_p=p.sk_p)
 
+        gk = w.kv(g)
+
         def chunk(masked):
             def body(j, carry):
                 m, l, acc = carry            # [1, tq], [1, tq], [d, tq]
                 k0 = _at(j, tk)
-                vb = w.moving(v_ref, g, pl.ds(k0, tk))
+                vb = w.moving(v_ref, gk, pl.ds(k0, tk))
                 s = _scores(
                     (keys_scr[g, pl.ds(k0, tk), :] if p.rot
-                     else w.moving(k_ref, g, pl.ds(k0, tk))),
+                     else w.moving(k_ref, gk, pl.ds(k0, tk))),
                     q, r0, c_base + k0, w, masked=masked,
                     bias_col=_col(bias_ref, mg, j) if w.have_bias else None,
                     segq_row=segq,
@@ -1021,28 +1068,38 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
     g, packed = p.heads, p.layout == "bsd"
     per_row = h // g
     fused = packed and ops.q.shape[-1] == 3 * h * p.d
+    # ``s``: the scalar-prefetch refs of a call with a traced ``q_offset``
+    # (the offset is then theirs, not the walk's), else nothing
     if dkv:
         cq = _qi_clamp(p.causal, p.block_q, p.block_k, w.nq, w.offset)
-        q_at, k_at = (lambda j, kk: cq(kk, j)), (lambda j, kk: j)
+        q_at, k_at = (lambda j, kk, *s: cq(kk, j)), (lambda j, kk, *s: j)
     else:
-        ck = _kj_clamp(p.causal, p.block_q, p.block_k, w.nk, w.offset,
-                       p.window)
-        q_at, k_at = (lambda j, kk: j), (lambda j, kk: ck(kk, j))
+        def k_at(j, kk, *s):
+            return _kj_clamp(p.causal, p.block_q, p.block_k, w.nk,
+                             s[0][0] if s else w.offset, p.window)(kk, j)
 
-    def block(rows, width, at, nth=0):
+        q_at = lambda j, kk, *s: j
+    # grouped heads: the step's block of k and v holds the ``gk`` key heads
+    # its ``g`` query heads read, block ``step * g // (group * gk)``
+    gk = max(g // p.group, 1)
+
+    def block(rows, width, at, nth=0, kv=False):
+        n = gk if kv else g
+        first = (lambda step: step * g // (p.group * gk)) if (
+            kv and p.group > 1) else (lambda step: step)
         if packed:
             off = nth * per_row if fused else 0
             return pl.BlockSpec(
-                (1, rows, g * width),
-                lambda i, j, kk: (i // per_row, at(j, kk),
-                                  i % per_row + off))
-        return pl.BlockSpec((g, rows, width),
-                            lambda i, j, kk: (i, at(j, kk), 0))
+                (1, rows, n * width),
+                lambda i, j, kk, *s: (i // per_row, at(j, kk, *s),
+                                      first(i % per_row) + off))
+        return pl.BlockSpec((n, rows, width),
+                            lambda i, j, kk, *s: (first(i), at(j, kk, *s), 0))
 
     def mask(at):
         if packed:
-            return lambda i, j, kk: (i // per_row, at(j, kk), 0, 0)
-        return lambda i, j, kk: (i, at(j, kk), 0, 0)
+            return lambda i, j, kk, *s: (i // per_row, at(j, kk, *s), 0, 0)
+        return lambda i, j, kk, *s: (i, at(j, kk, *s), 0, 0)
 
     masks, mask_args = _mask_specs(ops, p, mask(q_at), mask(k_at))
     rot = []
@@ -1054,24 +1111,26 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
                pl.BlockSpec((1, p.block_k, p.rot),
                             lambda i, j, kk: (i // per_row, k_at(j, kk), 0))]
     return _Specs(
-        q=block(p.block_q, p.d, q_at), k=block(p.block_k, p.d, k_at, 1),
-        v=block(p.block_k, p.dv, k_at, 2), o=block(p.block_q, p.dv, q_at),
-        dk=block(p.block_k, p.d, k_at),
+        q=block(p.block_q, p.d, q_at), k=block(p.block_k, p.d, k_at, 1, True),
+        v=block(p.block_k, p.dv, k_at, 2, True),
+        o=block(p.block_q, p.dv, q_at), dk=block(p.block_k, p.d, k_at),
         qrow=pl.BlockSpec((g, 1, w.nqt, p.tile_q),
-                          lambda i, j, kk: (i, q_at(j, kk), 0, 0)),
+                          lambda i, j, kk, *s: (i, q_at(j, kk, *s), 0, 0)),
         masks=masks, mask_args=mask_args, rot=rot)
 
 
 def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-             scale=None, num_heads=None, window=0, rot=()):
+             scale=None, num_heads=None, window=0, rot=(), kv_heads=None):
     """(plan, walk, operands, b, h) of one call: ``[b, h, s, d]`` operands,
     ``[b, s, num_heads * d]`` ones that :func:`lane_heads` admits, or,
     with ``k`` and ``v`` None, ``q`` as the three of them fused,
     ``[b, s, 3 * num_heads * d]``; ``rot``: the ``(q_rot, k_rot)`` of a
-    ``[b, s, num_heads * d]`` call that :func:`rot_lane_heads` admits."""
+    ``[b, s, num_heads * d]`` call that :func:`rot_lane_heads` admits;
+    ``kv_heads``: the heads ``k`` and ``v`` hold where they are fewer than
+    ``q``'s (a rank-4 call's are read off ``k``)."""
     if num_heads is None:
         b, h, sq, d = q.shape
-        sk, dv = k.shape[2], v.shape[-1]
+        sk, dv, kv_heads = k.shape[2], v.shape[-1], k.shape[1]
     else:
         (b, sq, width), h = q.shape, num_heads
         if k is None:
@@ -1082,7 +1141,8 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
     p = plan_blocks(sq, sk, d, q.dtype, causal, bias is not None,
                     seg_q is not None, block_q, block_k, bh=b * h,
                     dv=dv, scale=scale, num_heads=num_heads, window=window,
-                    rot=rot[1].shape[-1] if rot else 0)
+                    rot=rot[1].shape[-1] if rot else 0,
+                    group=h // (kv_heads or h))
     w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
     return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h, rot), b, h
 
@@ -1090,37 +1150,53 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
                block_q: Optional[int], block_k: Optional[int],
                interpret: bool, scale: Optional[float] = None,
-               num_heads: Optional[int] = None, window: int = 0, rot=()):
+               num_heads: Optional[int] = None, window: int = 0, rot=(),
+               kv_heads: Optional[int] = None, q_offset=None):
     """``q`` and ``k`` are ``[b, h, s, d]`` and ``v`` ``[b, h, s_k, dv]``:
     the scores contract over ``d``, the output rows are ``dv`` wide. With
     ``num_heads`` they are ``[b, s, num_heads * d]`` (or fused in ``q``,
     :func:`_planned`) and so is the output; lse is ``[b, h, s_q]`` for
     both. ``rot``: such a call's ``(q_rot [b, s, num_heads * rot], k_rot
-    [b, s_k, rot])``, the scores' further ``rot`` columns."""
+    [b, s_k, rot])``, the scores' further ``rot`` columns. ``kv_heads``:
+    ``k`` and ``v`` are ``[b, s_k, kv_heads * d]`` (rank-4: ``[b, kv_heads,
+    s_k, d]``, read off them). ``q_offset`` (a traced int32 scalar): the
+    key index of the first query's own key, where that is not ``s_k -
+    s_q``; it reaches the kernel and its index maps through SMEM."""
     p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
-                               block_k, scale, num_heads, window, rot)
-    _record_plan(p)
+                               block_k, scale, num_heads, window, rot,
+                               kv_heads)
+    _record_plan(p, h, q_offset is not None)
     bh, g, nq, nk, dv = b * h, p.heads, w.nq, w.nk, p.dv
     sp = _specs(ops, p, w, h)
     out_shape = ((bh, p.sq_p, dv) if num_heads is None
                  else (b, p.sq_p, h * dv))
-
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, w=w),
-        name="flash_fwd",
+    grid = dict(
         grid=(bh // g, nq, nk),
         in_specs=[sp.q, sp.k, sp.v] + sp.masks + sp.rot,
         out_specs=[sp.o, sp.qrow],
-        out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype),
-                   jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
-                                        jnp.float32)],
         scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
                         pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
                         pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)]
         + ([pltpu.VMEM((g, p.block_k, p.d + p.rot), q.dtype)]
-           if p.rot else []),
+           if p.rot else []))
+    kernel, prefetch = functools.partial(_fwd_kernel, w=w), ()
+    if q_offset is not None:
+        def kernel(off_ref, *refs):
+            _fwd_kernel(*refs, w=w._replace(offset=off_ref[0]))
+
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        prefetch = (jnp.asarray(q_offset, jnp.int32).reshape(1),)
+
+    out, lse = pl.pallas_call(
+        kernel,
+        name="flash_fwd",
+        out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype),
+                   jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
+                                        jnp.float32)],
         interpret=interpret,
-    )(ops.q, ops.k, ops.v, *sp.mask_args, *ops.rot)
+        **grid,
+    )(*prefetch, ops.q, ops.k, ops.v, *sp.mask_args, *ops.rot)
     out = _user_form(out, p.sq, q, p)
     lse = lse.reshape(b, h, p.sq_p)[:, :, :p.sq]
     return out, lse
@@ -1510,6 +1586,8 @@ def flash_attention(
     window: int = 0,
     q_rot: Optional[jax.Array] = None,
     k_rot: Optional[jax.Array] = None,
+    kv_heads: Optional[int] = None,
+    q_offset=None,
 ):
     """Flash attention over ``q``, ``k`` [b, h, s, d] and ``v``
     [b, h, s_k, dv]; the output is [b, h, s_q, dv]. ``dv`` may differ
@@ -1542,9 +1620,22 @@ def flash_attention(
     - ``scale``: the softmax scale; None is ``d ** -0.5``.
     - ``window``: with ``causal``, a query sees its own key and the
       ``window - 1`` before it (a sliding window; key tiles wholly behind
-      it are skipped, :func:`plan_blocks`). 0 is no window. Rank-4
-      operands and the forward only: no backward kernel takes one (ROADMAP
-      Reach).
+      it are skipped, :func:`plan_blocks`). 0 is no window. The forward
+      only: no backward kernel takes one (ROADMAP Reach).
+    - ``kv_heads``: grouped-query attention. ``k`` and ``v`` hold fewer
+      heads than ``q``, ``[b, s_k, kv_heads * d]`` beside ``[b, s, num_heads
+      * d]`` (rank-4 operands say it themselves: ``[b, kv_heads, s_k, d]``),
+      and query head ``h`` reads head ``h // (num_heads // kv_heads)``. The
+      kernel reads each key head's tiles where the cache holds them; no
+      head is repeated in HBM. Where a rank-3 head is not its own lane group
+      (``d`` no multiple of 128) the call goes through ``[b, h, s, d]``.
+      The forward only, under at most ``causal``, a ``window`` and a key
+      bias.
+    - ``q_offset`` (with ``causal``; an int or a traced int32 scalar): the
+      index among the keys of the first query's own key, where that is not
+      ``s_k - s_q``: a later piece of a prompt against a cache it has
+      written its keys into, ``k`` and ``v`` the cache whole. Keys past the
+      last query's own are never read. The forward only.
     - ``q_rot`` [b, s_q, num_heads * r] and ``k_rot`` [b, s_k, r], with
       rank-3 ``q``, ``k``, ``v``: a second score operand. Head ``h``'s
       scores are ``q_h . k_h + q_rot_h . k_rot``, one sum over ``d + r``
@@ -1586,17 +1677,37 @@ def flash_attention(
             default_interpret() if interpret is None else interpret, scale,
             num_heads)
 
-    if window:
-        enforce(causal and q.ndim == 4 and k is not None and attn_mask is None
-                and segment_ids is None,
-                "flash_attention: a window takes causal [b, h, s, d] q, k, v "
-                "and at most a key bias")
+    heads = num_heads if q.ndim == 3 else q.shape[1]
+    if q.ndim == 4 and k is not None:
+        kv_heads = k.shape[1]
+    grouped = kv_heads is not None and kv_heads != heads
+    if window or grouped or q_offset is not None:
+        enforce(k is not None and heads is not None and attn_mask is None
+                and segment_ids is None and heads % (kv_heads or heads) == 0
+                and (causal or not (window or q_offset is not None)),
+                "flash_attention: a window, a query offset and grouped heads "
+                "take q, k, v with their head counts (num_heads a multiple of "
+                "kv_heads) and at most a key bias; the first two are causal")
+        if q.ndim == 3:
+            d = q.shape[-1] // heads
+            dv = v.shape[-1] // (kv_heads or heads)
+            if not lane_heads(d, dv, heads) or (
+                    grouped and lane_heads(d, dv, heads) != 1):
+                out = flash_attention(
+                    _split_heads(q, heads),
+                    *(_split_heads(x, kv_heads or heads) for x in (k, v)),
+                    causal=causal, key_bias=key_bias, block_q=block_q,
+                    block_k=block_k, interpret=interpret, return_lse=True,
+                    scale=scale, window=window, q_offset=q_offset)
+                return ((_merge_heads(out[0]), out[1]) if return_lse
+                        else _merge_heads(out[0]))
         block_q, block_k = resolve_block_shapes(block_q, block_k)
         out = _flash_fwd(
             q, k, v, None if key_bias is None else key_bias.astype(jnp.float32),
-            None, None, True, block_q, block_k,
+            None, None, causal, block_q, block_k,
             default_interpret() if interpret is None else interpret, scale,
-            None, window)
+            num_heads if q.ndim == 3 else None, window,
+            kv_heads=kv_heads if q.ndim == 3 else None, q_offset=q_offset)
         return out if return_lse else out[0]
 
     fused = k is None

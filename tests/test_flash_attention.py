@@ -821,10 +821,11 @@ def test_projection_layout_lse_and_dense_mask(shape):
     ids=["gpt2m_train", "gpt2l_train", "gpt2m_prefill", "k25_prefill",
          "streamed"])
 def test_a_call_without_a_window_plans_as_it_did(kw, want):
-    """... and ``rot`` (PR 43) is the newest last field, 0 for them too."""
+    """... ``rot`` (PR 43) the next, 0 for them too, and ``group`` (PR 45) the
+    newest: 1 for a call that gives no key/value head count."""
     plan = fa.plan_blocks(dtype=jnp.bfloat16, **kw)
-    assert tuple(plan) == want + (0, 0)
-    assert fa.FlashPlan._fields[-2:] == ("window", "rot")
+    assert tuple(plan) == want + (0, 0, 1)
+    assert fa.FlashPlan._fields[-3:] == ("window", "rot", "group")
 
 
 # -- a rotary pair: a second score operand in the projections' layout (PR 43) --------
@@ -918,3 +919,112 @@ def test_rotary_pair_plan_backward_and_fallback():
     np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=2e-5)
     plan = [s[4] for s in profiler.spans(since) if s[0] == "flash.plan"][-1]
     assert (plan["layout"], plan["rot"], plan["d"]) == ("bhsd", 0, 16)
+
+
+# -- grouped heads, with and without a window and a query offset (PR 45) ----------------
+
+
+def _grouped_dense(q, k, v, heads, kv_heads, window, offset):
+    """Dense masked softmax in float64, key head ``h // group`` indexed and
+    never repeated: ``q [b, sq, heads * d]``, ``k, v [b, sk, kv_heads * d]``;
+    query ``i`` sees keys ``j <= i + offset`` (and ``> i + offset - window``)."""
+    b, sq, _ = q.shape
+    sk, d = k.shape[1], q.shape[-1] // heads
+    q4 = np.asarray(q, np.float64).reshape(b, sq, heads, d)
+    k4 = np.asarray(k, np.float64).reshape(b, sk, kv_heads, d)
+    v4 = np.asarray(v, np.float64).reshape(b, sk, kv_heads, d)
+    r, c = np.arange(sq)[:, None] + offset, np.arange(sk)[None, :]
+    keep = c <= r
+    if window:
+        keep &= c > r - window
+    out = np.zeros((b, sq, heads, d))
+    for h in range(heads):
+        s = np.einsum("bqd,bkd->bqk", q4[:, :, h], k4[:, :, h * kv_heads // heads])
+        s = np.where(keep, s / np.sqrt(d), -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, :, h] = np.einsum("bqk,bkd->bqd", p / p.sum(-1, keepdims=True),
+                                 v4[:, :, h * kv_heads // heads])
+    return out.reshape(b, sq, heads * d)
+
+
+@pytest.mark.parametrize("heads,kv_heads,d", [(48, 8, 128), (12, 4, 64),
+                                               (6, 1, 128), (4, 4, 64)],
+                         ids=["48_on_8", "12_on_4_of_64", "6_on_1",
+                              "4_on_4_of_64"])
+@pytest.mark.parametrize("sq,sk,window,offset", [
+    (128, 128, 0, None), (128, 384, 96, None), (128, 1280, 0, 300),
+    (256, 1280, 96, 517)],
+    ids=["causal", "window", "offset", "window_and_offset"])
+def test_grouped_heads_are_the_dense_composition(heads, kv_heads, d, sq, sk,
+                                                 window, offset):
+    """48/8, 12/4 and 6/1 heads, rank-3 (in place where a head is its own
+    lane group, through ``[b, h, s, d]`` where two share one) and rank-4,
+    under the causal rule, a window, a traced query offset (a later piece
+    of a prompt against the whole cache) and both: the kernel reads key head
+    ``h // group`` where the cache holds it."""
+    from paddle_tpu.core import profiler
+
+    b = 1 if heads == 48 else 2
+    rng = np.random.RandomState(heads + sq + window)
+    q, k, v = (jnp.asarray(rng.randn(b, s, n * d), jnp.float32)
+               for s, n in ((sq, heads), (sk, kv_heads), (sk, kv_heads)))
+    want = _grouped_dense(q, k, v, heads, kv_heads, window,
+                          sk - sq if offset is None else offset)
+    since = profiler.time.time_ns()
+    call = jax.jit(lambda q, k, v, off: fa.flash_attention(
+        q, k, v, causal=True, num_heads=heads, kv_heads=kv_heads,
+        window=window, q_offset=off))
+    got = call(q, k, v, None if offset is None else jnp.int32(offset))
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    plan = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"][-1]
+    assert plan["kv_heads"] == kv_heads and plan["window"] == window
+    assert plan["q_offset"] == (offset is not None)
+    # two 64-wide heads share a lane group: in place only where none is shared
+    assert plan["layout"] == ("bsd" if d == 128 or heads == kv_heads else "bhsd")
+    apart = lambda x, n: x.reshape(b, -1, n, d).transpose(0, 2, 1, 3)
+    got4 = fa.flash_attention(apart(q, heads), apart(k, kv_heads),
+                              apart(v, kv_heads), causal=True, window=window,
+                              q_offset=offset)
+    np.testing.assert_allclose(
+        np.asarray(got4.transpose(0, 2, 1, 3).reshape(b, sq, heads * d)), want,
+        atol=2e-5)
+
+
+def test_a_grouped_step_holds_the_key_heads_its_query_heads_read():
+    """A step's query heads lie in one group or are whole groups. At the
+    long-document cell's shapes (48 on 8 of 128, pieces of 2,048 over a
+    4,096-key window and over a 33,792-key cache) a piece's queries are
+    resident and a step holds one head, its group's key head streaming past
+    it in 1,024-key blocks; the window's walk skips the tiles behind it."""
+    for sk, window in ((6144, 4096), (33792, 0)):
+        plan = fa.plan_blocks(2048, sk, 128, jnp.bfloat16, causal=True,
+                              bh=8 * 48, num_heads=48, window=window, group=6)
+        assert (plan.layout, plan.heads, plan.group) == ("bsd", 1, 6)
+        assert (plan.block_q, plan.block_k, plan.tile_q, plan.tile_k) == (
+            2048, 1024, 512, 512)
+    # a 512-row query tile reaches 4,096 + 511 keys back: 9 tiles of 12
+    windowed = fa.plan_blocks(2048, 6144, 128, jnp.bfloat16, causal=True,
+                              bh=8 * 48, num_heads=48, window=4096, group=6)
+    assert (windowed.tiles_run, windowed.tiles_all) == (4 * 9, 4 * 12)
+    # shorter queries leave room for more heads a step: never astride a group
+    short = fa.plan_blocks(512, 4608, 128, jnp.bfloat16, causal=True,
+                           bh=8 * 48, num_heads=48, window=4096, group=6)
+    assert short.heads in (2, 3, 6) and 6 % short.heads == 0
+    assert not fa._in_groups(4, 6) and fa._in_groups(3, 6) and fa._in_groups(12, 6)
+
+
+def test_grouped_heads_and_a_query_offset_are_forward_only():
+    from paddle_tpu.core.errors import EnforceError
+
+    q, k = jnp.zeros((1, 128, 4 * 128)), jnp.zeros((1, 128, 2 * 128))
+    with pytest.raises(EnforceError, match="query offset"):
+        fa.flash_attention(q, k, k, causal=False, num_heads=4, kv_heads=2,
+                           q_offset=0)
+    with pytest.raises(EnforceError, match="multiple"):
+        fa.flash_attention(q, jnp.zeros((1, 128, 3 * 128)),
+                           jnp.zeros((1, 128, 3 * 128)), causal=True,
+                           num_heads=4, kv_heads=3)
+    loss = lambda q: fa.flash_attention(q, k, k, causal=True, num_heads=4,
+                                        kv_heads=2).sum()
+    with pytest.raises(Exception):
+        jax.grad(loss)(q)

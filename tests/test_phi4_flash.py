@@ -24,7 +24,7 @@ import paddle_tpu as pt
 from benchmarks.families import phi4_flash as family
 from benchmarks.reference import phi4_flash as reference
 from paddle_tpu.core import profiler
-from paddle_tpu.layers import blocks, sambay
+from paddle_tpu.layers import blocks, kv_ring, sambay
 from paddle_tpu.models import phi4_flash
 from paddle_tpu.ops import selective_scan as ss
 from paddle_tpu.ops.flash_attention import flash_attention, plan_blocks
@@ -338,7 +338,7 @@ def test_the_ring_wraps_and_the_steps_see_the_window(prefilled):
                                             jnp.asarray(at), layer)
             outs.append(y)
             at += piece
-        ring = sambay.ring_of(held, prefilled, DIMS)
+        ring = kv_ring.ring_of(held, prefilled, DIMS.window)
         step = jax.jit(lambda x1, ring, t: sambay.window_decode(
             x1, p, DIMS, ring, t, layer))
         for t in range(prefilled, total):

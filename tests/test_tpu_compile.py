@@ -718,6 +718,76 @@ def test_phi4_flash_generator_fits_one_v5e_and_its_steps_copy_no_cache(
     assert len(gates) >= 32 and set(gates) == {"bf16"}, gates
 
 
+def test_trinity_generator_fits_one_v5e_and_repeats_no_key_head(
+        chip, monkeypatch):
+    """The ``trinity-serve-long`` generator (8 rows, prompt 32,768 + 128
+    new, bfloat16, the benchmark's configuration file: layers 5-9, experts
+    0-31, 25,024 rows of the vocabulary) compiled for one described chip:
+    8.65 GB of arguments and the temporaries of a 2,048-token piece, under
+    14.5 GB together, so ISSUE 45's 8 rows stand; the configuration file's
+    ``memory`` group records what this compile said. The prefill's scan
+    holds five grouped ``flash_fwd`` calls and no conditional, the decode
+    loop the step's conditional and no kernel; no key/value slab is copied
+    to 48 heads anywhere (nothing ``[.., 6144]`` wide is as long as a cache
+    or a window's keys), and the decode loop copies or transposes neither a
+    ring ``bf16[8,4096,1024]`` nor the full cache ``bf16[8,33792,1024]``."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.families import trinity as family
+    from paddle_tpu.models import trinity
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "trinity-large-ep8.json")) as f:
+        cell_config = json.load(f)
+    rows, prompt, new = 8, 32768, 128
+    prog = pt.build(trinity.make_generator(
+        family.program_config(cell_config), max_new_tokens=new))
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    table = family.decoder_params(cell_config, 0, prompt, new).shapes
+    params = {name: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+              for name, s in table.items()}
+    ids = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=chip)
+    compiled = jax.jit(lambda p, i: prog.apply(p, {}, prompt_ids=i)[0]
+                       ).lower(params, ids).compile()
+    m = compiled.memory_analysis()
+    assert 8.64e9 < m.argument_size_in_bytes < 8.66e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9
+    recorded = cell_config["memory"]
+    assert abs(recorded["generator_weights_bytes"]
+               - m.argument_size_in_bytes) < 1e6
+    assert abs(recorded["generator_rows_8_temporaries_bytes"]
+               - m.temp_size_in_bytes) < 0.2e9
+    carried = recorded["window_kv_bytes"] + recorded["full_kv_bytes"]
+    assert carried == 2 * 2 * 8 * 1024 * (4 * 4096 + 33792)
+    assert carried < m.temp_size_in_bytes
+    text = compiled.as_text()
+    comps = _computations(text)
+    loops = re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text)
+    flash = lambda name: sum("tpu_custom_call" in ln and "flash_fwd" in ln
+                             for ln in comps[name])
+    decode = [name for name in loops
+              if any(" conditional(" in ln for ln in comps[name])]
+    scans = [name for name in loops if flash(name)]
+    assert len(decode) == 1 and len(scans) == 1 and decode != scans
+    assert flash(scans[0]) == 5
+    assert not any("tpu_custom_call" in ln for ln in comps[decode[0]])
+    # a key/value slab repeated to 48 heads would be [8, >= 4096, 6144]
+    repeated = [ln for ln in text.splitlines() if re.search(
+        r"= bf16\[8,(4096|6144|33792),6144\]", ln)]
+    assert not repeated, repeated[0][:300]
+    held = r"(bf16\[8,4096,1024\]|bf16\[8,33792,1024\])"
+    moved = [ln for ln in comps[decode[0]] if re.search(
+        r"= %s\S* (copy|transpose|copy-start)\(" % held, ln)]
+    assert not moved, moved[0][:300]
+    layouts = set(re.findall(held + r"(\{[^}]*\})", "\n".join(comps[decode[0]])))
+    assert layouts and all(
+        layout.startswith("{2,1,0:T(8,128)") for _, layout in layouts), layouts
+
+
 def test_loss_head_makes_three_products_a_chunk_for_v5e(chip):
     """gpt2-medium's loss head at the train cell's shape (32 x 1,024 rows,
     50,257 columns, bfloat16) under ``jax.value_and_grad``: the loop over
