@@ -39,11 +39,21 @@ The tile walk, shared by the kernels:
   in dk/dv), the inner loop walks the other in chunks. The tile is as
   large as 512 x 512: the MXU takes the still operand as weights anew
   for every dot, and a tile must stream enough rows past them to pay
-  for that (measured: see ``TILE``). Where the sequence is resident
-  whole and short, the walk is written out when the kernel is traced
-  (``_loop``): the compiler schedules a loop body alone, and only a walk
-  it sees whole lets one tile's matmuls run under the next one's
-  softmax.
+  for that (measured: see ``TILE``). What the kernel can know of the
+  walk when it is traced, it writes out (``_loop``): the compiler
+  schedules a loop body alone, and only a walk it sees whole lets one
+  tile's matmuls run under the next one's softmax. A sequence that is
+  resident whole and short has every bound a python int. A forward call
+  whose keys stream (:func:`_written`) knows every key block's bounds
+  where one q block meets them under a static offset (a window's piece
+  against its ring: each block a written-out body of its own, picked by
+  the grid's key index), and under a diagonal that a traced scalar places
+  it knows the blocks that lie wholly below it, whose tiles take no mask
+  and no bound (a later piece against the whole cache: one written-out
+  body, picked by one scalar predicate); the blocks a traced diagonal
+  crosses, and the backward kernels' streamed walks, keep traced loops.
+  In a written-out streamed block the order is written too: a tile's
+  scores are made before its neighbour's softmax (``_fwd_kernel``).
 - Scores are computed TRANSPOSED, ``[tile_k, tile_q]``: keys on
   sublanes, queries on lanes. Every per-query vector (running max and
   sum, lse, delta, query segment ids) is then one lane-major row — a
@@ -116,7 +126,11 @@ layout follows the call's shape, one tile walk for both:
   (the tile bounds and the mask) through SMEM.
 
 ``flash.plan`` records ``layout``, ``lane_heads``, ``backward``, ``window``,
-``rot``, ``kv_heads`` and ``q_offset`` for every call traced.
+``rot``, ``kv_heads``, ``q_offset`` and ``tiles_written`` (the tiles of
+``tiles_run`` that the forward kernel holds written out) for every call
+traced. The forward kernel itself is traced once for each distinct walk
+and operand shapes, however often a program calls it and however many
+passes trace the program (:func:`_fwd_call`).
 
 Masking: causal (bottom-right aligned), an additive per-key bias
 [b, s_k] (padding), and segment ids (the LoD ragged-batch equivalent,
@@ -132,6 +146,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 import time
 import warnings
 from typing import NamedTuple, Optional
@@ -156,12 +171,19 @@ from jax.experimental.pallas import tpu as pltpu
 # UNROLL: a resident walk of up to this many tiles a head is written out
 # for the compiler to see whole (``_loop``; the same three kernels take
 # 5.2 ms written out a head, 4.9 ms written out a step).
+# WRITTEN: the tile bodies a streamed forward kernel may hold written out
+# (:func:`_written`). The largest walk the chip has timed is a 2,048-row
+# piece on a 4,096-key window's 6,144 keys: 28 bodies of 512 x 512 for its
+# 36 tiles, 12.4 ms a call where the loops take 20.6, for 6.8 s of Mosaic
+# compile where the loops take 1.0 (PERF.md section 6, PRs 46 and 47). A
+# walk beyond it keeps its loops until a reading says otherwise.
 TILE = 512
 RESIDENT = 2048
 STREAM_BLOCK = 1024
 STEP_SCORES = 2 << 20
 STEP_BYTES = 6 << 20
 UNROLL = 8
+WRITTEN = 32
 # FUSED_BYTES: the blocks and accumulators one backward kernel may hold a
 # grid step (``_backward``). A compile for a described v5e takes 10.0 MB
 # at every shape tried (2048 x 128 and two heads of 1024 x 64 in bfloat16)
@@ -232,6 +254,7 @@ class FlashPlan(NamedTuple):
     window: int = 0       # keys a causal query sees, itself included; 0: all
     rot: int = 0          # width of a second score operand (q_rot, k_rot); 0: none
     group: int = 1        # query heads that read one key/value head
+    tiles_written: int = 0    # of tiles_run, written out in the forward kernel
 
 
 def _round_up(n, m):
@@ -332,6 +355,59 @@ def _window_bounds(r0, tile_q, c_base, n_chunks, tile_k, bounds, *, offset,
     return n_start, _clip(n_lead, n_start, n_plain), n_plain, n_end
 
 
+def _interior(qb, kb, p: FlashPlan, offset):
+    """Does every query of q block ``qb`` see every key of key block ``kb``:
+    the block wholly below the first row's diagonal, wholly inside the last
+    row's window, no padded key in it? Such a block's tiles are all visited
+    and take no mask. On python ints or on traced scalars."""
+    inside = True
+    if p.causal:
+        inside = (kb + 1) * p.block_k <= qb * p.block_q + offset + 1
+        if p.window:
+            inside &= (kb * p.block_k
+                       >= (qb + 1) * p.block_q + offset - p.window)
+    if p.sk != p.sk_p:
+        inside &= (kb + 1) * p.block_k <= p.sk
+    return inside
+
+
+def _written(p: FlashPlan, static_offset: bool):
+    """``(form, tiles)``: how the forward kernel of a call with plan ``p``
+    writes its walk out when it is traced, python iterations where a loop
+    would turn, and how many of ``tiles_run`` run so.
+
+    - ``all``: both sequences are one grid step's and a head's walk is up
+      to ``UNROLL`` tiles: every bound is a python int.
+    - ``blocks``: the keys stream past one q block under a static offset,
+      so a key block's bounds are python ints once the grid's key index is
+      known: the interior blocks (:func:`_interior`) share one body and
+      every other block that runs has its own, while the kernel stays
+      within ``WRITTEN`` tile bodies.
+    - ``interior``: a sequence streams and the diagonal lies where traced
+      scalars say (a ``q_offset``; several q blocks): the interior blocks
+      run one written-out body, the blocks that the diagonal, a window's
+      edge or padding touch run the traced loops. Counted at offset ``sk -
+      sq``, as ``tiles_run`` is (the last piece's, under a ``q_offset``).
+    - ``""``: nothing. A resident walk over ``UNROLL``; a streamed block of
+      one tile, which has no neighbour to run under; no interior block; a
+      body over ``WRITTEN``.
+
+    A packed step's heads are python iterations, so each is a body."""
+    nq, nk = p.sq_p // p.block_q, p.sk_p // p.block_k
+    block = (p.block_q // p.tile_q) * (p.block_k // p.tile_k)
+    if nq == nk == 1:
+        return ("all", p.tiles_run) if block <= UNROLL else ("", 0)
+    bodies = p.heads if p.layout == "bsd" else 1
+    if block == 1 or bodies * block > WRITTEN:
+        return "", 0
+    inside = block * sum(_interior(qb, kb, p, p.sk - p.sq)
+                         for qb in range(nq) for kb in range(nk))
+    if static_offset and nq == 1 and bodies * (
+            min(inside, block) + p.tiles_run - inside) <= WRITTEN:
+        return "blocks", p.tiles_run
+    return ("interior", inside) if inside else ("", 0)
+
+
 def _backward(p: FlashPlan, itemsize) -> str:
     """``fused`` or ``split`` (the module's docstring) for a call with plan
     ``p`` and operands of ``itemsize`` bytes: one kernel where a grid step
@@ -388,7 +464,7 @@ def rot_lane_heads(d, dv, num_heads, rot) -> int:
 def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
                 have_bias=False, have_seg=False, block_q=None, block_k=None,
                 bh=1, dv=None, scale=None, num_heads=None,
-                window=0, rot=0, group=1) -> FlashPlan:
+                window=0, rot=0, group=1, q_offset=False) -> FlashPlan:
     """Blocks, compute tile and heads a step for one attention call, from
     what the call can see. One rule for every shape: pad each axis to
     whole registers (128 queries, 16 keys; no further: 896 stays 896),
@@ -427,7 +503,12 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
     heads, head ``h`` reads ``h // group``). A step's heads then lie in one
     group or are whole groups, so that its block of ``k`` and ``v`` is the
     key heads they read, as the cache holds them; a ``bsd`` call needs a
-    head to be its own lane group. 1: every head its own."""
+    head to be its own lane group. 1: every head its own.
+
+    ``q_offset`` (forward only): the causal diagonal lies where a traced
+    scalar says. Blocks and tiles are the call's without it; what its
+    kernel can write out of the walk is less (``tiles_written``,
+    :func:`_written`)."""
     del have_bias, have_seg
     dv = d if dv is None else dv
     packed = lane_heads(d, dv, num_heads)
@@ -480,9 +561,10 @@ def plan_blocks(sq, sk, d, dtype=jnp.bfloat16, causal=False,
         layout = ()
     plan = FlashPlan(sq, sk, d, block_q, block_k, tile_q, tile_k, heads,
                      sq_p, sk_p, causal, fold, run, tiles_all, dv, *layout)
-    return plan._replace(
+    plan = plan._replace(
         backward=_backward(plan, jnp.dtype(dtype).itemsize), window=window,
         rot=rot, group=group)
+    return plan._replace(tiles_written=_written(plan, not q_offset)[1])
 
 
 def _record_plan(p: FlashPlan, h: int, q_offset: bool = False):
@@ -497,7 +579,8 @@ def _record_plan(p: FlashPlan, h: int, q_offset: bool = False):
         tile_k=p.tile_k, heads=p.heads, causal=p.causal,
         tiles_run=p.tiles_run, tiles_all=p.tiles_all, layout=p.layout,
         lane_heads=p.lane_heads, backward=p.backward, window=p.window,
-        rot=p.rot, kv_heads=h // p.group, q_offset=q_offset)
+        rot=p.rot, kv_heads=h // p.group, q_offset=q_offset,
+        tiles_written=p.tiles_written)
 
 
 # ---------------------------------------------------------------------------
@@ -533,8 +616,15 @@ class _Walk(NamedTuple):
     def written_out(self):
         """Is a head's walk short enough to be written out when the
         kernel is traced (a resident sequence of a few tiles)? Else its
-        loops are traced."""
-        return self.nq == self.nk == 1 and self.nqt * self.nkt <= UNROLL
+        loops are traced (the backward kernels know no other form)."""
+        return self.written == "all"
+
+    @property
+    def written(self):
+        """The form of the forward walk (:func:`_written`): ``all``,
+        ``blocks``, ``interior`` or ``""``. The offset is static where it
+        is the walk's own and not read from SMEM."""
+        return _written(self.plan, isinstance(self.offset, int))[0]
 
     @property
     def packed(self):
@@ -715,7 +805,8 @@ def _loop(lo, hi, body, carry):
     it sees whole lets one tile's matmuls run under its neighbour's
     softmax. A sequence that is resident whole has such a walk (its
     triangle is known when the kernel is traced); one that streams has
-    traced bounds."""
+    traced bounds but for the forward's key blocks that :func:`_written`
+    finds."""
     if isinstance(lo, int) and isinstance(hi, int):
         for j in range(lo, hi):
             carry = body(j, carry)
@@ -915,9 +1006,7 @@ def _over_tiles(n_tiles, tile_body, w: _Walk):
     heads too while the step stays within ``UNROLL`` tiles; else traced
     loops. A packed step's heads are always python iterations (the plan
     keeps them few): a head is picked by its lanes."""
-    heads = w.plan.heads
-
-    def head(g, carry=0):
+    def head(g):
         def tile(t, c):
             tile_body(g, t)
             return c
@@ -925,13 +1014,34 @@ def _over_tiles(n_tiles, tile_body, w: _Walk):
             _loop(0, n_tiles, tile, 0)
         else:
             jax.lax.fori_loop(0, n_tiles, tile, 0)
-        return carry
 
-    if w.packed or (w.written_out and heads * w.nqt * w.nkt <= UNROLL):
-        for g in range(heads):
-            head(g)
+    _over_heads(head, w, w.written_out
+                and w.plan.heads * w.nqt * w.nkt <= UNROLL)
+
+
+def _over_heads(head_body, w: _Walk, written=False):
+    """Run ``head_body(g)`` for every head ``g`` of the step: python
+    iterations for a packed step and for a ``written`` one, else a traced
+    loop."""
+    if w.packed or written:
+        for g in range(w.plan.heads):
+            head_body(g)
     else:
-        jax.lax.fori_loop(0, heads, head, 0)
+        jax.lax.fori_loop(0, w.plan.heads,
+                          lambda g, carry: (head_body(g), carry)[1], 0)
+
+
+class _QTile(NamedTuple):
+    """A query tile of the forward walk as it meets one key block: python
+    ints where the walk is written out, traced scalars in a loop."""
+    g: object       # head of the step
+    qt: object      # tile of the q block
+    kb: object      # key block
+    q0: object      # the tile's first row in the q block ...
+    r0: object      # ... and among the call's queries
+    mg: object      # the head's row of the mask operands
+    q: jax.Array    # the still operand, the scale folded in
+    segq: Optional[jax.Array]
 
 
 def _fwd_kernel(*refs, w: _Walk):
@@ -951,77 +1061,138 @@ def _fwd_kernel(*refs, w: _Walk):
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    c_base = kv * p.block_k
-    step = pl.program_id(0) if p.rot and w.rot_group else None
+    rot_step = pl.program_id(0) if p.rot and w.rot_group else None
 
-    def q_tile(g, qt):
+    def tile_of(g, qt, kb):
+        """Query tile ``qt`` of head ``g`` as it meets key block ``kb``
+        (``kv``, or the python int it is known to be)."""
         q0 = _at(qt, tq)
-        r0 = qb * p.block_q + q0
-        mg = w.mask_row(g)
+        r0, mg = qb * p.block_q + q0, w.mask_row(g)
         q = w.still(q_ref, g, pl.ds(q0, tq))
         if p.rot:       # the tile's two score operands side by side, in VMEM
             q = jnp.concatenate(
-                [q, w.rot_still(qrot_ref, g, pl.ds(q0, tq), step)], axis=1)
-        q = _fold_scale(q, w)
-        segq = segq_ref[mg, 0, pl.ds(qt, 1), :] if w.have_seg else None
-        n_plain, n_end = _chunk_bounds(r0, tq, c_base, w.nkt, tk,
-                                       causal=p.causal, offset=w.offset,
-                                       sk=p.sk, sk_p=p.sk_p)
+                [q, w.rot_still(qrot_ref, g, pl.ds(q0, tq), rot_step)],
+                axis=1)
+        return _QTile(g, qt, kb, q0, r0, mg, _fold_scale(q, w),
+                      segq_ref[mg, 0, pl.ds(qt, 1), :] if w.have_seg else None)
 
-        gk = w.kv(g)
+    def to_diagonal(t):
+        """``(n_plain, n_end)`` of the block's key chunks for tile ``t``."""
+        return _chunk_bounds(t.r0, tq, t.kb * p.block_k, w.nkt, tk,
+                             causal=p.causal, offset=w.offset, sk=p.sk,
+                             sk_p=p.sk_p)
 
-        def chunk(masked):
-            def body(j, carry):
-                m, l, acc = carry            # [1, tq], [1, tq], [d, tq]
-                k0 = _at(j, tk)
-                vb = w.moving(v_ref, gk, pl.ds(k0, tk))
-                s = _scores(
-                    (keys_scr[g, pl.ds(k0, tk), :] if p.rot
-                     else w.moving(k_ref, gk, pl.ds(k0, tk))),
-                    q, r0, c_base + k0, w, masked=masked,
-                    bias_col=_col(bias_ref, mg, j) if w.have_bias else None,
-                    segq_row=segq,
-                    segk_col=_col(segk_ref, mg, j) if w.have_seg else None)
-                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-                # a query every key so far is masked for keeps m at
-                # NEG_INF: its exp(s - m) would be exp(0) = 1, so the
-                # exponent's max is held above the masked scores
-                prob = jnp.exp(s - jnp.maximum(m_new, NEG_INF / 2))
-                alpha = jnp.exp(m - m_new)
-                l = l * alpha + jnp.sum(prob, axis=0, keepdims=True)
-                # prob rounded to the input dtype for the MXU pass;
-                # accumulator f32. v.T @ prob.T = (prob @ v).T
-                acc = acc * alpha + w.rows_dot(vb, prob.astype(vb.dtype), g)
-                return m_new, l, acc
-            return body
+    def in_window(t, plain_end):
+        """``(n_start, n_lead, n_plain, n_end)`` under the call's window."""
+        return _window_bounds(t.r0, tq, t.kb * p.block_k, w.nkt, tk, plain_end,
+                              offset=w.offset, window=p.window)
 
-        row, at = (g, pl.ds(qt, 1)), w.acc_at(g, qt)
-        carry = (m_scr[row], l_scr[row], acc_scr[at])
-        if p.window:
-            n_start, n_lead, n_plain, n_end = _window_bounds(
-                r0, tq, c_base, w.nkt, tk, (n_plain, n_end), offset=w.offset,
-                window=p.window)
-            carry = _loop(n_start, n_lead, chunk(True), carry)
-            carry = _loop(n_lead, n_plain, chunk(False), carry)
-            m, l, acc = _loop(n_plain, n_end, chunk(True), carry)
-        else:
-            m, l, acc = _two_loops(n_plain, n_end, chunk, carry, w)
+    def values(t, k0):
+        return w.moving(v_ref, w.kv(t.g), pl.ds(k0, tk))
+
+    def scores(t, j, k0, masked):
+        """Tile ``t``'s scores on key chunk ``j`` of the block, rows ``k0 ..``."""
+        return _scores(
+            (keys_scr[t.g, pl.ds(k0, tk), :] if p.rot
+             else w.moving(k_ref, w.kv(t.g), pl.ds(k0, tk))),
+            t.q, t.r0, t.kb * p.block_k + k0, w, masked=masked,
+            bias_col=_col(bias_ref, t.mg, j) if w.have_bias else None,
+            segq_row=t.segq,
+            segk_col=_col(segk_ref, t.mg, j) if w.have_seg else None)
+
+    def reduced(t, s, vb, carry):
+        """The running maximum, sum and output of tile ``t`` with one more
+        chunk's scores ``s`` and values ``vb`` in them."""
+        m, l, acc = carry            # [1, tq], [1, tq], [d, tq]
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        # a query every key so far is masked for keeps m at NEG_INF: its
+        # exp(s - m) would be exp(0) = 1, so the exponent's max is held
+        # above the masked scores
+        prob = jnp.exp(s - jnp.maximum(m_new, NEG_INF / 2))
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(prob, axis=0, keepdims=True)
+        # prob rounded to the input dtype for the MXU pass; accumulator
+        # f32. v.T @ prob.T = (prob @ v).T
+        acc = acc * alpha + w.rows_dot(vb, prob.astype(vb.dtype), t.g)
+        return m_new, l, acc
+
+    def held(t):
+        row = (t.g, pl.ds(t.qt, 1))
+        return m_scr[row], l_scr[row], acc_scr[w.acc_at(t.g, t.qt)]
+
+    def keep(t, carry):
+        """Tile ``t``'s state back in scratch; after the last key block,
+        its rows of the result."""
+        m, l, acc = carry
+        row, at = (t.g, pl.ds(t.qt, 1)), w.acc_at(t.g, t.qt)
         m_scr[row], l_scr[row], acc_scr[at] = m, l, acc
 
-        @pl.when(kv == last_kv)
+        @pl.when(t.kb == last_kv)
         def _finalize():
             l_safe = jnp.maximum(l, 1e-30)
             out = acc * (1.0 / l_safe)
             if w.shared_lanes:      # written with its lane group, below
                 acc_scr[at] = out
             else:
-                o_ref[w.head(g, pl.ds(q0, tq))] = out.T.astype(o_ref.dtype)
-            lse_ref[g, 0, pl.ds(qt, 1), :] = m + jnp.log(l_safe)
+                o_ref[w.head(t.g, pl.ds(t.q0, tq))] = out.T.astype(o_ref.dtype)
+            lse_ref[t.g, 0, pl.ds(t.qt, 1), :] = m + jnp.log(l_safe)
 
-    # causal: a key block strictly above the (offset) diagonal is only
-    # entered to write the result, if it is the last
-    @pl.when(_block_runs(qb, kv, w) | (kv == last_kv))
-    def _step():
+    def q_tile(g, qt):
+        """One query tile past the block's chunks, a chunk at a time: the
+        traced walk, and the resident one that is written out whole."""
+        t = tile_of(g, qt, kv)
+        n_plain, n_end = to_diagonal(t)
+
+        def chunk(masked):
+            def body(j, carry):
+                k0 = _at(j, tk)
+                vb = values(t, k0)
+                return reduced(t, scores(t, j, k0, masked), vb, carry)
+            return body
+
+        carry = held(t)
+        if p.window:
+            n_start, n_lead, n_plain, n_end = in_window(t, (n_plain, n_end))
+            carry = _loop(n_start, n_lead, chunk(True), carry)
+            carry = _loop(n_lead, n_plain, chunk(False), carry)
+            carry = _loop(n_plain, n_end, chunk(True), carry)
+        else:
+            carry = _two_loops(n_plain, n_end, chunk, carry, w)
+        keep(t, carry)
+
+    def head_ahead(g, kb, inside):
+        """Head ``g``'s walk of a streamed block whose bounds are python
+        ints (``inside``: the block is interior, every chunk of it plain),
+        written out, with every tile's scores made one tile ahead: the
+        compiler keeps near the order it is given, and a tile's score
+        product next to its neighbour's softmax is what lets the MXU run
+        under the vector unit's stores (written out in the loops' order
+        the window's call takes 14.4 ms, so 12.4: PERF.md section 6, PR
+        46). The same tiles meet each carry in the same order."""
+        walk = []
+        for t in (tile_of(g, qt, kb) for qt in range(w.nqt)):
+            n_start, n_lead, n_plain, n_end = 0, 0, w.nkt, w.nkt
+            if not inside:
+                n_plain, n_end = to_diagonal(t)
+                if p.window:
+                    n_start, n_lead, n_plain, n_end = in_window(
+                        t, (n_plain, n_end))
+            if n_start == n_end and kb == last_kv:  # only its result's write
+                keep(t, held(t))
+            walk += [(t, j, _at(j, tk), not n_lead <= j < n_plain)
+                     for j in range(n_start, n_end)]
+        s_next = scores(*walk[0]) if walk else None
+        for i, (t, _, k0, _) in enumerate(walk):
+            s, last = s_next, i + 1 == len(walk)
+            if not last:
+                s_next = scores(*walk[i + 1])
+            if i == 0 or walk[i - 1][0] is not t:
+                carry = held(t)
+            carry = reduced(t, s, values(t, k0), carry)
+            if last or walk[i + 1][0] is not t:
+                keep(t, carry)
+
+    def step(kb=kv, inside=False, ahead=False):
         if p.rot:
             # each head's keys with the batch row's one k_rot beside them,
             # once a step: a tile's scores are then one product over d +
@@ -1030,10 +1201,39 @@ def _fwd_kernel(*refs, w: _Walk):
             for g in range(p.heads):
                 keys_scr[g, :, pl.ds(0, p.d)] = k_ref[w.head(g, slice(None))]
                 keys_scr[g, :, pl.ds(p.d, p.rot)] = krot_ref[0]
-        _over_tiles(w.nqt, q_tile, w)
+        if ahead:
+            _over_heads(lambda g: head_ahead(g, kb, inside), w)
+        else:
+            _over_tiles(w.nqt, q_tile, w)
         if w.shared_lanes:
-            pl.when(kv == last_kv)(
+            pl.when(kb == last_kv)(
                 lambda: w.write_groups(acc_scr, o_ref, w.nqt, tq))
+
+    # causal: a key block strictly above the (offset) diagonal is only
+    # entered to write the result, if it is the last
+    runs, form = _block_runs(qb, kv, w) | (kv == last_kv), w.written
+    if form == "blocks":
+        # one q block under a static offset: what each key block's tiles
+        # do is known now. The interior blocks share a body, every other
+        # block that runs has its own, its bounds python ints
+        inside = [kb for kb in range(w.nk) if _interior(0, kb, p, w.offset)]
+        if inside:
+            pl.when(functools.reduce(operator.or_, (kv == kb for kb in inside)))(
+                functools.partial(step, kv, True, True))
+        for kb in range(w.nk):
+            if kb not in inside and (_block_runs(0, kb, w) or kb == last_kv):
+                pl.when(kv == kb)(functools.partial(step, kb, False, True))
+    elif form == "interior":
+        # the diagonal lies where traced scalars say: the blocks every
+        # query of the step sees whole run written out, the others traced
+        inside = _interior(qb, kv, p, w.offset)
+        if inside is True:      # nothing masks a key
+            step(kv, True, True)
+        else:
+            pl.when(inside)(functools.partial(step, kv, True, True))
+            pl.when(jnp.logical_not(inside) & runs)(step)
+    else:
+        pl.when(runs)(step)
 
 
 class _Specs(NamedTuple):
@@ -1120,14 +1320,16 @@ def _specs(ops: _Operands, p: FlashPlan, w: _Walk, h, dkv=False):
 
 
 def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
-             scale=None, num_heads=None, window=0, rot=(), kv_heads=None):
+             scale=None, num_heads=None, window=0, rot=(), kv_heads=None,
+             q_offset=False):
     """(plan, walk, operands, b, h) of one call: ``[b, h, s, d]`` operands,
     ``[b, s, num_heads * d]`` ones that :func:`lane_heads` admits, or,
     with ``k`` and ``v`` None, ``q`` as the three of them fused,
     ``[b, s, 3 * num_heads * d]``; ``rot``: the ``(q_rot, k_rot)`` of a
     ``[b, s, num_heads * d]`` call that :func:`rot_lane_heads` admits;
     ``kv_heads``: the heads ``k`` and ``v`` hold where they are fewer than
-    ``q``'s (a rank-4 call's are read off ``k``)."""
+    ``q``'s (a rank-4 call's are read off ``k``); ``q_offset``: a traced
+    scalar places the diagonal."""
     if num_heads is None:
         b, h, sq, d = q.shape
         sk, dv, kv_heads = k.shape[2], v.shape[-1], k.shape[1]
@@ -1142,9 +1344,58 @@ def _planned(q, k, v, bias, seg_q, seg_k, causal, block_q, block_k,
                     seg_q is not None, block_q, block_k, bh=b * h,
                     dv=dv, scale=scale, num_heads=num_heads, window=window,
                     rot=rot[1].shape[-1] if rot else 0,
-                    group=h // (kv_heads or h))
+                    group=h // (kv_heads or h), q_offset=q_offset)
     w = _Walk(p, scale, sk - sq, bias is not None, seg_q is not None)
     return p, w, _prepare(q, k, v, bias, seg_q, seg_k, p, b, h, rot), b, h
+
+
+@functools.partial(jax.jit, static_argnames=("w", "h", "interpret"),
+                   inline=True)
+def _fwd_call(ops: _Operands, q_offset, *, w: _Walk, h: int, interpret: bool):
+    """The forward kernel over a call's prepared operands: ``(out, lse)`` in
+    kernel form. Jitted so that jit's cache remembers the trace: the kernel
+    body is python that writes tiles out (28 bodies for a window's piece),
+    ``pallas_call`` traces it anew whenever it is reached, a generator
+    reaches it once a layer, and a program is traced in several passes
+    before it runs (export, lowering, warm-up). Calls with equal operand
+    shapes and an equal walk share one traced kernel within a trace and
+    across the passes of a process; ``inline`` puts the remembered
+    equations where the call stands, so the program that is lowered holds
+    the ``pallas_call`` as if it had been traced there, under the caller's
+    scopes. ``q_offset``: the int32 ``[1]`` a traced diagonal is read from,
+    or None."""
+    p = w.plan
+    lead, g = ops.q.shape[0], p.heads   # batch rows (``bsd``), or b * h
+    bh = lead * h if w.packed else lead
+    sp = _specs(ops, p, w, h)
+    grid = dict(
+        grid=(bh // g, w.nq, w.nk),
+        in_specs=[sp.q, sp.k, sp.v] + sp.masks + sp.rot,
+        out_specs=[sp.o, sp.qrow],
+        scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
+                        pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
+                        pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)]
+        + ([pltpu.VMEM((g, p.block_k, p.d + p.rot), ops.q.dtype)]
+           if p.rot else []))
+    kernel, prefetch = functools.partial(_fwd_kernel, w=w), ()
+    if q_offset is not None:
+        def kernel(off_ref, *refs):
+            _fwd_kernel(*refs, w=w._replace(offset=off_ref[0]))
+
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        prefetch = (q_offset,)
+    return pl.pallas_call(
+        kernel,
+        name="flash_fwd",
+        out_shape=[jax.ShapeDtypeStruct(
+                       (lead, p.sq_p, (h if w.packed else 1) * p.dv),
+                       ops.q.dtype),
+                   jax.ShapeDtypeStruct((bh, w.nq, w.nqt, p.tile_q),
+                                        jnp.float32)],
+        interpret=interpret,
+        **grid,
+    )(*prefetch, ops.q, ops.k, ops.v, *sp.mask_args, *ops.rot)
 
 
 def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
@@ -1161,42 +1412,16 @@ def _flash_fwd(q, k, v, bias, seg_q, seg_k, causal: bool,
     ``k`` and ``v`` are ``[b, s_k, kv_heads * d]`` (rank-4: ``[b, kv_heads,
     s_k, d]``, read off them). ``q_offset`` (a traced int32 scalar): the
     key index of the first query's own key, where that is not ``s_k -
-    s_q``; it reaches the kernel and its index maps through SMEM."""
+    s_q``; it reaches the kernel and its index maps through SMEM. A plan
+    is made and recorded at every call; the kernel is traced once a walk
+    (:func:`_fwd_call`)."""
     p, w, ops, b, h = _planned(q, k, v, bias, seg_q, seg_k, causal, block_q,
                                block_k, scale, num_heads, window, rot,
-                               kv_heads)
+                               kv_heads, q_offset is not None)
     _record_plan(p, h, q_offset is not None)
-    bh, g, nq, nk, dv = b * h, p.heads, w.nq, w.nk, p.dv
-    sp = _specs(ops, p, w, h)
-    out_shape = ((bh, p.sq_p, dv) if num_heads is None
-                 else (b, p.sq_p, h * dv))
-    grid = dict(
-        grid=(bh // g, nq, nk),
-        in_specs=[sp.q, sp.k, sp.v] + sp.masks + sp.rot,
-        out_specs=[sp.o, sp.qrow],
-        scratch_shapes=[pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
-                        pltpu.VMEM((g, w.nqt, p.tile_q), jnp.float32),
-                        pltpu.VMEM(w.acc_shape(w.nqt, p.tile_q), jnp.float32)]
-        + ([pltpu.VMEM((g, p.block_k, p.d + p.rot), q.dtype)]
-           if p.rot else []))
-    kernel, prefetch = functools.partial(_fwd_kernel, w=w), ()
     if q_offset is not None:
-        def kernel(off_ref, *refs):
-            _fwd_kernel(*refs, w=w._replace(offset=off_ref[0]))
-
-        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, **grid))
-        prefetch = (jnp.asarray(q_offset, jnp.int32).reshape(1),)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype),
-                   jax.ShapeDtypeStruct((bh, nq, w.nqt, p.tile_q),
-                                        jnp.float32)],
-        interpret=interpret,
-        **grid,
-    )(*prefetch, ops.q, ops.k, ops.v, *sp.mask_args, *ops.rot)
+        q_offset = jnp.asarray(q_offset, jnp.int32).reshape(1)
+    out, lse = _fwd_call(ops, q_offset, w=w, h=h, interpret=interpret)
     out = _user_form(out, p.sq, q, p)
     lse = lse.reshape(b, h, p.sq_p)[:, :, :p.sq]
     return out, lse

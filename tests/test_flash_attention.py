@@ -821,11 +821,19 @@ def test_projection_layout_lse_and_dense_mask(shape):
     ids=["gpt2m_train", "gpt2l_train", "gpt2m_prefill", "k25_prefill",
          "streamed"])
 def test_a_call_without_a_window_plans_as_it_did(kw, want):
-    """... ``rot`` (PR 43) the next, 0 for them too, and ``group`` (PR 45) the
-    newest: 1 for a call that gives no key/value head count."""
+    """... ``rot`` (PR 43) the next, 0 for them too, ``group`` (PR 45) 1 for a
+    call that gives no key/value head count, and ``tiles_written`` (PR 47)
+    the newest: every tile run where a resident walk is within ``UNROLL``
+    (the GPT cells'), none of latent attention's 16-tile walk, the tiles of
+    the blocks below the diagonal where both sequences stream."""
     plan = fa.plan_blocks(dtype=jnp.bfloat16, **kw)
-    assert tuple(plan) == want + (0, 0, 1)
-    assert fa.FlashPlan._fields[-3:] == ("window", "rot", "group")
+    assert tuple(plan)[:-1] == want + (0, 0, 1)
+    assert fa.FlashPlan._fields[-4:] == ("window", "rot", "group",
+                                         "tiles_written")
+    resident = (plan.sq_p, plan.sk_p) == (plan.block_q, plan.block_k)
+    assert plan.tiles_written == (
+        24 if not resident else
+        plan.tiles_run if plan.tiles_all <= fa.UNROLL else 0)
 
 
 # -- a rotary pair: a second score operand in the projections' layout (PR 43) --------
@@ -1028,3 +1036,187 @@ def test_grouped_heads_and_a_query_offset_are_forward_only():
                                         kv_heads=2).sum()
     with pytest.raises(Exception):
         jax.grad(loss)(q)
+
+
+# -- a streamed forward walk written out; one trace a walk (PR 47) ------------------
+
+
+@pytest.fixture
+def quarter_scale(monkeypatch):
+    """The plan's constants at a quarter of their size, so that a streamed
+    call with key blocks of several tiles is seconds on the CPU: tiles of
+    128, 512 resident rows, 256-key blocks (an explicit small ``block_k``
+    would make the tile follow the block, one tile a block)."""
+    monkeypatch.setattr(fa, "TILE", 128)
+    monkeypatch.setattr(fa, "RESIDENT", 512)
+    monkeypatch.setattr(fa, "STREAM_BLOCK", 256)
+
+
+def _two_on_one(sq, sk, seed):
+    """``q [1, sq, 2 * 128]``, ``k, v [1, sk, 128]``: two query heads on one
+    key head, float32."""
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(1, s, n * 128), jnp.float32)
+                 for s, n in ((sq, 2), (sk, 1), (sk, 1)))
+
+
+def _as_laid_out(layout, q, k, v, **kw):
+    """The call over ``[b, s, h * d]`` operands or over ``[b, h, s, d]``
+    ones; the output ``[b, s, h * d]`` either way."""
+    if layout == "bsd":
+        return fa.flash_attention(q, k, v, causal=True, num_heads=2,
+                                  kv_heads=1, **kw)
+    apart = lambda x, n: x.reshape(1, -1, n, 128).transpose(0, 2, 1, 3)
+    out = fa.flash_attention(apart(q, 2), apart(k, 1), apart(v, 1),
+                             causal=True, **kw)
+    return out.transpose(0, 2, 1, 3).reshape(q.shape)
+
+
+@pytest.mark.parametrize("layout,step_scores,form,written", [
+    ("bsd", 1 << 17, "blocks", 36), ("bhsd", None, "blocks", 36),
+    ("bsd", None, "interior", 16)],
+    ids=["bsd_head_a_step", "bhsd_heads_looped", "bsd_two_heads_a_step"])
+def test_a_windowed_piece_writes_out_every_key_block(
+        quarter_scale, monkeypatch, layout, step_scores, form, written):
+    """A piece of 512 queries on 2,048 keys under a 1,024-key window, a
+    static offset and a key bias (the long-document cell's sliding call at a
+    quarter, with two more key blocks behind it): eight key blocks of two
+    tiles past four query tiles. Blocks 0 and 1 lie wholly behind the
+    window and emit nothing, block 2 is cut by its edge, 3 has one tile cut
+    and its others plain, 4 and 5 are interior and share a body, 6 and 7
+    are the diagonal's. All 36 tiles run written out, in both layouts (a
+    packed step's head a python iteration, a ``[b, h, s, d]`` step's two
+    heads a traced loop round written-out tiles), against the dense
+    reference; a packed step of two heads would hold 56 bodies, over
+    ``WRITTEN``, and writes out the interior blocks' 16 tiles beside the
+    loops."""
+    if step_scores:     # a step of one head, as the cell's full-size one is
+        monkeypatch.setattr(fa, "STEP_SCORES", step_scores)
+    sq, sk, window = 512, 2048, 1024
+    q, k, v = _two_on_one(sq, sk, 7)
+    bias = jnp.where(jnp.arange(sk) < 700, -1e9, 0.0)[None].astype(jnp.float32)
+    plan = fa.plan_blocks(sq, sk, 128, jnp.float32, causal=True, bh=2,
+                          num_heads=2 if layout == "bsd" else None,
+                          window=window, group=2)
+    assert (plan.block_q, plan.block_k, plan.tile_q, plan.tile_k) == (
+        512, 256, 128, 128)
+    assert (plan.tiles_written, plan.tiles_run, plan.tiles_all) == (
+        written, 36, 64)
+    assert fa._written(plan, True)[0] == form
+    assert plan.heads == (1 if step_scores else 2)
+    inside = [kb for kb in range(8) if fa._interior(0, kb, plan, sk - sq)]
+    runs = [kb for kb in range(8) if fa._block_runs(
+        0, kb, fa._Walk(plan, 1.0, sk - sq, True, False))]
+    assert (inside, runs) == ([4, 5], [2, 3, 4, 5, 6, 7])
+    got = _as_laid_out(layout, q, k, v, window=window, key_bias=bias)
+    # the reference takes the bias as keys a query may not see
+    want = _grouped_dense(q, k[:, 700:], v[:, 700:], 2, 1, window, sk - sq - 700)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("layout", ["bsd", "bhsd"])
+@pytest.mark.parametrize("piece", [0, 1, 2], ids=["first", "middle", "last"])
+def test_a_piece_below_a_traced_diagonal_is_written_out(quarter_scale, layout,
+                                                        piece):
+    """A piece of 512 queries against a cache of 1,536 keys in six blocks,
+    the diagonal where a traced scalar says: a key block wholly below the
+    first query's diagonal runs the one written-out body of eight plain
+    tiles, the two blocks the diagonal crosses run the loops, the blocks
+    past it nothing. The plan counts the last piece: 32 written of 42 run
+    of 48."""
+    sq, sk = 512, 1536
+    q, k, v = _two_on_one(sq, sk, 11 + piece)
+    plan = fa.plan_blocks(sq, sk, 128, jnp.float32, causal=True, bh=2,
+                          num_heads=2 if layout == "bsd" else None, group=2,
+                          q_offset=True)
+    assert (plan.tiles_written, plan.tiles_run, plan.tiles_all) == (32, 42, 48)
+    assert fa._written(plan, False)[0] == "interior"
+    got = jax.jit(lambda q, k, v, off: _as_laid_out(
+        layout, q, k, v, q_offset=off))(q, k, v, jnp.int32(piece * sq))
+    want = _grouped_dense(q, k, v, 2, 1, 0, piece * sq)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+def test_the_plan_counts_the_tiles_it_writes_out():
+    """``tiles_written`` beside ``tiles_run`` at the long-document cell's
+    two calls (48 heads on 8 of 128; 2,048 queries): every tile of the
+    window's piece on its 6,144 keys, and of the 33,792-key cache the 31
+    blocks below the last piece's diagonal. A resident walk over ``UNROLL``
+    tiles, a streamed block of one tile and a walk over ``WRITTEN`` bodies
+    write nothing out; ``flash.plan`` carries the count."""
+    from paddle_tpu.core import profiler
+
+    cell = dict(d=128, dtype=jnp.bfloat16, causal=True, bh=8 * 48,
+                num_heads=48, group=6)
+    window = fa.plan_blocks(2048, 6144, window=4096, **cell)
+    assert (window.tiles_written, window.tiles_run, window.tiles_all) == (
+        36, 36, 48)
+    full = fa.plan_blocks(2048, 33792, q_offset=True, **cell)
+    assert (full.tiles_written, full.tiles_run, full.tiles_all) == (
+        248, 258, 264)
+    assert tuple(window)[:20] == tuple(fa.plan_blocks(
+        2048, 6144, window=4096, q_offset=True, **cell))[:20]
+    # latent attention's prefill: 16 tiles resident, over UNROLL
+    assert fa.plan_blocks(1984, 1984, 128, jnp.bfloat16, causal=True,
+                          bh=8 * 64, num_heads=64, rot=64).tiles_written == 0
+    # a block of one tile has no neighbour to run under
+    assert fa.plan_blocks(256, 256, 128, jnp.float32, causal=True, bh=2,
+                          block_q=128, block_k=128).tiles_written == 0
+    # a longer window adds interior blocks, which share the one body
+    long = fa.plan_blocks(2048, 10240, window=8192, **cell)
+    assert fa._written(long, True) == ("blocks", 68) and long.tiles_run == 68
+    # two 64-wide heads a step are two bodies a tile, 56 in all, over WRITTEN:
+    # the interior blocks' tiles are written out, the others walk in loops
+    pair = fa.plan_blocks(2048, 6144, 64, jnp.bfloat16, causal=True,
+                          bh=8 * 16, num_heads=16, window=4096)
+    assert pair.heads == 2 and 2 * 28 > fa.WRITTEN
+    assert fa._written(pair, True) == ("interior", 16) and pair.tiles_run == 36
+    since = profiler.time.time_ns()
+    q = jax.ShapeDtypeStruct((1, 2048, 6 * 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 6144, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q, k: fa.flash_attention(
+        q, k, k, causal=True, num_heads=6, kv_heads=1, window=4096), q, k)
+    ids = [sp[4] for sp in profiler.spans(since) if sp[0] == "flash.plan"][-1]
+    assert (ids["tiles_written"], ids["tiles_run"]) == (36, 36)
+
+
+def test_the_forward_kernel_is_traced_once_a_walk(monkeypatch):
+    """A generator calls the kernel once a layer and is traced in several
+    passes: four equal calls and one different one inside one trace trace
+    ``_fwd_kernel`` twice, a second trace of the same program not at all,
+    and a call that differs only in its window or in its key heads anew. A
+    plan is recorded at every call all the same. Counts, not seconds."""
+    from paddle_tpu.core import profiler
+
+    traced, kernel = [], fa._fwd_kernel
+    monkeypatch.setattr(fa, "_fwd_kernel", lambda *refs, w: (
+        traced.append(w), kernel(*refs, w=w))[1])
+    fa._fwd_call.clear_cache()
+    q = jax.ShapeDtypeStruct((1, 128, 4 * 128), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 384, 2 * 128), jnp.float32)
+
+    def layers(q, k, off, window=96, kv_heads=2):
+        k = k[..., :kv_heads * 128]
+        outs = [fa.flash_attention(q, k, k, causal=True, num_heads=4,
+                                   kv_heads=kv_heads, window=window)
+                for _ in range(4)]
+        return outs + [fa.flash_attention(q, k, k, causal=True, num_heads=4,
+                                          kv_heads=kv_heads, q_offset=off)]
+
+    since = profiler.time.time_ns()
+    first = jax.make_jaxpr(layers)(q, k, jnp.int32(7))
+    assert len(traced) == 2
+    again = jax.make_jaxpr(lambda *a: layers(*a))(q, k, jnp.int32(7))
+    assert len(traced) == 2
+    plans = [sp for sp in profiler.spans(since) if sp[0] == "flash.plan"]
+    assert len(plans) == 10
+    # the program holds the kernel where each call stands, as it did
+    for jaxpr in (first, again):
+        assert str(jaxpr).count("name=flash_fwd") == 5
+    jax.make_jaxpr(lambda *a: layers(*a, window=64))(q, k, jnp.int32(7))
+    assert len(traced) == 3     # the windowed walk anew, the other remembered
+    jax.make_jaxpr(lambda *a: layers(*a, kv_heads=1))(q, k, jnp.int32(7))
+    assert len(traced) == 5
+    assert [(w.plan.window, w.plan.group) for w in traced] == [
+        (96, 2), (0, 2), (64, 2), (96, 4), (0, 4)]
+    fa._fwd_call.clear_cache()

@@ -184,3 +184,37 @@ def test_a_length_over_resident_runs_the_pair_and_matches_dense():
     for x, a, b_ in zip("qkv", got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=3e-4,
                                    rtol=2e-3, err_msg=f"d{x} vs dense")
+
+
+@pytest.mark.parametrize("name", ["bsd_two_heads_a_group", "fused_qkv",
+                                  "bhsd_causal"])
+def test_a_remembered_forward_differentiates_as_a_traced_one(name, monkeypatch):
+    """The forward kernel is reached through jit's cache (``_fwd_call``):
+    the gradient of a jitted caller whose forward trace is remembered from
+    an earlier pass equals, bit for bit, the one whose kernel is traced
+    there, and the custom VJP's two forward rules share one trace."""
+    layout, sq, sk, h, d, _, _, _ = FUSED_BACKWARD[name]
+    q, k, v = _rand(b=2, h=h, s=sq, sk=sk, d=d, seed=3)
+    traced, kernel = [], fa._fwd_kernel
+    monkeypatch.setattr(fa, "_fwd_kernel", lambda *refs, w: (
+        traced.append(w), kernel(*refs, w=w))[1])
+
+    def loss(q, k, v):
+        if layout == "bhsd":
+            return fa.flash_attention(q, k, v, causal=True).sum()
+        parts = [_heads_together(x) for x in (q, k, v)]
+        if layout == "fused":
+            parts = [jnp.concatenate(parts, axis=-1)]
+        return fa.flash_attention(*parts, num_heads=h, causal=True).sum()
+
+    grads = lambda: jax.jit(jax.grad(lambda *a: loss(*a), argnums=(0, 1, 2)))(
+        q, k, v)
+    fa._fwd_call.clear_cache()
+    fresh = grads()
+    assert len(traced) == 1
+    jax.eval_shape(loss, q, k, v)       # another pass: the primal's rule
+    remembered = grads()
+    assert len(traced) == 1
+    for a, b_ in zip(fresh, remembered):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+    fa._fwd_call.clear_cache()

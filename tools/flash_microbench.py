@@ -33,6 +33,21 @@ side by side in one ``[b, s, 3*h*d]`` array, one matmul's output). Beside
 each kernel's time stands ``ms_call``: the device's busy time a call,
 everything the call runs (transposes, pads, the backward's ``delta``), so
 that a kernel PR can compare layouts on the chip without a train step.
+
+    chiprun -- python3 tools/flash_microbench.py --shapes trinity_window,trinity_full
+
+``trinity_window`` and ``trinity_full`` are the two forward calls a piece of a
+``trinity-serve-long`` prompt makes (``SERVE_CALLS``: 8 rows x 2,048 queries,
+48 heads on 8 key heads of 128, in the projections' layout; 6,144 keys under a
+4,096-key window and a key bias, or a 33,792-key cache under a traced
+``q_offset`` at pieces 0, 7 and 15). They are timed forward only, with the
+walk the plan gives them (``tiles_written`` of ``tiles_run`` in the row's
+plan): the program has no switch, so the other side of a comparison is this
+tool over the other tree's archive (``--tree <dir>``: where ``paddle_tpu`` is
+imported from; a tree whose plan knows no ``tiles_written`` prints none). A row holds the call's time, its
+tiles (``tiles_run`` of a causal call as long as the piece's last key) and
+microseconds a tile.
+
 One JSON line per measurement goes to ``--out``. One process: it owns the chip and starts no child; it fails
 where there is no TPU (a CPU time is not a device time).
 """
@@ -60,6 +75,14 @@ SHAPES = {
     "long_4k_d128": ((2, 8, 4096, 128), True),
     "long_32k": ((1, 8, 32768, 64), True),
 }
+# name -> (keys, window, the pieces whose ``q_offset`` is timed; None: the
+# offset is the call's own): trinity-serve-long's two calls a piece, forward
+# only. Every call is (b, 2048, 48 * 128) queries on (b, keys, 8 * 128)
+SERVE_CALLS = {
+    "trinity_window": (6144, 4096, (None,)),
+    "trinity_full": (33792, 0, (0, 7, 15)),
+}
+SERVE_ROWS, SERVE_PIECE, SERVE_HEADS, SERVE_KV_HEADS, SERVE_D = 8, 2048, 48, 8, 128
 # matmuls of 2 * sq * sk * d flops a head that each kernel needs
 MATMULS = {"flash_fwd": 2, "flash_bwd": 5, "flash_dq": 3, "flash_dkv": 4}
 
@@ -114,10 +137,15 @@ def main():
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--reference", type=int, default=1,
                     help="also time the jax pallas reference kernel")
+    ap.add_argument("--tree", default=ROOT,
+                    help="the checkout whose paddle_tpu is timed")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "flash_microbench.jsonl"))
     args = ap.parse_args()
     causal = bool(args.causal)
+    sys.path.insert(0, os.path.abspath(args.tree))
+
+    import inspect
 
     import jax
     import jax.numpy as jnp
@@ -213,10 +241,58 @@ def main():
         return (lambda qkv: fa.flash_attention(
             qkv, causal=causal, num_heads=h)), 1
 
+    def serve_call(name):
+        """One row a piece for a call of ``SERVE_CALLS``."""
+        sk, window, pieces = SERVE_CALLS[name]
+        b, sq, h, kvh, d = (SERVE_ROWS, SERVE_PIECE, SERVE_HEADS,
+                            SERVE_KV_HEADS, SERVE_D)
+        rng = np.random.RandomState(0)
+        q = jnp.asarray(rng.randn(b, sq, h * d), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.randn(b, sk, kvh * d), jnp.bfloat16)
+                for _ in range(2))
+        # the ring's first 1,024 slots hold nothing yet: a bias like any other
+        bias = jnp.broadcast_to(jnp.where(jnp.arange(sk) < 1024, fa.NEG_INF,
+                                          0.0)[None], (b, sk)) if window else None
+        fn = jax.jit(lambda q, k, v, bias, off: fa.flash_attention(
+            q, k, v, causal=True, num_heads=h, kv_heads=kvh, window=window,
+            key_bias=bias, q_offset=off))
+        for piece in pieces:
+            off = None if piece is None else jnp.int32(piece * sq)
+            row = {"kernel": "repo", "shape": name, "piece": piece}
+            try:
+                plan = fa.plan_blocks(
+                    sq, sk, d, jnp.bfloat16, True, bh=b * h, num_heads=h,
+                    window=window, group=h // kvh,
+                    **({"q_offset": True} if piece is not None and "q_offset"
+                       in inspect.signature(fa.plan_blocks).parameters
+                       else {}))
+                (ms, calls), = traced_kernel_ms(
+                    fn, (q, k, v, bias, off), args.iters, busy=row).values()
+                row["plan"] = plan._asdict()
+                # the tiles a piece whose last key is its own walks
+                tiles = plan.tiles_run if piece is None else fa.plan_blocks(
+                    sq, (piece + 1) * sq, d, jnp.bfloat16, True,
+                    block_k=plan.block_k).tiles_run
+                tf = (2 * 2.0 * plan.tile_q * plan.tile_k * d * tiles
+                      * b * h / (ms / 1e3) / 1e12)
+                row["kernels"] = {"flash_fwd": {
+                    "ms": round(ms, 4), "calls": calls, "tiles": tiles,
+                    "tiles_written": plan._asdict().get("tiles_written"),
+                    "us_tile": round(ms * 1e3 / (tiles * b * h), 4),
+                    "tflops": round(tf, 2),
+                    "of_peak": round(tf * 1e12 / peak, 4)}}
+                best[name, f"repo piece {piece}"] = (row["ms_call"], "ms_call")
+            except Exception as e:  # e.g. a walk the compiler refuses
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            record(row)
+
     plan_tile = fa.TILE
     layouts = args.layouts.split(",")
     best = {}
     for name in args.shapes.split(","):
+        if name in SERVE_CALLS:
+            serve_call(name)
+            continue
         shape, grad = SHAPES[name]
         b, h, s, d = shape
         rng = np.random.RandomState(0)
