@@ -108,10 +108,10 @@ def step_in_conditional(layers: Callable, head: Callable):
     """``step_fn`` with the layers inside the conditional: the first step
     takes the branch that hands back ``logp0`` and the carry untouched,
     every other runs ``layers(tokens, carried, index) -> (x [rows, 1, d],
-    carried)`` and ``head(x[:, 0])``. The plain form, and right where a step
-    writes its caches at one row with ``dynamic_update_slice``: GPT, Kimi-K2
-    and MiniCPM-SALA are built with it. (Whether the other form serves them
-    better is a question for the chip: ROADMAP D18.)"""
+    carried)`` and ``head(x[:, 0])``. The plain form, one step's layers a
+    request the cheaper: GPT, Kimi-K2 and Trinity are built with it, and
+    their steps on the chip copy no carry. (MiniCPM-SALA's copied all of
+    its and took the other form in PR 48: ROADMAP D18.)"""
 
     def step_fn(tokens, state):
         carried = _carried(state)
@@ -138,8 +138,8 @@ def step_with_write_switch(layers: Callable, head: Callable, p_len: int):
     and what a cache gets at position ``p`` the next step writes over, since
     it stands at ``p`` too. A conditional round arrays that a kernel writes
     in place makes the compiler copy them on both of its sides (0.6 GB a
-    layer of Brumby's states: PERF.md section 6, PR 39), so Brumby and
-    Phi-4-mini-flash are built with this form. ``given`` (what the audited
+    layer of Brumby's states, PR 39; MiniCPM-SALA's 36 arrays a step, PR 48),
+    so they and Phi-4-mini-flash take this form. ``given`` (what the audited
     recurrence was handed, ``()`` without an audit) goes into the state's
     :func:`audit_log` at ``index - p_len``: the first step's entry is
     written over as well."""

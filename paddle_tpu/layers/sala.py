@@ -379,7 +379,14 @@ def sparse_decode(x, p, dims: SparseDims, cache, index, prompt_len: int,
     ``dense_len`` and by selection beyond. ``prompt_len`` is the least
     ``index`` this step can see: a cache that ends within ``dense_len``
     never selects, a prompt beyond it always does, and only a generator
-    that crosses it holds both forms under a ``cond``."""
+    that crosses it holds both forms under a ``cond``.
+
+    A step run twice at one ``index`` leaves what its second run writes
+    and nothing of the first: position ``index`` of ``k`` and ``v``, and
+    compressed key ``j`` from the rewritten ``k`` (the one that covers
+    ``index`` itself where ``(index + 1) % stride == 0``). That is all
+    ``decoding.step_with_write_switch`` asks of a first step, which stands
+    at the prompt's length as the second does: no ``write`` switch here."""
     dims.check()
     k_cache, v_cache, ck_cache = cache
     r, total, _ = k_cache.shape
@@ -465,16 +472,20 @@ def lightning_prefill(x, p, dims: LightningDims, state, log_decay, p0, a: float)
 
 
 def lightning_decode(x, p, dims: LightningDims, state, log_decay, index,
-                     a: float):
+                     a: float, write=True):
     """One token at position ``index``: ``S <- lambda S + k^T v``, ``o = q
-    S``, the state read and written once, in float32."""
+    S``, the state read and written once, in float32. ``write`` (a traced
+    bool) false keeps the state handed in, bit for bit (a select inside the
+    update, not a product with one and a sum with zero): the first step of
+    ``decoding.step_with_write_switch``, whose ``x`` nothing reads."""
     f32 = jnp.float32
     with jax.named_scope("lightning"):
         u = rms_norm(x, p["attn_norm/g"], dims.eps)
         q, k, v = _lightning_qkv(u, p, dims, index[None])
-        state = (state * jnp.exp(log_decay.astype(f32))[None, :, None, None]
-                 + jnp.einsum("rhd,rhe->rhde", k[:, 0].astype(f32),
-                              v[:, 0].astype(f32)))
+        state = jnp.where(
+            write, state * jnp.exp(log_decay.astype(f32))[None, :, None, None]
+            + jnp.einsum("rhd,rhe->rhde", k[:, 0].astype(f32),
+                         v[:, 0].astype(f32)), state)
         o = jnp.einsum("rhd,rhde->rhe", q[:, 0].astype(f32), state)
         o = rms_norm(o.astype(x.dtype), p["o_norm/g"], dims.eps)
         x = residual(x, _gated_out(o.reshape(x.shape[0], 1, -1), u, p), a)

@@ -150,7 +150,7 @@ def _decoder(cfg: BrumbyConfig, prompt_ids, max_new_tokens: int):
         prefill={"chunk": chunk, "chunks": p_len // chunk},
         state_layers=len(states), state_dtype="float32",
         state_write_bytes=held * runs // pairs,
-        window_bytes=decoding.nbytes(windows))
+        window_bytes=decoding.nbytes(windows), first_step="write_switch")
     hd = dims.head_dim
     at = slice(AUDIT_HEAD * hd, (AUDIT_HEAD + 1) * hd)
 

@@ -203,9 +203,9 @@ def _decoder(cfg: GPTConfig, prompt_ids, max_new_tokens: int,
             caches = {"k": [grow(ks[i]) for i in range(L)],
                       "v": [grow(vs[i]) for i in range(L)]}
     slab = jax.tree.leaves(caches)[0]
-    decoding.record_plans(
-        "kv", rows, total, cfg.num_heads, L, str(slab.dtype), slab.shape[2],
-        {"cache": caches}, head_dim=cfg.d_model // cfg.num_heads)
+    decoding.record_plans("kv", rows, total, cfg.num_heads, L,
+        str(slab.dtype), slab.shape[2], {"cache": caches},
+        head_dim=cfg.d_model // cfg.num_heads, first_step="conditional")
     state0 = decoding.start(
         caches, p, jnp.repeat(first_logp, K, axis=0) if K > 1 else first_logp)
     with jax.named_scope("stack_slice"):

@@ -253,7 +253,7 @@ def _decoder(cfg: KimiK2Config, prompt_ids, max_new_tokens: int):
     decoding.record_plans(
         "latent", rows, c[0].shape[1], cfg.num_attention_heads, n_layers,
         str(c[0].dtype), c[0].shape[2], {"cache": c + r},
-        rope_lane_width=r[0].shape[-1],
+        rope_lane_width=r[0].shape[-1], first_step="conditional",
         q_b_regrouped_bytes=decoding.nbytes(
             [t[k] for t in (dense, sliced)
              for k in ("q_b/w_nope", "q_b/w_rope")]))

@@ -15,9 +15,10 @@ chips. Nothing stands in for it.
 
 This module serves only: :func:`make_generator`, through the contract of
 ``layers/decoding.py`` (``prompt_ids [b, p] -> {"ids": [b, new]}``, the
-first step's plain form). There is no ``make_model``: no cut of this model
-trains on one chip, and neither kernel has a backward (ROADMAP R5, R15).
-Matrices are created and held in ``cfg.dtype``; norm scales are float32.
+first step's write-switch form). There is no ``make_model``: no cut of
+this model trains on one chip, and neither kernel has a backward (ROADMAP
+R5, R15). Matrices are created and held in ``cfg.dtype``; norm scales are
+float32.
 
 The carried state has two kinds of entry, in per-layer lists: for each
 sparse layer a key slab, a value slab and a compressed-key slab, lane-dense
@@ -192,7 +193,8 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
         {"kv": carried["k"] + carried["v"], "index": carried["ck"],
          "state": carried["s"]},
         prefill={"chunk": chunk, "chunks": p_len // chunk},
-        sparse_layers=n_sparse, state_layers=n_light, state_dtype="float32")
+        sparse_layers=n_sparse, state_layers=n_light, state_dtype="float32",
+        first_step="write_switch")
 
     def through(x, carried, mix_sparse, mix_light):
         """``x`` through the layers held, each with its own entries of the
@@ -225,16 +227,22 @@ def _decoder(cfg: MiniCPMSALAConfig, prompt_ids, max_new_tokens: int):
                                                    p_len, chunk)
         first_logp = head(x_last)
 
-    # ---- one step: each layer's one-token form over its own entries
-    def layers(tokens, carried, index):
-        return through(
+    # ---- one step: each layer's one-token form over its own entries, in
+    # every iteration (no conditional round the slabs and states it writes
+    # in place: 36 copies of them a step went with it, PERF.md section 6,
+    # PR 48). The first stands at ``p_len`` as the second does, which
+    # writes the slabs there again; a state folds only the second's token
+    def layers(tokens, carried, index, first):
+        x, carried = through(
             embed(tokens)[:, None, :], carried,
             lambda x, lp, c: S.sparse_decode(x, lp, sp, c, index, p_len, a),
             lambda x, lp, s, ld: S.lightning_decode(x, lp, li, s, ld, index,
-                                                    a))
+                                                    a, write=~first))
+        return x, carried, ()
 
     return (decoding.start(carried, p_len, first_logp),
-            decoding.step_in_conditional(layers, head), decoding.no_audit)
+            decoding.step_with_write_switch(layers, head, p_len),
+            decoding.no_audit)
 
 
 # ``make_generator(cfg, max_new_tokens, bos_id=1, eos_id=2)``: greedy

@@ -186,7 +186,7 @@ def _decoder(cfg: Phi4FlashConfig, prompt_ids, max_new_tokens: int):
         state_layers=n_mamba, state_dtype="float32", window_layers=n_window,
         shared_kv_readers=1 + kinds.count(CROSS),
         carry_free_layers=kinds.count(GMU) + kinds.count(CROSS),
-        kv_bytes=decoding.nbytes((held, shared)))
+        kv_bytes=decoding.nbytes((held, shared)), first_step="write_switch")
     at = slice(0, min(AUDIT_CHANNELS, dims.d_inner))
     audit_slot = [l for l, k in enumerate(kinds) if k == MAMBA][AUDIT_LAYER]
 

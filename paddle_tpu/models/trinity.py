@@ -217,12 +217,12 @@ def _decoder(cfg: TrinityConfig, prompt_ids, max_new_tokens: int):
     held = [kv(window)] * sliding.count(True)
     full = [kv(padded_keys(max_len))] * sliding.count(False)
     chunk = min(cfg.prefill_chunk, p_len)
-    decoding.record_plans(
-        "kv", rows, max_len, dims.heads, len(indices), cfg.dtype,
-        dims.kv_width, {"window_kv": held, "full_kv": full},
+    decoding.record_plans("kv", rows, max_len, dims.heads, len(indices),
+        cfg.dtype, dims.kv_width, {"window_kv": held, "full_kv": full},
         prefill={"chunk": chunk, "pieces": -(-p_len // chunk)},
         kv_heads=dims.kv_heads, window=window, window_layers=len(held),
-        full_layers=len(full), full_len=padded_keys(max_len))
+        full_layers=len(full), full_len=padded_keys(max_len),
+        first_step="conditional")
 
     # ---- prefill: the prompt a piece at a time through every layer
     def prefill_piece(carried, p0, length):

@@ -280,6 +280,28 @@ def test_the_plan_record_gives_bytes_by_part_and_their_sum():
     assert pre == {"rows": 2, "chunk": 4, "chunks": 2}
 
 
+@pytest.mark.parametrize("model,form", [
+    ("gpt", "conditional"), ("kimi_k2", "conditional"),
+    ("trinity", "conditional"), ("minicpm_sala", "write_switch"),
+    ("brumby", "write_switch"), ("phi4_flash", "write_switch")])
+def test_a_model_s_plan_names_the_first_step_it_is_built_with(model, form):
+    """``decode.plan``'s ``first_step`` is a literal beside the call that
+    builds the step: each served model's ``_decoder`` names the form it
+    calls and not the other (MiniCPM-SALA moved to the write switch in
+    PR 48: 36 copies of its carried arrays a step went, PERF.md section 6)."""
+    import importlib
+    import inspect
+
+    source = inspect.getsource(
+        importlib.import_module(f"paddle_tpu.models.{model}")._decoder)
+    builder = {"conditional": "decoding.step_in_conditional(",
+               "write_switch": "decoding.step_with_write_switch("}
+    other = "write_switch" if form == "conditional" else "conditional"
+    assert builder[form] in source and builder[other] not in source
+    assert f'first_step="{form}"' in source
+    assert f'first_step="{other}"' not in source
+
+
 # -- the one FFN block ---------------------------------------------------------------
 
 

@@ -386,6 +386,139 @@ def test_generator_emits_the_scorer_s_argmax_and_records_its_plans(highest):
     assert (np.where(ended, 2, np.argmax(logp, -1)) == ids).all()
 
 
+# -- the first step: its layers run too, and leave nothing ------------------------------------
+
+# a prompt whose first generated token ends a stride: ``(p + 1) % 16 == 0``,
+# so compressed key ``(p + 1 - 32) // 16 = 14`` covers 224..255, position
+# ``p`` itself, and the first iteration writes it from that iteration's key
+STRIDE_ENDS = 255
+
+
+def test_lightning_decode_unwritten_keeps_its_state_bit_for_bit():
+    """``write=False`` hands back the state it was given, every bit of it (a
+    negative zero and a denormal among them: a product with one and a sum
+    with zero would turn the first and may flush the second); ``write=True``,
+    the default, is the recurrence."""
+    p = layer_params(sala.lightning_params, LIGHT, 4)
+    x, decay = rand(3, 2, 1, 64), sala.lightning_log_decay(4, 10, 32)
+    state = np.asarray(rand(4, 2, 4, 16, 16, scale=30.0)).copy()
+    state[0, 0, 0, :4] = [-0.0, 1e-40, -1e-45, 3e38]
+    step = jax.jit(lambda write: sala.lightning_decode(
+        x, p, LIGHT, jnp.asarray(state), decay, jnp.asarray(300, jnp.int32),
+        1.0, write=write))
+    _, kept = step(False)
+    assert np.array_equal(np.asarray(kept).view(np.uint32),
+                          state.view(np.uint32))
+    out, written = step(True)
+    plain, by_default = sala.lightning_decode(
+        x, p, LIGHT, jnp.asarray(state), decay, jnp.asarray(300, jnp.int32), 1.0)
+    np.testing.assert_allclose(np.asarray(written), np.asarray(by_default),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(plain), atol=1e-5)
+    assert np.abs(np.asarray(written)[1] - state[1]).max() > 1e-2
+
+
+@pytest.mark.parametrize("prompt_len", [STRIDE_ENDS, 384],
+                         ids=["stride_ends_at_the_prompt", "selected_prefill"])
+def test_the_first_iteration_leaves_nothing_that_outlasts_it(highest, prompt_len):
+    """The step's layers run in the first iteration too
+    (``decoding.step_with_write_switch``), on a token nothing should read.
+    After that iteration and one more, the distribution and every carried
+    array are the same bit for bit whatever the first was handed, and they
+    are what one step from the prefill's state leaves with the first
+    iteration skipped: the slabs' position ``p`` and the compressed key that
+    covers it are written again, a lightning state is not folded into."""
+    prompt = np.random.RandomState(9).randint(
+        3, VOCAB, (2, prompt_len)).astype(np.int32)
+    cfg = family.program_config(TINY)
+
+    def two_steps(prompt_ids, unread, token):
+        state0, step_fn, _ = minicpm_sala._decoder(cfg, prompt_ids, 8)
+        first_logp, once = step_fn(unread, state0)
+        logp, twice = step_fn(token, once)
+        skipped, direct = step_fn(token, {**state0, "first": jnp.asarray(False)})
+        carried = lambda st: {k: st[k] for k in ("k", "v", "ck", "s", "index")}
+        return {"first": first_logp, "logp0": state0["logp0"], "logp": logp,
+                "twice": carried(twice), "skipped": skipped,
+                "direct": carried(direct), "once_s": once["s"],
+                "s0": state0["s"], "once_index": once["index"]}
+
+    prog = pt.build(two_steps)
+    token = np.asarray([5, 11], np.int32)
+    feeds = [dict(prompt_ids=prompt, unread=np.asarray(u, np.int32), token=token)
+             for u in ([1, 1], [77, 40])]
+    params, _ = prog.init(jax.random.PRNGKey(3), **feeds[0])
+    a, b = (jax.tree.map(np.asarray, prog.apply(params, {}, training=False,
+                                                **feed)[0]) for feed in feeds)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert np.array_equal(x, y)
+    assert np.array_equal(a["first"], a["logp0"])
+    assert a["once_index"] == prompt_len and a["twice"]["index"] == prompt_len + 1
+    for kept, was in zip(a["once_s"], a["s0"]):
+        assert np.array_equal(kept.view(np.uint32), was.view(np.uint32))
+    for x, y in zip(jax.tree.leaves(a["twice"]), jax.tree.leaves(a["direct"])):
+        assert np.array_equal(x, y)
+    np.testing.assert_allclose(a["logp"], a["skipped"], atol=1e-6)
+    if prompt_len == STRIDE_ENDS:       # the key written twice is a new one
+        j = (prompt_len + 1 - 32) // 16
+        assert np.abs(a["twice"]["ck"][0][:, j]).max() > 0
+        assert not np.abs(a["twice"]["ck"][0][:, j + 1:]).any()
+
+
+def test_steps_from_a_prompt_that_ends_a_stride_against_reference(highest):
+    """A dense prefill of 255 tokens, then 72 steps: the first generated
+    token is the last key of compressed key 14, which the first iteration
+    and the second both write; the steps select from position 256 on, and
+    from 320 on block 3 (192..255, scored through keys 11 to 15) is read
+    only if chosen. Log-probabilities at every position against the
+    reference's one full forward."""
+    test_prefill_then_steps_against_reference(highest, STRIDE_ENDS, 72)
+
+
+def test_ids_do_not_depend_on_bos_id(highest):
+    """``bos_id`` is the token the search hands the first iteration, whose
+    distribution is the prefill's: with the layers run in that iteration
+    too it is the one thing that reads it, and nothing of it is left, in
+    the ids or (the sharper reading) in any bit of the last state."""
+    prompt = np.random.RandomState(11).randint(
+        3, VOCAB, (2, STRIDE_ENDS)).astype(np.int32)
+    cfg = family.program_config(TINY)
+
+    def with_last_state(cfg, prompt_ids, max_new_tokens):
+        state0, step_fn, _ = minicpm_sala._decoder(cfg, prompt_ids,
+                                                   max_new_tokens)
+        return state0, step_fn, lambda state: {
+            k: state[k] for k in ("k", "v", "ck", "s")}
+
+    served = []
+    for bos_id in (1, 50):
+        gen = pt.build(decoding.make_generator(with_last_state, cfg, 6,
+                                               bos_id=bos_id))
+        if not served:
+            params, _ = gen.init(jax.random.PRNGKey(5), prompt_ids=prompt)
+        served.append(jax.tree.map(np.asarray, gen.apply(
+            params, {}, training=False, prompt_ids=prompt)[0]))
+    assert served[0]["ids"].shape == (2, 6)
+    for x, y in zip(*map(jax.tree.leaves, served)):
+        assert np.array_equal(x, y)
+    direct = pt.build(minicpm_sala.make_generator(cfg, max_new_tokens=6, bos_id=7))
+    assert np.array_equal(served[0]["ids"], np.asarray(direct.apply(
+        params, {}, training=False, prompt_ids=prompt)[0]["ids"]))
+
+
+def test_the_decode_plan_says_the_first_step_s_form():
+    """One trace (no compile) leaves a ``decode.plan`` whose ``first_step``
+    is ``"write_switch"``: the layers outside the conditional."""
+    prompt = np.zeros((2, 384), np.int32)
+    gen = pt.build(minicpm_sala.make_generator(family.program_config(TINY),
+                                               max_new_tokens=5))
+    since = profiler.time.time_ns()
+    jax.eval_shape(lambda key: gen.init(key, prompt_ids=prompt)[0],
+                   jax.random.PRNGKey(0))
+    (plan,) = [s[4] for s in profiler.spans(since) if s[0] == "decode.plan"]
+    assert plan["first_step"] == "write_switch"
+
+
 def test_served_ids_are_the_direct_call_s(tmp_path, highest):
     """``export_decoder(model=minicpm_sala)`` -> ``decode_server``: a
     bucket-sized request and a single prompt that pads both return the ids
