@@ -2,9 +2,10 @@
 rotary map, the gated SiLU FFN with its parameters, the residual sum and the
 FFN block. ``layers/latent.py`` (latent attention), ``layers/sala.py``
 (sparse and lightning attention), ``layers/retention.py`` (power retention),
-``layers/sambay.py`` (Mamba, differential attention) and ``layers/gqa.py``
-(gated grouped-query attention) hold mixers only and take these from here; the models pass :func:`ffn_block` the arguments that
-give the expression each computes.
+``layers/sambay.py`` (Mamba, differential attention), ``layers/gqa.py``
+(grouped-query attention, gated or plain) and ``layers/mamba2.py`` (Mamba-2)
+hold mixers only and take these from here; the models pass :func:`ffn_block`
+the arguments that give the expression each computes.
 
 Pure functions of arrays, no ``LayerHelper`` call outside the two parameter
 makers, so they trace under ``lax.scan``. Matrices are created and held in

@@ -2,7 +2,8 @@
 its side of it from.
 
 Every served model (``models/gpt.py``, ``kimi_k2.py``, ``minicpm_sala.py``,
-``brumby.py``, ``phi4_flash.py``, ``trinity.py``) has one function::
+``brumby.py``, ``phi4_flash.py``, ``trinity.py``, ``granite_hybrid.py``) has
+one function::
 
     _decoder(cfg, prompt_ids [rows, p], max_new_tokens) -> (state0, step_fn, audit)
 
@@ -139,7 +140,7 @@ def step_with_write_switch(layers: Callable, head: Callable, p_len: int):
     it stands at ``p`` too. A conditional round arrays that a kernel writes
     in place makes the compiler copy them on both of its sides (0.6 GB a
     layer of Brumby's states, PR 39; MiniCPM-SALA's 36 arrays a step, PR 48),
-    so they and Phi-4-mini-flash take this form. ``given`` (what the audited
+    so they, Phi-4-mini-flash and Granite-4.0-H take this form. ``given`` (what the audited
     recurrence was handed, ``()`` without an audit) goes into the state's
     :func:`audit_log` at ``index - p_len``: the first step's entry is
     written over as well."""

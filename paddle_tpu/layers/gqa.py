@@ -51,7 +51,7 @@ import jax.numpy as jnp
 
 from ..framework import LayerHelper
 from ..ops.flash_attention import flash_attention
-from . import kv_ring
+from . import blocks, kv_ring
 from .blocks import params, rms_norm, rope
 from .stacked import NEG_INF
 
@@ -172,10 +172,11 @@ def full_prefill(x, p, dims: GQADims, cache, p0):
 # -- one token -----------------------------------------------------------------------
 
 
-def cache_attention(q, k_cache, v_cache, live, dims: GQADims):
+def cache_attention(q, k_cache, v_cache, live, dims: GQADims, scale=None):
     """One token's attention against a cache read in place: ``q [rows,
     heads * hd]``, caches ``[rows, T, kv_heads * hd]``, ``live [T]`` the
-    slots attended. Returns ``[rows, heads * hd]`` float32."""
+    slots attended; ``scale`` the softmax scale (None: ``hd ** -0.5``).
+    Returns ``[rows, heads * hd]`` float32."""
     rows, hd, kvh, grp = q.shape[0], dims.head_dim, dims.kv_heads, dims.group
     # [rows, kv_heads * hd, heads]: column h holds head h's query in the
     # lanes of the key head it reads, zeros elsewhere, so that ``cache @
@@ -185,7 +186,8 @@ def cache_attention(q, k_cache, v_cache, live, dims: GQADims):
     blocks = jnp.tile(q.reshape(rows, dims.heads, hd).transpose(0, 2, 1),
                       (1, kvh, 1)) * reads
     logits = jnp.einsum("rtc,rch->rth", k_cache, blocks,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
+                        preferred_element_type=jnp.float32) * (
+                            hd ** -0.5 if scale is None else scale)
     probs = jax.nn.softmax(jnp.where(live[None, :, None], logits, NEG_INF),
                            axis=1).astype(q.dtype)
     o = jnp.einsum("rth,rtc->rhc", probs, v_cache,
@@ -223,5 +225,60 @@ def full_decode(x, p, dims: GQADims, cache, index):
                        jnp.arange(cache[0].shape[1]) <= index, FULL)
 
 
+# -- plain grouped-query attention (no gate, no head norm, no rotation) --------------
+#
+# ``model_type: granitemoehybrid``'s attention layers: four projections of
+# the normed input, a softmax scale of the model's own (``attention_multiplier``,
+# not ``1 / sqrt(hd)``), no positions at all, every key seen, and the output
+# summed into the stream times ``residual``. The cache is a full layer's.
+
+
+def plain_params(dims: GQADims, dtype) -> Dict[str, jax.Array]:
+    d, qw, kvw = dims.d_model, dims.q_width, dims.kv_width
+    return params(LayerHelper("mixer", name="mixer"), {
+        "attn_norm/g": ((d,), None), "q/w": ((d, qw), d), "k/w": ((d, kvw), d),
+        "v/w": ((d, kvw), d), "o/w": ((qw, d), qw)}, None, dtype)
+
+
+def _plain_project(x, p, dims: GQADims):
+    a = rms_norm(x, p["attn_norm/g"], dims.eps)
+    return (jnp.matmul(a, p["q/w"]), jnp.matmul(a, p["k/w"]),
+            jnp.matmul(a, p["v/w"]))
+
+
+def plain_prefill(x, p, dims: GQADims, cache, p0, scale: float,
+                  residual: float):
+    """:func:`full_prefill` for the plain layer: ``x + residual * W_o
+    softmax(scale q k^T) v``, the piece's keys and values written into
+    ``cache`` at ``p0`` (traced) and the cache handed to the kernel whole."""
+    with jax.named_scope("attn"):
+        q, k, v = _plain_project(x, p, dims)
+        put = lambda held, new: jax.lax.dynamic_update_slice_in_dim(
+            held, new, p0, axis=1)
+        cache = (put(cache[0], k), put(cache[1], v))
+        _record_plan(FULL, dims, "prefill", cache[0].shape[1])
+        o = flash_attention(q, *cache, causal=True, num_heads=dims.heads,
+                            kv_heads=dims.kv_heads, q_offset=p0, scale=scale)
+        x = blocks.residual(x, jnp.matmul(o, p["o/w"]), residual)
+    return x, cache
+
+
+def plain_decode(x, p, dims: GQADims, cache, index, scale: float,
+                 residual: float):
+    """:func:`full_decode` for the plain layer: one token at position
+    ``index`` (traced), its key and value written into ``cache`` there."""
+    with jax.named_scope("attn"):
+        q, k, v = _plain_project(x, p, dims)
+        cache = (kv_ring.write(cache[0], k[:, 0], index),
+                 kv_ring.write(cache[1], v[:, 0], index))
+        _record_plan(FULL, dims, "step", cache[0].shape[1])
+        o = cache_attention(q[:, 0], *cache,
+                            jnp.arange(cache[0].shape[1]) <= index, dims, scale)
+        x = blocks.residual(x, jnp.matmul(o.astype(x.dtype)[:, None], p["o/w"]),
+                            residual)
+    return x, cache
+
+
 __all__ = ["FULL", "GQADims", "WINDOW", "attention_params", "cache_attention",
-           "full_decode", "full_prefill", "window_decode", "window_prefill"]
+           "full_decode", "full_prefill", "plain_decode", "plain_params",
+           "plain_prefill", "window_decode", "window_prefill"]

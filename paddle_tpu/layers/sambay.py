@@ -55,7 +55,7 @@ from .. import initializer as init
 from ..framework import LayerHelper
 from ..ops.flash_attention import flash_attention
 from ..ops.selective_scan import mamba_step, selective_scan
-from . import kv_ring
+from . import conv_tail, kv_ring
 from .blocks import gated_ffn_params, layer_norm, params
 from .stacked import NEG_INF
 
@@ -156,19 +156,16 @@ def _mamba_inputs(x, p, dims: SambaDims, tail):
     convolution and its SiLU, ``delta``, ``B, C [b, s, d_state]``, the gate
     ``z`` in ``x``'s dtype, the new tail)``."""
     f32 = jnp.float32
-    di, n, r, s = dims.d_inner, dims.d_state, dims.dt_rank, x.shape[1]
+    di, n, r = dims.d_inner, dims.d_state, dims.dt_rank
     u = layer_norm(x, p["norm/g"], p["norm/b"], dims.eps)
     az = jnp.matmul(u, p["in/w"])
-    a = jnp.concatenate([tail, az[..., :di]], axis=1)
-    conv = sum(a[:, i:i + s].astype(f32) * p["conv/w"][i]
-               for i in range(dims.d_conv))
-    c = jax.nn.silu(conv + p["conv/b"])
+    c, tail = conv_tail.conv_silu(az[..., :di], tail, p["conv/w"],
+                                  p["conv/b"])
     low = jnp.matmul(c.astype(x.dtype), p["x/w"], preferred_element_type=f32)
     delta = jax.nn.softplus(jnp.matmul(
         low[..., :r].astype(x.dtype), p["dt/w"], preferred_element_type=f32)
         + p["dt/b"])
-    return (c, delta, low[..., r:r + n], low[..., r + n:], az[..., di:],
-            a[:, s:])
+    return c, delta, low[..., r:r + n], low[..., r + n:], az[..., di:], tail
 
 
 def _mamba_out(x, p, y, c, z):

@@ -11,9 +11,12 @@ A channel ``c`` of ``d_inner`` carries ``d_state`` numbers. With the step
     y_t[c]    = sum_n S_t[n, c] C_t[n]
 
 (the skip ``D x_t`` and the gate are the caller's, ``layers/sambay.py``).
-Unlike ``lightning_fwd`` and ``retention_fwd`` this has no matrix-product
-form: the decay is a channel's and a state's own, so a chunk cannot be
-written as a masked quadratic form times a value. The work is on the vector
+Unlike ``lightning_fwd`` (``ops/lightning_attention.py``: one fixed decay a
+head), ``retention_fwd`` (``ops/power_retention.py``: a gate a token and key
+head) and ``ssd_fwd`` (``ops/ssd.py``: Mamba-2, one scalar a head and token),
+whose chunks are products on the MXU, this has no matrix-product form: the
+decay is a channel's and a state's own, so a chunk cannot be written as a
+masked quadratic form times a value. The work is on the vector
 units, one ``exp`` and five multiply-adds a state element and token, and is
 bound by them and by the bytes of ``Delta``, ``u`` and ``y``.
 

@@ -282,7 +282,24 @@ def sigmoid_topk_route(h, router_w, select_bias, top_k: int,
     return experts.astype(jnp.int32), weights
 
 
-def _record_held_plan(tokens, total, held, first, top_k, banks):
+def softmax_topk_route(h, router_w, top_k: int):
+    """GraniteMoe's routing: the ``top_k`` largest of the raw logits ``h
+    W_r`` (float32 at full precision, as :func:`sigmoid_topk_route` takes its
+    scores and for its reason), weighted by the softmax over those ``top_k``
+    logits alone: the weights sum to 1 and the unselected logits move
+    nothing. ``h [t, d]`` -> ``(experts [t, top_k] int32, weights [t, top_k]
+    f32)``."""
+    with jax.named_scope("router"):
+        logits = jnp.matmul(
+            h.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        picked, experts = jax.lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(picked, axis=-1)
+    return experts.astype(jnp.int32), weights
+
+
+def _record_held_plan(tokens, total, held, first, top_k, banks, block, back,
+                      routing):
     """One zero-length span for each expert layer traced; ``banks`` may
     hold more groups than the ``held`` this layer reads."""
     from ..core import profiler
@@ -290,13 +307,50 @@ def _record_held_plan(tokens, total, held, first, top_k, banks):
     profiler.record_span(
         "moe.plan", time.time_ns(), 0, experts_total=total, experts_held=held,
         first_expert=first, top_k=top_k, tokens=tokens, form="ragged_dot",
-        pair_block=min(PAIR_BLOCK, tokens * top_k),
+        pair_block=min(block, tokens * top_k), back=back, routing=routing,
         expert_bytes_held=sum(
             held * math.prod(w.shape[1:]) * w.dtype.itemsize for w in banks))
 
 
+def _sorted_pairs(experts, first_expert: int, experts_held: int):
+    """The ``[t * top_k]`` (token, expert) pairs by held expert: ``(order,
+    sizes, ends)``, the pairs' indices sorted by expert with those held
+    elsewhere last, each held expert's count and where its run ends."""
+    local = experts.reshape(-1) - first_expert          # [t * top_k]
+    here = (local >= 0) & (local < experts_held)
+    key = jnp.where(here, local, experts_held)          # absent ones last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=experts_held + 1)[:experts_held]
+    return order, sizes, jnp.cumsum(sizes)
+
+
+def _block_rows(h, banks, flat_w, order, sizes, ends, n_pairs, lo, block: int,
+                top_k: int, bank_offset):
+    """Rows ``lo .. lo + block`` of the sorted pairs through the three
+    grouped products: ``(token [block], live [block], y [block, d]`` float32,
+    weighted, zero where no pair stands)``."""
+    w_gate, w_up, w_down = banks
+    pair = jax.lax.dynamic_slice(order, (lo,), (block,))
+    live = lo + jnp.arange(block) < n_pairs
+    token = jnp.where(live, pair // top_k, 0)
+    # this block's rows of each group: the group's span, clipped
+    in_block = (jnp.clip(ends, lo, lo + block)
+                - jnp.clip(ends - sizes, lo, lo + block))
+    gs = jax.lax.dynamic_update_slice(
+        jnp.zeros((w_gate.shape[0],), jnp.int32), in_block.astype(jnp.int32),
+        (bank_offset,))
+    x = h[token]
+    gate = jax.lax.ragged_dot(x, w_gate, gs, preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(x, w_up, gs, preferred_element_type=jnp.float32)
+    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(h.dtype), w_down, gs,
+                           preferred_element_type=jnp.float32)
+    return token, live, jnp.where(live[:, None], y * flat_w[pair][:, None], 0.0)
+
+
 def moe_held(h, experts, weights, w_gate, w_up, w_down, *, first_expert: int,
-             experts_held: int, experts_total: int, bank_offset=0):
+             experts_held: int, experts_total: int, bank_offset=0,
+             pair_block: int = PAIR_BLOCK, back: str = "scatter",
+             routing: str = "sigmoid_topk"):
     """The part of a routed layer's result that the experts held here
     give: ``sum over e in a token's selection, first_expert <= e <
     first_expert + experts_held, of weights_e * E_e(h)``, each ``E_e`` a
@@ -313,43 +367,39 @@ def moe_held(h, experts, weights, w_gate, w_up, w_down, *, first_expert: int,
     layer's (a stack of layers, flattened):
     ``bank_offset`` (traced or static) says where this layer's
     ``experts_held`` start, and every other group gets size 0, so a layer
-    of a scanned stack reads its experts in place, with no slice taken."""
+    of a scanned stack reads its experts in place, with no slice taken.
+
+    ``back="gather"`` is the form for a layer of many narrow experts
+    (Granite: ten pairs a token, experts 768 wide, 160 blocks of 512 a piece
+    where Trinity has 16): blocks of ``pair_block`` pairs, their rows
+    returned to the tokens by gathers (:func:`_held_by_gathers`), and pairs
+    that fit one block (a step's rows) through every held expert with no
+    pair sorted (:func:`_held_densely`); the defaults are the walk above,
+    unchanged. ``routing`` names the routing function in the ``moe.plan``
+    span."""
     t, top_k = experts.shape
-    groups = w_gate.shape[0]
+    banks = (w_gate, w_up, w_down)
+    if back == "gather" and t * top_k <= pair_block:
+        back = "dense"
     _record_held_plan(t, experts_total, experts_held, first_expert, top_k,
-                      (w_gate, w_up, w_down))
+                      banks, pair_block, back, routing)
+    if back == "dense":
+        return _held_densely(h, experts, weights, banks, first_expert,
+                             experts_held)
+    if back == "gather":
+        return _held_by_gathers(h, experts, weights, banks, first_expert,
+                                experts_held, bank_offset, pair_block)
     with jax.named_scope("moe"):
-        local = experts.reshape(-1) - first_expert          # [t * top_k]
-        here = (local >= 0) & (local < experts_held)
-        key = jnp.where(here, local, experts_held)          # absent ones last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        sizes = jnp.bincount(key, length=experts_held + 1)[:experts_held]
-        ends = jnp.cumsum(sizes)
+        order, sizes, ends = _sorted_pairs(experts, first_expert, experts_held)
         n_pairs = ends[-1]
-        block = min(PAIR_BLOCK, t * top_k)
+        block = min(pair_block, t * top_k)
         order = jnp.pad(order, (0, block))
         flat_w = weights.reshape(-1)
 
         def one_block(i, out):
-            lo = i * block
-            pair = jax.lax.dynamic_slice(order, (lo,), (block,))
-            live = lo + jnp.arange(block) < n_pairs
-            token = jnp.where(live, pair // top_k, 0)
-            # this block's rows of each group: the group's span, clipped
-            in_block = (jnp.clip(ends, lo, lo + block)
-                        - jnp.clip(ends - sizes, lo, lo + block))
-            gs = jax.lax.dynamic_update_slice(
-                jnp.zeros((groups,), jnp.int32), in_block.astype(jnp.int32),
-                (bank_offset,))
-            x = h[token]
-            gate = jax.lax.ragged_dot(x, w_gate, gs,
-                                      preferred_element_type=jnp.float32)
-            up = jax.lax.ragged_dot(x, w_up, gs,
-                                    preferred_element_type=jnp.float32)
-            y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(h.dtype),
-                                   w_down, gs,
-                                   preferred_element_type=jnp.float32)
-            y = jnp.where(live[:, None], y * flat_w[pair][:, None], 0.0)
+            token, live, y = _block_rows(h, banks, flat_w, order, sizes, ends,
+                                         n_pairs, i * block, block, top_k,
+                                         bank_offset)
             if t > block:
                 return out.at[token].add(y)     # a dead row adds 0 to token 0
             # a step's few tokens: a 0/1 [t, block] operand times the block,
@@ -363,6 +413,92 @@ def moe_held(h, experts, weights, w_gate, w_up, w_down, *, first_expert: int,
             return one_block(0, out)
         return jax.lax.fori_loop(0, (n_pairs + block - 1) // block,
                                  one_block, out)
+
+
+def _held_densely(h, experts, weights, banks, first_expert, experts_held):
+    """:func:`moe_held`'s result for a step's few tokens where the experts
+    are many and small: every held expert applied to every token, a token's
+    weight zero for an expert it did not select. 32 rows of ten pairs touch
+    35.7 of 36 held experts, so the grouped products read every bank anyway,
+    a group of four or five rows at a time, at 57% of the HBM's rate (0.49 ms
+    a product of 226 MB in ``granite-serve-agent``'s step; PERF.md section 6,
+    PR 49); here the gate and up banks are one batched product each and the
+    down bank, weighted activations against ``[held * f, d]``, one plain one.
+    Seven times the multiply-adds, which a step has to spare. The banks hold
+    this layer's experts and no others."""
+    w_gate, w_up, w_down = banks
+    held, f, d = w_down.shape
+    assert w_gate.shape[0] == experts_held == held, (w_gate.shape, experts_held)
+    with jax.named_scope("moe"):
+        # [t, held]: what a token gives each held expert
+        mine = (experts - first_expert)[..., None] == jnp.arange(held)
+        share = jnp.sum(jnp.where(mine, weights[..., None], 0.0), axis=1)
+        gate = jnp.einsum("td,edf->tef", h, w_gate,
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("td,edf->tef", h, w_up,
+                        preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up * share[..., None]).astype(h.dtype)
+        return jnp.matmul(act.reshape(-1, held * f), w_down.reshape(held * f, d),
+                          preferred_element_type=jnp.float32)
+
+
+# Tokens a walk of :func:`_held_by_gathers` takes at once: the rows its
+# blocks leave wait in one buffer of ``tokens * top_k`` rows (every pair
+# could be held here), 335 MB at 4,096 tokens of ten pairs 4,096 wide (a
+# walk of 2,048 gives a group of Granite's pairs 284 rows and the grouped
+# products a fifth more time: 25.0 ms a piece of 8,192 tokens against 21.2;
+# my chip run, PR 49, call 207).
+GATHER_TOKENS = 4096
+
+
+def _held_by_gathers(h, experts, weights, banks, first_expert, experts_held,
+                     bank_offset, block):
+    """:func:`moe_held`'s result with the rows returned by gathers, for
+    ``t * top_k`` pairs that are many blocks: the pairs sorted by expert go
+    through the grouped products in blocks of ``block`` and each block's
+    rows, weighted, are written where they stand in the sorted order (one
+    contiguous update a block); then a token's ``top_k`` rows are read back
+    from where its pairs stood (the inverse of the sort) and summed in
+    float32. A pair held elsewhere is sorted behind every pair held here,
+    where no block writes: it reads zeros. XLA's scatter-add returns a row a
+    microsecond (``PAIR_BLOCK``'s comment), a gather of as many rows takes a
+    fifth of that and the update nothing (PERF.md section 6, PR 31 and PR
+    49). The tokens are walked ``GATHER_TOKENS`` at a time so that the
+    buffer stays that size."""
+    t, top_k = experts.shape
+    d = h.shape[-1]
+    pieces = next(n for n in range(-(-t // GATHER_TOKENS), t + 1) if t % n == 0)
+    size = t // pieces
+    rows = -(-size * top_k // block) * block
+
+    def walk(args):
+        h, experts, weights = args
+        order, sizes, ends = _sorted_pairs(experts, first_expert, experts_held)
+        place = jnp.argsort(order).astype(jnp.int32)        # the sort's inverse
+        n_pairs = ends[-1]
+        order = jnp.pad(order, (0, rows - size * top_k))
+        flat_w = weights.reshape(-1)
+
+        def one_block(i, stood):
+            _, _, y = _block_rows(h, banks, flat_w, order, sizes, ends, n_pairs,
+                                  i * block, block, top_k, bank_offset)
+            return jax.lax.dynamic_update_slice(stood, y.astype(h.dtype),
+                                                (i * block, 0))
+
+        stood = jax.lax.fori_loop(0, (n_pairs + block - 1) // block, one_block,
+                                  jnp.zeros((rows, d), h.dtype))
+        place = place.reshape(size, top_k)
+        out = jnp.zeros((size, d), jnp.float32)
+        for k in range(top_k):
+            out = out + stood[place[:, k]].astype(jnp.float32)
+        return out
+
+    with jax.named_scope("moe"):
+        if pieces == 1:
+            return walk((h, experts, weights))
+        cut = lambda a: a.reshape((pieces, size) + a.shape[1:])
+        return jax.lax.map(walk, (cut(h), cut(experts), cut(weights))
+                           ).reshape(t, d)
 
 
 def moe_ep_rules():
